@@ -225,11 +225,9 @@ _SYSOBS_WATERMARKS = ("peak_queued", "peak_slots_active",
                       "peak_pool_retained_pages", "peak_pool_pages_in_use",
                       "peak_host_offloaded_pages", "peak_host_bytes",
                       "peak_device_bytes_in_use")
-# device allocator stats (ISSUE 12 satellite): engine sysobs.device_mem
-# key -> localai_mem_device_<metric>; absent on CPU backends
-_DEVICE_MEM_GAUGES = (("bytes_in_use", "bytes_in_use"),
-                      ("peak_bytes_in_use", "peak_bytes_in_use"),
-                      ("bytes_limit", "bytes_limit"))
+# device allocator stats: engine sysobs.device_mem, one entry per local
+# device -> localai_mem_device_<key>{device=}; no counters on CPU
+_DEVICE_MEM_GAUGES = ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
 # per-class SLO engine (ISSUE 12): burn-rate gauges per
 # (model, priority, metric, window) + violation totals, from engine
 # metrics()["slo"]; flight-recorder dump counters ride along
@@ -281,7 +279,7 @@ def _refresh_engine_metrics(state):
               "queue_depth_class", "resume_queue_depth",
               *_SYSOBS_COUNTERS, *_SYSOBS_GAUGES,
               *(f"mem_{k}" for k in _SYSOBS_WATERMARKS),
-              *(f"mem_device_{m}" for _k, m in _DEVICE_MEM_GAUGES),
+              *(f"mem_device_{k}" for k in _DEVICE_MEM_GAUGES),
               "slo_burn_rate", "slo_objective_ms", "slo_violations_total",
               "slo_error_budget", "flight_dumps_total",
               "flight_dumps_suppressed_total",
@@ -483,12 +481,12 @@ def _refresh_engine_metrics(state):
                                   label_str(model=name))
             # device allocator stats (ISSUE 12 satellite): real HBM
             # numbers when the backend platform exposes memory_stats()
-            dm = so.get("device_mem")
-            if dm:
-                for skey, mkey in _DEVICE_MEM_GAUGES:
-                    if skey in dm:
-                        METRICS.set_gauge(f"mem_device_{mkey}", dm[skey],
-                                          label_str(model=name))
+            for dm in so.get("device_mem") or []:
+                for key in _DEVICE_MEM_GAUGES:
+                    if key in dm:
+                        METRICS.set_gauge(
+                            f"mem_device_{key}", dm[key],
+                            label_str(model=name, device=str(dm["id"])))
         # per-class SLO engine (ISSUE 12): burn-rate gauges + violation
         # counters per (priority class, metric); the flight recorder's
         # dump/suppression totals ride the same pull
@@ -1007,16 +1005,26 @@ async def backend_shutdown(request):
     return web.json_response({})
 
 
+def _backend_devices(state) -> list:
+    """Devices as the loaded backends report them (GetState -> engine
+    state_snapshot). The HTTP process never asks jax itself: touching a
+    backend here would take the chip from the runner that needs it —
+    one process owns a chip. Nothing loaded -> nothing known -> []."""
+    devices = {}
+    for model, payload in _backend_state_payloads(state).items():
+        st = payload.get("state") or {}
+        for d in st.get("device_mem") or []:
+            devices.setdefault(d["id"], {
+                "id": d["id"], "platform": st.get("platform", ""),
+                "kind": d.get("device_kind", ""), "models": []})
+            devices[d["id"]]["models"].append(model)
+    return [devices[i] for i in sorted(devices)]
+
+
 async def system_info(request):
     """(reference: routes/localai.go:60-66 /system)"""
-    import jax
-
     state = get_state(request)
-    try:
-        devices = [{"id": d.id, "platform": d.platform,
-                    "kind": getattr(d, "device_kind", "")} for d in jax.devices()]
-    except Exception:
-        devices = []
+    devices = await state.run_blocking(_backend_devices, state)
     return web.json_response({
         "backends": sorted(state.caps.loader.list_loaded()),
         "devices": devices,
@@ -1133,17 +1141,12 @@ async def remove_gallery(request):
 async def p2p_nodes(request):
     """On TPU the 'swarm' is the static device mesh — report it in the
     same shape the reference reports federated nodes (reference:
-    core/http/endpoints/localai/p2p.go)."""
-    import jax
-
-    try:
-        nodes = [
-            {"name": f"device-{d.id}", "id": str(d.id), "online": True,
-             "platform": d.platform}
-            for d in jax.devices()
-        ]
-    except Exception:
-        nodes = []
+    core/http/endpoints/localai/p2p.go), as the loaded backends see it
+    (_backend_devices)."""
+    state = get_state(request)
+    devices = await state.run_blocking(_backend_devices, state)
+    nodes = [{"name": f"device-{d['id']}", "id": str(d["id"]),
+              "online": True, "platform": d["platform"]} for d in devices]
     return web.json_response({"nodes": nodes, "federated_nodes": []})
 
 

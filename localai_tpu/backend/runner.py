@@ -146,6 +146,7 @@ class EngineServicer(BackendServicer):
         from localai_tpu.parallel import mesh as meshlib
         from localai_tpu.parallel import sharding as shardlib
 
+        require_accelerator()
         model_dir = request.model
         if request.model_path and not os.path.isabs(model_dir):
             model_dir = os.path.join(request.model_path, model_dir)
@@ -424,11 +425,9 @@ class EngineServicer(BackendServicer):
             **({"stall_dump_dir": sdd} if (sdd := str(
                 extra.get("stall_dump_dir", "") or "")) else {}),
             # system observability (ISSUE 8): structured event-log sink
-            # (path|stderr|off) + peak device TFLOP/s for MFU accounting
+            # (path|stderr|off)
             **({"event_log": evl} if (evl := str(
                 extra.get("event_log", "") or "")) else {}),
-            **({"peak_tflops": ptf} if (ptf := float(
-                extra.get("peak_tflops", 0) or 0)) > 0 else {}),
             # event-driven hot path (ISSUE 9): emitter=0 restores in-loop
             # emission; event_log_max_mb bounds the file sink (0 disables
             # rotation, so isdigit passes the explicit 0 through)
@@ -939,37 +938,26 @@ class EngineServicer(BackendServicer):
             context.abort(grpc.StatusCode.FAILED_PRECONDITION, "no model loaded")
 
 
-def _apply_platform_env():
-    """Honor LOCALAI_JAX_PLATFORM / LOCALAI_JAX_CPU_DEVICES before any jax use.
+def require_accelerator() -> str:
+    """The platform this backend will serve from — "tpu", or "cpu" only
+    where the environment asks for it by name (JAX_PLATFORMS=cpu: the
+    test suite, CPU rehearsals). Anything else is refused: with
+    JAX_PLATFORMS unset jax falls back to the CPU with only a warning
+    when it cannot open the chip (another process holds it, or there is
+    none), and a model sized for HBM then "serves" from host memory."""
+    import jax
 
-    The TPU plugin ignores the JAX_PLATFORMS env var, so spawned backends
-    (e.g. hermetic tests forcing a CPU mesh) need an explicit config hook.
-    """
-    plat = os.environ.get("LOCALAI_JAX_PLATFORM")
-    ndev = os.environ.get("LOCALAI_JAX_CPU_DEVICES")
-    if plat or ndev:
-        if ndev and not ndev.isdigit():
-            raise SystemExit(
-                f"LOCALAI_JAX_CPU_DEVICES must be an integer, got {ndev!r}")
-        if ndev:
-            # pre-jax_num_cpu_devices releases read the count from
-            # XLA_FLAGS at backend init — set it before jax imports
-            import re
-
-            os.environ["XLA_FLAGS"] = (re.sub(
-                r"--xla_force_host_platform_device_count=\d+", "",
-                os.environ.get("XLA_FLAGS", ""))
-                + f" --xla_force_host_platform_device_count={ndev}").strip()
-
-        import jax
-
-        if plat:
-            jax.config.update("jax_platforms", plat)
-        if ndev:
-            try:
-                jax.config.update("jax_num_cpu_devices", int(ndev))
-            except AttributeError:
-                pass  # covered by XLA_FLAGS above
+    platform = jax.default_backend()
+    # jax's default backend is the FIRST platform the variable names
+    # ("tpu,cpu", as TPU hosts set it, asks for the TPU)
+    asked = os.environ.get("JAX_PLATFORMS", "").lower().split(",")[0]
+    if platform != "tpu" and platform != asked:
+        raise RuntimeError(
+            f"no TPU: jax initialised the {platform!r} backend "
+            f"(devices {jax.devices()}). Is another process holding the "
+            "chip? One process owns a chip at a time. To run on the CPU "
+            "on purpose, set JAX_PLATFORMS=cpu.")
+    return platform
 
 
 def main(argv=None):
@@ -978,7 +966,6 @@ def main(argv=None):
     parser.add_argument("--log-level", default="info")
     args = parser.parse_args(argv)
     logging.basicConfig(level=args.log_level.upper())
-    _apply_platform_env()
     from localai_tpu.utils.jaxtools import enable_compilation_cache
 
     enable_compilation_cache()

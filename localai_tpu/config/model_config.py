@@ -154,9 +154,7 @@ class ModelConfig:
     # slow_request_ms=N (log a span decomposition when TTFT or e2e
     # exceeds N ms; 0 = off), or the system-observability knobs (ISSUE 8)
     # event_log=path|stderr|off (structured JSON-lines event sink for the
-    # backend process; the ring at /debug/events works regardless) and
-    # peak_tflops=N (override the device peak used for MFU — needed on
-    # CPU/unknown device kinds where the built-in table reports 0), or
+    # backend process; the ring at /debug/events works regardless), or
     # the per-class SLO objectives (ISSUE 12) slo_ttft_ms= / slo_itl_ms=
     # / slo_queue_wait_ms= with value "500" (all classes), "250:1000:5000"
     # (high:normal:low) or "high=250:low=5000" (named subset) and
@@ -399,14 +397,6 @@ class ModelConfig:
                     if not h or not p.isdigit():
                         problems.append(
                             f"kv_serve must be 0|1|host:port, got {v!r}")
-            elif k == "peak_tflops":
-                try:
-                    if float(v) < 0:
-                        problems.append(
-                            f"peak_tflops must be >= 0, got {v!r}")
-                except ValueError:
-                    problems.append(
-                        f"peak_tflops must be a number, got {v!r}")
             elif k in ("slo_ttft_ms", "slo_itl_ms", "slo_queue_wait_ms"):
                 # per-class SLO objectives (ISSUE 12): same fail-at-scan
                 # contract as priority_weights — the parser IS the

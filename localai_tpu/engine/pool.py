@@ -941,14 +941,18 @@ class EnginePool:
         return out
 
     def state_snapshot(self) -> dict:
+        replicas = [e.state_snapshot() for e in self._engines]
         out = {
+            # replicas share one process, so one set of devices
+            **{k: replicas[0][k] for k in ("platform", "device_kind",
+                                           "device_count", "device_mem")},
             "engine_replicas": len(self._engines),
             "pool": {
                 "replicas_alive": sum(1 for d in self._dead if not d),
                 "affinity_hits": self.affinity_hits,
                 "migrations": dict(self._migrations),
             },
-            "replicas": [e.state_snapshot() for e in self._engines],
+            "replicas": replicas,
         }
         # target-vs-actual + last decision for /debug/state and /readyz
         # (ISSUE 19) — present whenever pooled so operators see the loop

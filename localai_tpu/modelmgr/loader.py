@@ -326,7 +326,8 @@ class ModelLoader:
             n_respawns = self.respawns[lm.model_id]
         log.warning(
             "backend for model %s died unexpectedly (exit %s); "
-            "respawning with backoff", lm.model_id, rc)
+            "respawning with backoff. Its last stderr:\n%s",
+            lm.model_id, rc, lm.process.stderr_tail())
         EVENTS.emit("respawn", model=lm.model_id, exit_code=rc,
                     respawns=n_respawns)
         base = self.respawn_backoff_base_s
@@ -398,12 +399,21 @@ class ModelLoader:
             t_recv = time.time()
             if not res.success:
                 raise RuntimeError(f"LoadModel failed: {res.message}")
-        except Exception:
+        except Exception as e:
             client.close()
             if server is not None:
                 server.stop(grace=0)
-            if process is not None:
-                process.stop()
+            if process is None:
+                raise
+            died = not process.alive()
+            process.stop()
+            if died:
+                # the child is gone (the chip was taken, a kernel failed
+                # to compile, an import broke): its output is logged at
+                # DEBUG, so the error itself must say why
+                raise RuntimeError(
+                    f"{e} (exit {process.proc.returncode}); the backend's "
+                    f"last stderr:\n{process.stderr_tail()}") from e
             raise
         lm = LoadedModel(model_id, backend_name, client, process, server)
         lm.clock = _parse_handshake(res.message, t_send, t_recv)

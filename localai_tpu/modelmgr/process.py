@@ -7,6 +7,7 @@ core logs, SIGTERM cleanup), re-based on subprocess + threads.
 
 from __future__ import annotations
 
+import collections
 import logging
 import os
 import shlex
@@ -19,6 +20,12 @@ import time
 from typing import Optional
 
 log = logging.getLogger("localai_tpu.modelmgr.process")
+
+# the directory that holds the localai_tpu package: put on every spawned
+# backend's PYTHONPATH so `python -m localai_tpu.backend.*` imports
+# whatever directory the server was started from
+PACKAGE_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
 def free_port() -> int:
@@ -43,11 +50,25 @@ class BackendProcess:
         # race ("address already in use", raised by make_server)
         self.started = threading.Event()
         self.bind_failed = threading.Event()
+        # the child's last stderr lines: its output is logged at DEBUG,
+        # so when it dies this is what the error must carry
+        self._stderr_tail: collections.deque = collections.deque(maxlen=30)
+
+    def stderr_tail(self) -> str:
+        """The last lines the backend wrote to stderr (drains the tail
+        readers first when the process is already dead)."""
+        if not self.alive():
+            for t in self._tail_threads:
+                t.join(timeout=1.0)
+        return "\n".join(self._stderr_tail)
 
     def start(self):
         env = dict(os.environ)
         if self._env:
             env.update(self._env)
+        env["PYTHONPATH"] = os.pathsep.join(
+            [PACKAGE_ROOT] + [p for p in env.get("PYTHONPATH", "").split(
+                os.pathsep) if p and p != PACKAGE_ROOT])
         log.info("starting backend %s: %s (addr %s)", self.name,
                  shlex.join(self.command), self.addr)
         self.proc = subprocess.Popen(
@@ -57,13 +78,13 @@ class BackendProcess:
             env=env,
             start_new_session=True,  # own process group for clean kill
         )
-        for stream, level in ((self.proc.stdout, logging.DEBUG),
-                              (self.proc.stderr, logging.DEBUG)):
-            t = threading.Thread(target=self._tail, args=(stream, level), daemon=True)
+        for stream in (self.proc.stdout, self.proc.stderr):
+            t = threading.Thread(target=self._tail, args=(stream,), daemon=True)
             t.start()
             self._tail_threads.append(t)
 
-    def _tail(self, stream, level):
+    def _tail(self, stream):
+        keep = stream is self.proc.stderr
         try:
             for line in iter(stream.readline, b""):
                 text = line.decode(errors="replace").rstrip()
@@ -71,7 +92,9 @@ class BackendProcess:
                     self.started.set()
                 elif "address already in use" in text.lower():
                     self.bind_failed.set()
-                log.log(level, "[%s] %s", self.name, text)
+                if keep:
+                    self._stderr_tail.append(text)
+                log.debug("[%s] %s", self.name, text)
         except ValueError:
             pass  # stream closed
 

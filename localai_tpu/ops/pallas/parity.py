@@ -1,0 +1,219 @@
+"""On-device parity of every Pallas kernel against its jnp reference.
+
+    python -m localai_tpu.ops.pallas.parity
+
+runs each kernel of this package COMPILED, on whatever device jax finds
+(chip_smoke.py runs it on the chip before it boots the server; the
+process exits and releases the chip), at Llama-3.1-8B head shapes: 8 KV
+heads x 4 query groups of 128, page 64. Interpret-mode tests prove the
+kernels' arithmetic; tests/test_tpu_compile.py proves they build for the
+chip; only this proves the built kernel computes the right thing there —
+the in-kernel strided head slices and the scalar-prefetched page table
+are where a layout surprise would show.
+
+Slot lengths are mixed on purpose: an empty slot, one row, a slot ending
+exactly on a page boundary, one just past it, a full slot (_lengths).
+Inputs are seeded and bf16-exact, so the kernel (bf16 in, f32 inside)
+and the reference (the same values in f32, matmuls at highest precision)
+see identical numbers, and what remains is the kernel's own error plus
+one bf16 rounding of the output.
+
+Prints one JSON line: the device and the largest error per kernel (see
+TOLERANCE for the measure). Exits 1 if any exceeds it.
+"""
+
+from __future__ import annotations
+
+import json
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from localai_tpu.ops import kvcache
+from localai_tpu.ops.attention import decode_attention_append
+from localai_tpu.ops.pallas.decode_attention import (
+    decode_attention_append_pallas)
+from localai_tpu.ops.pallas.paged_attention import (
+    paged_decode_attention_append, paged_decode_attention_append_quant)
+from localai_tpu.ops.pallas.ragged_prefill import (
+    ragged_kernel_plan, ragged_prefill_attention_pallas)
+from localai_tpu.ops.ragged_prefill import ragged_prefill_attention
+
+# The error of a kernel is max |out - ref| / (1 + |ref|): absolute for
+# small outputs, relative for large ones (an empty slot's output is the
+# new value row itself, up to ~4 in magnitude). Two things spend it. The
+# kernel rounds its f32 result to bf16 once: up to 2^-9 ~ 0.002 of the
+# value, and all there is in interpret mode. On the chip the MXU's
+# default precision also rounds the f32 operands of both matmuls (scaled
+# q . k, then probs . v) to bf16 — the same precision the jnp serving
+# path runs at — which measured 0.003-0.0075 on a v5e (PERF.md, PR 21).
+# The tolerance leaves those a factor of 2.5; a layout bug (wrong head,
+# wrong page) is an error of order 1.
+TOLERANCE = 2e-2
+
+KV, G, HD, PAGE = 8, 4, 128, 64     # Llama-3.1-8B heads, engine page size
+S, MP = 8, 8                        # slots, pages per slot (context 512)
+
+
+def _lengths(page: int):
+    return jnp.asarray((0, 1, page, page + 1, 3 * page, 3 * page + 8,
+                        MP * page - 1, page // 2 + 5), jnp.int32)
+
+
+def _bf16_exact(rng, shape):
+    x = rng.standard_normal(shape, dtype=np.float32)
+    return np.asarray(jnp.asarray(x, jnp.bfloat16).astype(jnp.float32))
+
+
+def _paged_kv(rng, dtype, heads, hd, page):
+    """One layer's paged K and V caches over ONE shuffled page table (a
+    kernel that ignored the table would read the wrong rows). Returns
+    ((k, v) in ``dtype``, (k, v) as the reference reads them: float32
+    twins, or the same int8 + scales, which it folds itself)."""
+    n_pages = S * MP
+    ptab = jnp.asarray(rng.permutation(n_pages).astype(np.int32)
+                       .reshape(S, MP))
+
+    def layer():
+        rows = jnp.asarray(_bf16_exact(rng, (n_pages, page, heads, hd)))
+        if dtype == jnp.int8:
+            q, s = kvcache.quantize(rows)
+            lc = {"pages": q, "scales": s, "ptab": ptab}
+            return lc, lc
+        return ({"pages": rows.astype(dtype), "ptab": ptab},
+                {"pages": rows, "ptab": ptab})
+
+    (k, k_ref), (v, v_ref) = layer(), layer()
+    return (k, v), (k_ref, v_ref)
+
+
+def _max_err(out, ref, keep=None):
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    err = np.abs(out - ref) / (1.0 + np.abs(ref))
+    if keep is not None:
+        err = err[keep]
+    assert np.isfinite(err).all(), "non-finite kernel output"
+    return float(err.max())
+
+
+def check_paged_decode(quant: bool, interpret: bool = False,
+                       heads=(KV, G, HD), page: int = PAGE) -> float:
+    kv, g, hd = heads
+    rng = np.random.default_rng(1 + quant)
+    (lc, lv), (lc32, lv32) = _paged_kv(
+        rng, jnp.int8 if quant else jnp.bfloat16, kv, hd, page)
+    q = _bf16_exact(rng, (S, kv * g, hd))
+    nk, nv = _bf16_exact(rng, (S, kv, hd)), _bf16_exact(rng, (S, kv, hd))
+    lengths = _lengths(page)
+    bf = lambda a: jnp.asarray(a, jnp.bfloat16)   # noqa: E731
+    if quant:
+        out = paged_decode_attention_append_quant(
+            bf(q), bf(nk), bf(nv), lc["pages"], lc["scales"], lv["pages"],
+            lv["scales"], lc["ptab"], lengths, q_per_kv=g,
+            interpret=interpret)
+    else:
+        out = paged_decode_attention_append(
+            bf(q), bf(nk), bf(nv), lc["pages"], lv["pages"], lc["ptab"],
+            lengths, q_per_kv=g, interpret=interpret)
+    with jax.default_matmul_precision("highest"):
+        ref = decode_attention_append(
+            jnp.asarray(q), jnp.asarray(nk), jnp.asarray(nv),
+            kvcache.gather_all_rows(lc32), kvcache.gather_all_rows(lv32),
+            lengths, g)
+    return _max_err(out, ref)
+
+
+def check_contiguous_decode(interpret: bool = False,
+                            heads=(KV, G, HD), page: int = PAGE) -> float:
+    kv, g, hd = heads
+    rng = np.random.default_rng(3)
+    C = MP * page
+    ck, cv = _bf16_exact(rng, (S, C, kv, hd)), _bf16_exact(rng, (S, C, kv, hd))
+    q = _bf16_exact(rng, (S, kv * g, hd))
+    nk, nv = _bf16_exact(rng, (S, kv, hd)), _bf16_exact(rng, (S, kv, hd))
+    lengths = _lengths(page)
+    bf = lambda a: jnp.asarray(a, jnp.bfloat16)   # noqa: E731
+    out = decode_attention_append_pallas(
+        bf(q), bf(nk), bf(nv), bf(ck), bf(cv), lengths, q_per_kv=g,
+        interpret=interpret)
+    with jax.default_matmul_precision("highest"):
+        ref = decode_attention_append(
+            jnp.asarray(q), jnp.asarray(nk), jnp.asarray(nv),
+            jnp.asarray(ck), jnp.asarray(cv), lengths, g)
+    return _max_err(out, ref)
+
+
+def check_ragged_prefill(N: int, interpret: bool = False,
+                         heads=(KV, G, HD), page: int = PAGE) -> float:
+    """An [N]-token pack of five segments: a fresh prompt, a
+    continuation from mid-page, one from exactly a page boundary, a
+    one-token tail, and the rest of the pack continuing a long prefix —
+    then pad segments (length 0) up to S."""
+    kv, g, hd = heads
+    rng = np.random.default_rng(4)
+    (lc, lv), (lc32, lv32) = _paged_kv(rng, jnp.bfloat16, kv, hd, page)
+    a = N // 8
+    assert a > 5, "pack too small for the segment plan"
+    lens = [3 * a, a + 5, a - 5, 1]
+    lens.append(N - sum(lens) - 7)             # 7 trailing pad tokens
+    starts = [0, page + 9, 2 * page, page + 13, 5 * page + 3]
+    assert all(st < MP * page for st in starts), "prefixes exceed the context"
+    slots = [2, 0, 5, 7, 3]
+    B = S
+    seg_slots = np.full((B,), S, np.int32)     # pad segments: sentinel slot
+    seg_start = np.zeros((B,), np.int32)
+    seg_off = np.zeros((B,), np.int32)
+    seg_len = np.zeros((B,), np.int32)
+    seg_of = np.full((N,), B, np.int32)        # pad tokens: sentinel segment
+    off = 0
+    for b, (ln, st, sl) in enumerate(zip(lens, starts, slots)):
+        seg_slots[b], seg_start[b], seg_off[b], seg_len[b] = sl, st, off, ln
+        seg_of[off:off + ln] = b
+        off += ln
+    q = _bf16_exact(rng, (N, kv * g, hd))
+    k, v = _bf16_exact(rng, (N, kv, hd)), _bf16_exact(rng, (N, kv, hd))
+    qb, pkb = ragged_kernel_plan(N, kv, g, hd, page_size=page, itemsize=2)
+    bf = lambda a: jnp.asarray(a, jnp.bfloat16)   # noqa: E731
+    out = ragged_prefill_attention_pallas(
+        bf(q), bf(k), bf(v), lc["pages"], lv["pages"], lc["ptab"],
+        jnp.asarray(seg_slots), jnp.asarray(seg_start), jnp.asarray(seg_off),
+        jnp.asarray(seg_len), q_per_kv=g, pkb=pkb, qb=qb,
+        interpret=interpret)
+    with jax.default_matmul_precision("highest"):
+        ref = ragged_prefill_attention(
+            jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+            jnp.asarray(seg_of), jnp.asarray(seg_slots),
+            jnp.asarray(seg_start), lc32, lv32, g, continued=True)
+    return _max_err(out, ref, keep=seg_of < B)    # pad rows are garbage
+
+
+def main(argv=None) -> int:
+    # --interpret: the CPU rehearsal of chip_smoke.py (Pallas interpreter,
+    # one small pack); without it the kernels run compiled, which needs
+    # the chip
+    interpret = "--interpret" in (sys.argv[1:] if argv is None else argv)
+    dev = jax.devices()[0]
+    if not interpret and dev.platform != "tpu":
+        print(f"no TPU: jax found {jax.devices()}", file=sys.stderr)
+        return 2
+    errors = {
+        "paged_decode": check_paged_decode(False, interpret),
+        "paged_decode_int8": check_paged_decode(True, interpret),
+        "decode_append": check_contiguous_decode(interpret),
+        # the pack buckets chip_smoke.py's engine builds
+        **{f"ragged_prefill[{n}]": check_ragged_prefill(n, interpret)
+           for n in ((128,) if interpret else (128, 512, 1024))},
+    }
+    ok = all(e <= TOLERANCE for e in errors.values())
+    print(json.dumps({
+        "ok": ok, "interpret": interpret, "tolerance": TOLERANCE,
+        "max_error": errors,
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())}}))
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -27,13 +27,16 @@ instead of keeping the whole pack's query rows resident:
     runs across the whole (segment, kv-step) sweep without per-segment
     resets; the q-block's output is written once at the final step.
 
-Scratch is therefore INDEPENDENT of the pack size N — the old
-whole-pack layout hit a VMEM wall at ~1k packed tokens for 8B head
-shapes (KV=8, G=4, hd=128) and fell back to the jnp scan exactly where
-packing matters most. ``ragged_kernel_plan`` below is the single
-source of truth for the blocking and for "does this pack stay on the
-kernel path", shared by models/llama.py and the engine's fallback
-counter.
+Scratch is therefore INDEPENDENT of the pack size N. What VMEM does
+bound is the per-block width; ``ragged_kernel_plan`` below counts it
+(padding and double-buffering included) against the same limit the
+kernel hands the compiler, and is the single source of truth for the
+blocking and for "does this pack stay on the kernel path", shared by
+models/llama.py and the engine's fallback counter.
+
+Operands enter the kernel HEAD-MAJOR — q [KV, N*G, hd], pack keys
+[KV, N, hd] — so one head's rows are a dense [rows, hd] tile; the
+wrapper transposes in XLA on the way in and out.
 
 Plain float paged caches only (the int8 paged prefill folds scales
 through the jnp fallback).
@@ -52,49 +55,84 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
-# Per-q-block f32 scratch budget (m + l + acc over QB*G rows). QB tops
-# out at 128, so this never binds for transformer shapes; it guards
-# pathological configs rather than pack length.
-_VMEM_SCRATCH_BUDGET = 8 * 1024 * 1024
+# The kernel's VMEM ceiling: ragged_kernel_plan sizes the blocking
+# against it and pallas_call hands the same number to Mosaic as
+# vmem_limit_bytes, so the plan and the compiler cannot disagree about
+# the budget. 32 MiB is a quarter of a v5e core's 128 MiB VMEM (the
+# compiler's own default scoped limit there is 16 MiB).
+_VMEM_LIMIT = 32 * 1024 * 1024
 
 
-def ragged_kernel_plan(N: int, kv_heads: int, q_per_kv: int,
-                       head_dim: int) -> Optional[Tuple[int, int]]:
-    """Blocking plan ``(qb, pkb)`` for an N-token pack, or None when the
-    pack cannot run on the kernel path.
+def _pad(n: int, m: int) -> int:
+    return -(-n // m) * m
 
-    ``qb`` (query block) and ``pkb`` (pack-key block) are the largest
-    power of two <= 128 dividing N — gcd with 128, so power-of-two pack
-    buckets get full 128-row MXU tiles and any other N still divides
-    cleanly. Scratch is per-q-block (independent of N): the plan only
-    fails for configs whose PER-BLOCK scratch exceeds VMEM, not for
-    long packs — the ~1k-token cliff of the whole-pack layout is gone.
-    """
+
+def _vmem_bytes(qb: int, pkb: int, kv_heads: int, q_per_kv: int,
+                head_dim: int, page_size: int, itemsize: int) -> int:
+    """VMEM the kernel needs at this blocking, counted the way Mosaic
+    lays it out: the last dim pads to 128 lanes, the second-to-last to
+    the dtype's sublane tile (8 rows of 32 bits), every pipelined block
+    is double-buffered, and the body's f32 temporaries (one head's
+    scaled q, one score/prob/mask block, one k and v operand) live
+    beside the scratch, plus 1 MiB for the compiler's own use. Within
+    0.5 MiB of the smallest limit Mosaic accepts at the shapes
+    tests/test_tpu_compile.py compiles."""
+    sub = 32 // itemsize
+    lanes = _pad(head_dim, 128)
+    rows = _pad(qb * q_per_kv, 8)
+    q_blk = kv_heads * _pad(qb * q_per_kv, sub) * lanes * itemsize
+    pack_blk = kv_heads * _pad(pkb, sub) * lanes * itemsize
+    page_blk = page_size * _pad(kv_heads, sub) * lanes * itemsize
+    pipelined = 2 * (2 * q_blk + 2 * pack_blk + 2 * page_blk)
+    scratch = kv_heads * rows * (128 + 128 + lanes) * 4    # m, l, acc
+    blk = _pad(max(page_size, pkb), 128)
+    temps = (rows * (3 * blk + 2 * lanes) + 2 * blk * lanes) * 4
+    return pipelined + scratch + temps + (1 << 20)
+
+
+def ragged_kernel_plan(N: int, kv_heads: int, q_per_kv: int, head_dim: int,
+                       page_size: int = 64, itemsize: int = 2
+                       ) -> Optional[Tuple[int, int]]:
+    """Blocking plan ``(qb, pkb)`` for an N-token pack, or None when no
+    blocking fits VMEM.
+
+    ``qb`` (query block) and ``pkb`` (pack-key block) are equal: the
+    largest power of two <= 128 dividing N whose footprint
+    (``_vmem_bytes``) fits ``_VMEM_LIMIT``. A plan this returns compiles
+    — tests/test_tpu_compile.py holds it to that for v5e at 8B and 1B
+    head shapes. Scratch is per-q-block, so pack LENGTH never
+    disqualifies a pack; only per-block width can."""
     if N <= 0:
         return None
     qb = math.gcd(N, 128)
-    scratch = kv_heads * qb * q_per_kv * (head_dim + 2) * 4
-    if scratch > _VMEM_SCRATCH_BUDGET:
-        return None
-    return qb, qb
+    while qb >= 8:
+        if _vmem_bytes(qb, qb, kv_heads, q_per_kv, head_dim, page_size,
+                       itemsize) <= _VMEM_LIMIT:
+            return qb, qb
+        qb //= 2
+    return None
 
 
 def _kernel(ptab_ref, slots_ref, start_ref, off_ref, len_ref,
             q_ref, ck_ref, cv_ref, kp_ref, vp_ref,
-            out_ref, m_ref, l_ref, acc_ref, *, mp: int, pkb: int, qb: int):
-    """One (q-block, segment, kv-step) program. q [QB, KV, G, hd];
-    ck/cv pack keys [PKB, KV, hd]; kp/vp one page [1, Pg, KV, hd]."""
+            out_ref, m_ref, l_ref, acc_ref, *, mp: int, pkb: int, qb: int,
+            G: int):
+    """One (q-block, segment, kv-step) program. q [KV, QB*G, hd] (row r
+    is query q_lo + r // G, group r % G); ck/cv pack keys [KV, PKB, hd];
+    kp/vp one page [1, Pg, KV, hd]. A step is EITHER a page step
+    (j < mp) or a pack-key step; each runs its own predicated body."""
     i = pl.program_id(0)
     b = pl.program_id(1)
     j = pl.program_id(2)
     nb = pl.num_programs(1)
     nj = pl.num_programs(2)
-    _, kv_heads, G, hd = q_ref.shape
+    kv_heads, rows, hd = q_ref.shape
     pg = kp_ref.shape[1]
     start = start_ref[b]
     off = off_ref[b]
     length = len_ref[b]
     q_lo = i * qb
+    scale = jax.lax.rsqrt(jnp.float32(hd))
 
     @pl.when((b == 0) & (j == 0))
     def _reset():
@@ -102,67 +140,62 @@ def _kernel(ptab_ref, slots_ref, start_ref, off_ref, len_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # global query index n for each of the QB*G flattened rows
-    n_of_row = q_lo + \
-        jax.lax.broadcasted_iota(jnp.int32, (qb * G, 1), 0) // G
-    in_seg_row = (n_of_row >= off) & (n_of_row < off + length)
+    def row_mask(width):
+        """(query index n per score row, is the row in this segment?) at
+        the full [rows, width] score shape — built from 2-D iotas so no
+        boolean vector is ever broadcast or concatenated (Mosaic cannot
+        relayout i1 vregs)."""
+        n = q_lo + jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0) // G
+        return n, (n >= off) & (n < off + length)
 
-    # does this (q-block, segment, kv-step) contribute anything? A
-    # skipped step is exact: all its scores would mask to -inf, so
-    # m/l/acc are unchanged (alpha == 1, probs == 0).
+    def accumulate(h, scores, mask, v):
+        """Online-softmax update of head h with one masked score block."""
+        scores = jnp.where(mask, scores, _NEG_INF)
+        m_prev = m_ref[h]                                     # [rows, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # explicit zero where masked: an all-masked row has
+        # m == _NEG_INF and exp(score - m) would be exp(0) == 1
+        probs = jnp.where(mask, jnp.exp(scores - m_new), 0.0)
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(probs, axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+            probs, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[h] = m_new
+
+    def scores_of(h, k):
+        qf = q_ref[h].astype(jnp.float32) * scale             # [rows, hd]
+        return jax.lax.dot_general(
+            qf, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)               # [rows, BLK]
+
+    # does this (q-block, segment) pair overlap at all? A skipped step
+    # is exact: all its scores would mask to -inf, so m/l/acc are
+    # unchanged (alpha == 1, probs == 0).
     seg_hit = (length > 0) & (off < q_lo + qb) & (off + length > q_lo)
-    if_page = j < mp
     pk_lo = (j - mp) * pkb
-    need = seg_hit & jnp.where(
-        if_page,
-        j * pg < start,
-        (pk_lo < off + length) & (pk_lo + pkb > off) & (pk_lo < q_lo + qb))
 
-    @pl.when(need)
-    def _compute():
-        scale = jax.lax.rsqrt(jnp.float32(hd))
+    @pl.when(seg_hit & (j < mp) & (j * pg < start))
+    def _page():
+        _, in_seg = row_mask(pg)
+        col = jax.lax.broadcasted_iota(jnp.int32, (rows, pg), 1) + j * pg
+        mask = in_seg & (col < start)
         for h in range(kv_heads):
-            qf = q_ref[:, h].astype(jnp.float32).reshape(qb * G, hd) * scale
-            # both regions compute with the SAME [QB*G, BLK] shape so
-            # the online update below is region-agnostic; pkb == pg is
-            # not required — the two score blocks mask independently
-            k_page = kp_ref[0, :, h, :].astype(jnp.float32)       # [Pg, hd]
-            s_page = jax.lax.dot_general(
-                qf, k_page, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)               # [QB*G, Pg]
-            col = jax.lax.broadcasted_iota(jnp.int32, s_page.shape, 1) \
-                + j * pg
-            mask_page = in_seg_row & (col < start) & if_page
-            s_page = jnp.where(mask_page, s_page, _NEG_INF)
+            k = kp_ref[0, :, h, :].astype(jnp.float32)        # [Pg, hd]
+            v = vp_ref[0, :, h, :].astype(jnp.float32)
+            accumulate(h, scores_of(h, k), mask, v)
 
-            k_pack = ck_ref[:, h, :].astype(jnp.float32)          # [PKB, hd]
-            s_pack = jax.lax.dot_general(
-                qf, k_pack, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)               # [QB*G, PKB]
-            midx = jax.lax.broadcasted_iota(jnp.int32, s_pack.shape, 1) \
-                + pk_lo
-            mask_pack = in_seg_row & (midx >= off) & (midx < off + length) \
-                & (midx <= n_of_row) & jnp.logical_not(if_page)
-            s_pack = jnp.where(mask_pack, s_pack, _NEG_INF)
-
-            scores = jnp.concatenate([s_page, s_pack], axis=1)
-            masked = jnp.concatenate([mask_page, mask_pack], axis=1)
-            m_prev = m_ref[h]                                     # [QB*G, 1]
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(scores, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            # explicit zero where masked: an all-masked row has
-            # m == _NEG_INF and exp(score - m) would be exp(0) == 1
-            probs = jnp.where(masked, jnp.exp(scores - m_new), 0.0)
-            l_ref[h] = l_ref[h] * alpha \
-                + jnp.sum(probs, axis=-1, keepdims=True)
-            v_page = vp_ref[0, :, h, :].astype(jnp.float32)
-            v_pack = cv_ref[:, h, :].astype(jnp.float32)
-            v_all = jnp.concatenate([v_page, v_pack], axis=0)
-            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
-                probs, v_all, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[h] = m_new
+    @pl.when(seg_hit & (j >= mp) & (pk_lo < off + length)
+             & (pk_lo + pkb > off) & (pk_lo < q_lo + qb))
+    def _pack():
+        n, in_seg = row_mask(pkb)
+        midx = jax.lax.broadcasted_iota(jnp.int32, (rows, pkb), 1) + pk_lo
+        # causal within the segment; midx <= n < off + length bounds it above
+        mask = in_seg & (midx >= off) & (midx <= n)
+        for h in range(kv_heads):
+            k = ck_ref[h].astype(jnp.float32)                 # [PKB, hd]
+            v = cv_ref[h].astype(jnp.float32)
+            accumulate(h, scores_of(h, k), mask, v)
 
     @pl.when((b == nb - 1) & (j == nj - 1))
     def _finish():
@@ -170,8 +203,7 @@ def _kernel(ptab_ref, slots_ref, start_ref, off_ref, len_ref,
         # segments masked it); rows in no segment have l == 0 -> 0
         for h in range(kv_heads):
             denom = l_ref[h] + (l_ref[h] == 0.0)                  # pad: 0/1
-            out_ref[:, h] = (acc_ref[h] / denom).reshape(
-                qb, G, hd).astype(out_ref.dtype)
+            out_ref[h] = (acc_ref[h] / denom).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -200,7 +232,13 @@ def ragged_prefill_attention_pallas(q, chunk_k, chunk_v, pages_k, pages_v,
     assert N % pkb == 0 and N % qb == 0, (N, pkb, qb)
     nkb = N // pkb
     nqb = N // qb
-    qg = q.reshape(N, kv_heads, G, hd)
+    # head-major operands: each head's [rows, hd] tile is then dense in
+    # VMEM (a [.., G, hd] minor pair would pad G up to the sublane tile
+    # and the body would relayout it on every step)
+    qh = q.reshape(N, kv_heads, G, hd).transpose(1, 0, 2, 3) \
+        .reshape(kv_heads, N * G, hd)
+    ckh = chunk_k.transpose(1, 0, 2)
+    cvh = chunk_v.transpose(1, 0, 2)
 
     def _seg_hit(i, b, off_ref, len_ref):
         q_lo = i * qb
@@ -208,7 +246,7 @@ def ragged_prefill_attention_pallas(q, chunk_k, chunk_v, pages_k, pages_v,
             & (off_ref[b] + len_ref[b] > q_lo)
 
     def q_map(i, b, j, *refs):
-        return (i, 0, 0, 0)
+        return (0, i, 0)
 
     def page_map(i, b, j, ptab_ref, slots_ref, start_ref, off_ref, len_ref):
         # pages past the segment's last committed one — and every page
@@ -217,8 +255,9 @@ def ragged_prefill_attention_pallas(q, chunk_k, chunk_v, pages_k, pages_v,
         # their compute is predicated off in the kernel
         n_valid = (start_ref[b] + pg - 1) // pg
         last = jnp.maximum(n_valid - 1, 0)
-        pid = ptab_ref[slots_ref[b], jnp.minimum(jnp.minimum(j, mp - 1),
-                                                 last)]
+        # pad segments carry a sentinel slot id one past the table
+        slot = jnp.minimum(slots_ref[b], ptab_ref.shape[0] - 1)
+        pid = ptab_ref[slot, jnp.minimum(jnp.minimum(j, mp - 1), last)]
         hit = _seg_hit(i, b, off_ref, len_ref) & (j * pg < start_ref[b])
         return (jnp.where(hit, jnp.clip(pid, 0, n_pages - 1), 0), 0, 0, 0)
 
@@ -229,30 +268,33 @@ def ragged_prefill_attention_pallas(q, chunk_k, chunk_v, pages_k, pages_v,
         pk_lo = blk * pkb
         hit = _seg_hit(i, b, off_ref, len_ref) & (j >= mp) \
             & (pk_lo < hi) & (pk_lo + pkb > lo) & (pk_lo < q_lo + qb)
-        return (jnp.where(hit, blk, 0), 0, 0)
+        return (0, jnp.where(hit, blk, 0), 0)
 
+    rows = qb * G
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,      # ptab, seg_slots, seg_start/off/len
         grid=(nqb, B, mp + nkb),
         in_specs=[
-            pl.BlockSpec((qb, kv_heads, G, hd), q_map),
-            pl.BlockSpec((pkb, kv_heads, hd), pack_map),
-            pl.BlockSpec((pkb, kv_heads, hd), pack_map),
+            pl.BlockSpec((kv_heads, rows, hd), q_map),
+            pl.BlockSpec((kv_heads, pkb, hd), pack_map),
+            pl.BlockSpec((kv_heads, pkb, hd), pack_map),
             pl.BlockSpec((1, pg, kv_heads, hd), page_map),
             pl.BlockSpec((1, pg, kv_heads, hd), page_map),
         ],
-        out_specs=pl.BlockSpec((qb, kv_heads, G, hd), q_map),
+        out_specs=pl.BlockSpec((kv_heads, rows, hd), q_map),
         scratch_shapes=[
-            pltpu.VMEM((kv_heads, qb * G, 1), jnp.float32),    # running max
-            pltpu.VMEM((kv_heads, qb * G, 1), jnp.float32),    # running denom
-            pltpu.VMEM((kv_heads, qb * G, hd), jnp.float32),   # running out
+            pltpu.VMEM((kv_heads, rows, 1), jnp.float32),    # running max
+            pltpu.VMEM((kv_heads, rows, 1), jnp.float32),    # running denom
+            pltpu.VMEM((kv_heads, rows, hd), jnp.float32),   # running out
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, mp=mp, pkb=pkb, qb=qb),
+        functools.partial(_kernel, mp=mp, pkb=pkb, qb=qb, G=G),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, kv_heads, G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((kv_heads, N * G, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(ptab, seg_slots, seg_start, seg_off, seg_len,
-      qg, chunk_k, chunk_v, pages_k, pages_v)
-    return out.reshape(N, H, hd)
+      qh, ckh, cvh, pages_k, pages_v)
+    return out.reshape(kv_heads, N, G, hd).transpose(1, 0, 2, 3) \
+        .reshape(N, H, hd)

@@ -103,11 +103,8 @@ def ring_causal_attention(q, k, v, mesh: Mesh, q_per_kv: int = 1,
         return causal_attention(q, k, v, valid, q_per_kv)
     spec = P(None, axis, None, None)
     body = functools.partial(_ring_body, axis=axis, n=n, q_per_kv=q_per_kv)
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # pre-0.6 release: only the experimental alias
-        from jax.experimental.shard_map import shard_map
-    fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec)
     return fn(q, k, v)
 
 

@@ -7,8 +7,11 @@ underneath the request stream.
 
 **Compile tracking.** jax emits a
 ``/jax/core/compile/backend_compile_duration`` monitoring event once
-per real XLA compilation, synchronously on the compiling thread (cached
-executions emit only cheap trace events). We register ONE module-level
+per program built, synchronously on the compiling thread — whether the
+compiler ran or the persistent compilation cache served it; the latter
+also emits ``/jax/compilation_cache/cache_hits``, counted separately
+(``compiles_from_cache``) so a warm restart can be told from a cold one.
+Executions of an already-built program emit neither. We register ONE module-level
 listener and dispatch to the engine whose thread is compiling via a
 thread-local registration: the engine loop thread registers its
 CompileTracker at startup, and ``precompile()`` (which runs on the
@@ -30,8 +33,8 @@ from the engine loop so peaks between /metrics scrapes are not lost.
 **Goodput / MFU.** Analytic FLOPs-per-token from the model config
 (matmul params ×2 + attention term) and achieved tokens/s over a
 rolling window → model FLOPs utilization against the device's peak
-(``LOCALAI_PEAK_TFLOPS`` env or per-kind table; 0 ⇒ unknown ⇒ MFU
-reported as 0.0, the honest answer on CPU rigs). Goodput counts ONLY
+(the ``PEAK_FLOPS`` table keyed by device kind; a CPU has no entry and
+MFU reads 0.0 there, "not measured"). Goodput counts ONLY
 completed-request tokens — sheds, timeouts, stalls and errors produce
 no goodput even though they burned FLOPs.
 """
@@ -49,6 +52,9 @@ from collections import deque
 log = logging.getLogger("localai_tpu.sysobs")
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# fired (before the duration event, same thread) when the program came
+# out of the persistent compilation cache instead of the compiler
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _LAST_COMPILES = 32     # ring of recent compiles kept per tracker
 
 _tl = threading.local()
@@ -64,6 +70,14 @@ def _on_event_duration(name: str, secs: float, **kw):
         tracker.on_compile(secs)
 
 
+def _on_event(name: str, **kw):
+    if name != _CACHE_HIT_EVENT:
+        return
+    tracker = getattr(_tl, "tracker", None)
+    if tracker is not None:
+        tracker.on_cache_hit()
+
+
 def install_listener():
     """Register the module-level jax.monitoring listener (idempotent).
     Gated on import success so non-jax processes can still import the
@@ -75,6 +89,7 @@ def install_listener():
         try:
             from jax import monitoring
             monitoring.register_event_duration_secs_listener(_on_event_duration)
+            monitoring.register_event_listener(_on_event)
             _listener_installed = True
         except Exception as e:  # pragma: no cover - jax always present in CI
             log.warning("compile-event listener unavailable: %s", e)
@@ -111,6 +126,7 @@ class CompileTracker:
         self.model = model
         self.on_storm = on_storm    # callable(rec) — eventlog write-through
         self.compiles = 0
+        self.compiles_from_cache = 0   # of those, persistent-cache loads
         self.compile_seconds = 0.0
         self.compiles_after_warmup = 0
         self.warm = False
@@ -127,6 +143,10 @@ class CompileTracker:
         """precompile() finished: every compile from now on is a storm."""
         with self._lock:
             self.warm = True
+
+    def on_cache_hit(self):
+        with self._lock:
+            self.compiles_from_cache += 1
 
     def on_compile(self, secs: float):
         program = getattr(_tl, "program", None) or "?"
@@ -160,6 +180,7 @@ class CompileTracker:
     def snapshot(self) -> dict:
         with self._lock:
             return {"compiles_total": self.compiles,
+                    "compiles_from_cache": self.compiles_from_cache,
                     "compile_seconds_total": round(self.compile_seconds, 4),
                     "compiles_after_warmup": self.compiles_after_warmup,
                     "warm": self.warm}
@@ -208,38 +229,33 @@ def flops_per_token(cfg, ctx: int = 0) -> float:
     return 2.0 * matmul_params + attn
 
 
-# peak dense (bf16) FLOP/s per chip by device-kind substring. CPU rigs
-# fall through to 0.0: "unknown" — README documents that MFU reads 0
-# there rather than inventing a laptop-core number.
-_PEAK_FLOPS_TABLE = (
-    ("v6e", 918e12),
-    ("v5p", 459e12),
-    ("v5e", 197e12),
-    ("v5litepod", 197e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-)
+# Peak dense bf16 FLOP/s of one chip, keyed by the device_kind string
+# jax reports for it (checked against libtpu 0.0.34's topology client).
+# Source for every row: Google Cloud TPU documentation, the system
+# architecture page of that generation ("TPU v5e": 197 TFLOP/s bf16,
+# 819 GB/s HBM, 16 GB). One jax device per chip in each.
+PEAK_FLOPS = {
+    "TPU v5 lite": 197e12,    # v5e
+    "TPU v6 lite": 918e12,    # v6e
+    "TPU v5": 459e12,         # v5p
+    "TPU v4": 275e12,
+}
 
 
-def peak_device_flops() -> float:
-    """Peak FLOP/s of one local device: LOCALAI_PEAK_TFLOPS env wins,
-    else a TPU device-kind table, else 0.0 (unknown — e.g. CPU)."""
-    env = os.environ.get("LOCALAI_PEAK_TFLOPS", "")
-    if env:
-        try:
-            return float(env) * 1e12
-        except ValueError:
-            log.warning("bad LOCALAI_PEAK_TFLOPS=%r; ignoring", env)
-    try:
-        import jax
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
+def peak_device_flops(device) -> float:
+    """Peak FLOP/s of ``device`` from PEAK_FLOPS. A CPU has no entry and
+    reads 0.0 (MFU "not measured"); an accelerator that is not in the
+    table is an error — a made-up or zero peak would put a wrong MFU on
+    the very hardware the number is for."""
+    if device.platform == "cpu":
         return 0.0
-    for sub, flops in _PEAK_FLOPS_TABLE:
-        if sub in kind:
-            return flops
-    return 0.0
+    try:
+        return PEAK_FLOPS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak FLOP/s known for device kind {device.device_kind!r}; "
+            f"add it to services/sysobs.py PEAK_FLOPS with its source "
+            f"(known: {sorted(PEAK_FLOPS)})") from None
 
 
 class GoodputMeter:
@@ -597,27 +613,19 @@ class FlightRecorder:
                     "min_interval_s": self.min_interval_s}
 
 
-def device_memory_stats() -> dict:
-    """Real-device memory watermarks (closes the PR-8 follow-up):
-    `jax.local_devices()[0].memory_stats()` where the platform provides
-    it (TPU and GPU runtimes do; CPU returns None/raises -> {}). Keys
-    normalized to bytes_in_use / peak_bytes_in_use / bytes_limit; {}
-    means "no device counters here — analytic accounting is the
-    fallback"."""
-    try:
-        import jax
+def device_memory_stats() -> list:
+    """Allocator counters of EVERY local device, in device order:
+    [{id, device_kind, bytes_in_use, peak_bytes_in_use, bytes_limit}].
+    TPU runtimes report them; the CPU client returns None, and the
+    entry then carries id and kind alone ("no device counters here —
+    the analytic weight/KV accounting is what there is")."""
+    import jax
 
-        dev = jax.local_devices()[0]
-        stats = dev.memory_stats()
-    except Exception:
-        return {}
-    if not stats:
-        return {}
-    out = {}
-    for key in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit"):
-        v = stats.get(key)
-        if v is not None:
-            out[key] = int(v)
-    if out:
-        out["device_kind"] = getattr(dev, "device_kind", "")
+    out = []
+    for dev in jax.local_devices():
+        stats = dev.memory_stats() or {}
+        out.append({"id": dev.id, "device_kind": dev.device_kind,
+                    **{k: int(stats[k]) for k in
+                       ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+                       if k in stats}})
     return out

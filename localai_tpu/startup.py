@@ -10,6 +10,7 @@ from __future__ import annotations
 import asyncio
 import logging
 import os
+import signal
 from typing import Optional
 
 from localai_tpu.capabilities import Capabilities, build_model_options
@@ -77,9 +78,16 @@ async def serve(app_config: AppConfig):
     app = build_app(caps, app_config, gallery_service)
     runner = await run_app(app, app_config.address)
     log.info("localai-tpu listening on %s", app_config.address)
+    # SIGTERM stops the server the way Ctrl-C does: through the finally
+    # below, which stops the backends. A server killed without it leaves
+    # its runner orphaned (backends run in their own sessions) — and an
+    # orphaned runner keeps the chip, so the next start finds none.
+    stop = asyncio.Event()
+    loop = asyncio.get_running_loop()
+    for sig in (signal.SIGINT, signal.SIGTERM):
+        loop.add_signal_handler(sig, stop.set)
     try:
-        while True:
-            await asyncio.sleep(3600)
+        await stop.wait()
     finally:
         await runner.cleanup()
         gallery_service.shutdown()

@@ -24,24 +24,15 @@ def _skip_if_no_multiprocess_cpu(outs):
 
 
 _WORKER = r"""
-import os, re, sys
+import os, sys
 import numpy as np
 
-# 2 local x 2 procs = 4 global. Pre-jax_num_cpu_devices releases spell the
-# count as an XLA flag read at backend init, so scrub the 8-device flag the
-# parent conftest exported and set ours BEFORE jax initializes.
-os.environ["XLA_FLAGS"] = (re.sub(
-    r"--xla_force_host_platform_device_count=\d+", "",
-    os.environ.get("XLA_FLAGS", ""))
-    + " --xla_force_host_platform_device_count=2").strip()
+# 2 local x 2 procs = 4 global: override the 8 devices the parent
+# conftest exported, BEFORE jax initializes (JAX_PLATFORMS=cpu rides the
+# same inherited environment)
+os.environ["JAX_NUM_CPU_DEVICES"] = "2"
 
 import jax
-
-jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 2)
-except AttributeError:
-    pass  # covered by XLA_FLAGS above
 
 coordinator, pid = sys.argv[1], int(sys.argv[2])
 jax.distributed.initialize(coordinator_address=coordinator,
@@ -71,24 +62,16 @@ print(f"OK pid={pid} total={got}", flush=True)
 
 
 _COMMON = r"""
-import os, re, sys
+import os, sys
 import numpy as np
 
 coordinator, bus_addr, ckpt, http_port, pid = (
     sys.argv[1], sys.argv[2], sys.argv[3], sys.argv[4], int(sys.argv[5]))
 
-# 1 local device per process; see _WORKER for why XLA_FLAGS is scrubbed.
-os.environ["XLA_FLAGS"] = (re.sub(
-    r"--xla_force_host_platform_device_count=\d+", "",
-    os.environ.get("XLA_FLAGS", ""))
-    + " --xla_force_host_platform_device_count=1").strip()
+# 1 local device per process (see _WORKER)
+os.environ["JAX_NUM_CPU_DEVICES"] = "1"
 
 import jax
-jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 1)
-except AttributeError:
-    pass  # covered by XLA_FLAGS above
 jax.distributed.initialize(coordinator_address=coordinator,
                            num_processes=2, process_id=pid)
 assert len(jax.devices()) == 2
@@ -283,8 +266,7 @@ def test_lockstep_engine_http_two_process(tmp_path):
     follower_py = tmp_path / "follower.py"
     follower_py.write_text(_FOLLOWER)
 
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    env = dict(os.environ)   # JAX_PLATFORMS=cpu comes with it (conftest)
     env["PYTHONPATH"] = os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))
     env["LOCALAI_PRECOMPILE"] = "0"
@@ -329,8 +311,7 @@ def test_two_process_distributed_mesh(tmp_path):
 
     script = tmp_path / "worker.py"
     script.write_text(_WORKER)
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)  # the script forces cpu itself
+    env = dict(os.environ)   # JAX_PLATFORMS=cpu comes with it (conftest)
     procs = [
         subprocess.Popen([sys.executable, str(script), coord, str(pid)],
                          stdout=subprocess.PIPE, stderr=subprocess.STDOUT,

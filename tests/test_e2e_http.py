@@ -58,9 +58,6 @@ def real_server(tmp_path_factory):
     write_tiny_checkpoint(str(models / "tiny-ckpt"))
     (models / "tiny.yaml").write_text(TINY_YAML)
 
-    # the spawned runner must come up on the CPU platform even on TPU hosts
-    os.environ["LOCALAI_JAX_PLATFORM"] = "cpu"
-
     port = free_port()
     app_config = AppConfig(models_path=str(models), address=f"127.0.0.1:{port}")
     loader = ModelLoader(health_attempts=600, health_interval_s=0.2)

@@ -49,6 +49,27 @@ def test_load_failure_surfaces(loader):
         loader.backend_loader("fake", "bad", pb.ModelOptions(model="fail-this"))
 
 
+def test_dead_backend_error_carries_its_stderr(loader):
+    """A backend that dies before it is ready (the chip is taken, a
+    kernel does not compile, an import breaks) must say why in the error
+    the caller gets — its output is only logged at DEBUG."""
+    loader.register_external("doomed", "localai_tpu.no_such_backend")
+    with pytest.raises(RuntimeError) as ei:
+        loader.backend_loader("doomed", "d1", pb.ModelOptions(model="x"))
+    msg = str(ei.value)
+    assert "died during startup" in msg and "exit 1" in msg
+    assert "No module named" in msg and "no_such_backend" in msg
+
+
+def test_spawned_backend_imports_from_any_cwd(loader, tmp_path, monkeypatch):
+    """The spawner puts the package root on the child's PYTHONPATH: the
+    server no longer has to be started from the checkout."""
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.delenv("PYTHONPATH", raising=False)
+    lm = loader.backend_loader("fake", "cwd1", pb.ModelOptions(model="x"))
+    assert lm.process.alive() and lm.client.health()
+
+
 def test_model_reuse_same_client(loader):
     loader.register_embedded("fake", FakeServicer)
     a = loader.backend_loader("fake", "m3", pb.ModelOptions(model="x"))

@@ -195,12 +195,22 @@ def test_mfu_honest_zero_without_peak():
     assert m.mfu(tok_s=1e6) == 0.0
 
 
-def test_peak_device_flops_env_override(monkeypatch):
+def test_peak_device_flops_is_a_table_keyed_by_device_kind(monkeypatch):
+    """The chip reports "TPU v5 lite" (not "v5e"); a CPU is "not
+    measured" (0.0); an accelerator outside the table is an error, and
+    no environment variable can assume a rate."""
+    import types
+
+    import jax
+
+    dev = lambda plat, kind: types.SimpleNamespace(  # noqa: E731
+        platform=plat, device_kind=kind)
+    assert sysobs.peak_device_flops(dev("tpu", "TPU v5 lite")) == 197e12
+    assert sysobs.peak_device_flops(jax.devices()[0]) == 0.0
     monkeypatch.setenv("LOCALAI_PEAK_TFLOPS", "2.5")
-    assert sysobs.peak_device_flops() == pytest.approx(2.5e12)
-    monkeypatch.setenv("LOCALAI_PEAK_TFLOPS", "garbage")
-    # bad override falls through to the table (CPU -> 0.0)
-    assert sysobs.peak_device_flops() == 0.0
+    assert sysobs.peak_device_flops(dev("cpu", "cpu")) == 0.0
+    with pytest.raises(ValueError, match="TPU v9"):
+        sysobs.peak_device_flops(dev("tpu", "TPU v9"))
 
 
 def test_engine_goodput_counts_only_completions(warm_engine,
