@@ -647,9 +647,11 @@ def _collect_traces(state) -> dict:
 
         (backend_t0_epoch - offset_s - frontend_t0_epoch) µs
 
-    where offset_s is the LoadModel clock-handshake estimate of the
-    backend-vs-frontend wall-clock skew (loader.LoadedModel.clock; the
-    residual error is bounded by that handshake's rtt_s). Backends
+    where offset_s is the backend-vs-frontend wall-clock skew
+    (loader.LoadedModel.clock): 0 for a backend the model manager
+    started on this machine, else the estimate of the shortest of three
+    Health round trips after the load, its error within rtt_s / 2
+    (loader.measure_clock; never the LoadModel round trip). Backends
     without GetTrace or without the epoch block (old fakes) and RPC
     failures are skipped/unshifted — a debug surface must never 500
     because one backend is old."""
@@ -825,8 +827,10 @@ async def debug_profile(request):
         if lm is None:
             continue
         try:
+            # the capture itself, then stop_trace: 7-8 s per captured
+            # second of a busy device, measured (PERF.md section 6, PR 25)
             r = await state.run_blocking(
-                lm.client.profile, seconds, max(30.0, seconds + 30.0))
+                lm.client.profile, seconds, 30.0 + 12.0 * seconds)
         except Exception as e:
             return api_error(f"profile RPC failed: {e}", 502)
         return web.json_response({

@@ -78,6 +78,11 @@ class EngineServicer(BackendServicer):
     Status/GetMetrics on top of the continuous-batching engine."""
 
     def __init__(self):
+        from localai_tpu.services.tracing import RingTracer
+
+        # the process's one span ring, from process start: LoadModel's
+        # spans go in first, then the Engine is handed the same ring
+        self.tracer = RingTracer()
         self.engine = None
         self.tokenizer = None
         self.model_cfg = None
@@ -116,7 +121,9 @@ class EngineServicer(BackendServicer):
     def LoadModel(self, request: pb.ModelOptions, context) -> pb.Result:
         with self._load_lock:
             try:
-                self._load(request)
+                with self.tracer.span("load_model", "load",
+                                      model=request.model):
+                    self._load(request)
                 self._state = pb.StatusResponse.READY
                 # clock handshake (ISSUE 12): Result.message carries this
                 # process's wall/monotonic clocks and the tracer epoch so
@@ -128,7 +135,7 @@ class EngineServicer(BackendServicer):
                       "handshake": {
                           "wall": time.time(),
                           "mono": time.monotonic(),
-                          "trace_epoch": self.engine.tracer.t0_epoch,
+                          "trace_epoch": self.tracer.t0_epoch,
                           "pid": os.getpid()}}
                 return pb.Result(success=True, message=json.dumps(hs))
             except Exception as e:  # surface the error to the core
@@ -137,16 +144,27 @@ class EngineServicer(BackendServicer):
                 return pb.Result(success=False, message=f"{type(e).__name__}: {e}")
 
     def _load(self, request: pb.ModelOptions):
-        import jax
-        import jax.numpy as jnp
+        with self.tracer.span("load_imports", "load"):
+            # a process's first load pays for importing jax and the
+            # engine, and for jax reaching the chip
+            import jax
+            import jax.numpy as jnp
 
-        from localai_tpu.engine import engine as eng
-        from localai_tpu.engine import weights
-        from localai_tpu.models import llama
-        from localai_tpu.parallel import mesh as meshlib
-        from localai_tpu.parallel import sharding as shardlib
+            from localai_tpu.engine import engine as eng
+            from localai_tpu.engine import weights
+            from localai_tpu.models import llama
+            from localai_tpu.parallel import mesh as meshlib
+            from localai_tpu.parallel import sharding as shardlib
 
-        require_accelerator()
+            require_accelerator()
+        # the model's trace / trace_ring_size options, applied to the
+        # process's ring before the load records into it
+        extra = parse_options(request.options)
+        self.tracer.configure(
+            int(extra.get("trace_ring_size", 0) or 0) or self.tracer.size,
+            enabled=str(extra.get("trace", "")).strip().lower()
+            not in ("0", "false", "off", "no"))
+        span = self.tracer.span
         model_dir = request.model
         if request.model_path and not os.path.isabs(model_dir):
             model_dir = os.path.join(request.model_path, model_dir)
@@ -246,9 +264,7 @@ class EngineServicer(BackendServicer):
         lora_dir = request.lora_adapter
         if lora_dir and request.model_path and not os.path.isabs(lora_dir):
             lora_dir = os.path.join(request.model_path, lora_dir)
-        # parsed BEFORE the weight load: weight_prefetch=1 swaps the
-        # loader itself (ISSUE 19)
-        extra = parse_options(request.options)
+        # weight_prefetch=1 swaps the loader itself (ISSUE 19)
         stream_load = str(extra.get("weight_prefetch", "")
                           ).strip().lower() in ("1", "true", "on", "yes")
         stream_auto = str(extra.get("autoscale", "")
@@ -283,7 +299,8 @@ class EngineServicer(BackendServicer):
                 model_dir, cfg, mesh=mesh, dtype=dtype,
                 quantize=request.quantization or
                 ("int8" if request.dtype == "int8" else ""),
-                lora_adapter=lora_dir, lora_scale=request.lora_scale or 1.0)
+                lora_adapter=lora_dir, lora_scale=request.lora_scale or 1.0,
+                tracer=self.tracer)
             log.info("streamed weight load: %d leaves, %.1f MB, %.0f ms",
                      self.weight_stream_stats["leaves"],
                      self.weight_stream_stats["bytes"] / 1e6,
@@ -293,17 +310,23 @@ class EngineServicer(BackendServicer):
                 model_dir, cfg, mesh=mesh, dtype=dtype,
                 quantize=request.quantization or
                 ("int8" if request.dtype == "int8" else ""),
-                lora_adapter=lora_dir, lora_scale=request.lora_scale or 1.0)
+                lora_adapter=lora_dir, lora_scale=request.lora_scale or 1.0,
+                tracer=self.tracer)
+        with span("load_device_wait", "load"):
+            # the one wait of the load: what the per-leaf host calls left
+            # in flight (copies to the device, the cast where it runs there)
+            jax.block_until_ready(params)
 
-        if gguf_path is not None and not request.tokenizer:
-            from localai_tpu.engine import gguf_tokenizer
+        with span("load_tokenizer", "load"):
+            if gguf_path is not None and not request.tokenizer:
+                from localai_tpu.engine import gguf_tokenizer
 
-            self.tokenizer = gguf_tokenizer.from_gguf(gguf_path)
-        else:
-            from transformers import AutoTokenizer
+                self.tokenizer = gguf_tokenizer.from_gguf(gguf_path)
+            else:
+                from transformers import AutoTokenizer
 
-            tok_dir = request.tokenizer or model_dir
-            self.tokenizer = AutoTokenizer.from_pretrained(tok_dir)
+                tok_dir = request.tokenizer or model_dir
+                self.tokenizer = AutoTokenizer.from_pretrained(tok_dir)
 
         ecfg = eng.EngineConfig(
             num_slots=request.num_slots or 8,
@@ -584,17 +607,29 @@ class EngineServicer(BackendServicer):
             # the policy add replicas later (ISSUE 19)
             from localai_tpu.engine.pool import EnginePool
 
-            self.engine = EnginePool.build(
-                cfg, params, self.tokenizer, ecfg, engines=n_engines,
-                mesh=mesh, draft=draft, family=family)
+            with span("load_engine_init", "load", engines=n_engines):
+                # pool replicas keep a ring each (their slot tracks would
+                # collide in one); Profile switches them all
+                self.engine = EnginePool.build(
+                    cfg, params, self.tokenizer, ecfg, engines=n_engines,
+                    mesh=mesh, draft=draft, family=family)
         else:
-            self.engine = eng.Engine(cfg, params, self.tokenizer, ecfg,
-                                     mesh=mesh, draft=draft, family=family)
+            with span("load_engine_init", "load", engines=1):
+                self.engine = eng.Engine(
+                    cfg, params, self.tokenizer, ecfg, mesh=mesh,
+                    draft=draft, family=family, tracer=self.tracer)
         # compile the whole serving surface before accepting traffic (a cold
         # compile mid-request stalls every active slot for 20-40s); skippable
         # for tests that only care about wiring
-        self.engine.start(
-            precompile=os.environ.get("LOCALAI_PRECOMPILE", "1") != "0")
+        with span("load_precompile", "load") as sp:
+            self.engine.start(
+                precompile=os.environ.get("LOCALAI_PRECOMPILE", "1") != "0")
+            # (a pool's snapshot has no process-wide compile count)
+            comp = self.engine.state_snapshot().get("compiles") or {}
+            sp.args.update(
+                programs=comp.get("compiles_total", 0),
+                from_cache=comp.get("compiles_from_cache", 0),
+                compile_seconds=comp.get("compile_seconds_total", 0.0))
         # cross-host KV federation (ISSUE 17): kv_serve=1|host:port makes
         # this host's KV tier network-addressable (peers stream chain
         # entries out of it); kv_peers=host:port|host:port attaches the
@@ -907,10 +942,33 @@ class EngineServicer(BackendServicer):
                           f"state export failed: {type(e).__name__}: {e}")
         return pb.Reply(message=payload.encode("utf-8"))
 
+    def _engines(self) -> list:
+        """The Engine, or a pool's replicas."""
+        return getattr(self.engine, "_engines", None) or [self.engine]
+
+    def _tracers(self) -> list:
+        """The process's ring, and a pool's per-replica rings."""
+        out = [self.tracer]
+        for e in self._engines():
+            if all(e.tracer is not t for t in out):
+                out.append(e.tracer)
+        return out
+
     def Profile(self, request, context) -> pb.Result:
         """Capture a jax.profiler trace (TensorBoard/perfetto format) for
         the requested number of seconds while the engine keeps serving.
-        Request rides PredictOptions.prompt as JSON {"seconds": N}."""
+        Request rides PredictOptions.prompt as JSON {"seconds": N}.
+
+        While the capture runs every ``RingTracer.span()`` of this process
+        is also an annotation in it. The first host event is
+        ``clock_anchor``, carrying this process's monotonic and wall
+        clocks: with the event's own timestamp it places every ring span
+        and every client-clock instant on the capture's timeline. The
+        python tracer is off (host TraceMe events are kept; nothing reads
+        python frames). stop_trace still takes 7-8 s per captured second
+        of a busy device, whatever the host options (PERF.md section 6,
+        PR 25): the route's deadline allows for it. /debug/state ->
+        profile describes the last capture."""
         self._require_ready(context)
         import tempfile
         import time as _time
@@ -921,16 +979,40 @@ class EngineServicer(BackendServicer):
             req = {}
         seconds = min(60.0, max(0.1, float(req.get("seconds", 3) or 3)))
         out_dir = req.get("dir") or tempfile.mkdtemp(prefix="localai-prof-")
+        prof = {"capture_dir": out_dir, "seconds": seconds}
         try:
             import jax
 
-            jax.profiler.start_trace(out_dir)
-            _time.sleep(seconds)
-            jax.profiler.stop_trace()
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(out_dir, profiler_options=opts)
+            prof["monotonic_ns"] = _time.monotonic_ns()
+            prof["epoch_ns"] = _time.time_ns()
+            with jax.profiler.TraceAnnotation(
+                    "clock_anchor", monotonic_ns=prof["monotonic_ns"],
+                    epoch_ns=prof["epoch_ns"]):
+                pass
+            for tr in self._tracers():
+                tr.set_capturing(True)
+            try:
+                _time.sleep(seconds)
+            finally:
+                for tr in self._tracers():
+                    tr.set_capturing(False)
+                t_stop = _time.monotonic()
+                jax.profiler.stop_trace()
+                prof["stop_trace_s"] = round(_time.monotonic() - t_stop, 3)
+                prof["capture_bytes"] = sum(
+                    os.path.getsize(os.path.join(d, f))
+                    for d, _, fs in os.walk(out_dir) for f in fs)
         except Exception as e:
             return pb.Result(
                 success=False,
                 message=f"profiler capture failed: {type(e).__name__}: {e}")
+        finally:
+            for e in self._engines():
+                e.profile_state = prof
+        log.info("profiler capture: %s", json.dumps(prof))
         return pb.Result(success=True, message=out_dir)
 
     def _require_ready(self, context):

@@ -76,7 +76,12 @@ class BackendServicer:
     """
 
     def Health(self, request, context) -> pb.Reply:
-        return pb.Reply(message=b"OK")
+        # the reply carries this process's wall clock (epoch ms) in the
+        # Reply's spare double: a front end on another machine estimates
+        # the clock offset from a few of these round trips
+        # (modelmgr/loader.py::measure_clock)
+        return pb.Reply(message=b"OK",
+                        timing_prompt_processing=time.time() * 1e3)
 
     def __getattr__(self, name):
         if name in METHODS:
@@ -234,6 +239,12 @@ class BackendClient:
             return r.message == b"OK"
         except grpc.RpcError:
             return False
+
+    def health_clock(self, timeout: float = 5.0) -> float:
+        """One Health round trip -> the backend's wall clock (epoch s) as
+        stamped in the reply, 0.0 from a backend that does not stamp."""
+        r = self._stubs["Health"](pb.HealthMessage(), timeout=timeout)
+        return r.timing_prompt_processing / 1e3
 
     def load_model(self, opts: pb.ModelOptions, timeout: float = 900.0) -> pb.Result:
         return self._stubs["LoadModel"](opts, timeout=timeout)

@@ -80,17 +80,6 @@ _HIST_BUCKETS = {
 }
 
 
-class _NullCtx:
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_CTX = _NullCtx()
-
-
 @dataclasses.dataclass
 class EngineConfig:
     num_slots: int = 8
@@ -239,7 +228,7 @@ class EngineConfig:
     # metrics()["trace"], Chrome trace export via trace_events().
     # trace=0 makes every record() call a no-op on the hot path.
     trace: bool = True
-    trace_ring_size: int = 4096
+    trace_ring_size: int = 32768   # tracing.DEFAULT_RING_SIZE
     # slow-request structured log: when a finished request's TTFT or
     # end-to-end wall exceeds this many ms, log one WARNING with the
     # span decomposition. 0 disables.
@@ -700,6 +689,7 @@ class Engine:
         family=None,                     # model-family module (default llama)
         replica_id: int = 0,             # position in an EnginePool (ISSUE 14)
         shared_kv=None,                  # pool.SharedKV: one host tier + index
+        tracer=None,                     # the process's RingTracer (runner)
     ):
         self.cfg = model_cfg
         self.ecfg = engine_cfg or EngineConfig()
@@ -1067,19 +1057,18 @@ class Engine:
         self._mask_builder = None
         self._token_strs: Optional[list] = None
 
-        # loop-phase tracing (LOCALAI_ENGINE_TRACE=1): cumulative seconds
-        # per phase + counts, dumped at shutdown — the tool that found the
-        # r3 serving-vs-kernel gap
-        import os as _os
-
-        self._trace = _os.environ.get("LOCALAI_ENGINE_TRACE", "") == "1"
-        self._tstats: dict = {}
-        # request-lifecycle span tracer (services/tracing.py): always
-        # constructed; trace=0 makes record() a no-op on the hot path
+        # the span tracer (services/tracing.py): the runner hands in the
+        # process's ring (its LoadModel spans are already in it); a bare
+        # Engine builds its own. trace=0 makes span()/record() no-ops on
+        # the hot path
         from localai_tpu.services.tracing import RingTracer
 
-        self.tracer = RingTracer(self.ecfg.trace_ring_size,
-                                 enabled=bool(self.ecfg.trace))
+        self.tracer = tracer if tracer is not None else RingTracer(
+            self.ecfg.trace_ring_size, enabled=bool(self.ecfg.trace))
+        self.profile_state: Optional[dict] = None   # set by runner.Profile
+        # what the current tick dispatched, for the tick span's counts
+        self._tick_prefill_tokens = 0
+        self._tick_decode_tokens = 0
         self._slow_ms = float(self.ecfg.slow_request_ms)
         # per-request latency histograms (re-exposed by /metrics as real
         # Prometheus histograms): name -> [bucket counts + +Inf, sum, n].
@@ -1281,27 +1270,31 @@ class Engine:
                     item.ready.set()
                     self._wake.set()
                     continue
-            try:
-                if isinstance(item, _Burst):
-                    item.pack_np = np.asarray(item.pack)
-                elif isinstance(item, _PendingOffload):
-                    # terminal here: offloads produce no tokens, so they
-                    # never enter the dispatch FIFO — sync + store insert
-                    # both live on this thread, off the serving loop
-                    item.run()
-                    continue
-                else:
-                    item.ids_np = np.asarray(item.out_ids)
-                    item.lps_np = np.asarray(item.logprobs)
-                    item.mu_np = np.asarray(item.mu_out)
-            except Exception as e:  # surfaced when the item is processed
-                if isinstance(item, _PendingOffload):
-                    # a failed offload only loses a reusable copy — log
-                    # and keep serving (the chain just re-prefills later)
-                    __import__("logging").getLogger(__name__).exception(
-                        "kv page offload failed")
-                    continue
-                item.err = e
+            # sync_wait: this thread blocked on the device (and the copy
+            # back) for one dispatched item; with an idle device under it,
+            # the host is what the device waits for
+            with self.tracer.span("sync_wait", "sync"):
+                try:
+                    if isinstance(item, _Burst):
+                        item.pack_np = np.asarray(item.pack)
+                    elif isinstance(item, _PendingOffload):
+                        # terminal here: offloads produce no tokens, so they
+                        # never enter the dispatch FIFO — sync + store insert
+                        # both live on this thread, off the serving loop
+                        item.run()
+                        continue
+                    else:
+                        item.ids_np = np.asarray(item.out_ids)
+                        item.lps_np = np.asarray(item.logprobs)
+                        item.mu_np = np.asarray(item.mu_out)
+                except Exception as e:  # surfaced when the item is processed
+                    if isinstance(item, _PendingOffload):
+                        # a failed offload only loses a reusable copy — log
+                        # and keep serving (the chain just re-prefills later)
+                        __import__("logging").getLogger(__name__).exception(
+                            "kv page offload failed")
+                        continue
+                    item.err = e
             # the ready-set stamp IS the device-completion observation
             # point (the np.asarray above returned): span
             # t_dispatch->t_ready is device time, t_ready->process
@@ -1309,13 +1302,6 @@ class Engine:
             item.t_ready = self._t_last_ready = time.monotonic()
             item.ready.set()
             self._wake.set()
-
-    def _tmark(self, key: str, t0: float):
-        if self._trace:
-            t = time.monotonic()
-            s = self._tstats.setdefault(key, [0.0, 0])
-            s[0] += t - t0
-            s[1] += 1
 
     def _hobserve(self, name: str, seconds: float, rid: str = ""):
         h = self._hists[name]
@@ -1493,30 +1479,41 @@ class Engine:
                 f"slo:{violations[0]['metric']}:{violations[0]['class']}",
                 tag="slo", violations=violations)
 
-    def _annot(self, name: str):
-        """jax.profiler annotation around a dispatch, so device traces
-        captured via /debug/profile line up with engine spans. No-op
-        context when trace=0 or the profiler is unavailable."""
-        if not self.tracer.enabled:
-            return _NULL_CTX
-        try:
-            return jax.profiler.TraceAnnotation(name)
-        except Exception:  # pragma: no cover - profiler unavailable
-            return _NULL_CTX
+    def _annot(self, name: str, **args):
+        """The span around a dispatch call: a ring span on track
+        ``engine`` and, while a profiler capture runs, an annotation of
+        the same name in it (benchmark/reduce_trace.py::HOST_NAMES reads
+        these names)."""
+        return self.tracer.span(name, "engine", **args)
 
-    def _program(self, kind: str, key, attention: str, fn):
-        """Register a jitted model-forward program: name it for compile
-        attribution (the next compile on this thread is its), record
-        which attention implementation it is built with, and count its
-        dispatches — state_snapshot()["attention"]["programs"]."""
-        self._cobs.note_program(kind, key)
-        rec = self._programs[f"{kind}:{key}"] = {"attention": attention,
-                                                 "dispatches": 0}
+    def _program(self, kind: str, key, attention: str, fn, **jit_kw):
+        """Every jitted program of the engine is built here. The function
+        it jits is named after ``kind``, so a profiler capture's module
+        line and the HLO read ``jit_<kind>`` (the shape key stays out of
+        the name: one name per kind). Each call names the program for
+        compile attribution around the call that may compile it
+        (sysobs.CompileTracker), and counts the dispatch; the
+        implementation of attention it was built with is recorded for
+        state_snapshot()["attention"]["programs"]."""
+        def named(*args):
+            return fn(*args)
+
+        named.__name__ = named.__qualname__ = kind
+        jit_fn = jax.jit(named, **jit_kw)
+        name = kind if key is None else f"{kind}:{key}"
+        rec = self._programs[name] = {"attention": attention,
+                                      "dispatches": 0}
+        cobs = self._cobs
 
         def dispatch(*args):
             rec["dispatches"] += 1
-            return fn(*args)
+            cobs.note_program(kind, key)
+            try:
+                return jit_fn(*args)
+            finally:
+                cobs.note_program(None)
 
+        dispatch.jit_fn = jit_fn
         return dispatch
 
     def _decode_attn(self) -> str:
@@ -1774,8 +1771,8 @@ class Engine:
     def _get_page_clone_fn(self):
         fn = self._fork_fns.get("page_clone")
         if fn is None:
-            self._cobs.note_program("page_clone")
-            fn = jax.jit(
+            fn = self._program(
+                "kv_page_clone", None, "none",
                 lambda ck, cv, src, dst: (kvcache.clone_page(ck, src, dst),
                                           kvcache.clone_page(cv, src, dst)),
                 donate_argnums=(0, 1))
@@ -1785,8 +1782,8 @@ class Engine:
     def _get_draft_clone_fn(self):
         fn = self._fork_fns.get("page_clone_draft")
         if fn is None:
-            self._cobs.note_program("page_clone_draft")
-            fn = jax.jit(
+            fn = self._program(
+                "draft_kv_page_clone", None, "none",
                 lambda ck, cv, src, dst: (kvcache.clone_page(ck, src, dst),
                                           kvcache.clone_page(cv, src, dst)),
                 donate_argnums=(0, 1))
@@ -1819,9 +1816,10 @@ class Engine:
         key = ("offload_gather", batch)
         fn = self._fork_fns.get(key)
         if fn is None:
-            self._cobs.note_program("offload_gather", batch)
-            fn = jax.jit(lambda ck, cv, idx: (kvcache.gather_pages(ck, idx),
-                                              kvcache.gather_pages(cv, idx)))
+            fn = self._program(
+                "kv_offload_gather", batch, "none",
+                lambda ck, cv, idx: (kvcache.gather_pages(ck, idx),
+                                     kvcache.gather_pages(cv, idx)))
             self._fork_fns[key] = fn
         return fn
 
@@ -1829,8 +1827,8 @@ class Engine:
         key = ("restore_scatter", batch)
         fn = self._fork_fns.get(key)
         if fn is None:
-            self._cobs.note_program("restore_scatter", batch)
-            fn = jax.jit(
+            fn = self._program(
+                "kv_restore_scatter", batch, "none",
                 lambda ck, cv, idx, kr, vr: (
                     kvcache.scatter_pages(ck, idx, kr),
                     kvcache.scatter_pages(cv, idx, vr)),
@@ -1843,7 +1841,6 @@ class Engine:
         pages and queue the host transfer on the sync worker. The batch
         pads to a power of two (repeat-last — duplicate reads are free)
         so only log2 gather programs ever compile."""
-        t0 = time.monotonic()
         n = len(victims)
         B = 1
         while B < n:
@@ -1851,7 +1848,7 @@ class Engine:
         idx = np.full((B,), victims[-1][3], np.int32)
         for i, (_k, _p, _d, page) in enumerate(victims):
             idx[i] = page
-        with self._annot("kv_offload_gather"):
+        with self._annot("kv_offload_gather", pages=n):
             k_rows, v_rows = self._get_offload_gather_fn(B)(self.ck,
                                                             self.cv, idx)
         d_rows = None
@@ -1866,10 +1863,6 @@ class Engine:
         item = _PendingOffload([(k, p, d) for k, p, d, _pg in victims],
                                k_rows, v_rows, self._hstore, d_rows)
         self._sync_q.put(item)
-        self._tmark("offload_dispatch", t0)
-        if self.tracer.enabled:
-            self.tracer.record("offload_dispatch", "engine", t0,
-                               time.monotonic(), args={"pages": n})
 
     def _upload_pages(self, pages: list, host_hits: list):
         """Dispatch the async host->device scatter copying ``host_hits``
@@ -1896,7 +1889,7 @@ class Engine:
         ks = self._rstager.fill(par, "k", host_hits, lambda e: e.k, B)
         vs = self._rstager.fill(par, "v", host_hits, lambda e: e.v, B)
 
-        with self._annot("kv_restore_scatter"):
+        with self._annot("kv_restore_scatter", pages=n):
             self.ck, self.cv = self._get_restore_scatter_fn(B)(
                 self.ck, self.cv, idx, ks, vs)
         # paged draft cache (ISSUE 13): restore the draft rows of any hit
@@ -1940,7 +1933,6 @@ class Engine:
             for p in pages:
                 pool.unref_detached(p)
             return 0
-        t0 = time.monotonic()
         n = len(host_hits)
         self._upload_pages(pages, host_hits)
         for e, p in zip(host_hits, pages[:n]):
@@ -1950,10 +1942,6 @@ class Engine:
             # boundary write COW-clones instead of corrupting the copy
             self._pcache.attach(pool, e.key, e.parent, p, e.depth)
         self._hstore.note_restore(n)
-        self._tmark("restore_dispatch", t0)
-        if self.tracer.enabled:
-            self.tracer.record("restore_dispatch", "engine", t0,
-                               time.monotonic(), args={"pages": n})
         return n
 
     def _share_prefix(self, src: int, dst: int, rows: int) -> int:
@@ -2623,10 +2611,9 @@ class Engine:
             fn = self._program(
                 "prefill_fused", (bucket, batch),
                 f"jnp:causal + {self._decode_attn()}",
-                jax.jit(
-                    lambda *a: self._fused_body(
-                        *a, n_steps=self.ecfg.decode_burst),
-                    donate_argnums=(2, 3, 8)))
+                lambda *a: self._fused_body(
+                    *a, n_steps=self.ecfg.decode_burst),
+                donate_argnums=(2, 3, 8))
             self._burst_fns[key] = fn
         return fn
 
@@ -2701,10 +2688,9 @@ class Engine:
             fn = self._program(
                 "prefill_pack", (bucket, continued),
                 self._ragged_attn(bucket, continued),
-                jax.jit(
-                    lambda *a: self._packed_prefill_body(*a,
-                                                         continued=continued),
-                    donate_argnums=(9, 10, 14)))
+                lambda *a: self._packed_prefill_body(*a,
+                                                     continued=continued),
+                donate_argnums=(9, 10, 14))
             self._final_fns[key] = fn
         return fn
 
@@ -2790,11 +2776,10 @@ class Engine:
                 "prefill_pack_fused", (bucket, continued),
                 f"{self._ragged_attn(bucket, continued)} + "
                 f"{self._decode_attn()}",
-                jax.jit(
-                    lambda *a: self._fused_packed_body(
-                        *a, n_steps=self.ecfg.decode_burst,
-                        continued=continued),
-                    donate_argnums=(2, 3, 8)))
+                lambda *a: self._fused_packed_body(
+                    *a, n_steps=self.ecfg.decode_burst,
+                    continued=continued),
+                donate_argnums=(2, 3, 8))
             self._burst_fns[key] = fn
         return fn
 
@@ -2862,9 +2847,8 @@ class Engine:
             fn = self._program(
                 "prefill_pack_head", (bucket, continued),
                 self._ragged_attn(bucket, continued),
-                jax.jit(
-                    lambda *a: self._split_head_body(*a, continued=continued),
-                    donate_argnums=(2, 3, 8)))
+                lambda *a: self._split_head_body(*a, continued=continued),
+                donate_argnums=(2, 3, 8))
             self._final_fns[key] = fn
         return fn
 
@@ -2878,7 +2862,10 @@ class Engine:
         key = ("draft_packed", bucket)
         fn = self._chunk_fns.get(key)
         if fn is None:
-            fn = jax.jit(
+            fn = self._program(
+                "draft_prefill_pack", bucket,
+                llama.ragged_attn_impl(self.draft_cfg, self.dck, bucket,
+                                       True),
                 lambda p, t, pos, so, ss, st, off, ln, ck, cv:
                     llama.ragged_prefill(
                         p, self.draft_cfg, t, pos, so, ss, st, off, ln,
@@ -2895,10 +2882,9 @@ class Engine:
             # tiny, and mirror-fed dispatches pass host numpy for them)
             fn = self._program(
                 "decode_burst", key, self._decode_attn(),
-                jax.jit(
-                    lambda *a: self._decode_burst_body(*a, n_steps=n_steps,
-                                                       flags=flags),
-                    donate_argnums=(2, 3, 8)))
+                lambda *a: self._decode_burst_body(*a, n_steps=n_steps,
+                                                   flags=flags),
+                donate_argnums=(2, 3, 8))
             self._burst_fns[key] = fn
         return fn
 
@@ -2907,7 +2893,7 @@ class Engine:
         if fn is None:
             fn = self._program(
                 "prefill_chunk", bucket, "jnp:gather_mixed",
-                jax.jit(self._prefill_chunk_body, donate_argnums=(3, 4)))
+                self._prefill_chunk_body, donate_argnums=(3, 4))
             self._chunk_fns[bucket] = fn
         return fn
 
@@ -2919,7 +2905,8 @@ class Engine:
         key = ("draft", bucket)
         fn = self._chunk_fns.get(key)
         if fn is None:
-            fn = jax.jit(
+            fn = self._program(
+                "draft_prefill_chunk", bucket, "jnp:gather_mixed",
                 lambda p, t, s, ck, cv, sl, st: llama.prefill(
                     p, self.draft_cfg, t, s, ck, cv, sl, st,
                     continued=True)[1:],
@@ -2934,10 +2921,9 @@ class Engine:
             fn = self._program(
                 "prefill_final", key,
                 "jnp:gather_mixed" if continued else "jnp:causal",
-                jax.jit(
-                    lambda *a: self._prefill_final_body(
-                        *a, continued=continued),
-                    donate_argnums=(3, 4, 10)))
+                lambda *a: self._prefill_final_body(
+                    *a, continued=continued),
+                donate_argnums=(3, 4, 10))
             self._final_fns[key] = fn
         return fn
 
@@ -2948,7 +2934,8 @@ class Engine:
         key = ("ga", bucket)
         fn = self._chunk_fns.get(key)
         if fn is None:
-            fn = jax.jit(
+            fn = self._program(
+                "prefill_chunk_ga", bucket, "jnp:gather_mixed",
                 lambda p, t, sl, ck, cv, slo, st, pos: llama.prefill(
                     p, self.cfg, t, sl, ck, cv, slo, st, continued=True,
                     positions=pos)[1:],
@@ -2960,7 +2947,8 @@ class Engine:
         key = ("ga_final", bucket, continued)
         fn = self._final_fns.get(key)
         if fn is None:
-            fn = jax.jit(
+            fn = self._program(
+                "prefill_final_ga", (bucket, continued), "jnp:gather_mixed",
                 lambda *a: self._prefill_final_body(
                     *a[:13], continued=continued, positions=a[13]),
                 donate_argnums=(3, 4, 10))
@@ -2970,7 +2958,8 @@ class Engine:
     def _get_ga_rotate_fn(self):
         fn = self._fork_fns.get("ga_rotate")
         if fn is None:
-            fn = jax.jit(
+            fn = self._program(
+                "kv_ga_rotate", None, "none",
                 lambda ck, slot, deltas: llama.shift_cache_positions(
                     ck, self.cfg, slot, deltas),
                 donate_argnums=(0,))
@@ -2984,7 +2973,9 @@ class Engine:
         key = ("mm", bucket, pbucket)
         fn = self._chunk_fns.get(key)
         if fn is None:
-            fn = jax.jit(self._prefill_chunk_body, donate_argnums=(3, 4))
+            fn = self._program(
+                "prefill_chunk_mm", (bucket, pbucket), "jnp:gather_mixed",
+                self._prefill_chunk_body, donate_argnums=(3, 4))
             self._chunk_fns[key] = fn
         return fn
 
@@ -2992,7 +2983,9 @@ class Engine:
         key = ("mm", bucket, pbucket, continued)
         fn = self._final_fns.get(key)
         if fn is None:
-            fn = jax.jit(
+            fn = self._program(
+                "prefill_final_mm", (bucket, pbucket, continued),
+                "jnp:gather_mixed" if continued else "jnp:causal",
                 lambda *a: self._prefill_final_body(*a[:13], continued=continued,
                                                     mm_pos=a[13], mm_vec=a[14]),
                 donate_argnums=(3, 4, 10))
@@ -3271,16 +3264,6 @@ class Engine:
                     "post-drain kv audit failed")
         if self._bus is not None:
             self._bus.close()
-        if self._trace and self._tstats:
-            import sys
-
-            total = sum(v[0] for k, v in self._tstats.items()
-                        if k != "burst_steps")
-            for k, (sec, n) in sorted(self._tstats.items(),
-                                      key=lambda kv: -kv[1][0]):
-                print(f"[engine-trace] {k:14s} {sec:8.2f}s n={n:<7d} "
-                      f"avg={sec/max(n,1)*1e3:7.2f}ms", file=sys.stderr)
-            print(f"[engine-trace] traced total {total:.2f}s", file=sys.stderr)
         # close every consumer: queued requests and still-active slots
         while True:
             try:
@@ -3764,6 +3747,13 @@ class Engine:
             "warm": self._cobs.snapshot()["warm"],
             "compiles": self._cobs.snapshot(),
             "last_compiles": self._cobs.last_compiles(),
+            "compiles_by_kind": self._cobs.by_kind(),
+            # per-span totals since process start (they survive the
+            # ring's wrap) and from when the ring is complete
+            "trace": self.tracer.summary(),
+            # the last profiler capture (runner.Profile): where it is and
+            # the clock anchor that places ring spans on its timeline
+            "profile": self.profile_state,
             "watermarks": self._wm.snapshot(),
             "goodput": self._goodput.snapshot(),
             "weight_bytes": self._weight_bytes,
@@ -3982,43 +3972,55 @@ class Engine:
                         self.replica_id)
 
     def _run_ticks(self, t_wm: float):
+        # Every stretch of the loop is inside one tick_* phase span on
+        # track "sched" (PERF.md section 3 lists them), so that an idle
+        # gap of the device in a profiler capture names what the host was
+        # doing: tick_idle_wait is "nothing to do", every other phase is
+        # host work the device may be waiting for. The tick span, their
+        # parent, is recorded when the tick did something.
+        span = self.tracer.span
         while not self._stop:
             try:
-                t0 = time.monotonic()
-                t_tick = t0
+                t_tick = time.monotonic()
+                self._tick_prefill_tokens = self._tick_decode_tokens = 0
                 if FAULTS.active and FAULTS.take(self._die_fault) is not None:
                     raise _ReplicaDead()
-                # live migration out (ISSUE 14): eject requested streams
-                # at the tick top — previous tick fully processed, so
-                # the pause point is a burst boundary like any preempt
-                if self._migrate_req:
-                    self._process_migrations()
-                # prefill/decode disaggregation (ISSUE 17): on a
-                # prefill-role engine, slots whose prefill completed
-                # (first token out) retire to the cluster transport at
-                # the same burst boundary migration uses
-                if self._disagg_prefill and self.disagg_handoff is not None:
-                    self._process_disagg()
-                if t0 - t_wm > 0.5:
-                    # watermark fold (ISSUE 8): cheap max() samples so
-                    # pool peaks between /metrics scrapes are not lost
-                    t_wm = t0
-                    self._sample_watermarks()
-                    if self.kv_checkpoint:
-                        # cluster mode (ISSUE 17): stream active slots'
-                        # warm chains to the host tier so a host crash
-                        # leaves them fetchable by re-adopting siblings
-                        self._checkpoint_active_chains()
-                    if self._kv_audit is not None:
-                        # online KV invariant audit (ISSUE 15): same
-                        # cadence, same thread — the mirrors are between
-                        # ticks, so the O(num_pages) scans see a
-                        # consistent pool
-                        self._kv_audit_tick()
-                # emitter-detected stop finishes land as notes (ISSUE 9);
-                # apply before admission so the freed slots are admittable
-                # this very tick
-                self._apply_emitter_notes()
+                with span("tick_housekeeping", "sched"):
+                    # live migration out (ISSUE 14): eject requested
+                    # streams at the tick top — previous tick fully
+                    # processed, so the pause point is a burst boundary
+                    # like any preempt
+                    if self._migrate_req:
+                        self._process_migrations()
+                    # prefill/decode disaggregation (ISSUE 17): on a
+                    # prefill-role engine, slots whose prefill completed
+                    # (first token out) retire to the cluster transport
+                    # at the same burst boundary migration uses
+                    if self._disagg_prefill and \
+                            self.disagg_handoff is not None:
+                        self._process_disagg()
+                    if t_tick - t_wm > 0.5:
+                        # watermark fold (ISSUE 8): cheap max() samples
+                        # so pool peaks between /metrics scrapes are not
+                        # lost
+                        t_wm = t_tick
+                        self._sample_watermarks()
+                        if self.kv_checkpoint:
+                            # cluster mode (ISSUE 17): stream active
+                            # slots' warm chains to the host tier so a
+                            # host crash leaves them fetchable by
+                            # re-adopting siblings
+                            self._checkpoint_active_chains()
+                        if self._kv_audit is not None:
+                            # online KV invariant audit (ISSUE 15): same
+                            # cadence, same thread — the mirrors are
+                            # between ticks, so the O(num_pages) scans
+                            # see a consistent pool
+                            self._kv_audit_tick()
+                    # emitter-detected stop finishes land as notes
+                    # (ISSUE 9); apply before admission so the freed
+                    # slots are admittable this very tick
+                    self._apply_emitter_notes()
                 # pick up whatever completed while the previous tick was
                 # packing/dispatching BEFORE spending this tick's host
                 # time — ready bursts otherwise pay a full tick of
@@ -4027,26 +4029,34 @@ class Engine:
                 # expensive enough that extra drain points would starve
                 # dispatch, so emitter=0 keeps the seed cadence.
                 ev_mode = self._emitter is not None
-                drained0 = self._drain_fifo(block=False) if ev_mode \
-                    else False
-                admitted = self._admit()
-                self._tmark("admit", t0)
+                drained0 = False
+                if ev_mode:
+                    with span("tick_drain", "sched"):
+                        drained0 = self._drain_fifo(block=False)
+                with span("tick_admit", "sched"):
+                    admitted = self._admit()
                 if self._prefetch is not None:
                     # prefetch-ahead for the requests STILL queued after
                     # this tick's admissions (ISSUE 16): their host-tier
                     # restores overlap the decode work dispatched below
-                    self._prefetch_tick()
-                t0 = time.monotonic()
-                prefilled = self._prefill_step()
-                self._tmark("prefill", t0)
+                    with span("tick_prefetch", "sched"):
+                        self._prefetch_tick()
+                with span("tick_prefill_pack", "sched"):
+                    prefilled = self._prefill_step()
                 # prompt packing is the longest host stretch of the tick;
                 # collect anything that completed under it (no-op when
                 # nothing is ready)
                 if ev_mode:
-                    drained0 |= self._drain_fifo(block=False)
-                dispatched = self._dispatch_decode()
-                drained = self._drain_fifo(
-                    can_feed=dispatched or prefilled) or drained0
+                    with span("tick_drain", "sched"):
+                        drained0 |= self._drain_fifo(block=False)
+                with span("tick_dispatch_decode", "sched"):
+                    dispatched = self._dispatch_decode()
+                # the batch this tick's decode steps run with (a slot that
+                # finishes in the drain below was still part of it)
+                slots_active = self.num_active
+                with span("tick_drain", "sched"):
+                    drained = self._drain_fifo(
+                        can_feed=dispatched or prefilled) or drained0
                 if self.tracer.enabled and (admitted or prefilled
                                             or dispatched or drained):
                     self.tracer.record(
@@ -4054,21 +4064,29 @@ class Engine:
                         args={"admitted": int(admitted),
                               "prefilled": int(prefilled),
                               "dispatched": int(dispatched),
-                              "drained": int(drained)})
+                              "drained": int(drained),
+                              "slots_active": slots_active,
+                              "prefill_tokens": self._tick_prefill_tokens,
+                              "decode_tokens": self._tick_decode_tokens,
+                              "queued": self._queue.qsize()})
                 if not (admitted or prefilled or dispatched or drained):
                     # a dispatched item the loop is NOT blocked on (e.g. a
                     # prefill whose worker-side sync wedged) parks in the
                     # FIFO while the loop idles here — the watchdog must
                     # cover that wedge too, not just _wait_ready callers
-                    self._check_parked_stall()
-                    self._check_emitter_wedge()
+                    with span("tick_housekeeping", "sched"):
+                        self._check_parked_stall()
+                        self._check_emitter_wedge()
                     # event-driven idle (ISSUE 9): the sync worker and the
                     # emitter note channel both set _wake, so the fixed
                     # 50 ms poll tick is gone — park until woken, waking
                     # on a watchdog-scaled timeout only to re-run the
                     # stall/wedge checks above
-                    self._wake.wait(timeout=self._idle_wait_s)
-                    self._wake.clear()
+                    with span("tick_idle_wait", "sched",
+                              queued=self._queue.qsize(),
+                              in_flight=len(self._fifo)):
+                        self._wake.wait(timeout=self._idle_wait_s)
+                        self._wake.clear()
             except _DispatchStall as st:
                 # stall watchdog (ISSUE 7): a narrower failure than the
                 # generic handler below — abort ONLY the stalled item's
@@ -5026,7 +5044,8 @@ class Engine:
                 return (kvcache.tree_slot_update(ck, dst, nk),
                         kvcache.tree_slot_update(cv, dst, nv))
 
-            fn = jax.jit(body, donate_argnums=(0, 1))
+            fn = self._program("kv_fork", shape_key, "none", body,
+                               donate_argnums=(0, 1))
             self._fork_fns[shape_key] = fn
         return fn
 
@@ -5123,7 +5142,8 @@ class Engine:
                 return (kvcache.tree_slot_update(ck, slot, nk),
                         kvcache.tree_slot_update(cv, slot, nv))
 
-            fn = jax.jit(body, donate_argnums=(0, 1))
+            fn = self._program("prompt_cache_restore", None, "none", body,
+                               donate_argnums=(0, 1))
             self._fork_fns["restore"] = fn
         return fn
 
@@ -5227,8 +5247,9 @@ class Engine:
                 return (kvcache.rows_to_float(kr, jnp.float16),
                         kvcache.rows_to_float(vr, jnp.float16))
 
-            fn = jax.jit(body, static_argnums=(),
-                         out_shardings=(out_sh, out_sh) if out_sh else None)
+            fn = self._program(
+                "prompt_cache_export", n2, "none", body,
+                out_shardings=(out_sh, out_sh) if out_sh else None)
             self._fork_fns[key] = fn
         return fn
 
@@ -5535,6 +5556,7 @@ class Engine:
                     self._bus.send("chunk", bucket=bucket, tokens=tokens,
                                    seq_len=args[2], slot=args[5],
                                    start=args[6])
+            self._tick_prefill_tokens += take
             with self._annot("prefill_chunk"):
                 self.ck, self.cv = fn(*args)
             if self.dck is not None and s.spec_ok:
@@ -5623,6 +5645,7 @@ class Engine:
                                seq_len=seq_len, slots_v=slots_v,
                                start_v=start_v, ring=args[7],
                                ring_pos=args[8], spp=args[11], mu=args[12])
+        self._tick_prefill_tokens += sum(t for _g, t in group)
         with self._annot("prefill_final"):
             out_ids, logprobs, self.ck, self.cv, self.rng_keys, mu_out = \
                 fn(*args)
@@ -5753,6 +5776,7 @@ class Engine:
 
         self._pack_stats["dispatches"] += 1
         self._pack_stats["tokens"] += total
+        self._tick_prefill_tokens += total
         self._pack_stats["segments"] += len(segs)
         self._pack_stats["pad_tokens"] += bucket - total
         if continued and llama.ragged_kernel_shape_fallback(
@@ -5819,7 +5843,6 @@ class Engine:
                 # order (same contract as the legacy chunk path)
                 s.committed = s.written
                 s.t_prefill_ms += (t1 - t0) * 1e3
-        self._tmark("dispatch_packed", t0)
         self._hobserve("prefill_dispatch_seconds", t1 - t0)
         if self.tracer.enabled:
             self.tracer.record("prefill_dispatch", "engine", t0, t1,
@@ -5894,25 +5917,19 @@ class Engine:
         fn = self._get_fused_packed_fn(bucket, continued)
         spp = sampling.pack_slot_params(self.slot_params)
         ovp = self._pack_ov(ov_mask)
-        with self._annot("prefill_pack_fused"):
+        self._tick_decode_tokens += K * len(included)
+        with self._annot("prefill_pack_fused", steps=K,
+                         slots=len(included)):
             pack, self.ck, self.cv, self.rng_keys, self._chain = fn(
                 self.params, chain[0], self.ck, self.cv, chain[1],
                 chain[2], chain[3], self.bias, self.rng_keys,
                 spp, active, chain[4], ovp, *args, *meta)
-        self._tmark("dispatch_packed_fused", t0)
         self._hobserve("prefill_dispatch_seconds", time.monotonic() - t0)
         if self.tracer.enabled:
             self.tracer.record("prefill_dispatch", "engine", t0,
                                time.monotonic(),
                                args={"segments": len(segs), "bucket": bucket,
                                      "packed": True, "fused": True})
-        if self._trace:
-            s_ = self._tstats.setdefault("burst_steps", [0.0, 0])
-            s_[0] += K
-            s_[1] += 1
-            occ = self._tstats.setdefault("active_slots", [0.0, 0])
-            occ[0] += len(included)
-            occ[1] += 1
         b = _Burst(K, included, pack, group=group_snaps, t_dispatch=t0)
         self._fifo.append(b)
         self._sync_q.put(b)
@@ -6003,9 +6020,7 @@ class Engine:
         self._sync_q.put(head)
         self._wait_ready(head, t0)
         self._fifo.remove(head)
-        tp = time.monotonic()
         self._process_prefill(head)
-        self._tmark("finalize", tp)
         # a grammar rollback / context shift inside the head's emission
         # corrects host mirrors and poisons in-flight bursts by walking
         # the FIFO — the chained burst isn't dispatched yet, so it missed
@@ -6016,25 +6031,18 @@ class Engine:
         # consumed by the head, so its ov mask is all-False (pos_offset
         # still rides — it is current-host-truth every dispatch)
         burst_fn = self._get_burst_fn(K)
-        with self._annot("decode_burst"):
+        self._tick_decode_tokens += K * len(included)
+        with self._annot("decode_burst", steps=K, slots=len(included)):
             pack, self.ck, self.cv, self.rng_keys, self._chain = burst_fn(
                 self.params, chain[0], self.ck, self.cv, chain[1],
                 chain[2], chain[3], self.bias, self.rng_keys, spp,
                 active, chain[4], self._pack_ov(np.zeros((S,), np.bool_)))
-        self._tmark("dispatch_packed_split", t0)
         self._hobserve("prefill_dispatch_seconds", time.monotonic() - t0)
         if self.tracer.enabled:
             self.tracer.record("prefill_dispatch", "engine", t0,
                                time.monotonic(),
                                args={"segments": len(segs), "bucket": bucket,
                                      "packed": True, "fused": "split"})
-        if self._trace:
-            s_ = self._tstats.setdefault("burst_steps", [0.0, 0])
-            s_[0] += K
-            s_[1] += 1
-            occ = self._tstats.setdefault("active_slots", [0.0, 0])
-            occ[0] += len(included)
-            occ[1] += 1
         b = _Burst(K, included, pack, group=group_snaps, t_dispatch=t0,
                    head=head)
         b.skip_slots |= poisoned
@@ -6123,7 +6131,9 @@ class Engine:
                            spp=spp, active=active, ovp=ovp,
                            p_tokens=p_tokens, p_seq=p_seq, p_slots=p_slots,
                            p_start=p_start)
-        with self._annot("prefill_fused"):
+        self._tick_prefill_tokens += sum(t for _g, t in group)
+        self._tick_decode_tokens += K * len(included)
+        with self._annot("prefill_fused", steps=K, slots=len(included)):
             pack, self.ck, self.cv, self.rng_keys, self._chain = fn(
                 self.params, chain[0], self.ck, self.cv, chain[1],
                 chain[2], chain[3], self.bias, self.rng_keys,
@@ -6134,20 +6144,12 @@ class Engine:
             self.dck, self.dcv = self._get_draft_chunk_fn(bucket)(
                 self.draft_params, p_tokens, p_seq, self.dck, self.dcv,
                 p_slots, p_start)
-        self._tmark("dispatch_fused", t_d)
         self._hobserve("prefill_dispatch_seconds", time.monotonic() - t_d)
         if self.tracer.enabled:
             self.tracer.record("prefill_dispatch", "engine", t_d,
                                time.monotonic(),
                                args={"slots": len(group_snaps),
                                      "bucket": bucket, "fused": True})
-        if self._trace:
-            s_ = self._tstats.setdefault("burst_steps", [0.0, 0])
-            s_[0] += K
-            s_[1] += 1
-            occ = self._tstats.setdefault("active_slots", [0.0, 0])
-            occ[0] += len(included)
-            occ[1] += 1
         b = _Burst(K, included, pack, group=group_snaps, t_dispatch=t_d)
         self._fifo.append(b)
         self._sync_q.put(b)
@@ -6159,9 +6161,7 @@ class Engine:
         them as chain OVERRIDES so the next burst dispatch picks their
         state from the host mirrors without a chain rebuild."""
         if not item.ready.is_set():
-            tr = time.monotonic()
             self._wait_ready(item, item.t0)
-            self._tmark("finalize_sync", tr)
         if item.err is not None:
             raise item.err
         if item.split:
@@ -6390,9 +6390,7 @@ class Engine:
         for item in [x for x in self._fifo
                      if not isinstance(x, _Burst) and x.ready.is_set()]:
             self._fifo.remove(item)
-            t0 = time.monotonic()
             self._process_prefill(item)
-            self._tmark("finalize", t0)
             progressed = True
         synced = False
         while True:
@@ -6408,9 +6406,7 @@ class Engine:
                         break
                     synced = True
                 del self._fifo[idx]
-                t0 = time.monotonic()
                 self._process_burst(item)
-                self._tmark("process_burst", t0)
                 progressed = True
                 acted = True
                 break
@@ -6537,13 +6533,14 @@ class Engine:
         def round_step(carry, _):
             (tokens, ck, cv, dck, dcv, lengths, ring, ring_pos, keys,
              mu) = carry
-            if model_mode:
-                drafts, dck, dcv = speculative.draft_propose(
-                    dparams, self.draft_cfg, tokens, lengths, dck, dcv,
-                    spec_active, D)
-            else:
-                drafts = speculative.ngram_propose(
-                    tokens, ring, ring_pos, D, self.ecfg.spec_ngram)
+            with jax.named_scope("spec_draft"):
+                if model_mode:
+                    drafts, dck, dcv = speculative.draft_propose(
+                        dparams, self.draft_cfg, tokens, lengths, dck, dcv,
+                        spec_active, D)
+                else:
+                    drafts = speculative.ngram_propose(
+                        tokens, ring, ring_pos, D, self.ecfg.spec_ngram)
             # plain decode step for the non-spec rows (bit-identical ops
             # to _make_scan_step; spec rows masked out of the KV write)
             logits, ck, cv = self.family.engine_decode(
@@ -6559,45 +6556,46 @@ class Engine:
             # proposals scored in one continued prefill; plain rows park
             # at the OOB start so their writes drop (their single KV
             # write stays the decode step's above)
-            tin = jnp.concatenate([tokens[:, None], drafts], axis=1)
-            seq = jnp.full((S,), W, jnp.int32)
-            start = jnp.where(spec_active, lengths, C)
-            all_logits, ck, cv = self.family.prefill(
-                params, self.cfg, tin, seq, ck, cv, slot_ids, start,
-                continued=True, return_all_logits=True)
-            # filtered verify distribution via the sampler's own code
-            # path (sampling.filter_window under verify_dist): idx[:,:,0]
-            # is approx_max_k's retained global argmax with the same
-            # tie-breaks as sampling.sample's greedy path, so the greedy
-            # spec stream matches plain greedy bit-for-bit — and the
-            # window probs ARE the law plain sampling draws from, so
-            # rejection acceptance against them is distribution-lossless
-            vidx, vprobs = sampling.verify_dist(all_logits, sp,
-                                                use_typical=flags[1])
-            greedy = vidx[:, :, 0]
-            out_spec, n_spec, _k = speculative.accept_greedy(
-                drafts, greedy, spec_active)
-            logp = jax.nn.log_softmax(all_logits, axis=-1)
-            lp_spec = jnp.take_along_axis(
-                logp, out_spec[:, :, None], axis=2)[:, :, 0]
-            # ISSUE 18: sampled spec rows accept via rejection sampling.
-            # Scatter the window distribution to vocab for acceptance and
-            # residual resampling (n-gram/greedy-draft proposals are
-            # deterministic, so draft_probs=None one-hot degeneration)
-            samp_active = spec_active & ~jnp.asarray(sp["greedy"])
-            V = all_logits.shape[-1]
-            rows = jnp.arange(S * W, dtype=jnp.int32)[:, None]
-            tgt = jnp.zeros((S * W, V), jnp.float32).at[
-                rows, vidx.reshape(S * W, -1)].set(
-                vprobs.reshape(S * W, -1)).reshape(S, W, V)
-            out_ss, n_ss, _ks, keys_ss = speculative.accept_sampled(
-                drafts, tgt, None, keys, samp_active)
-            lp_ss = jnp.log(jnp.clip(jnp.take_along_axis(
-                tgt, out_ss[:, :, None], axis=2)[:, :, 0], 1e-20))
-            out_spec = jnp.where(samp_active[:, None], out_ss, out_spec)
-            n_spec = jnp.where(samp_active, n_ss, n_spec)
-            lp_spec = jnp.where(samp_active[:, None], lp_ss, lp_spec)
-            keys = jnp.where(samp_active[:, None], keys_ss, keys)
+            with jax.named_scope("spec_verify"):
+                tin = jnp.concatenate([tokens[:, None], drafts], axis=1)
+                seq = jnp.full((S,), W, jnp.int32)
+                start = jnp.where(spec_active, lengths, C)
+                all_logits, ck, cv = self.family.prefill(
+                    params, self.cfg, tin, seq, ck, cv, slot_ids, start,
+                    continued=True, return_all_logits=True)
+                # filtered verify distribution via the sampler's own code
+                # path (sampling.filter_window under verify_dist): idx[:,:,0]
+                # is approx_max_k's retained global argmax with the same
+                # tie-breaks as sampling.sample's greedy path, so the greedy
+                # spec stream matches plain greedy bit-for-bit — and the
+                # window probs ARE the law plain sampling draws from, so
+                # rejection acceptance against them is distribution-lossless
+                vidx, vprobs = sampling.verify_dist(all_logits, sp,
+                                                    use_typical=flags[1])
+                greedy = vidx[:, :, 0]
+                out_spec, n_spec, _k = speculative.accept_greedy(
+                    drafts, greedy, spec_active)
+                logp = jax.nn.log_softmax(all_logits, axis=-1)
+                lp_spec = jnp.take_along_axis(
+                    logp, out_spec[:, :, None], axis=2)[:, :, 0]
+                # ISSUE 18: sampled spec rows accept via rejection sampling.
+                # Scatter the window distribution to vocab for acceptance and
+                # residual resampling (n-gram/greedy-draft proposals are
+                # deterministic, so draft_probs=None one-hot degeneration)
+                samp_active = spec_active & ~jnp.asarray(sp["greedy"])
+                V = all_logits.shape[-1]
+                rows = jnp.arange(S * W, dtype=jnp.int32)[:, None]
+                tgt = jnp.zeros((S * W, V), jnp.float32).at[
+                    rows, vidx.reshape(S * W, -1)].set(
+                    vprobs.reshape(S * W, -1)).reshape(S, W, V)
+                out_ss, n_ss, _ks, keys_ss = speculative.accept_sampled(
+                    drafts, tgt, None, keys, samp_active)
+                lp_ss = jnp.log(jnp.clip(jnp.take_along_axis(
+                    tgt, out_ss[:, :, None], axis=2)[:, :, 0], 1e-20))
+                out_spec = jnp.where(samp_active[:, None], out_ss, out_spec)
+                n_spec = jnp.where(samp_active, n_ss, n_spec)
+                lp_spec = jnp.where(samp_active[:, None], lp_ss, lp_spec)
+                keys = jnp.where(samp_active[:, None], keys_ss, keys)
             pad = jnp.zeros((S, D), jnp.int32)
             out = jnp.where(spec_mask[:, None], out_spec,
                             jnp.concatenate([ids0[:, None], pad], axis=1))
@@ -6643,10 +6641,9 @@ class Engine:
             fn = self._program(
                 "spec_tick", (n_rounds, flags),
                 f"{self._decode_attn()} + verify jnp:gather_mixed",
-                jax.jit(
-                    lambda *a: self._spec_tick_body(*a, n_rounds=n_rounds,
-                                                    flags=flags),
-                    donate_argnums=donate))
+                lambda *a: self._spec_tick_body(*a, n_rounds=n_rounds,
+                                                flags=flags),
+                donate_argnums=donate)
             self._burst_fns[key] = fn
         return fn
 
@@ -6799,7 +6796,11 @@ class Engine:
             self._bus.send("burst", k=n_steps, flags=flags,
                            chain=chain if cold else None,
                            spp=spp, active=active, ovp=ovp)
-        with self._annot("decode_burst"):
+        self._tick_decode_tokens += n_steps * len(included)
+        with self._annot(
+                "decode_burst", steps=n_steps, slots=len(included),
+                **({"spec_slots": int(spec_mask.sum()), "spec_width": W}
+                   if plan is not None else {})):
             if plan is None:
                 pack, self.ck, self.cv, self.rng_keys, self._chain = fn(
                     self.params, chain[0], self.ck, self.cv, chain[1],
@@ -6820,23 +6821,6 @@ class Engine:
                     chain[2], chain[3], self.bias, self.rng_keys,
                     spp, active, chain[4], ovp, spec_mask,
                 )
-        self._tmark("dispatch", t_d)
-        if self.tracer.enabled:
-            self.tracer.record(
-                "decode_dispatch", "engine", t_d, time.monotonic(),
-                args={"steps": n_steps, "slots": len(included),
-                      **({"spec_slots": int(spec_mask.sum()),
-                          "spec_width": W} if plan is not None else {})})
-        if self._trace:
-            s = self._tstats.setdefault("burst_steps", [0.0, 0])
-            s[0] += n_steps
-            s[1] += 1
-            # occupancy: the compiled step computes ALL slots, so every
-            # inactive slot wastes 1/S of the burst — this stat is the
-            # device-waste diagnostic (avg = slots riding per burst)
-            occ = self._tstats.setdefault("active_slots", [0.0, 0])
-            occ[0] += len(included)
-            occ[1] += 1
         b = _Burst(n_steps, burst_slots, pack, t_dispatch=t_d)
         if plan is not None:
             b.spec_mask = spec_mask
@@ -6862,13 +6846,11 @@ class Engine:
         so it can overlap the NEXT dispatch."""
         if b.folded:
             return
-        t0 = time.monotonic()
         if not b.ready.is_set():
             self._wait_ready(b, b.t_dispatch)   # worker-side sync in flight
         if b.err is not None:
             raise b.err
         packed = b.pack_np                  # [2K+1(+2), S] f32
-        self._tmark("burst_wait", t0)
         K = b.n_steps
         if b.spec_width:
             # spec tick pack: ids/lps are [R*W, S] round-major, then the
@@ -7005,24 +6987,24 @@ class Engine:
                                / max(1.0, steps))
             self._t_last_burst = t_proc
             if tr.enabled:
+                live = [(i, snap.req.request_id) for i, snap in b.slots
+                        if self._live(i, snap) and i not in b.skip_slots]
+                # the slots that rode the burst and their requests:
+                # chrome_trace draws the slot tracks from these
                 tr.record("decode_burst_device", "engine",
                           b.t_dispatch, t_rdy,
                           args={"steps": b.n_steps, "slots": len(b.slots),
                                 "fused": bool(b.group),
-                                "spec": bool(b.spec_width)})
+                                "spec": bool(b.spec_width),
+                                "slot_ids": [i for i, _ in live],
+                                "rids": [r for _, r in live]})
                 if b.spec_width:
-                    # spec_round span, split draft-vs-verify so decomp_ms
-                    # attributes speculation honestly. The split is
-                    # ANALYTIC (the fused program has no host-visible
-                    # internal boundary): the model drafter runs D of the
-                    # round's D+1 sequential forwards, the n-gram match
-                    # is a fixed small slice of the round
+                    # the fused program has no host-visible boundary
+                    # between drafting and verifying: on the device they
+                    # are the named scopes spec_draft / spec_verify
                     nsp = b.n_out_np
                     spec_idx = [i for i, _s in b.slots if b.spec_mask[i]]
                     tot = int(sum(int(nsp[:, i].sum()) for i in spec_idx))
-                    share = ((b.spec_width - 1) / b.spec_width
-                             if self._spec_mode == "model" else 0.1)
-                    mid = b.t_dispatch + (t_rdy - b.t_dispatch) * share
                     tr.record("spec_round", "engine", b.t_dispatch, t_rdy,
                               args={"mode": self._spec_mode,
                                     "rounds": b.n_steps,
@@ -7032,16 +7014,7 @@ class Engine:
                                     "accepted": max(
                                         0, tot - b.n_steps
                                         * len(spec_idx))})
-                    tr.record("spec_draft", "engine", b.t_dispatch, mid,
-                              args={"analytic": True})
-                    tr.record("spec_verify", "engine", mid, t_rdy,
-                              args={"analytic": True})
                 tr.record("finish_detect", "engine", t_rdy, t_proc)
-                for i, snap in b.slots:
-                    if self._live(i, snap) and i not in b.skip_slots:
-                        tr.record("decode", f"slot{i}", b.t_dispatch, t_rdy,
-                                  rid=snap.req.request_id,
-                                  args={"steps": b.n_steps})
         # emitter mode hands tokens over as one immutable batch instead
         # of coalescing events in-loop (ISSUE 9)
         self._sink_buf = {} if self._emitter is None else None
@@ -7116,7 +7089,6 @@ class Engine:
                             rolled.add(i)
         finally:
             buf, self._sink_buf = self._sink_buf, None
-            self._tmark("emit_loop", t0)
             self._flush_grammar_bias()
             self._flush_em_batch()
             t0 = time.monotonic()
@@ -7130,7 +7102,6 @@ class Engine:
             if buf:
                 for (_slot, out), evs in buf.items():
                     out.put(evs[0] if len(evs) == 1 else _merge_events(evs))
-            self._tmark("emit_flush", t0)
             if tr.enabled:
                 tr.record("stream_flush", "engine", t0, time.monotonic(),
                           args={"streams": len(buf) if buf else 0})

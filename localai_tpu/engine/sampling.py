@@ -357,6 +357,7 @@ def filter_window(logits, slot_params, ring, ring_pos, logit_bias, mu=None,
     return idx, masked, vals
 
 
+@jax.named_scope("sample")   # the scope device time is sorted by (PERF.md 3)
 def sample(logits, slot_params, ring, ring_pos, logit_bias, rng_keys, mu=None,
            use_penalties: bool = True, use_typical: bool = True,
            use_mirostat: bool = True):

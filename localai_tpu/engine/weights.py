@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from localai_tpu.services.tracing import NO_TRACER
+
 log = logging.getLogger("localai_tpu.weights")
 
 try:
@@ -68,11 +70,20 @@ _QUANT_NAMES = {"embed", "lm_head", "wq", "wk", "wv", "wo",
                 "w_gate", "w_up", "w_down"}
 
 
-def _make_put(cfg, mesh, dtype, quantize, adapter=None, pace=None):
+def _make_put(cfg, mesh, dtype, quantize, adapter=None, pace=None,
+              tracer=None):
     """Leaf placer: host array + pytree path -> (LoRA-merged) cast /
     int8/int4-quantized / mesh-sharded device leaf. ``pace`` (streaming
     loads, ISSUE 19) is called with each host leaf before placement —
-    the accounting/chaos/yield seam of ``stream_llama_params``."""
+    the accounting/chaos/yield seam of ``stream_llama_params``.
+
+    ``tracer`` (the runner's RingTracer) gets, per leaf, ``load_quantize``
+    (ops/quant.py::quantize_weight*, which also hands the int8 array to
+    the device), ``load_cast`` (``jnp.asarray(arr, dtype)``: the cast and,
+    off a mesh, the hand-over to the device in one call) and ``load_put``
+    (the mesh placement). Host calls only: nothing here waits for the
+    device (the runner waits once, ``load_device_wait``)."""
+    span = (tracer or NO_TRACER).span
 
     def leaf_spec(spec_path: tuple):
         from localai_tpu.parallel import sharding as shardlib
@@ -111,11 +122,15 @@ def _make_put(cfg, mesh, dtype, quantize, adapter=None, pace=None):
                                     leaf_spec(spec_path))[-2]
                     if axis is not None:
                         divisor = mesh.shape[axis]
-                leaf = quantize_weight_int4(arr, shard_divisor=divisor)
+                with span("load_quantize", "load", leaf=leaf_name,
+                          bits=4):
+                    leaf = quantize_weight_int4(arr, shard_divisor=divisor)
             else:
-                leaf = quantize_weight(arr)
+                with span("load_quantize", "load", leaf=leaf_name, bits=8):
+                    leaf = quantize_weight(arr)
         else:
-            leaf = jnp.asarray(arr, dtype)
+            with span("load_cast", "load", leaf=leaf_name):
+                leaf = jnp.asarray(arr, dtype)
         if mesh is not None:
             from jax.sharding import NamedSharding
             from localai_tpu.ops.quant import scale_spec
@@ -124,23 +139,36 @@ def _make_put(cfg, mesh, dtype, quantize, adapter=None, pace=None):
             node = fit_spec(
                 mesh, (leaf["q"] if isinstance(leaf, dict) else leaf).shape,
                 leaf_spec(spec_path))
-            if isinstance(leaf, dict):
-                q = jax.device_put(leaf["q"], NamedSharding(mesh, node))
-                s = jax.device_put(leaf["s"], NamedSharding(
-                    mesh, scale_spec(leaf, node)))
-                return {"q": q, "s": s}
-            return jax.device_put(leaf, NamedSharding(mesh, node))
+            with span("load_put", "load", leaf=leaf_name):
+                if isinstance(leaf, dict):
+                    q = jax.device_put(leaf["q"], NamedSharding(mesh, node))
+                    s = jax.device_put(leaf["s"], NamedSharding(
+                        mesh, scale_spec(leaf, node)))
+                    return {"q": q, "s": s}
+                return jax.device_put(leaf, NamedSharding(mesh, node))
         return leaf
 
     return put
 
 
-def _assemble(source, put) -> dict:
+def _assemble(source, put, tracer=None) -> dict:
     """Fold a (spec_path, host array) stream into the stacked pytree,
     placing each leaf as it arrives and freeing the host copy — peak
-    host memory is one stacked leaf, not the dense model."""
+    host memory is one stacked leaf, not the dense model. Each pull from
+    ``source`` (read, stack, transpose: _host_leaf_source is lazy) is one
+    ``load_source`` span."""
+    span = (tracer or NO_TRACER).span
     params: dict = {"layers": {}}
-    for spec_path, arr in source:
+    it = iter(source)
+    while True:
+        with span("load_source", "load") as sp:
+            item = next(it, None)
+            if item is not None:
+                sp.args["leaf"] = item[0][-1]
+        if item is None:
+            break
+        spec_path, arr = item
+        del item
         node = params
         for k in spec_path[:-1]:
             node = node[k]
@@ -234,6 +262,7 @@ def load_llama_params(
     quantize: str = "",
     lora_adapter: str = "",
     lora_scale: float = 1.0,
+    tracer=None,
 ) -> dict:
     """Load HF llama/mistral/qwen2-style weights into the stacked pytree.
 
@@ -251,7 +280,8 @@ def load_llama_params(
 
     adapter = maybe_adapter(lora_adapter, lora_scale)
     source, quantize = _host_leaf_source(model_dir, cfg, quantize)
-    return _assemble(source, _make_put(cfg, mesh, dtype, quantize, adapter))
+    return _assemble(source, _make_put(cfg, mesh, dtype, quantize, adapter,
+                                       tracer=tracer), tracer)
 
 
 def stream_llama_params(
@@ -263,6 +293,7 @@ def stream_llama_params(
     lora_adapter: str = "",
     lora_scale: float = 1.0,
     prefetcher: "Optional[WeightPrefetcher]" = None,
+    tracer=None,
 ) -> tuple:
     """Streaming variant of :func:`load_llama_params` -> (params, stats).
 
@@ -306,7 +337,8 @@ def stream_llama_params(
     else:
         source, quantize = _host_leaf_source(model_dir, cfg, quantize)
     params = _assemble(
-        source, _make_put(cfg, mesh, dtype, quantize, adapter, pace=pace))
+        source, _make_put(cfg, mesh, dtype, quantize, adapter, pace=pace,
+                          tracer=tracer), tracer)
     stats["ms"] = (time.monotonic() - t0) * 1000.0
     return params, stats
 

@@ -136,9 +136,11 @@ class LoadedModel:
         # operator-requested shutdown from a crash it must respawn
         self.intentional_stop = False
         self.supervisor: Optional[threading.Thread] = None
-        # cross-process clock handshake (ISSUE 12): offset/rtt measured
-        # around LoadModel, used to shift backend trace timestamps onto
-        # the frontend timeline. {} when the backend sent no handshake
+        # cross-process clock (ISSUE 12): the offset that shifts backend
+        # trace timestamps onto the frontend timeline. A backend this
+        # manager started on this machine shares its wall clock: offset
+        # 0. A remote one is measured over Health round trips after the
+        # load (measure_clock). {} when the backend sent no handshake
         # (e.g. FakeServicer's plain "loaded") — merge then falls back
         # to raw epochs. Re-measured automatically on respawn because
         # every spawn goes through _spawn_and_load.
@@ -394,9 +396,7 @@ class ModelLoader:
         client, process, server = self._connect_backend(backend_name)
         try:
             self._wait_healthy(client, process)
-            t_send = time.time()
             res = client.load_model(model_opts)
-            t_recv = time.time()
             if not res.success:
                 raise RuntimeError(f"LoadModel failed: {res.message}")
         except Exception as e:
@@ -416,7 +416,11 @@ class ModelLoader:
                     f"last stderr:\n{process.stderr_tail()}") from e
             raise
         lm = LoadedModel(model_id, backend_name, client, process, server)
-        lm.clock = _parse_handshake(res.message, t_send, t_recv)
+        lm.clock = _parse_handshake(res.message)
+        if lm.clock:
+            local = process is not None or server is not None
+            lm.clock.update(measure_clock(
+                None if local else client.health_clock))
         lm.watchdog = self.watchdog
         if self.watchdog is not None:
             self.watchdog.add(model_id, lm)
@@ -518,19 +522,13 @@ class ModelLoader:
             self._close_lm(lm)
 
 
-def _parse_handshake(message: str, t_send: float, t_recv: float) -> dict:
-    """Clock-offset handshake from a LoadModel reply (ISSUE 12).
-
-    The backend stamps its wall clock inside the Result.message JSON;
-    the midpoint of the RPC round-trip is the best single-sample
-    estimate of WHEN that stamp was taken on the frontend's clock, so
-
-        offset_s = backend_wall - (t_send + t_recv) / 2
-
-    with the full round-trip as the honest uncertainty bound (the true
-    offset lies within ±rtt/2 of the estimate). Backends that reply
-    with a plain string (FakeServicer's "loaded", older runners) yield
-    {} — merged traces then fall back to raw epoch alignment."""
+def _parse_handshake(message: str) -> dict:
+    """The backend's identity from a LoadModel reply (ISSUE 12): its pid
+    and trace epoch. Backends that reply with a plain string
+    (FakeServicer's "loaded", older runners) yield {} — merged traces
+    then fall back to raw epoch alignment. The clock offset is NOT taken
+    from this reply: a load lasts minutes, and half of its round trip
+    was the estimate's error (measure_clock)."""
     try:
         doc = __import__("json").loads(message)
         hs = doc.get("handshake") or {}
@@ -538,13 +536,41 @@ def _parse_handshake(message: str, t_send: float, t_recv: float) -> dict:
     except (ValueError, TypeError, KeyError, AttributeError):
         return {}
     return {
-        "offset_s": bw - (t_send + t_recv) / 2.0,
-        "rtt_s": max(0.0, t_recv - t_send),
         "backend_wall": bw,
         "backend_pid": int(hs.get("pid", 0) or 0),
         "trace_epoch": float(hs.get("trace_epoch", 0.0) or 0.0),
-        "measured_at": t_recv,
     }
+
+
+def measure_clock(probe, trips: int = 3) -> dict:
+    """Backend-minus-frontend wall-clock offset.
+
+    ``probe`` None: the backend runs on this machine (the manager spawned
+    or embedded it) and shares its wall clock: offset 0. Otherwise
+    ``probe()`` makes one Health round trip and returns the backend's
+    wall clock as stamped in the reply; the midpoint of a round trip is
+    the best estimate of when the stamp was taken, so
+
+        offset_s = backend_wall - (t_send + t_recv) / 2
+
+    with the true offset within +-rtt/2 of it. Of ``trips`` round trips
+    the shortest wins. A backend that stamps nothing (0.0) or fails the
+    probe yields offset 0."""
+    out = {"offset_s": 0.0, "rtt_s": 0.0, "local": probe is None}
+    best = None
+    for _ in range(trips if probe is not None else 0):
+        t_send = time.time()
+        try:
+            wall = float(probe())
+        except Exception:
+            continue
+        t_recv = time.time()
+        if wall > 0 and (best is None or t_recv - t_send < best[0]):
+            best = (t_recv - t_send, wall - (t_send + t_recv) / 2.0)
+    if best is not None:
+        out["rtt_s"], out["offset_s"] = max(0.0, best[0]), best[1]
+    out["measured_at"] = time.time()
+    return out
 
 
 def _looks_like_addr(target: str) -> bool:

@@ -293,6 +293,12 @@ def dequantize_params(params: dict, dtype=jnp.bfloat16) -> dict:
     return out
 
 
+# Scope names on everything the device runs (PERF.md section 3): a
+# profiler capture's operations carry them in their op_name path, and
+# benchmark/reduce_named.py sorts device time by them. Metadata only.
+_scope = jax.named_scope
+
+
 def _project_qkv(x, layer, cfg: LlamaConfig):
     """x: [B, T, D] -> q [B,T,H,hd], k/v [B,T,KV,hd]."""
     B, T, _ = x.shape
@@ -356,7 +362,8 @@ def prefill(
     if positions is None:
         positions = start_pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
     sin, cos = rope_frequencies(cfg, positions)
-    x = _embed_rows(params["embed"], tokens, cfg.dtype)
+    with _scope("embed"):
+        x = _embed_rows(params["embed"], tokens, cfg.dtype)
     if mm_pos is not None:
         bidx = jnp.arange(B, dtype=jnp.int32)[:, None] * jnp.ones_like(mm_pos)
         x = x.at[bidx, mm_pos].set(mm_vec.astype(cfg.dtype), mode="drop")
@@ -365,10 +372,22 @@ def prefill(
     def layer_fn(carry, layer):
         x, ck, cv = carry
         li = layer.pop("_idx")
-        h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = _project_qkv(h, layer, cfg)
-        q = apply_rope(q, sin, cos)
-        k = apply_rope(k, sin, cos)
+        with _scope("layer/attn_proj"):
+            h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
+            q, k, v = _project_qkv(h, layer, cfg)
+            q = apply_rope(q, sin, cos)
+            k = apply_rope(k, sin, cos)
+        with _scope("layer/attn"):
+            attn, ck, cv = attend_write(q, k, v, ck, cv, li)
+        with _scope("layer/attn_proj"):
+            x = x + jnp.einsum("bth,hd->btd", attn.reshape(B, T, -1),
+                               _mat(layer["wo"], x.dtype))
+        with _scope("layer/mlp"):
+            h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
+            x = x + _mlp(h, layer)
+        return (x, ck, cv), None
+
+    def attend_write(q, k, v, ck, cv, li):
         if continued:
             # continued prefix: committed keys live in the cache. Rows are
             # read BEFORE this chunk's scatter (attention combines them
@@ -395,22 +414,23 @@ def prefill(
         cols = start_pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]  # [B, T]
         ck = kvcache.scatter_prefill(ck, li, rows, cols, k)
         cv = kvcache.scatter_prefill(cv, li, rows, cols, v)
-        x = x + jnp.einsum("bth,hd->btd", attn.reshape(B, T, -1), _mat(layer["wo"], x.dtype))
-        h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
-        x = x + _mlp(h, layer)
-        return (x, ck, cv), None
+        return attn, ck, cv
 
     layers = dict(params["layers"])
     layers["_idx"] = jnp.arange(cfg.num_layers, dtype=jnp.int32)
     (x, cache_k, cache_v), _ = jax.lax.scan(layer_fn, (x, cache_k, cache_v), layers)
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    with _scope("final_norm"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     # gather hidden state at the last valid position of each prompt
     if return_all_logits:
         # [B, T, V] — used by speculative verification (every draft
         # position needs the target's next-token distribution)
-        return _unembed(x, params, cfg), cache_k, cache_v
-    last = jnp.take_along_axis(x, (seq_lens - 1)[:, None, None].astype(jnp.int32), axis=1)
-    logits = _unembed(last, params, cfg)[:, 0, :]
+        with _scope("lm_head"):
+            return _unembed(x, params, cfg), cache_k, cache_v
+    with _scope("lm_head"):
+        last = jnp.take_along_axis(
+            x, (seq_lens - 1)[:, None, None].astype(jnp.int32), axis=1)
+        logits = _unembed(last, params, cfg)[:, 0, :]
     return logits, cache_k, cache_v
 
 
@@ -483,7 +503,8 @@ def ragged_prefill(
     B = seg_slots.shape[0]
     rp = positions if rope_positions is None else rope_positions
     sin, cos = rope_frequencies(cfg, rp[None, :])
-    x = _embed_rows(params["embed"], tokens, cfg.dtype)[None]   # [1, N, D]
+    with _scope("embed"):
+        x = _embed_rows(params["embed"], tokens, cfg.dtype)[None]   # [1, N, D]
     # per-token target slot for the ragged KV scatter (pads ride the
     # clipped lookup; their position sentinel drops the write)
     slot_of = jnp.take(seg_slots, jnp.minimum(seg_of, B - 1))
@@ -491,10 +512,34 @@ def ragged_prefill(
     def layer_fn(carry, layer):
         x, ck, cv = carry
         li = layer.pop("_idx")
-        h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = _project_qkv(h, layer, cfg)     # [1, N, {H|KV}, hd]
-        q = apply_rope(q, sin, cos)
-        k = apply_rope(k, sin, cos)
+        with _scope("layer/attn_proj"):
+            h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
+            q, k, v = _project_qkv(h, layer, cfg)     # [1, N, {H|KV}, hd]
+            q = apply_rope(q, sin, cos)
+            k = apply_rope(k, sin, cos)
+        with _scope("layer/attn"):
+            attn, ck, cv = attend_write(q, k, v, ck, cv, li)
+        attn_r = attn[None].reshape(1, N, -1)
+
+        def out_proj(t):
+            with _scope("layer/attn_proj"):
+                return jnp.einsum("bth,hd->btd", t,
+                                  _mat(layer["wo"], x.dtype))
+
+        def mlp_half(t):
+            with _scope("layer/mlp"):
+                return _mlp(rms_norm(t, layer["mlp_norm"],
+                                     cfg.rms_norm_eps), layer)
+
+        if comm_overlap:
+            x = x + overlap_halves(out_proj, attn_r, axis=1)
+            x = x + overlap_halves(mlp_half, x, axis=1)
+        else:
+            x = x + out_proj(attn_r)
+            x = x + mlp_half(x)
+        return (x, ck, cv), None
+
+    def attend_write(q, k, v, ck, cv, li):
         lck, lcv = kvcache.layer(ck, li), kvcache.layer(cv, li)
         # committed rows are read BEFORE this pack's scatter (the same
         # no-read-after-write rule as every other attention path here)
@@ -518,32 +563,19 @@ def ragged_prefill(
                 cfg.q_per_kv, continued=continued)
         ck = kvcache.scatter_ragged(ck, li, slot_of, positions, k[0])
         cv = kvcache.scatter_ragged(cv, li, slot_of, positions, v[0])
-        attn_r = attn[None].reshape(1, N, -1)
-
-        def out_proj(t):
-            return jnp.einsum("bth,hd->btd", t, _mat(layer["wo"], x.dtype))
-
-        def mlp_half(t):
-            return _mlp(rms_norm(t, layer["mlp_norm"], cfg.rms_norm_eps),
-                        layer)
-
-        if comm_overlap:
-            x = x + overlap_halves(out_proj, attn_r, axis=1)
-            x = x + overlap_halves(mlp_half, x, axis=1)
-        else:
-            x = x + out_proj(attn_r)
-            x = x + mlp_half(x)
-        return (x, ck, cv), None
+        return attn, ck, cv
 
     layers = dict(params["layers"])
     layers["_idx"] = jnp.arange(cfg.num_layers, dtype=jnp.int32)
     (x, cache_k, cache_v), _ = jax.lax.scan(layer_fn, (x, cache_k, cache_v),
                                             layers)
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    # hidden state at each segment's LAST packed token (pads clamp to 0)
-    last = jnp.maximum(seg_off + seg_len - 1, 0)
-    hs = jnp.take(x[0], last, axis=0)                           # [B, D]
-    logits = _unembed(hs[None], params, cfg)[0]
+    with _scope("final_norm"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    with _scope("lm_head"):
+        # hidden state at each segment's LAST packed token (pads clamp to 0)
+        last = jnp.maximum(seg_off + seg_len - 1, 0)
+        hs = jnp.take(x[0], last, axis=0)                       # [B, D]
+        logits = _unembed(hs[None], params, cfg)[0]
     return logits, cache_k, cache_v
 
 
@@ -648,31 +680,39 @@ def decode_step(
     if pos_offset is not None:
         positions = positions - pos_offset[:, None]
     sin, cos = rope_frequencies(cfg, positions)
-    x = _embed_rows(params["embed"], tokens, cfg.dtype)[:, None, :]  # [S,1,D]
+    with _scope("embed"):
+        x = _embed_rows(params["embed"], tokens, cfg.dtype)[:, None, :]  # [S,1,D]
     C = kvcache.shape(cache_k)[2]
 
     def layer_fn(carry, layer):
         x, ck, cv = carry
         li = layer.pop("_idx")
-        h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = _project_qkv(h, layer, cfg)  # q [S,1,H,hd], k/v [S,1,KV,hd]
-        q = apply_rope(q, sin, cos)
-        k = apply_rope(k, sin, cos)
-        lck, lcv = kvcache.layer(ck, li), kvcache.layer(cv, li)
-        attn, lk, lv = _decode_attend_write(q[:, 0], k[:, 0], v[:, 0],
-                                            lck, lcv, lengths, cfg)
-        ck = kvcache.set_layer(ck, li, lk)
-        cv = kvcache.set_layer(cv, li, lv)
-        x = x + jnp.einsum("sh,hd->sd", attn.reshape(S, -1), _mat(layer["wo"], x.dtype))[:, None, :]
-        h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
-        x = x + _mlp(h, layer)
+        with _scope("layer/attn_proj"):
+            h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
+            q, k, v = _project_qkv(h, layer, cfg)  # q [S,1,H,hd], k/v [S,1,KV,hd]
+            q = apply_rope(q, sin, cos)
+            k = apply_rope(k, sin, cos)
+        with _scope("layer/attn"):
+            lck, lcv = kvcache.layer(ck, li), kvcache.layer(cv, li)
+            attn, lk, lv = _decode_attend_write(q[:, 0], k[:, 0], v[:, 0],
+                                                lck, lcv, lengths, cfg)
+            ck = kvcache.set_layer(ck, li, lk)
+            cv = kvcache.set_layer(cv, li, lv)
+        with _scope("layer/attn_proj"):
+            x = x + jnp.einsum("sh,hd->sd", attn.reshape(S, -1),
+                               _mat(layer["wo"], x.dtype))[:, None, :]
+        with _scope("layer/mlp"):
+            h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
+            x = x + _mlp(h, layer)
         return (x, ck, cv), None
 
     layers = dict(params["layers"])
     layers["_idx"] = jnp.arange(cfg.num_layers, dtype=jnp.int32)
     (x, cache_k, cache_v), _ = jax.lax.scan(layer_fn, (x, cache_k, cache_v), layers)
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    logits = _unembed(x, params, cfg)[:, 0, :]
+    with _scope("final_norm"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    with _scope("lm_head"):
+        logits = _unembed(x, params, cfg)[:, 0, :]
     return logits, cache_k, cache_v
 
 
@@ -732,9 +772,10 @@ def fused_prefill_decode(
     pos_all = jnp.concatenate([dpos.reshape(1, S), ppos.reshape(1, B * T)],
                               axis=1)               # [1, S+B*T]
     sin, cos = rope_frequencies(cfg, pos_all)
-    xd = _embed_rows(params["embed"], tokens, cfg.dtype)        # [S, D]
-    xp = _embed_rows(params["embed"], pr_tokens, cfg.dtype)     # [B, T, D]
-    x = jnp.concatenate([xd, xp.reshape(B * T, D)], axis=0)[None]  # [1,N,D]
+    with _scope("embed"):
+        xd = _embed_rows(params["embed"], tokens, cfg.dtype)        # [S, D]
+        xp = _embed_rows(params["embed"], pr_tokens, cfg.dtype)     # [B, T, D]
+        x = jnp.concatenate([xd, xp.reshape(B * T, D)], axis=0)[None]  # [1,N,D]
     valid = jnp.arange(T, dtype=jnp.int32)[None, :] < pr_seq[:, None]
     rows = pr_slots[:, None] * jnp.ones((1, T), jnp.int32)
     cols = ppos
@@ -742,39 +783,46 @@ def fused_prefill_decode(
     def layer_fn(carry, layer):
         x, ck, cv = carry
         li = layer.pop("_idx")
-        h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = _project_qkv(h, layer, cfg)       # ONE weight read each
-        q = apply_rope(q, sin, cos)
-        k = apply_rope(k, sin, cos)
-        qd, qp = q[0, :S], q[0, S:].reshape(B, T, cfg.num_heads, hd)
-        kd, kp = k[0, :S], k[0, S:].reshape(B, T, cfg.num_kv_heads, hd)
-        vd, vp = v[0, :S], v[0, S:].reshape(B, T, cfg.num_kv_heads, hd)
-        lck, lcv = kvcache.layer(ck, li), kvcache.layer(cv, li)
-        attn_d, lk, lv = _decode_attend_write(qd, kd, vd, lck, lcv,
-                                              write_lengths, cfg)
-        ck = kvcache.set_layer(ck, li, lk)
-        cv = kvcache.set_layer(cv, li, lv)
-        attn_p = causal_attention(qp, kp, vp, valid, cfg.q_per_kv)
-        ck = kvcache.scatter_prefill(ck, li, rows, cols, kp)
-        cv = kvcache.scatter_prefill(cv, li, rows, cols, vp)
-        attn = jnp.concatenate([attn_d.reshape(S, -1),
-                                attn_p.reshape(B * T, -1)], axis=0)[None]
-        x = x + jnp.einsum("bth,hd->btd", attn, _mat(layer["wo"], x.dtype))
-        h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
-        x = x + _mlp(h, layer)
+        with _scope("layer/attn_proj"):
+            h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
+            q, k, v = _project_qkv(h, layer, cfg)   # ONE weight read each
+            q = apply_rope(q, sin, cos)
+            k = apply_rope(k, sin, cos)
+        with _scope("layer/attn"):
+            qd, qp = q[0, :S], q[0, S:].reshape(B, T, cfg.num_heads, hd)
+            kd, kp = k[0, :S], k[0, S:].reshape(B, T, cfg.num_kv_heads, hd)
+            vd, vp = v[0, :S], v[0, S:].reshape(B, T, cfg.num_kv_heads, hd)
+            lck, lcv = kvcache.layer(ck, li), kvcache.layer(cv, li)
+            attn_d, lk, lv = _decode_attend_write(qd, kd, vd, lck, lcv,
+                                                  write_lengths, cfg)
+            ck = kvcache.set_layer(ck, li, lk)
+            cv = kvcache.set_layer(cv, li, lv)
+            attn_p = causal_attention(qp, kp, vp, valid, cfg.q_per_kv)
+            ck = kvcache.scatter_prefill(ck, li, rows, cols, kp)
+            cv = kvcache.scatter_prefill(cv, li, rows, cols, vp)
+            attn = jnp.concatenate([attn_d.reshape(S, -1),
+                                    attn_p.reshape(B * T, -1)], axis=0)[None]
+        with _scope("layer/attn_proj"):
+            x = x + jnp.einsum("bth,hd->btd", attn,
+                               _mat(layer["wo"], x.dtype))
+        with _scope("layer/mlp"):
+            h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
+            x = x + _mlp(h, layer)
         return (x, ck, cv), None
 
     layers = dict(params["layers"])
     layers["_idx"] = jnp.arange(cfg.num_layers, dtype=jnp.int32)
     (x, cache_k, cache_v), _ = jax.lax.scan(layer_fn, (x, cache_k, cache_v),
                                             layers)
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    xd = x[0, :S]                                   # [S, D]
-    xp = x[0, S:].reshape(B, T, D)
-    last = jnp.take_along_axis(
-        xp, (pr_seq - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    both = jnp.concatenate([xd, last], axis=0)[None]   # [1, S+B, D]
-    logits = _unembed(both, params, cfg)[0]
+    with _scope("final_norm"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    with _scope("lm_head"):
+        xd = x[0, :S]                                   # [S, D]
+        xp = x[0, S:].reshape(B, T, D)
+        last = jnp.take_along_axis(
+            xp, (pr_seq - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+        both = jnp.concatenate([xd, last], axis=0)[None]   # [1, S+B, D]
+        logits = _unembed(both, params, cfg)[0]
     return logits[:S], logits[S:], cache_k, cache_v
 
 

@@ -133,6 +133,7 @@ def decode_attention_append_pallas_full(q, new_k, new_v, cache_k, cache_v,
     )
     out = pl.pallas_call(
         _kernel_full,
+        name="decode_attention_full",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, KV, G, hd), q.dtype),
         interpret=interpret,
@@ -156,6 +157,7 @@ def decode_attention_append_pallas(q, new_k, new_v, cache_k, cache_v,
 
     out = pl.pallas_call(
         _kernel,
+        name="decode_attention",
         grid=(S,),
         in_specs=[
             # full lengths vector in SMEM (rank-1 SMEM blocks must cover

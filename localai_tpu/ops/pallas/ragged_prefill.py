@@ -290,6 +290,7 @@ def ragged_prefill_attention_pallas(q, chunk_k, chunk_v, pages_k, pages_v,
     )
     out = pl.pallas_call(
         functools.partial(_kernel, mp=mp, pkb=pkb, qb=qb, G=G),
+        name="ragged_prefill_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((kv_heads, N * G, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),

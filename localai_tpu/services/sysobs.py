@@ -16,9 +16,13 @@ listener and dispatch to the engine whose thread is compiling via a
 thread-local registration: the engine loop thread registers its
 CompileTracker at startup, and ``precompile()`` (which runs on the
 loader/caller thread) wraps itself in :func:`activated`. Program
-attribution rides the same thread-local — the engine's fn-getters call
-``note_program(kind, key)`` on a jit-cache miss immediately before the
-compiling call, so the listener can name the program that compiled.
+attribution rides the same thread-local — ``Engine._program`` brackets
+every call of a jitted program with ``note_program(kind, key)`` /
+``note_program(None)``, so whatever compiles inside the call (the first
+call, or a re-specialisation to a new shape) is named after it, and a
+helper's compile between two programs never takes a program's name.
+Seconds and counts per program kind are kept for the process's life
+(``by_kind``); ``last_compiles`` is a short ring of the most recent.
 
 The warm boundary is marked at the END of ``precompile()``: everything
 before it (including incidental helper fills like ``jnp.ones``) is
@@ -55,7 +59,8 @@ _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # fired (before the duration event, same thread) when the program came
 # out of the persistent compilation cache instead of the compiler
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
-_LAST_COMPILES = 32     # ring of recent compiles kept per tracker
+_LAST_COMPILES = 256    # ring of recent compiles kept per tracker: a
+# precompile of some fifty programs must not push its first ones out
 
 _tl = threading.local()
 _listener_lock = threading.Lock()
@@ -130,14 +135,15 @@ class CompileTracker:
         self.compile_seconds = 0.0
         self.compiles_after_warmup = 0
         self.warm = False
+        self._by_kind: dict = {}   # kind -> [seconds, compiles]
         self._last: deque = deque(maxlen=_LAST_COMPILES)
         self._lock = threading.Lock()
         install_listener()
 
-    def note_program(self, kind: str, key=None):
-        """Name the program about to compile on THIS thread (called by
-        the engine's fn-getters on a jit-cache miss)."""
-        _tl.program = f"{kind}:{key}" if key is not None else kind
+    def note_program(self, kind, key=None):
+        """Name the program whose call is about to be made on THIS thread
+        (a compile inside the call is its); ``None`` ends the call."""
+        _tl.program = (f"{kind}:{key}" if key is not None else kind)
 
     def mark_warm(self):
         """precompile() finished: every compile from now on is a storm."""
@@ -149,11 +155,16 @@ class CompileTracker:
             self.compiles_from_cache += 1
 
     def on_compile(self, secs: float):
+        # the note stays until the call it names returns: one call may
+        # compile more than one executable
         program = getattr(_tl, "program", None) or "?"
-        _tl.program = None   # consume: one note names one compile
+        kind = program.split(":", 1)[0]
         with self._lock:
             self.compiles += 1
             self.compile_seconds += secs
+            k = self._by_kind.setdefault(kind, [0.0, 0])
+            k[0] += secs
+            k[1] += 1
             storm = self.warm
             rec = {"t": round(time.time(), 3), "seconds": round(secs, 4),
                    "program": program, "after_warmup": storm}
@@ -176,6 +187,12 @@ class CompileTracker:
     def last_compiles(self) -> list:
         with self._lock:
             return list(self._last)
+
+    def by_kind(self) -> dict:
+        """{program kind: {"seconds", "compiles"}} since process start."""
+        with self._lock:
+            return {k: {"seconds": round(v[0], 4), "compiles": v[1]}
+                    for k, v in sorted(self._by_kind.items())}
 
     def snapshot(self) -> dict:
         with self._lock:

@@ -8,6 +8,14 @@ name survive ring wraparound, so the host-walltime vs device-time
 decomposition (``summary()["decomp_ms"]``) reflects the whole engine
 lifetime even when individual spans have been overwritten.
 
+``span()`` is the one instrumentation call (engine, runner, loader): a
+context manager that records the ring span on exit and, only while a
+profiler capture is running (``capturing``, set and cleared by the
+runner's ``Profile`` RPC), also enters a ``jax.profiler.TraceAnnotation``
+of the same name, so the capture's host plane shows the same spans as the
+ring on the profiler's own clock. ``record()`` stays for spans whose ends
+are observed on different threads (dispatch -> sync-worker ready).
+
 ``chrome_trace()`` renders the ring as Chrome trace-event JSON
 (https://ui.perfetto.dev loads it directly): one track per slot plus
 one for the scheduler tick loop and one for engine-level dispatches.
@@ -29,16 +37,17 @@ import time
 
 # Span names counted as HOST loop work in the decomposition: time the
 # engine thread spends dispatching / detokenizing / flushing, measured
-# as plain walltime deltas on the engine thread.
+# as plain walltime deltas on the engine thread. The ``tick_*`` phase
+# spans are NOT here: they contain these and would count them twice.
 HOST_SPANS = frozenset({
     "admission",
     "prefill_chunk",
     "prefill_dispatch",
-    "decode_dispatch",
+    "decode_burst",
     "emit",
     "stream_flush",
-    "offload_dispatch",
-    "restore_dispatch",
+    "kv_offload_gather",
+    "kv_restore_scatter",
 })
 
 # Span names counted as DEVICE time: dispatch call → sync-worker
@@ -64,59 +73,164 @@ EMITTER_SPANS = frozenset({
 # finish-detection latency called out in the r5 verdict.
 FINISH_DETECT_SPAN = "finish_detect"
 
+# 60 s of a saturated 16-slot engine with a factor of two to spare, from
+# the span rate measured on the chip (PERF.md section 6, PR 25); a slot
+# costs one tuple, a few short strings and a small dict: about 0.3 KB.
+DEFAULT_RING_SIZE = 32768
+
+class _NullSpan:
+    """What ``span()`` returns with tracing off: one shared object, so the
+    call costs a branch. Writes to its ``args`` go nowhere."""
+
+    class _Sink(dict):
+        def __setitem__(self, k, v):
+            pass
+
+        def update(self, *a, **kw):
+            pass
+
+    args = _Sink()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NULL_SPAN = _NullSpan()
+
+
+class _Span:
+    """One ``RingTracer.span()`` in flight. ``args`` may be filled in
+    while the span is open (counts known only at its end); the profiler
+    annotation carries the scalar args known at entry."""
+
+    __slots__ = ("_tr", "name", "track", "rid", "args", "t0", "_ann")
+
+    def __init__(self, tr, name, track, rid, args):
+        self._tr, self.name, self.track = tr, name, track
+        self.rid, self.args = rid, args
+        self._ann = None
+
+    def __enter__(self):
+        ann = self._tr._annotation
+        if ann is not None:
+            try:
+                self._ann = ann(self.name, **{
+                    k: v for k, v in self.args.items()
+                    if isinstance(v, (int, float, str))})
+                self._ann.__enter__()
+            except Exception:  # pragma: no cover - profiler went away
+                self._ann = None
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.monotonic()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._tr.record(self.name, self.track, self.t0, t1, self.rid,
+                        self.args or None)
+        return False
+
 
 class RingTracer:
     """Fixed-size span ring with always-on per-name aggregates.
 
-    ``record()`` is the only hot-path entry point; when ``enabled`` is
-    False it returns immediately without taking the lock (trace=0 is a
-    true no-op). Spans are (name, track, t0, t1, rid, args) tuples with
-    t0/t1 from time.monotonic().
+    ``span()`` and ``record()`` are the hot-path entry points; when
+    ``enabled`` is False both return on their first line without taking
+    the lock (trace=0 is a true no-op). Spans are (name, track, t0, t1,
+    rid, args) tuples with t0/t1 from time.monotonic(), entered in the
+    order they END.
     """
 
-    def __init__(self, size: int = 4096, enabled: bool = True):
+    def __init__(self, size: int = DEFAULT_RING_SIZE, enabled: bool = True):
         self.size = max(1, int(size))
         self.enabled = bool(enabled) and int(size) > 0
         self._buf: list = [None] * self.size
         self._n = 0  # total spans ever recorded (monotonic)
+        self._w = 0  # next write position
+        self._held = 0  # spans in the ring (<= size)
         self._agg: dict = {}  # name -> [total_s, count]
         self._lock = threading.Lock()
+        # jax.profiler.TraceAnnotation while a capture runs, else None
+        # (set_capturing): this module never imports jax by itself, the
+        # HTTP process uses it too
+        self._annotation = None
         # Trace epoch: chrome_trace timestamps are relative to this so
         # perfetto's timeline starts near zero.
         self.t0 = time.monotonic()
         self.t0_epoch = time.time()
 
+    def configure(self, size: int, enabled: bool = True):
+        """Apply a model's ``trace`` / ``trace_ring_size`` options to the
+        process's ring (the runner builds it before it knows them). The
+        newest retained spans are kept."""
+        size = max(1, int(size))
+        with self._lock:
+            if size != self.size:
+                kept = self._retained()[-size:]
+                self._buf = kept + [None] * (size - len(kept))
+                self.size, self._held = size, len(kept)
+                self._w = len(kept) % size
+            self.enabled = bool(enabled)
+        if not self.enabled:
+            self._annotation = None
+
+    @property
+    def capturing(self) -> bool:
+        return self._annotation is not None
+
+    def set_capturing(self, on: bool):
+        """The Profile RPC brackets a capture with this: while it is on,
+        every ``span()`` is also a TraceAnnotation in the capture."""
+        if on and self.enabled:
+            import jax
+
+            self._annotation = jax.profiler.TraceAnnotation
+        else:
+            self._annotation = None
+
+    def span(self, name, track, rid="", **args):
+        if not self.enabled:
+            return _NULL_SPAN
+        return _Span(self, name, track, rid, args)
+
     def record(self, name, track, t0, t1, rid="", args=None):
         if not self.enabled:
             return
         with self._lock:
-            self._buf[self._n % self.size] = (name, track, t0, t1, rid, args)
+            self._buf[self._w] = (name, track, t0, t1, rid, args)
+            self._w = (self._w + 1) % self.size
             self._n += 1
+            if self._held < self.size:
+                self._held += 1
             a = self._agg.get(name)
             if a is None:
                 a = self._agg[name] = [0.0, 0]
             a[0] += t1 - t0
             a[1] += 1
 
+    def _retained(self) -> list:
+        if self._held < self.size:
+            return self._buf[:self._held]
+        return self._buf[self._w:] + self._buf[:self._w]
+
     def spans(self) -> list:
         """Retained spans, oldest first, as dicts."""
         with self._lock:
-            n = self._n
-            if n <= self.size:
-                raw = self._buf[:n]
-            else:
-                cut = n % self.size
-                raw = self._buf[cut:] + self._buf[:cut]
+            raw = self._retained()
         return [
             {"name": s[0], "track": s[1], "t0": s[2], "t1": s[3],
              "rid": s[4], "args": s[5]}
-            for s in raw if s is not None
+            for s in raw
         ]
 
     def reset(self):
         with self._lock:
             self._buf = [None] * self.size
-            self._n = 0
+            self._n = self._w = self._held = 0
             self._agg = {}
             self.t0 = time.monotonic()
             self.t0_epoch = time.time()
@@ -126,8 +240,9 @@ class RingTracer:
         if not self.enabled:
             return {"enabled": False}
         with self._lock:
-            n = self._n
+            n, held = self._n, self._held
             agg = {k: (v[0], v[1]) for k, v in self._agg.items()}
+            oldest = self._buf[self._w] if held == self.size else None
         by_span = {
             name: {"total_ms": round(tot * 1e3, 3), "count": cnt,
                    "avg_ms": round(tot * 1e3 / cnt, 4) if cnt else 0.0}
@@ -138,11 +253,18 @@ class RingTracer:
         emitter = sum(t for name, (t, _) in agg.items()
                       if name in EMITTER_SPANS)
         fin = agg.get(FINISH_DETECT_SPAN, (0.0, 0))[0]
+        # the wall time from which the ring still holds EVERY span: its
+        # own epoch until the first span is overwritten, then the moment
+        # the oldest retained span was recorded (spans enter when they
+        # end, so whatever ended later is still here)
+        dropped = max(0, n - held)
+        since = oldest[3] if dropped and oldest is not None else self.t0
         return {
             "enabled": True,
             "ring_size": self.size,
             "spans_recorded": n,
-            "spans_dropped": max(0, n - self.size),
+            "spans_dropped": dropped,
+            "oldest_retained_epoch": self.t0_epoch + (since - self.t0),
             "by_span_ms": by_span,
             "decomp_ms": {
                 "host_loop": round(host * 1e3, 3),
@@ -151,6 +273,10 @@ class RingTracer:
                 "finish_detect": round(fin * 1e3, 3),
             },
         }
+
+
+# for callers handed no tracer (a bare load_llama_params): every call a no-op
+NO_TRACER = RingTracer(1, enabled=False)
 
 
 def _track_order_key(track: str):
@@ -178,10 +304,21 @@ def chrome_trace(tracer: RingTracer, pid: int = 1,
     The top-level ``localai`` block carries this process's trace epoch
     (wall-clock t0 of the relative-µs timeline) and pid — the anchor the
     HTTP process uses to re-base backend timelines onto ONE merged
-    cross-process trace (ISSUE 12), corrected by the LoadModel clock
-    handshake offset.
+    cross-process trace (ISSUE 12); a backend on another machine is
+    corrected by the clock offset measured over Health round trips.
     """
-    spans = tracer.spans()
+    spans = []
+    for s in tracer.spans():
+        spans.append(s)
+        a = s["args"] or {}
+        if s["name"] == "decode_burst_device" and a.get("slot_ids"):
+            # the burst span names the slots that rode it; their tracks
+            # are drawn from it (no span per slot per burst is recorded)
+            for i, rid in zip(a["slot_ids"], a.get("rids") or
+                              [""] * len(a["slot_ids"])):
+                spans.append({"name": "decode", "track": f"slot{i}",
+                              "t0": s["t0"], "t1": s["t1"], "rid": rid,
+                              "args": {"steps": a.get("steps")}})
     tracks = sorted({s["track"] for s in spans}, key=_track_order_key)
     tid = {t: i for i, t in enumerate(tracks)}
     events: list = [{

@@ -190,7 +190,6 @@ def test_grammar_slot_keeps_bursts_full(monkeypatch, byte_tokenizer):
     from localai_tpu.engine import sampling as smp
     from localai_tpu.models import llama as _llama
 
-    monkeypatch.setenv("LOCALAI_ENGINE_TRACE", "1")
     cfg = _llama.LlamaConfig(
         vocab_size=258, hidden_size=64, intermediate_size=128,
         num_layers=2, num_heads=4, num_kv_heads=2, max_position_embeddings=256)
@@ -239,7 +238,8 @@ def test_grammar_slot_keeps_bursts_full(monkeypatch, byte_tokenizer):
         assert _re.fullmatch(r"\[\d(,\d){0,8}\]", text), text
         # the engine really did run multi-step bursts while the grammar
         # slot was active
-        steps, n_bursts = e._tstats.get("burst_steps", [0, 1])
-        assert n_bursts and steps / n_bursts > 1.0
+        bursts = [s["args"]["steps"] for s in e.tracer.spans()
+                  if s["name"] == "decode_burst_device"]
+        assert bursts and sum(bursts) / len(bursts) > 1.0
     finally:
         e.shutdown()

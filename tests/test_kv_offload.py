@@ -434,57 +434,54 @@ def test_offload_restore_greedy_parity(tiny_cfg_params):
     non-blocking assertion: only dispatch-time marks exist; there is no
     sync/wait mark in the restore path at all)."""
     cfg, params = tiny_cfg_params
-    os.environ["LOCALAI_ENGINE_TRACE"] = "1"
+    rng = np.random.default_rng(10)
+    a = _prompt(rng, 48)
+    # pool = ONE slot's worth of context: every admission pressures.
+    # The engine's own FIRST run of ``a`` is the cold reference —
+    # the pool is empty at that point, so it IS the cold prefill.
+    e = _engine(cfg, params, pool_pages=8)
     try:
-        rng = np.random.default_rng(10)
-        a = _prompt(rng, 48)
-        # pool = ONE slot's worth of context: every admission pressures.
-        # The engine's own FIRST run of ``a`` is the cold reference —
-        # the pool is empty at that point, so it IS the cold prefill.
-        e = _engine(cfg, params, pool_pages=8)
-        try:
-            ref, _ = _greedy(e, a)
-            slot0 = next(i for i, t in enumerate(e._cache_tokens)
-                         if t[:48] == a)
-            e._commit_ptab()
-            ref_rows = np.asarray(kvcache.slot_rows(e.ck, slot0))[:, :47]
-            for _ in range(3):
-                _greedy(e, _prompt(rng, 48))
-            _wait_offloaded(e, 3)
-            assert not any(t[:48] == a for t in e._cache_tokens), \
-                "churn failed to overwrite the conversation's slot"
-            st0 = e._hstore.stats()
-            assert st0["offloaded_pages"] >= 3
-            got2, evs = _greedy(e, a)
-            assert got2 == ref                       # byte-identical
-            st = e._hstore.stats()
-            assert st["restores"] == st0["restores"] + 1
-            assert st["restored_pages"] >= st0["restored_pages"] + 1
-            assert evs[-1].timings["reused_prompt_tokens"] >= 16
-            # restored device rows == the cold prefill's rows, byte-wise
-            # (minus the COW boundary row the tail prefill rewrites)
-            slot1 = next(i for i, t in enumerate(e._cache_tokens)
-                         if t[:48] == a)
-            e._commit_ptab()
-            got_rows = np.asarray(kvcache.slot_rows(e.ck, slot1))[:, :47]
-            reused = evs[-1].timings["reused_prompt_tokens"]
-            np.testing.assert_array_equal(got_rows[:, :reused],
-                                          ref_rows[:, :reused])
-            # timing marks: restore + offload were DISPATCHED on the
-            # serving loop (no blocking marks exist for either path)
-            assert "restore_dispatch" in e._tstats
-            assert "offload_dispatch" in e._tstats
-            assert not any("wait" in k for k in e._tstats
-                           if "restore" in k or "offload" in k)
-            m = e.metrics()
-            assert m["kv_pages_offloaded"] == e._hstore.pages
-            assert m["kv_offload"]["restores"] >= 1
-            assert (m["kv_pages_free"] + m["kv_pages_retained"]
-                    + m["kv_pages_active"] == m["kv_pages_total"])
-        finally:
-            e.shutdown()
+        ref, _ = _greedy(e, a)
+        slot0 = next(i for i, t in enumerate(e._cache_tokens)
+                     if t[:48] == a)
+        e._commit_ptab()
+        ref_rows = np.asarray(kvcache.slot_rows(e.ck, slot0))[:, :47]
+        for _ in range(3):
+            _greedy(e, _prompt(rng, 48))
+        _wait_offloaded(e, 3)
+        assert not any(t[:48] == a for t in e._cache_tokens), \
+            "churn failed to overwrite the conversation's slot"
+        st0 = e._hstore.stats()
+        assert st0["offloaded_pages"] >= 3
+        got2, evs = _greedy(e, a)
+        assert got2 == ref                       # byte-identical
+        st = e._hstore.stats()
+        assert st["restores"] == st0["restores"] + 1
+        assert st["restored_pages"] >= st0["restored_pages"] + 1
+        assert evs[-1].timings["reused_prompt_tokens"] >= 16
+        # restored device rows == the cold prefill's rows, byte-wise
+        # (minus the COW boundary row the tail prefill rewrites)
+        slot1 = next(i for i, t in enumerate(e._cache_tokens)
+                     if t[:48] == a)
+        e._commit_ptab()
+        got_rows = np.asarray(kvcache.slot_rows(e.ck, slot1))[:, :47]
+        reused = evs[-1].timings["reused_prompt_tokens"]
+        np.testing.assert_array_equal(got_rows[:, :reused],
+                                      ref_rows[:, :reused])
+        # timing marks: restore + offload were DISPATCHED on the
+        # serving loop (no blocking marks exist for either path)
+        by = e.tracer.summary()["by_span_ms"]
+        assert by["kv_restore_scatter"]["count"] >= 1
+        assert by["kv_offload_gather"]["count"] >= 1
+        assert not any("wait" in k for k in by
+                       if "restore" in k or "offload" in k)
+        m = e.metrics()
+        assert m["kv_pages_offloaded"] == e._hstore.pages
+        assert m["kv_offload"]["restores"] >= 1
+        assert (m["kv_pages_free"] + m["kv_pages_retained"]
+                + m["kv_pages_active"] == m["kv_pages_total"])
     finally:
-        os.environ.pop("LOCALAI_ENGINE_TRACE", None)
+        e.shutdown()
 
 
 def test_restore_miss_falls_back_to_prefill(tiny_cfg_params):

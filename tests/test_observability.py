@@ -89,7 +89,9 @@ def test_reset_clears_ring_and_aggregates():
 
 def test_decomp_classification():
     tr = RingTracer(size=64)
-    tr.record("decode_dispatch", "engine", 0.0, 0.010)   # host
+    tr.record("decode_burst", "engine", 0.0, 0.010)      # host (dispatch)
+    tr.record("tick_dispatch_decode", "sched", 0.0, 0.011)  # phase: not
+    # counted (it contains the dispatch span)
     tr.record("emit", "slot0", 0.0, 0.005)               # host
     tr.record("decode_burst_device", "engine", 0.0, 0.100)  # device
     tr.record("finish_detect", "engine", 0.0, 0.002)
@@ -106,10 +108,10 @@ def test_chrome_trace_valid_and_track_ordered():
     tr = RingTracer(size=64)
     base = tr.t0
     tr.record("tick", "sched", base, base + 0.001)
-    tr.record("decode_dispatch", "engine", base, base + 0.002)
-    tr.record("decode", "slot1", base, base + 0.003, rid="r-1")
-    tr.record("decode", "slot0", base, base + 0.003, rid="r-0",
-              args={"steps": 4})
+    tr.record("decode_burst", "engine", base, base + 0.002)
+    # the slot tracks are drawn from the burst span's slots and requests
+    tr.record("decode_burst_device", "engine", base, base + 0.003,
+              args={"steps": 4, "slot_ids": [1, 0], "rids": ["r-1", "r-0"]})
     doc = chrome_trace(tr)
     # round-trips as JSON (the /debug/trace body)
     doc = json.loads(json.dumps(doc))
@@ -214,7 +216,7 @@ def test_engine_records_spans_and_histograms(traced_engine, byte_tokenizer):
     for k in ("host_loop", "device", "finish_detect"):
         assert k in tr["decomp_ms"]
     # the request lifecycle spans all landed
-    for span in ("queue_wait", "admission", "decode_dispatch",
+    for span in ("queue_wait", "admission", "decode_burst",
                  "decode_burst_device", "finish_detect", "emit",
                  "stream_flush", "request"):
         assert span in tr["by_span_ms"], span

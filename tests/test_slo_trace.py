@@ -16,7 +16,7 @@ import pytest
 from localai_tpu.engine import engine as eng
 from localai_tpu.engine import sampling
 from localai_tpu.models import llama
-from localai_tpu.modelmgr.loader import _parse_handshake
+from localai_tpu.modelmgr.loader import _parse_handshake, measure_clock
 from localai_tpu.services import sysobs
 from localai_tpu.services.eventlog import EVENTS
 
@@ -182,13 +182,25 @@ def test_parse_handshake_midpoint_math():
         "status": "loaded",
         "handshake": {"wall": 2000.0, "mono": 5.0,
                       "trace_epoch": 1999.5, "pid": 424242},
-    }), t_send=1000.0, t_recv=1000.2)
-    assert hs["offset_s"] == pytest.approx(2000.0 - 1000.1)
-    assert hs["rtt_s"] == pytest.approx(0.2)
+    }))
+    # the LoadModel reply names the backend; the offset is never taken
+    # from its round trip (a load lasts minutes)
+    assert "offset_s" not in hs and "rtt_s" not in hs
     assert hs["backend_wall"] == 2000.0
     assert hs["backend_pid"] == 424242
     assert hs["trace_epoch"] == 1999.5
-    assert hs["measured_at"] == 1000.2
+    # the midpoint math lives in measure_clock, over Health round trips
+    times = iter([1000.0, 1000.2])
+    import localai_tpu.modelmgr.loader as ld
+
+    real = ld.time.time
+    ld.time.time = lambda: next(times, 1000.2)
+    try:
+        c = measure_clock(lambda: 2000.0, trips=1)
+    finally:
+        ld.time.time = real
+    assert c["offset_s"] == pytest.approx(2000.0 - 1000.1)
+    assert c["rtt_s"] == pytest.approx(0.2)
 
 
 @pytest.mark.parametrize("message", [
@@ -199,7 +211,7 @@ def test_parse_handshake_midpoint_math():
     '{"handshake": {"wall": "x"}}',  # non-numeric stamp
 ])
 def test_parse_handshake_tolerates_legacy(message):
-    assert _parse_handshake(message, 1.0, 2.0) == {}
+    assert _parse_handshake(message) == {}
 
 
 # ----------------------------------------------------- config validation
