@@ -427,7 +427,9 @@ def serve_and_check(c, server, rng, shape, tp, want):
     # every program class of the tick ran, with the attention it should
     classes = {}
     for name, p in ran.items():
-        kind, key = name.split(":", 1)
+        if p["attention"] == "none":    # page clone, offload: no model step
+            continue
+        kind, _, key = name.partition(":")
         if kind.startswith("prefill_pack"):
             kind = "pack_continued" if key.endswith("True)") else "pack_fresh"
         classes.setdefault(kind, set()).add(p["attention"])

@@ -83,7 +83,7 @@ def _target(cfg: "LlamaConfig") -> AttnTarget:
 
 def decode_attn_impl(cfg: "LlamaConfig", lck) -> str:
     """Name of the decode-attention implementation a program over the
-    single-layer cache ``lck`` is built with — _decode_attend_write
+    cache ``lck`` (whole or one layer) is built with — _decode_attend_write
     dispatches on this string and the engine reports it per program."""
     pallas = _target(cfg).pallas
     if kvcache.is_paged(lck):
@@ -134,6 +134,11 @@ def _on_mesh(cfg: "LlamaConfig", kernel, in_specs, out_spec):
         return kernel
     return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
                          out_specs=out_spec, check_vma=False)
+
+
+# the stacked page pool [L, n_pages, page_size, KV, hd] under shard_map:
+# KV heads over "tp", everything else (the layer axis too) replicated
+_POOL = P(None, None, None, "tp", None)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -391,11 +396,17 @@ def prefill(
         if continued:
             # continued prefix: committed keys live in the cache. Rows are
             # read BEFORE this chunk's scatter (attention combines them
-            # with the in-register chunk keys) — reading the same-step
-            # scattered rows forces XLA to materialize a full layer copy
-            # (measured +8 ms/step at decode; same hazard here). int8
-            # caches pass the {"q","s"} rows straight through — the
-            # attention op folds scales without a dequantized copy.
+            # with the in-register chunk keys). int8 caches pass the
+            # {"q","s"} rows straight through — the attention op folds
+            # scales without a dequantized copy. What this read costs on
+            # the chip (it is the speculative verify pass): kvcache.layer
+            # slices the layer's pool out of the scan carry and
+            # gather_layer_rows gathers a dense [B, C, KV, hd] copy of
+            # it, per layer — 404 MB of temporaries at 16 x 4096
+            # (compiled for a v5e, PERF.md section 7). The decode step
+            # and the packed prefill no longer slice a layer (PR 27:
+            # that copy, out and back, was 17-24% of decode device
+            # time); this path still does.
             k_rows = kvcache.gather_layer_rows(kvcache.layer(ck, li), slot_ids)
             v_rows = kvcache.gather_layer_rows(kvcache.layer(cv, li), slot_ids)
             if not kvcache.is_quant(k_rows):
@@ -540,26 +551,27 @@ def ragged_prefill(
         return (x, ck, cv), None
 
     def attend_write(q, k, v, ck, cv, li):
-        lck, lcv = kvcache.layer(ck, li), kvcache.layer(cv, li)
         # committed rows are read BEFORE this pack's scatter (the same
         # no-read-after-write rule as every other attention path here)
-        if ragged_attn_impl(cfg, lck, N, continued).startswith("pallas"):
+        if ragged_attn_impl(cfg, ck, N, continued).startswith("pallas"):
             from localai_tpu.ops.pallas.ragged_prefill import (
                 ragged_prefill_attention_pallas)
 
-            qb, pkb = _ragged_plan(cfg, lck, N)
+            # the kernel indexes the stacked pool by layer: slicing the
+            # layer out here would copy it out of the scan carry
+            qb, pkb = _ragged_plan(cfg, ck, N)
             heads, rep = P(None, "tp", None), P(None)
-            pool = P(None, None, "tp", None)
             attn = _on_mesh(
                 cfg, partial(ragged_prefill_attention_pallas,
                              q_per_kv=cfg.q_per_kv, pkb=pkb, qb=qb),
-                (heads, heads, heads, pool, pool, P(None, None),
-                 rep, rep, rep, rep), heads)(
-                q[0], k[0], v[0], lck["pages"], lcv["pages"], lck["ptab"],
-                seg_slots, seg_start, seg_off, seg_len)
+                (heads, heads, heads, _POOL, _POOL, P(None, None),
+                 rep, rep, rep, rep, P()), heads)(
+                q[0], k[0], v[0], ck["pages"], cv["pages"], ck["ptab"],
+                seg_slots, seg_start, seg_off, seg_len, li)
         else:
             attn = ragged_prefill_attention(
-                q[0], k[0], v[0], seg_of, seg_slots, seg_start, lck, lcv,
+                q[0], k[0], v[0], seg_of, seg_slots, seg_start,
+                kvcache.layer(ck, li), kvcache.layer(cv, li),
                 cfg.q_per_kv, continued=continued)
         ck = kvcache.scatter_ragged(ck, li, slot_of, positions, k[0])
         cv = kvcache.scatter_ragged(cv, li, slot_of, positions, v[0])
@@ -579,10 +591,11 @@ def ragged_prefill(
     return logits, cache_k, cache_v
 
 
-def _decode_attend_write(q1, k1, v1, lck, lcv, lengths, cfg: LlamaConfig):
-    """One decode token per slot: attend + scatter the new K/V row.
+def _decode_attend_write(q1, k1, v1, ck, cv, li, lengths, cfg: LlamaConfig):
+    """One decode token per slot in layer ``li``: attend + write the new
+    K/V row, both on the WHOLE cache (the scan carry).
 
-    q1 [S, H, hd]; k1/v1 [S, KV, hd]; returns (attn [S, H, hd], lk, lv).
+    q1 [S, H, hd]; k1/v1 [S, KV, hd]; returns (attn [S, H, hd], ck, cv).
 
     The implementation is decode_attn_impl's choice. The paged layout
     has one form per backend (ragged paged kernel on TPU, page gather +
@@ -590,34 +603,49 @@ def _decode_attend_write(q1, k1, v1, lck, lcv, lengths, cfg: LlamaConfig):
     selected by LOCALAI_DECODE_ATTN: post-scatter einsum (default),
     append-attention (pre-scatter read) in jnp, or the same through
     ops/pallas/decode_attention.py. Semantically identical; which is
-    fastest on the chip is not measured (ROADMAP S5/D2)."""
+    fastest on the chip is not measured (ROADMAP S5/D2).
+
+    The paged kernels index the stacked pool by ``li`` and the row write
+    is one scatter into the carry, which XLA performs in place. Slicing
+    the layer out for the kernel and setting it back after the write
+    made XLA copy a layer of the pool out of the carry and back, per
+    layer per step: 17 and 24% of decode device time (PERF.md section 6,
+    PR 27)."""
     S = q1.shape[0]
-    slot_idx = jnp.arange(S, dtype=jnp.int32)
-    impl = decode_attn_impl(cfg, lck)
-    heads, pool = P(None, "tp", None), P(None, None, "tp", None)
+    slot = jnp.arange(S, dtype=jnp.int32)[:, None]
+
+    def write(cache, new):
+        # cache[li, s, lengths[s]] = new[s]; out-of-range positions
+        # (lengths == C: inactive slots) are dropped, preserving the
+        # capacity invariant
+        return kvcache.scatter_prefill(cache, li, slot, lengths[:, None],
+                                       new[:, None])
+
+    impl = decode_attn_impl(cfg, ck)
+    heads = P(None, "tp", None)
     if impl == "jnp:scatter":
-        # scatter new k/v at [slot, lengths[slot]], then attend over the
-        # updated rows ([0, lengths]); out-of-range positions
-        # (lengths==C) are dropped, preserving the capacity invariant
-        lk = kvcache.scatter_decode(lck, slot_idx, lengths, k1)
-        lv = kvcache.scatter_decode(lcv, slot_idx, lengths, v1)
-        return decode_attention(q1, lk, lv, lengths + 1, cfg.q_per_kv), lk, lv
-    # every other form reads the cache BEFORE the scatter and appends
-    # the current token's k/v from registers
+        # write first, then attend over the updated rows ([0, lengths])
+        ck, cv = write(ck, k1), write(cv, v1)
+        attn = decode_attention(q1, kvcache.layer(ck, li),
+                                kvcache.layer(cv, li), lengths + 1,
+                                cfg.q_per_kv)
+        return attn, ck, cv
+    # every other form reads the cache BEFORE the write and appends the
+    # current token's k/v from registers
     if impl == "pallas:paged_decode_int8":
         # int8 pages stay quantized in HBM: the {q, scales} kernel
         # variant folds the scales in VMEM
         from localai_tpu.ops.pallas.paged_attention import (
             paged_decode_attention_append_quant)
 
-        scales = P(None, None, "tp")
+        scales = P(*_POOL[:-1])
         attn = _on_mesh(
             cfg, partial(paged_decode_attention_append_quant,
                          q_per_kv=cfg.q_per_kv),
-            (heads, heads, heads, pool, scales, pool, scales,
-             P(None, None), P(None)), heads)(
-            q1, k1, v1, lck["pages"], lck["scales"], lcv["pages"],
-            lcv["scales"], lck["ptab"], lengths)
+            (heads, heads, heads, _POOL, scales, _POOL, scales,
+             P(None, None), P(None), P()), heads)(
+            q1, k1, v1, ck["pages"], ck["scales"], cv["pages"],
+            cv["scales"], ck["ptab"], lengths, li)
     elif impl == "pallas:paged_decode":
         from localai_tpu.ops.pallas.paged_attention import (
             paged_decode_attention_append)
@@ -625,9 +653,9 @@ def _decode_attend_write(q1, k1, v1, lck, lcv, lengths, cfg: LlamaConfig):
         attn = _on_mesh(
             cfg, partial(paged_decode_attention_append,
                          q_per_kv=cfg.q_per_kv),
-            (heads, heads, heads, pool, pool, P(None, None), P(None)),
-            heads)(
-            q1, k1, v1, lck["pages"], lcv["pages"], lck["ptab"], lengths)
+            (heads, heads, heads, _POOL, _POOL, P(None, None), P(None),
+             P()), heads)(
+            q1, k1, v1, ck["pages"], cv["pages"], ck["ptab"], lengths, li)
     elif impl == "pallas:decode_append":
         from localai_tpu.ops.pallas.decode_attention import (
             decode_attention_append_pallas)
@@ -637,20 +665,21 @@ def _decode_attend_write(q1, k1, v1, lck, lcv, lengths, cfg: LlamaConfig):
             cfg, partial(decode_attention_append_pallas,
                          q_per_kv=cfg.q_per_kv),
             (heads, heads, heads, rows, rows, P(None)), heads)(
-            q1, k1, v1, lck, lcv, lengths)
+            q1, k1, v1, kvcache.layer(ck, li), kvcache.layer(cv, li),
+            lengths)
     elif impl == "jnp:paged_gather_append":
         # pure-jnp page gather + append-attention (the gathered
         # {"q","s"} rows fold scales exactly like the contiguous path)
         attn = decode_attention_append(
-            q1, k1, v1, kvcache.gather_all_rows(lck),
-            kvcache.gather_all_rows(lcv), lengths, cfg.q_per_kv)
+            q1, k1, v1, kvcache.gather_all_rows(kvcache.layer(ck, li)),
+            kvcache.gather_all_rows(kvcache.layer(cv, li)), lengths,
+            cfg.q_per_kv)
     else:
         assert impl == "jnp:append", impl
-        attn = decode_attention_append(q1, k1, v1, lck, lcv, lengths,
-                                       cfg.q_per_kv)
-    lk = kvcache.scatter_decode(lck, slot_idx, lengths, k1)
-    lv = kvcache.scatter_decode(lcv, slot_idx, lengths, v1)
-    return attn, lk, lv
+        attn = decode_attention_append(
+            q1, k1, v1, kvcache.layer(ck, li), kvcache.layer(cv, li),
+            lengths, cfg.q_per_kv)
+    return attn, write(ck, k1), write(cv, v1)
 
 
 def decode_step(
@@ -693,11 +722,8 @@ def decode_step(
             q = apply_rope(q, sin, cos)
             k = apply_rope(k, sin, cos)
         with _scope("layer/attn"):
-            lck, lcv = kvcache.layer(ck, li), kvcache.layer(cv, li)
-            attn, lk, lv = _decode_attend_write(q[:, 0], k[:, 0], v[:, 0],
-                                                lck, lcv, lengths, cfg)
-            ck = kvcache.set_layer(ck, li, lk)
-            cv = kvcache.set_layer(cv, li, lv)
+            attn, ck, cv = _decode_attend_write(q[:, 0], k[:, 0], v[:, 0],
+                                                ck, cv, li, lengths, cfg)
         with _scope("layer/attn_proj"):
             x = x + jnp.einsum("sh,hd->sd", attn.reshape(S, -1),
                                _mat(layer["wo"], x.dtype))[:, None, :]
@@ -792,11 +818,8 @@ def fused_prefill_decode(
             qd, qp = q[0, :S], q[0, S:].reshape(B, T, cfg.num_heads, hd)
             kd, kp = k[0, :S], k[0, S:].reshape(B, T, cfg.num_kv_heads, hd)
             vd, vp = v[0, :S], v[0, S:].reshape(B, T, cfg.num_kv_heads, hd)
-            lck, lcv = kvcache.layer(ck, li), kvcache.layer(cv, li)
-            attn_d, lk, lv = _decode_attend_write(qd, kd, vd, lck, lcv,
+            attn_d, ck, cv = _decode_attend_write(qd, kd, vd, ck, cv, li,
                                                   write_lengths, cfg)
-            ck = kvcache.set_layer(ck, li, lk)
-            cv = kvcache.set_layer(cv, li, lv)
             attn_p = causal_attention(qp, kp, vp, valid, cfg.q_per_kv)
             ck = kvcache.scatter_prefill(ck, li, rows, cols, kp)
             cv = kvcache.scatter_prefill(cv, li, rows, cols, vp)

@@ -231,7 +231,12 @@ def gather_slots(cache: Cache, slot_ids: jax.Array) -> Cache:
 
 
 def layer(cache: Cache, li) -> Cache:
-    """Select one layer (inside the lax.scan over layers)."""
+    """Select one layer (inside the lax.scan over layers) for a jnp
+    reader. There is no inverse: writers (scatter_prefill and its
+    callers) update the whole cache at ``[li, ...]`` in place, and the
+    Pallas kernels index the stacked pool by layer themselves — a layer
+    sliced out of the scan carry for a custom call, or set back into it,
+    is a copy of that layer's pool."""
     if is_paged(cache):
         out = {"pages": cache["pages"][li], "ptab": cache["ptab"]}
         if "scales" in cache:
@@ -240,19 +245,6 @@ def layer(cache: Cache, li) -> Cache:
     if is_quant(cache):
         return {"q": cache["q"][li], "s": cache["s"][li]}
     return cache[li]
-
-
-def set_layer(cache: Cache, li, lcache: Cache) -> Cache:
-    if is_paged(cache):
-        out = {"pages": cache["pages"].at[li].set(lcache["pages"]),
-               "ptab": cache["ptab"]}
-        if "scales" in cache:
-            out["scales"] = cache["scales"].at[li].set(lcache["scales"])
-        return out
-    if is_quant(cache):
-        return {"q": cache["q"].at[li].set(lcache["q"]),
-                "s": cache["s"].at[li].set(lcache["s"])}
-    return cache.at[li].set(lcache)
 
 
 def gather_layer_rows(lcache: Cache, slot_ids: jax.Array) -> Cache:
@@ -287,38 +279,15 @@ def gather_all_rows(lcache: Cache) -> Cache:
     return gather_layer_rows(lcache, jnp.arange(s, dtype=jnp.int32))
 
 
-def scatter_decode(lcache: Cache, slot_idx: jax.Array, lengths: jax.Array,
-                   new_kv: jax.Array) -> Cache:
-    """Write one token per slot at [slot, lengths[slot]] (mode=drop).
-
-    lcache: single-layer [S, C, KV, hd]; new_kv: [S, KV, hd] float.
-    """
-    if is_paged(lcache):
-        n_pages = lcache["pages"].shape[0]
-        pg = lcache["pages"].shape[-3]
-        page, off = _page_of(lcache["ptab"][slot_idx], lengths, pg, n_pages)
-        out = dict(lcache)
-        if "scales" in lcache:
-            q, s = quantize(new_kv)
-            out["pages"] = lcache["pages"].at[page, off].set(q, mode="drop")
-            out["scales"] = lcache["scales"].at[page, off].set(s, mode="drop")
-        else:
-            out["pages"] = lcache["pages"].at[page, off].set(
-                new_kv.astype(lcache["pages"].dtype), mode="drop")
-        return out
-    if is_quant(lcache):
-        q, s = quantize(new_kv)
-        return {"q": lcache["q"].at[slot_idx, lengths].set(q, mode="drop"),
-                "s": lcache["s"].at[slot_idx, lengths].set(s, mode="drop")}
-    return lcache.at[slot_idx, lengths].set(
-        new_kv.astype(lcache.dtype), mode="drop")
-
-
 def scatter_prefill(cache: Cache, li, rows: jax.Array, cols: jax.Array,
                     new_kv: jax.Array) -> Cache:
-    """Batched prompt scatter: cache[li, rows[b,t], cols[b,t]] = new_kv[b,t].
+    """Batched row scatter: cache[li, rows[b,t], cols[b,t]] = new_kv[b,t]
+    — every KV write of the model step (prompt chunks, ragged packs, and
+    the decode step's one row per slot as rows/cols [S, 1]).
 
     cache: full [L, S, C, KV, hd]; rows/cols: [B, T]; new_kv: [B, T, KV, hd].
+    A column past the slot's capacity maps to page n_pages, out of range
+    on the PAGE axis, so the write drops — it cannot land in layer li+1.
     """
     if is_paged(cache):
         n_pages = cache["pages"].shape[1]

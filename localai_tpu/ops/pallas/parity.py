@@ -8,8 +8,9 @@ process exits and releases the chip), at Llama-3.1-8B head shapes: 8 KV
 heads x 4 query groups of 128, page 64. Interpret-mode tests prove the
 kernels' arithmetic; tests/test_tpu_compile.py proves they build for the
 chip; only this proves the built kernel computes the right thing there —
-the in-kernel strided head slices and the scalar-prefetched page table
-are where a layout surprise would show.
+the in-kernel strided head slices, the scalar-prefetched page table and
+the layer coordinate of the stacked pool (_stacked) are where a layout
+surprise would show.
 
 Slot lengths are mixed on purpose: an empty slot, one row, a slot ending
 exactly on a page boundary, one just past it, a full slot (_lengths).
@@ -89,6 +90,14 @@ def _paged_kv(rng, dtype, heads, hd, page):
     return (k, v), (k_ref, v_ref)
 
 
+def _stacked(pool):
+    """The pool as layer 1 of a stacked [2, ...] pool whose layer 0
+    holds the same rows on the wrong pages: the program hands the
+    kernels the stacked pool and a layer index, and a kernel that
+    ignored the index would read layer 0."""
+    return jnp.stack([jnp.roll(pool, 1, axis=0), pool])
+
+
 def _max_err(out, ref, keep=None):
     out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
     err = np.abs(out - ref) / (1.0 + np.abs(ref))
@@ -110,13 +119,15 @@ def check_paged_decode(quant: bool, interpret: bool = False,
     bf = lambda a: jnp.asarray(a, jnp.bfloat16)   # noqa: E731
     if quant:
         out = paged_decode_attention_append_quant(
-            bf(q), bf(nk), bf(nv), lc["pages"], lc["scales"], lv["pages"],
-            lv["scales"], lc["ptab"], lengths, q_per_kv=g,
+            bf(q), bf(nk), bf(nv), _stacked(lc["pages"]),
+            _stacked(lc["scales"]), _stacked(lv["pages"]),
+            _stacked(lv["scales"]), lc["ptab"], lengths, 1, q_per_kv=g,
             interpret=interpret)
     else:
         out = paged_decode_attention_append(
-            bf(q), bf(nk), bf(nv), lc["pages"], lv["pages"], lc["ptab"],
-            lengths, q_per_kv=g, interpret=interpret)
+            bf(q), bf(nk), bf(nv), _stacked(lc["pages"]),
+            _stacked(lv["pages"]), lc["ptab"], lengths, 1, q_per_kv=g,
+            interpret=interpret)
     with jax.default_matmul_precision("highest"):
         ref = decode_attention_append(
             jnp.asarray(q), jnp.asarray(nk), jnp.asarray(nv),
@@ -177,10 +188,10 @@ def check_ragged_prefill(N: int, interpret: bool = False,
     qb, pkb = ragged_kernel_plan(N, kv, g, hd, page_size=page, itemsize=2)
     bf = lambda a: jnp.asarray(a, jnp.bfloat16)   # noqa: E731
     out = ragged_prefill_attention_pallas(
-        bf(q), bf(k), bf(v), lc["pages"], lv["pages"], lc["ptab"],
-        jnp.asarray(seg_slots), jnp.asarray(seg_start), jnp.asarray(seg_off),
-        jnp.asarray(seg_len), q_per_kv=g, pkb=pkb, qb=qb,
-        interpret=interpret)
+        bf(q), bf(k), bf(v), _stacked(lc["pages"]), _stacked(lv["pages"]),
+        lc["ptab"], jnp.asarray(seg_slots), jnp.asarray(seg_start),
+        jnp.asarray(seg_off), jnp.asarray(seg_len), 1, q_per_kv=g, pkb=pkb,
+        qb=qb, interpret=interpret)
     with jax.default_matmul_precision("highest"):
         ref = ragged_prefill_attention(
             jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),

@@ -6,7 +6,10 @@ to B slots; each token attends its slot's committed cache PAGES plus the
 pack's own keys causally within its segment. A naive XLA lowering
 gathers every segment's dense [C] row window per layer; this kernel
 walks the pages IN PLACE, the same way ops/pallas/paged_attention.py
-does for decode.
+does for decode — and like it out of the STACKED pool
+[L, n_pages, page_size, KV, hd], the layer index being one more
+scalar-prefetch argument and the leading coordinate of the page blocks,
+so no layer of the pool is copied out of the scan carry for the call.
 
 The grid blocks QUERIES PER SEGMENT (Ragged Paged Attention style)
 instead of keeping the whole pack's query rows resident:
@@ -14,9 +17,9 @@ instead of keeping the whole pack's query rows resident:
   * Grid (NQB, B, MP + NKB): for each QB-row query block, sweep every
     segment's MP committed page-table entries, then the NKB blocks of
     the pack's own keys.
-  * The page table and the per-segment metadata (slot, start, offset,
-    length) are SCALAR-PREFETCH arguments consumed by the K/V BlockSpec
-    index maps — the pipeline knows page j+1's physical address while
+  * The page table, the per-segment metadata (slot, start, offset,
+    length) and the layer are SCALAR-PREFETCH arguments consumed by the
+    K/V BlockSpec index maps — the pipeline knows page j+1's address while
     page j computes. Entries past a segment's last committed page, and
     every (q-block, segment) pair that does not overlap, clamp to a
     constant block so consecutive skipped steps revisit (no DMA), and
@@ -52,6 +55,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from localai_tpu.ops.pallas.paged_attention import stacked_pool
 
 _NEG_INF = -1e30
 
@@ -113,14 +118,15 @@ def ragged_kernel_plan(N: int, kv_heads: int, q_per_kv: int, head_dim: int,
     return None
 
 
-def _kernel(ptab_ref, slots_ref, start_ref, off_ref, len_ref,
+def _kernel(ptab_ref, slots_ref, start_ref, off_ref, len_ref, layer_ref,
             q_ref, ck_ref, cv_ref, kp_ref, vp_ref,
             out_ref, m_ref, l_ref, acc_ref, *, mp: int, pkb: int, qb: int,
             G: int):
     """One (q-block, segment, kv-step) program. q [KV, QB*G, hd] (row r
     is query q_lo + r // G, group r % G); ck/cv pack keys [KV, PKB, hd];
     kp/vp one page [1, Pg, KV, hd]. A step is EITHER a page step
-    (j < mp) or a pack-key step; each runs its own predicated body."""
+    (j < mp) or a pack-key step; each runs its own predicated body.
+    ``layer_ref`` is read by the index maps alone."""
     i = pl.program_id(0)
     b = pl.program_id(1)
     j = pl.program_id(2)
@@ -210,20 +216,24 @@ def _kernel(ptab_ref, slots_ref, start_ref, off_ref, len_ref,
                    static_argnames=("q_per_kv", "pkb", "qb", "interpret"))
 def ragged_prefill_attention_pallas(q, chunk_k, chunk_v, pages_k, pages_v,
                                     ptab, seg_slots, seg_start, seg_off,
-                                    seg_len, q_per_kv: int, pkb: int = 128,
+                                    seg_len, layer=0, *, q_per_kv: int,
+                                    pkb: int = 128,
                                     qb: Optional[int] = None,
                                     interpret: bool = False):
     """q: [N, H, hd]; chunk_k/chunk_v: [N, KV, hd] (this pack's keys, not
-    yet scattered); pages_k/v: [n_pages, page_size, KV, hd] single-layer
-    page pool; ptab: [S, MP] int32 (sentinel n_pages = unallocated);
+    yet scattered); pages_k/v: [L, n_pages, page_size, KV, hd] stacked
+    page pool (without the layer axis: L = 1); ptab: [S, MP] int32
+    (sentinel n_pages = unallocated);
     seg_slots/seg_start/seg_off/seg_len: [B] int32 segment tables (pad
-    segments: seg_len == 0). ``pkb`` (pack-key block) and ``qb`` (query
+    segments: seg_len == 0); layer: int32 scalar, traced inside the scan
+    over layers. ``pkb`` (pack-key block) and ``qb`` (query
     block, default ``gcd(N, 128)``) must divide N; use
     ``ragged_kernel_plan`` to pick both. Returns [N, H, hd] (q.dtype);
     semantics match ops/ragged_prefill.py::ragged_prefill_attention over
     a paged cache."""
     N, H, hd = q.shape
-    n_pages, pg, kv_heads, _ = pages_k.shape
+    (pages_k, pages_v), layer = stacked_pool((pages_k, pages_v), layer)
+    _, n_pages, pg, kv_heads, _ = pages_k.shape
     mp = ptab.shape[1]
     B = seg_slots.shape[0]
     G = q_per_kv
@@ -248,7 +258,8 @@ def ragged_prefill_attention_pallas(q, chunk_k, chunk_v, pages_k, pages_v,
     def q_map(i, b, j, *refs):
         return (0, i, 0)
 
-    def page_map(i, b, j, ptab_ref, slots_ref, start_ref, off_ref, len_ref):
+    def page_map(i, b, j, ptab_ref, slots_ref, start_ref, off_ref, len_ref,
+                 layer_ref):
         # pages past the segment's last committed one — and every page
         # of a (q-block, segment) pair with no overlap — clamp to a
         # constant so consecutive skipped steps revisit (no DMA);
@@ -259,9 +270,11 @@ def ragged_prefill_attention_pallas(q, chunk_k, chunk_v, pages_k, pages_v,
         slot = jnp.minimum(slots_ref[b], ptab_ref.shape[0] - 1)
         pid = ptab_ref[slot, jnp.minimum(jnp.minimum(j, mp - 1), last)]
         hit = _seg_hit(i, b, off_ref, len_ref) & (j * pg < start_ref[b])
-        return (jnp.where(hit, jnp.clip(pid, 0, n_pages - 1), 0), 0, 0, 0)
+        return (layer_ref[0],
+                jnp.where(hit, jnp.clip(pid, 0, n_pages - 1), 0), 0, 0, 0)
 
-    def pack_map(i, b, j, ptab_ref, slots_ref, start_ref, off_ref, len_ref):
+    def pack_map(i, b, j, ptab_ref, slots_ref, start_ref, off_ref, len_ref,
+                 layer_ref):
         blk = jnp.clip(j - mp, 0, nkb - 1)
         q_lo = i * qb
         lo, hi = off_ref[b], off_ref[b] + len_ref[b]
@@ -272,14 +285,14 @@ def ragged_prefill_attention_pallas(q, chunk_k, chunk_v, pages_k, pages_v,
 
     rows = qb * G
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,      # ptab, seg_slots, seg_start/off/len
+        num_scalar_prefetch=6,      # ptab, seg_slots, seg_start/off/len, layer
         grid=(nqb, B, mp + nkb),
         in_specs=[
             pl.BlockSpec((kv_heads, rows, hd), q_map),
             pl.BlockSpec((kv_heads, pkb, hd), pack_map),
             pl.BlockSpec((kv_heads, pkb, hd), pack_map),
-            pl.BlockSpec((1, pg, kv_heads, hd), page_map),
-            pl.BlockSpec((1, pg, kv_heads, hd), page_map),
+            pl.BlockSpec((None, 1, pg, kv_heads, hd), page_map),
+            pl.BlockSpec((None, 1, pg, kv_heads, hd), page_map),
         ],
         out_specs=pl.BlockSpec((kv_heads, rows, hd), q_map),
         scratch_shapes=[
@@ -295,7 +308,7 @@ def ragged_prefill_attention_pallas(q, chunk_k, chunk_v, pages_k, pages_v,
         out_shape=jax.ShapeDtypeStruct((kv_heads, N * G, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(ptab, seg_slots, seg_start, seg_off, seg_len,
+    )(ptab, seg_slots, seg_start, seg_off, seg_len, layer,
       qh, ckh, cvh, pages_k, pages_v)
     return out.reshape(kv_heads, N, G, hd).transpose(1, 0, 2, 3) \
         .reshape(N, H, hd)

@@ -54,13 +54,10 @@ def _paged_layer(shape, dtype, pgs, rng):
     for s in range(S):
         ptab[s] = np.arange(s * (C // pgs), (s + 1) * (C // pgs))
     pc = kvcache.with_page_table(pc, jnp.asarray(ptab))
-    lc = kvcache.layer(pc, 0)
     rows = jnp.asarray(rng.normal(size=shape[1:]).astype(np.float32))
-    for c in range(C):
-        lc = kvcache.scatter_decode(lc, jnp.arange(S),
-                                    jnp.full((S,), c, jnp.int32),
-                                    rows[:, c])
-    return lc
+    slot = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[:, None], (S, C))
+    col = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32)[None, :], (S, C))
+    return kvcache.layer(kvcache.scatter_prefill(pc, 0, slot, col, rows), 0)
 
 
 def _pack_meta(C, N, B, segs):
@@ -137,7 +134,7 @@ def test_ragged_prefill_pallas_matches_jnp():
                                    lc, lc, G, continued=True)
     out = ragged_prefill_attention_pallas(
         q, ck, cv, lc["pages"], lc["pages"], lc["ptab"], seg_slots,
-        seg_start, seg_off, seg_len, G, pkb=8, interpret=True)
+        seg_start, seg_off, seg_len, q_per_kv=G, pkb=8, interpret=True)
     real = np.asarray(seg_of) < S
     np.testing.assert_allclose(np.asarray(out)[real], np.asarray(ref)[real],
                                atol=2e-4)
@@ -159,7 +156,7 @@ def test_paged_pallas_int8_decode_matches_jnp():
     lengths = jnp.asarray([20, 5, 32, 0], jnp.int32)
     out = paged_decode_attention_append_quant(
         q, nk, nv, lq["pages"], lq["scales"], lq["pages"], lq["scales"],
-        lq["ptab"], lengths, G, interpret=True)
+        lq["ptab"], lengths, q_per_kv=G, interpret=True)
     ref = decode_attention_append(q, nk, nv, kvcache.gather_all_rows(lq),
                                   kvcache.gather_all_rows(lq), lengths, G)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -456,7 +453,7 @@ def test_long_pack_parity_vs_per_slot(dtype):
     assert plan == (128, 128)
     out = ragged_prefill_attention_pallas(
         q, ck, cv, lc["pages"], lc["pages"], lc["ptab"], seg_slots,
-        seg_start, seg_off, seg_len, G, pkb=plan[1], qb=plan[0],
+        seg_start, seg_off, seg_len, q_per_kv=G, pkb=plan[1], qb=plan[0],
         interpret=True)
     real = np.asarray(seg_of) < S
     np.testing.assert_allclose(np.asarray(out)[real],

@@ -105,15 +105,14 @@ def test_paged_decode_attention_matches_contiguous(dtype):
     S, C, KV, G, hd, pgs = 2, 32, 2, 2, 16, 8
     shape = (1, S, C, KV, hd)
     perm = rng.permutation(S * C // pgs)
-    pk = kvcache.layer(_paged_pair(shape, dtype, pgs, perm), 0)
-    ck = kvcache.layer(kvcache.init(shape, dtype), 0)
     rows = jnp.asarray(rng.normal(size=(S, C, KV, hd)).astype(np.float32))
     lengths = jnp.asarray([20, 7], jnp.int32)
-    for c in range(C):
-        pk = kvcache.scatter_decode(pk, jnp.arange(S),
-                                    jnp.full((S,), c, jnp.int32), rows[:, c])
-        ck = kvcache.scatter_decode(ck, jnp.arange(S),
-                                    jnp.full((S,), c, jnp.int32), rows[:, c])
+    slot = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[:, None], (S, C))
+    col = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32)[None, :], (S, C))
+    pk = kvcache.layer(kvcache.scatter_prefill(
+        _paged_pair(shape, dtype, pgs, perm), 0, slot, col, rows), 0)
+    ck = kvcache.layer(kvcache.scatter_prefill(
+        kvcache.init(shape, dtype), 0, slot, col, rows), 0)
     q = jnp.asarray(rng.normal(size=(S, KV * G, hd)).astype(np.float32))
     nk = jnp.asarray(rng.normal(size=(S, KV, hd)).astype(np.float32))
     nv = jnp.asarray(rng.normal(size=(S, KV, hd)).astype(np.float32))
@@ -144,13 +143,114 @@ def test_ragged_paged_pallas_kernel_matches_jnp_reference():
     ptab[2] = [0, 3, 4, 6]
     ptab = jnp.asarray(ptab)
     lengths = jnp.asarray([20, 5, 32, 0], jnp.int32)
-    out = paged_decode_attention_append(q, nk, nv, pk, pv, ptab, lengths, G,
-                                        interpret=True)
+    out = paged_decode_attention_append(q, nk, nv, pk, pv, ptab, lengths,
+                                        q_per_kv=G, interpret=True)
     lk = {"pages": pk, "ptab": ptab}
     lv = {"pages": pv, "ptab": ptab}
     ref = decode_attention_append(q, nk, nv, kvcache.gather_all_rows(lk),
                                   kvcache.gather_all_rows(lv), lengths, G)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+def _stacked_pool(rng, quant, L, n_pages, pgs, KV, hd, ptab):
+    """A stacked paged cache whose every page (allocated or not) holds
+    random rows, so a read or a write that strays shows."""
+    shape = (L, n_pages, pgs, KV, hd)
+    if quant:
+        return {"pages": jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
+                "scales": jnp.asarray(rng.uniform(0.01, 0.03, shape[:-1]),
+                                      jnp.float32),
+                "ptab": ptab}
+    return {"pages": jnp.asarray(rng.normal(size=shape).astype(np.float32)),
+            "ptab": ptab}
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
+def test_paged_kernels_index_the_stacked_pool_by_layer(quant, layer):
+    """Both kernel variants (interpret mode) read layer ``li`` of the
+    STACKED pool, ``li`` a traced scalar as inside the scan over layers,
+    through a shuffled page table with sentinel entries and an empty
+    slot == decode_attention_append over that layer's gathered rows."""
+    from localai_tpu.ops.attention import decode_attention_append
+    from localai_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention_append, paged_decode_attention_append_quant)
+
+    rng = np.random.default_rng(3)
+    L, S, KV, G, hd, pgs, mp, n_pages = 3, 4, 2, 3, 16, 8, 4, 10
+    q = jnp.asarray(rng.normal(size=(S, KV * G, hd)).astype(np.float32))
+    nk = jnp.asarray(rng.normal(size=(S, KV, hd)).astype(np.float32))
+    nv = jnp.asarray(rng.normal(size=(S, KV, hd)).astype(np.float32))
+    ptab = np.full((S, mp), n_pages, np.int32)
+    ptab[0, :3] = [5, 1, 7]
+    ptab[1, :1] = [2]
+    ptab[2] = [9, 3, 4, 6]
+    ptab = jnp.asarray(ptab)
+    lengths = jnp.asarray([20, 5, 32, 0], jnp.int32)
+    ck = _stacked_pool(rng, quant, L, n_pages, pgs, KV, hd, ptab)
+    cv = _stacked_pool(rng, quant, L, n_pages, pgs, KV, hd, ptab)
+
+    @jax.jit
+    def run(li):
+        if quant:
+            return paged_decode_attention_append_quant(
+                q, nk, nv, ck["pages"], ck["scales"], cv["pages"],
+                cv["scales"], ptab, lengths, li, q_per_kv=G, interpret=True)
+        return paged_decode_attention_append(
+            q, nk, nv, ck["pages"], cv["pages"], ptab, lengths, li,
+            q_per_kv=G, interpret=True)
+
+    out = run(jnp.int32(layer))
+    ref = decode_attention_append(
+        q, nk, nv, kvcache.gather_all_rows(kvcache.layer(ck, layer)),
+        kvcache.gather_all_rows(kvcache.layer(cv, layer)), lengths, G)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=3e-4, rtol=3e-4)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+def test_decode_step_writes_only_its_rows(dtype):
+    """One decode step on a paged cache changes, in each layer, the one
+    row of each active slot and NOTHING else: the other layers' pages,
+    unallocated pages, inactive slots' rows (column C: page n_pages must
+    drop, never wrap into layer li + 1) and a row whose page the table
+    does not name stay bit-identical."""
+    cfg = llama.LlamaConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=3,
+        num_heads=4, num_kv_heads=2, max_position_embeddings=64)
+    params = llama.init_params(cfg, jax.random.PRNGKey(1))
+    rng = np.random.default_rng(4)
+    S, pgs, mp, n_pages = 4, 8, 4, 12
+    ptab = np.full((S, mp), n_pages, np.int32)
+    ptab[0, :3] = [5, 1, 7]
+    ptab[1, :1] = [2]
+    ptab[2, :2] = [9, 3]        # row 16 sits in a page it does not own
+    ptab[3] = [0, 4, 6, 8]
+    ptab = jnp.asarray(ptab)
+    lengths = jnp.asarray([20, 5, 16, 31], jnp.int32)
+    active = jnp.asarray([True, True, True, False])
+    hd = cfg.head_dim_
+    ck = _stacked_pool(rng, dtype == jnp.int8, cfg.num_layers, n_pages, pgs,
+                       cfg.num_kv_heads, hd, ptab)
+    cv = _stacked_pool(rng, dtype == jnp.int8, cfg.num_layers, n_pages, pgs,
+                       cfg.num_kv_heads, hd, ptab)
+    if dtype != jnp.int8:
+        ck["pages"], cv["pages"] = (c["pages"].astype(dtype)
+                                    for c in (ck, cv))
+    tokens = jnp.asarray([3, 9, 27, 11], jnp.int32)
+    _, nk, nv = jax.jit(lambda *a: llama.engine_decode(params, cfg, *a))(
+        tokens, lengths, active, ck, cv)
+    written = {(7, 20 % pgs), (2, 5 % pgs)}     # slots 0 and 1 only
+    for before, after in ((ck, nk), (cv, nv)):
+        for leaf in [k for k in before if k != "ptab"]:
+            b, a = np.asarray(before[leaf]), np.asarray(after[leaf])
+            changed = {(li, pg, off)
+                       for li, pg, off in zip(*np.nonzero(
+                           (b != a).reshape(b.shape[:3] + (-1,)).any(-1)))}
+            assert changed == {(li, pg, off)
+                               for li in range(cfg.num_layers)
+                               for pg, off in written}, (leaf, changed)
 
 
 def test_cow_divergence_preserves_source_rows(tiny_cfg_params):
@@ -179,10 +279,9 @@ def test_cow_divergence_preserves_source_rows(tiny_cfg_params):
     # slot 1 diverges at row 17
     div = jnp.asarray(rng.normal(size=(cfg.num_layers, cfg.num_kv_heads,
                                        cfg.head_dim_)).astype(np.float32))
-    lc = kvcache.layer(pc, 0)
-    lc = kvcache.scatter_decode(lc, jnp.asarray([1], jnp.int32),
-                                jnp.asarray([17], jnp.int32), div[0][None])
-    pc = kvcache.set_layer(pc, 0, lc)
+    pc = kvcache.scatter_prefill(pc, 0, jnp.asarray([[1]], jnp.int32),
+                                 jnp.asarray([[17]], jnp.int32),
+                                 div[0][None, None])
     s0 = np.asarray(kvcache.slot_rows(pc, 0))
     s1 = np.asarray(kvcache.slot_rows(pc, 1))
     np.testing.assert_array_equal(s0[:, :20], np.asarray(rows)[:, :20])

@@ -66,15 +66,16 @@ def test_paged_decode_kernels_compile(topo, quant):
     A = _on(SingleDeviceSharding(topo.devices[0]))
     bf, i32 = jnp.bfloat16, jnp.int32
     q, nk = A((S, KV * G, HD), bf), A((S, KV, HD), bf)
-    tail = (A((S, MP), i32), A((S,), i32))
+    # the stacked pool and a traced layer, as the layer scan hands them over
+    tail = (A((S, MP), i32), A((S,), i32), A((), i32))
     if quant:
-        pages, scales = A((NP, PAGE, KV, HD), jnp.int8), \
-            A((NP, PAGE, KV), jnp.float32)
+        pages, scales = A((3, NP, PAGE, KV, HD), jnp.int8), \
+            A((3, NP, PAGE, KV), jnp.float32)
         pa.paged_decode_attention_append_quant.lower(
             q, nk, nk, pages, scales, pages, scales, *tail,
             q_per_kv=G).compile()
     else:
-        pages = A((NP, PAGE, KV, HD), bf)
+        pages = A((3, NP, PAGE, KV, HD), bf)
         pa.paged_decode_attention_append.lower(
             q, nk, nk, pages, pages, *tail, q_per_kv=G).compile()
 
@@ -106,14 +107,15 @@ def test_ragged_prefill_compiles_wherever_the_plan_says(topo, heads, dtype,
 
     kv, g, hd = heads
     A = _on(SingleDeviceSharding(topo.devices[0]))
-    pages = A((NP, PAGE, kv, hd), dtype)
+    pages = A((3, NP, PAGE, kv, hd), dtype)     # stacked, layer traced
     for N in packs:
         qb, pkb = rp.ragged_kernel_plan(
             N, kv, g, hd, page_size=PAGE, itemsize=jnp.dtype(dtype).itemsize)
         rp.ragged_prefill_attention_pallas.lower(
             A((N, kv * g, hd), dtype), A((N, kv, hd), dtype),
             A((N, kv, hd), dtype), pages, pages, A((S, MP), jnp.int32),
-            *_seg_tables(A), q_per_kv=g, pkb=pkb, qb=qb).compile()
+            *_seg_tables(A), A((), jnp.int32), q_per_kv=g, pkb=pkb,
+            qb=qb).compile()
 
 
 def test_ragged_plan_shrinks_then_refuses(topo):
@@ -216,3 +218,130 @@ def test_8b_serving_programs_compile(topo, tp):
         jax.jit(pack).lower(
             params, rep((N,), i32), rep((N,), i32), rep((N,), i32),
             *[rep((S,), i32) for _ in range(4)], ck, cv).compile()
+
+
+# ---------- the layer scan copies no layer of the page pool ----------
+
+CFG_7B_L12 = llama.LlamaConfig(     # the chat cell: Mistral-7B widths, 12 layers
+    vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+    num_layers=12, num_heads=32, num_kv_heads=8, head_dim=128,
+    rope_theta=1e6, max_position_embeddings=32768,
+    attn=llama.AttnTarget(pallas=True))
+
+
+def _abstract_7b(A, context, kv_dtype):
+    """(params, ck, cv): bf16 weights and a paged cache of 16 slots x
+    ``context``, as ShapeDtypeStructs on one described device."""
+    def place(tree):
+        return jax.tree.map(lambda x: A(x.shape, x.dtype), tree)
+
+    params = jax.eval_shape(
+        lambda: llama.init_params(CFG_7B_L12, jax.random.PRNGKey(0)))
+    ck, cv = jax.eval_shape(lambda: llama.init_cache(
+        CFG_7B_L12, S, context, kv_dtype, page_size=PAGE))
+    return place(params), place(ck), place(cv)
+
+
+_HLO_DTYPE = {"bf16": jnp.bfloat16, "s8": jnp.int8, "f32": jnp.float32}
+
+
+def _loop_instructions(hlo: str):
+    """The instruction lines of every computation a ``while`` of the
+    optimised module runs: loop bodies and conditions, and the fusions
+    and calls under them."""
+    import re
+
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    callee = re.compile(r"(?:body|condition|calls|to_apply)=%?([\w.\-]+)")
+    todo = [c for lines in comps.values() for ln in lines if " while(" in ln
+            for c in re.findall(r"(?:body|condition)=%?([\w.\-]+)", ln)]
+    assert todo, "no while loop in the module: the layer scan is gone"
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            todo += [c for ln in comps[name] for c in callee.findall(ln)]
+    return [ln for name in sorted(seen) for ln in comps[name]]
+
+
+def _assert_no_layer_of_the_pool(compiled, ck):
+    """No instruction inside the layer loop produces one layer of the
+    pool — an array of the pages' (or the scales') dtype and trailing
+    dims with one layer's element count, however the leading dims are
+    folded — and the program's temporaries are smaller than one layer's
+    K pool. A Mosaic call handed ``pool[li]``, or a layer set back with
+    ``.at[li].set``, shows as both: the parent read 269,485,568 bytes of
+    temporaries at 16 x 4096 (two layers of a 134 MB pool).
+
+    int8 pages: the kernel's tiling pads the scales' 8 KV heads to 128
+    lanes, and XLA relays the stacked scales out to that at the
+    program's edge (PERF.md section 7); that one relayout is taken off
+    the temporaries before the comparison."""
+    import math
+    import re
+
+    leaves = [v for k, v in ck.items() if k != "ptab"]
+    found = []
+    for line in _loop_instructions(compiled.as_text()):
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\w+)\[([\d,]*)\]", line)
+        if not m or m.group(1) not in _HLO_DTYPE:
+            continue
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        for leaf in leaves:
+            tail = leaf.shape[3:]       # (KV, hd) of pages, (KV,) of scales
+            if (_HLO_DTYPE[m.group(1)] == leaf.dtype
+                    and dims[-len(tail):] == tail
+                    and math.prod(dims) == math.prod(leaf.shape[1:])):
+                found.append(line.strip()[:200])
+    assert not found, "\n".join(found)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if "scales" in ck:
+        temp -= 2 * math.prod(ck["scales"].shape[:-1]) * 128 * 4    # K, V
+    assert temp < math.prod(ck["pages"].shape[1:]) * ck["pages"].dtype.itemsize
+
+
+@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+@pytest.mark.parametrize("context", [1024, 4096])
+def test_decode_step_copies_no_layer_of_the_pool(topo, context, kv_dtype):
+    """decode_step with donated caches at the benchmark cells' cache
+    geometry: the paged kernel reads the stacked pool by layer index and
+    the row write is an in-place scatter on the scan carry."""
+    A = _on(SingleDeviceSharding(topo.devices[0]))
+    params, ck, cv = _abstract_7b(A, context, kv_dtype)
+
+    def decode(p, t, ln, ck, cv):
+        return llama.decode_step(p, CFG_7B_L12, t, ln, ck, cv)
+
+    compiled = jax.jit(decode, donate_argnums=(3, 4)).lower(
+        params, A((S,), jnp.int32), A((S,), jnp.int32), ck, cv).compile()
+    _assert_no_layer_of_the_pool(compiled, ck)
+
+
+@pytest.mark.parametrize("context", [1024, 4096])
+def test_ragged_prefill_copies_no_layer_of_the_pool(topo, context):
+    """The same for a continued 1024-token pack through the ragged
+    prefill kernel (bf16 pages: int8 pages take the jnp path)."""
+    A = _on(SingleDeviceSharding(topo.devices[0]))
+    params, ck, cv = _abstract_7b(A, context, jnp.bfloat16)
+    i32, N = jnp.int32, 1024
+    assert llama.ragged_attn_impl(CFG_7B_L12, ck, N, True) == \
+        "pallas:ragged_prefill"
+
+    def pack(p, t, pos, so, ss, st, off, ln, ck, cv):
+        return llama.ragged_prefill(p, CFG_7B_L12, t, pos, so, ss, st, off,
+                                    ln, ck, cv, continued=True)
+
+    compiled = jax.jit(pack, donate_argnums=(8, 9)).lower(
+        params, A((N,), i32), A((N,), i32), A((N,), i32),
+        *_seg_tables(A), ck, cv).compile()
+    _assert_no_layer_of_the_pool(compiled, ck)
