@@ -28,6 +28,14 @@ from localai_tpu.backend.service import (BackendServicer, make_server,
 
 log = logging.getLogger("localai_tpu.backend.runner")
 
+# config.json model_type -> how tpu-llm serves it: the dense Llama block
+# (models/llama.py), or a family module localai_tpu/models/<model_type>.py
+# and its config class
+_LLAMA_TYPES = frozenset({"llama", "mistral", "qwen2", "qwen", "gemma",
+                          "phi3"})
+_FAMILIES = {"mamba": "MambaConfig", "rwkv": "RwkvConfig",
+             "olmo_hybrid": "OlmoHybridConfig"}
+
 # engine lifecycle failure kinds -> gRPC status codes, so the core can
 # distinguish shed (retry later) from timeout from stall without parsing
 # message strings (services/errors.py maps them back to HTTP 429/504/503)
@@ -186,21 +194,18 @@ class EngineServicer(BackendServicer):
             with open(cfg_path) as f:
                 cfg_dict = json.load(f)
             mtype = cfg_dict.get("model_type", "")
-            if mtype in ("mamba", "rwkv"):
-                # non-attention LLM families (reference: backend/python/
-                # mamba selective-scan SSM; backend/go/llm/rwkv/rwkv.go
-                # linear-attention RWKV): fixed-size recurrent state rides
-                # the same engine slot lanes via the family adapter
-                if mtype == "mamba":
-                    from localai_tpu.models import mamba as family
+            if mtype in _FAMILIES:
+                # families beside the dense Llama block ride the same
+                # engine slots through the family adapter: mamba / rwkv
+                # with a fixed-size recurrent state in the cache lanes
+                # (reference: backend/python/mamba, backend/go/llm/rwkv),
+                # olmo_hybrid with paged K/V and a recurrent state
+                import importlib
 
-                    cfg = family.MambaConfig.from_hf_config(cfg_dict,
-                                                            dtype=dtype)
-                else:
-                    from localai_tpu.models import rwkv as family
-
-                    cfg = family.RwkvConfig.from_hf_config(cfg_dict,
-                                                           dtype=dtype)
+                family = importlib.import_module(
+                    "localai_tpu.models." + mtype)
+                cfg = getattr(family, _FAMILIES[mtype]).from_hf_config(
+                    cfg_dict, dtype=dtype)
                 if request.lora_adapter:
                     raise ValueError("LoRA adapters are llama-family only")
                 if request.draft_model:
@@ -216,6 +221,11 @@ class EngineServicer(BackendServicer):
                     raise ValueError(
                         f"quantization={request.quantization!r} is not "
                         f"supported for {mtype} (only weight-only int8)")
+            elif mtype and mtype not in _LLAMA_TYPES:
+                raise ValueError(
+                    f"model_type {mtype!r} is not served by tpu-llm: it "
+                    f"knows {sorted(_LLAMA_TYPES)} (the dense Llama block) "
+                    f"and {sorted(_FAMILIES)}")
             else:
                 cfg = llama.LlamaConfig.from_hf_config(cfg_dict, dtype=dtype)
 
@@ -241,12 +251,13 @@ class EngineServicer(BackendServicer):
         if family is not None and cache_dtype == jnp.int8:
             # mamba/rwkv cache lanes hold recurrent STATE, not KV rows;
             # quantizing recurrent state accumulates error every step
+            # (olmo_hybrid's full layers have rows, but no int8 form)
             raise ValueError(
                 f"kv_cache_dtype {kv_dt_name!r} is llama-family only "
                 f"(mamba/rwkv cache lanes carry recurrent state, kept "
                 f"fp32); float dtypes are accepted as no-ops for these "
                 f"families")
-        if family is not None:
+        if family is not None and "paged" not in family.CAPABILITIES:
             # float kv_cache_dtype values are NO-OPS for recurrent-state
             # families (their init_cache pins fp32 — SSM/wkv recurrences
             # are precision-sensitive) and the YAML validator accepts
@@ -261,6 +272,11 @@ class EngineServicer(BackendServicer):
         if tp * dp > 1:
             mesh = meshlib.make_mesh(meshlib.MeshPlan(dp=dp, tp=tp),
                                      devices=jax.devices()[: tp * dp])
+        if mesh is not None and "mesh" not in (family or llama).CAPABILITIES:
+            raise ValueError(
+                f"{family.__name__.rsplit('.', 1)[-1]} serves on one device: "
+                "the family declares no sharding rule for its cache (set "
+                f"mesh_tp: 1, not {tp})")
         lora_dir = request.lora_adapter
         if lora_dir and request.model_path and not os.path.isabs(lora_dir):
             lora_dir = os.path.join(request.model_path, lora_dir)
@@ -271,12 +287,14 @@ class EngineServicer(BackendServicer):
                           ).strip().lower() in ("1", "true", "on", "yes")
         self.weight_stream_stats = None
         if family is not None:
-            params = family.load_hf_params(model_dir, cfg, dtype=dtype)
             # r5 (VERDICT r4 #7): mamba is no longer a single-chip
             # second-class citizen — weight-only int8 of the mixer
             # projections and Megatron-style tp over d_inner
-            if request.quantization == "int8" or request.dtype == "int8":
-                params = family.quantize_params(params)
+            params = family.load_hf_params(
+                model_dir, cfg, dtype=dtype,
+                quantize=request.quantization or
+                ("int8" if request.dtype == "int8" else ""),
+                tracer=self.tracer)
             if mesh is not None and mtype == "mamba":
                 from jax.sharding import PartitionSpec as P
 

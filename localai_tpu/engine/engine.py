@@ -704,15 +704,24 @@ class Engine:
         self.replica_id = int(replica_id)
         self._shared_kv = shared_kv
         self._hstore_owned = shared_kv is None
-        # model-family adapter (init_cache / engine_decode / prefill):
-        # llama-family by default; models/mamba.py rides the same slot
-        # model with a fixed-size (conv, ssm) state in the cache lanes.
-        # Families without a positional KV-row cache get the llama-only
-        # features gated off (prefix reuse, prompt-cache persistence,
-        # fork-dedup, multimodal injection, speculative draft, ga).
+        # model-family adapter (init_cache / engine_decode / prefill /
+        # ragged_prefill). What the engine may do with a family is what
+        # the family DECLARES (its CAPABILITIES): "paged" (K/V rows in the
+        # page pool, through llama's attention kernels), "packed_prefill"
+        # (a ragged_prefill forward), "prefix_reuse" (a slot can resume
+        # from cached K/V pages alone: prefix cache, COW sharing, fork
+        # dedup, prompt-cache files, offload), "speculation",
+        # "self_extend", "multimodal", and "mesh" (the family's params
+        # and cache have sharding rules). models/llama.py declares all;
+        # mamba / rwkv only "mesh" (a fixed-size state in the cache
+        # lanes, on the contiguous fallback); models/olmo_hybrid.py the
+        # first two: its slot holds paged K/V AND a recurrent state, a
+        # page without the state at its boundary cannot be resumed from,
+        # and the state has no sharding rule.
         self.family = family if family is not None else llama
-        self._fam_llama = self.family is llama
-        if self._fam_llama:
+        self._caps = caps = frozenset(
+            getattr(self.family, "CAPABILITIES", ()))
+        if "paged" in caps:
             # where attention runs (Pallas kernels or jnp) is decided
             # HERE, from the platform and the mesh, and rides the config
             # into every trace; state_snapshot()["attention"] reports it
@@ -720,9 +729,12 @@ class Engine:
                 model_cfg, attn=llama.attn_target(model_cfg, mesh))
         self._fam_name = getattr(self.family, "__name__",
                                  "llama").rsplit(".", 1)[-1]
-        if not self._fam_llama:
-            assert draft is None, "draft speculation is llama-family only"
-            assert self.ecfg.ga_n <= 1, "self-extend is llama-family only"
+        assert mesh is None or "mesh" in caps, \
+            f"a mesh is not declared by {self._fam_name}"
+        assert draft is None or "speculation" in caps, \
+            f"draft speculation is not declared by {self._fam_name}"
+        assert self.ecfg.ga_n <= 1 or "self_extend" in caps, \
+            f"self-extend is not declared by {self._fam_name}"
         # multi-host lockstep mode: every device dispatch is mirrored to
         # follower processes (see parallel/lockstep.py); features whose
         # dispatches are not in the descriptor set are rejected/disabled
@@ -758,8 +770,8 @@ class Engine:
             mode = "ngram"
         else:   # auto
             mode = "model" if self.draft_params is not None else "ngram"
-        if (not self._fam_llama or bus is not None or self.ecfg.ga_n > 1
-                or self.ecfg.n_draft <= 0):
+        if ("speculation" not in caps or bus is not None
+                or self.ecfg.ga_n > 1 or self.ecfg.n_draft <= 0):
             mode = "off"
         self._spec_mode = mode
         self._state_shardings = self._make_state_shardings()
@@ -777,7 +789,7 @@ class Engine:
         # in so compressed rows only ever match under the same mapping.
         # "auto" still degrades to contiguous under ga (the historical
         # default); opt in with an explicit kv_layout=paged.
-        self._paged = self._fam_llama and (
+        self._paged = "paged" in caps and (
             self.ecfg.kv_layout == "paged"
             or (self.ecfg.kv_layout == "auto" and bus is None
                 and self.ecfg.ga_n <= 1))
@@ -807,10 +819,11 @@ class Engine:
                 # would manufacture admission failures, not save HBM.
                 self._pool_pages = max(full * 3 // 4, S + C // pg)
             self._pool = PagePool(S, C, pg, self._pool_pages)
-            if self.ecfg.kv_prefix_cache:
+            if self.ecfg.kv_prefix_cache and "prefix_reuse" in caps:
                 # cross-release page retention; NEVER built for the
                 # contiguous fallbacks (lockstep / mamba / rwkv) — those
-                # layouts have no pages to retain
+                # layouts have no pages to retain — nor for a family
+                # whose slot cannot resume from pages alone
                 from localai_tpu.engine import prefix_cache
 
                 scope = prefix_cache.build_scope(
@@ -902,6 +915,8 @@ class Engine:
             model_cfg, S, C, self.ecfg.cache_dtype,
             **({"page_size": pg, "num_pages": self._pool_pages}
                if self._paged else {}))
+        # per-slot recurrent state beside the K/V rows (ops/kvcache.py)
+        self._state_bytes = kvcache.state_bytes(self.ck)
         # draft cache is allocated LAZILY at the first spec-eligible
         # admission (r2 allocated it up front, doubling per-slot KV HBM
         # even when no request could ever speculate)
@@ -1016,8 +1031,14 @@ class Engine:
         # a packed ragged program (_get_draft_packed_fn); a ga engine's
         # UNcompressed slots pack normally (compressed ones need
         # explicit grouped positions and go singly, _prefill_ga_piece).
-        self._packed = (self.ecfg.prefill_packed and self._fam_llama
-                        and bus is None)
+        self._packed = (self.ecfg.prefill_packed
+                        and "packed_prefill" in caps and bus is None)
+        # the per-slot prefill programs serve what cannot ride a pack
+        # (multimodal shapes, compressed self-extend positions, the
+        # snap-back window): a family that declares none of those and
+        # packs never dispatches them, so they are not warmed either
+        self._per_slot_prefill = (not self._packed or bool(
+            caps & {"multimodal", "self_extend", "prefix_reuse"}))
         fuse = str(self.ecfg.prefill_packed_fuse)
         # fused-tick mode: "off" | "mono" (prefill + first tokens +
         # burst as literally one program) | "split" (early-emit pair:
@@ -1123,8 +1144,9 @@ class Engine:
                         "device_kind": dev0.device_kind,
                         "device_count": len(jax.devices())}
         peak = sysobs.peak_device_flops(dev0)   # unknown accelerator: raises
+        # (the formula is the dense Llama block's)
         fpt = (sysobs.flops_per_token(self.cfg, ctx=C // 2)
-               if self._fam_llama else 0.0)
+               if self.family is llama else 0.0)
         self._goodput = sysobs.GoodputMeter(flops_per_tok=fpt,
                                             peak_flops=peak)
         # exemplar tracking: worst observation per histogram since the
@@ -1517,7 +1539,7 @@ class Engine:
         return dispatch
 
     def _decode_attn(self) -> str:
-        if not self._fam_llama:
+        if "paged" not in self._caps:
             return f"{self._fam_name}:recurrent"
         return llama.decode_attn_impl(self.cfg, self.ck)
 
@@ -1528,7 +1550,7 @@ class Engine:
         """Where this engine's attention runs, and per compiled program
         the implementation it was built with plus its dispatch count
         since warm-up."""
-        target = self.cfg.attn if self._fam_llama else None
+        target = self.cfg.attn if "paged" in self._caps else None
         pallas = bool(target and target.pallas)
         return {
             "pallas": pallas,
@@ -1552,7 +1574,7 @@ class Engine:
         dp = self.mesh.shape.get("dp", 1)
         tp = self.mesh.shape.get("tp", 1)
         slot_ax = "dp" if dp > 1 and self.ecfg.num_slots % dp == 0 else None
-        if self._fam_llama:
+        if "paged" in self._caps:
             # [L, S, C, KV, hd]: kv heads on tp
             kv_ax = "tp" if tp > 1 and self.cfg.num_kv_heads % tp == 0 \
                 else None
@@ -2304,6 +2326,9 @@ class Engine:
         which sets self._adm_win_off; this method always resets it."""
         pool = self._pool
         self._adm_win_off = 0
+        if "prefix_reuse" not in self._caps:
+            pool.release(slot, 0)       # tier 4, always
+            return 0
         min_rows = max(1, self.ecfg.kv_prefix_cache_min_rows)
         cap = len(ids) - 1              # always leave >= 1 token to prefill
         best_src, best_rows = -1, 0
@@ -3072,7 +3097,7 @@ class Engine:
                             c_rpos, self.bias, self.rng_keys,
                             spp, self.active_dev, c_mu, no_ov,
                             no_spec)
-        for bucket in self._buckets:
+        for bucket in (self._buckets if self._per_slot_prefill else ()):
             one = np.ones((1,), np.int32)
             zero = np.zeros((1,), np.int32)
             tokens = np.zeros((1, bucket), np.int32)
@@ -3757,6 +3782,9 @@ class Engine:
             "watermarks": self._wm.snapshot(),
             "goodput": self._goodput.snapshot(),
             "weight_bytes": self._weight_bytes,
+            "family": self._fam_name,
+            "capabilities": sorted(self._caps),
+            "recurrent_state_bytes": self._state_bytes,
             **self._device,
             "device_mem": sysobs.device_memory_stats(),
             "attention": self._attention_report(),
@@ -4174,7 +4202,7 @@ class Engine:
         # op is not in the descriptor set — mutually exclusive
         if not req.grammar and req.mm_vectors is None \
                 and self.ecfg.ga_n <= 1 and self._bus is None \
-                and self._fam_llama:
+                and "prefix_reuse" in self._caps:
             # truncation depends on max_new_tokens; bucket it into the key
             key = (tuple(req.prompt_ids),
                    min(req.max_new_tokens, self.ecfg.max_context // 4))
@@ -4431,6 +4459,9 @@ class Engine:
             self.tracer.record("resume", f"slot{slot}", t0,
                                time.monotonic(), rid=req.request_id,
                                args={"reused_rows": s.reused,
+                                     # prompt AND produced tokens: all of
+                                     # them where a dropped recurrent
+                                     # state cannot be restored
                                      "reprefill_rows": len(ids) - s.reused})
         return slot
 
@@ -4801,8 +4832,9 @@ class Engine:
             ids = [getattr(self.tokenizer, "eos_token_id", 0) or 0]
 
         mm_pos = mm_vec = None
-        if req.mm_vectors is not None and not self._fam_llama:
-            raise ValueError("multimodal injection is llama-family only")
+        if req.mm_vectors is not None and "multimodal" not in self._caps:
+            raise ValueError("multimodal injection is not declared by "
+                             f"the {self._fam_name} family")
         if req.mm_vectors is not None and len(req.mm_positions):
             pos = np.asarray(req.mm_positions, np.int64) - shift
             keep = (pos >= 0) & (pos < len(ids))
@@ -4828,10 +4860,11 @@ class Engine:
         # never reuse (their cache rows hold image embeddings, not tokens).
         if common < 16 or mm_pos is not None:
             common = 0
-        if self.ecfg.ga_n > 1 or not self._fam_llama:
-            # self-extend re-maps positions as the context grows, and
-            # non-llama families have no positional KV rows to share —
-            # prefix reuse and prompt-cache restore are llama-only
+        if self.ecfg.ga_n > 1 or "prefix_reuse" not in self._caps:
+            # self-extend re-maps positions as the context grows, and a
+            # family that does not declare prefix reuse has no rows a
+            # slot could resume from (none at all, or none without the
+            # recurrent state at their boundary)
             common = 0
         win_off = 0
         if self._paged:
@@ -4849,8 +4882,8 @@ class Engine:
                 # snap-back admission (ISSUE 16): ``common`` is COMPACT
                 # (sink + window rows); win_off is the skipped middle
                 win_off = self._adm_win_off
-        if self._fam_llama and self.ecfg.ga_n <= 1 and mm_pos is None \
-                and win_off == 0:
+        if "prefix_reuse" in self._caps and self.ecfg.ga_n <= 1 \
+                and mm_pos is None and win_off == 0:
             # (the disk prompt cache stores contiguous rows — a windowed
             # table has no contiguous image to overlay, skip it)
             common = self._restore_prompt_cache(slot, req, ids, common)
@@ -4990,7 +5023,11 @@ class Engine:
             # (_paged_admission / _restore_prompt_cache above)
             tr.record("admission", f"slot{slot}", t_adm, t1,
                       rid=req.request_id,
-                      args={"prompt_tokens": len(ids), "reused_rows": common})
+                      args={"prompt_tokens": len(ids), "reused_rows": common,
+                            # the prefill that starts at row 0 zeroes
+                            # the slot's recurrent state
+                            "state_reset": bool(self._state_bytes
+                                                and common == 0)})
         return slot, ids, s
 
     def _start_fork_sibling(self, req: GenRequest, leader_slot: int,
@@ -5257,7 +5294,7 @@ class Engine:
         """Persist the slot's committed rows + tokens on finish."""
         req = s.req
         if not req.prompt_cache_path or req.prompt_cache_ro \
-                or not self._fam_llama:
+                or "prefix_reuse" not in self._caps:
             return
         if self.ecfg.ga_n > 1:
             # rows may hold position-compressed (self-extend) keys; a

@@ -67,7 +67,9 @@ def find_gguf(model_dir: str) -> Optional[str]:
 
 
 _QUANT_NAMES = {"embed", "lm_head", "wq", "wk", "wv", "wo",
-                "w_gate", "w_up", "w_down"}
+                "w_gate", "w_up", "w_down",
+                # models/olmo_hybrid.py's linear-attention projections
+                "lin_qkv", "lin_g", "lin_o"}
 
 
 def _make_put(cfg, mesh, dtype, quantize, adapter=None, pace=None,
@@ -282,6 +284,86 @@ def load_llama_params(
     source, quantize = _host_leaf_source(model_dir, cfg, quantize)
     return _assemble(source, _make_put(cfg, mesh, dtype, quantize, adapter,
                                        tracer=tracer), tracer)
+
+
+def _olmo_hybrid_leaf_source(model_dir: str, cfg):
+    """(spec_path, host array) for models/olmo_hybrid.py's stacked layout:
+    linear-attention leaves ``[periods, 3, ...]``, full-attention leaves
+    ``[periods, ...]``, the MLP and the two post-norms of every layer
+    ``[periods, 4, ...]``. Linear weights become ``[in, out]``; q|k|v (and
+    a|b) projections are laid side by side for one matmul; the three
+    depthwise convolutions ``[ch, 1, W]`` become one ``[W, ch]`` leaf."""
+    tensors = _open_shards(model_dir)
+    P_ = cfg.periods
+
+    def top(name: str) -> np.ndarray:
+        return tensors[name].get_tensor(name)
+
+    def get(i: int, name: str) -> np.ndarray:
+        return top(f"model.layers.{i}.{name}")
+
+    def per_period(js, one) -> np.ndarray:
+        """one(layer index) stacked [periods, len(js), ...]."""
+        return np.stack([np.stack([one(4 * p + j) for j in js])
+                         for p in range(P_)])
+
+    def lin(one):
+        return per_period((0, 1, 2), one)
+
+    def full(name: str, transpose=False):
+        a = per_period((3,), lambda i: get(i, name))[:, 0]
+        return a.swapaxes(-1, -2) if transpose else a
+
+    def every(name: str, transpose=False):
+        a = per_period((0, 1, 2, 3), lambda i: get(i, name))
+        return a.swapaxes(-1, -2) if transpose else a
+
+    def side_by_side(i: int, names, conv=False):
+        if conv:                        # [ch, 1, W] -> [W, ch]
+            return np.concatenate(
+                [get(i, f"linear_attn.{n}_conv1d.weight")[:, 0, :].T
+                 for n in names], axis=-1)
+        return np.concatenate(
+            [get(i, f"linear_attn.{n}_proj.weight").T for n in names],
+            axis=-1)
+
+    la, sa = "linear_attn.", "self_attn."
+    yield ("embed",), top("model.embed_tokens.weight")
+    yield ("layers", "lin_qkv"), lin(lambda i: side_by_side(i, "qkv"))
+    yield ("layers", "lin_g"), lin(lambda i: get(i, la + "g_proj.weight").T)
+    yield ("layers", "lin_ab"), lin(lambda i: side_by_side(i, "ab"))
+    yield ("layers", "lin_o"), lin(lambda i: get(i, la + "o_proj.weight").T)
+    yield ("layers", "lin_conv"), lin(
+        lambda i: side_by_side(i, "qkv", conv=True))
+    yield ("layers", "lin_A_log"), lin(lambda i: get(i, la + "A_log"))
+    yield ("layers", "lin_dt_bias"), lin(lambda i: get(i, la + "dt_bias"))
+    yield ("layers", "lin_o_norm"), lin(
+        lambda i: get(i, la + "o_norm.weight"))
+    for leaf, name in (("wq", "q"), ("wk", "k"), ("wv", "v"), ("wo", "o")):
+        yield ("layers", leaf), full(sa + name + "_proj.weight", True)
+    yield ("layers", "q_norm"), full(sa + "q_norm.weight")
+    yield ("layers", "k_norm"), full(sa + "k_norm.weight")
+    yield ("layers", "mixer_norm"), every("post_attention_layernorm.weight")
+    yield ("layers", "mlp_norm"), every("post_feedforward_layernorm.weight")
+    yield ("layers", "w_gate"), every("mlp.gate_proj.weight", True)
+    yield ("layers", "w_up"), every("mlp.up_proj.weight", True)
+    yield ("layers", "w_down"), every("mlp.down_proj.weight", True)
+    yield ("final_norm",), top("model.norm.weight")
+    if not cfg.tie_word_embeddings:
+        yield ("lm_head",), top("lm_head.weight").T
+
+
+def load_olmo_hybrid_params(model_dir: str, cfg, dtype=jnp.bfloat16,
+                            quantize: str = "", tracer=None) -> dict:
+    """Load an ``olmo_hybrid`` checkpoint (HF safetensors) through the
+    same cast / int8 / placement path as ``load_llama_params``. No mesh:
+    the family refuses one (models/olmo_hybrid.py)."""
+    if quantize not in ("", "int8"):
+        raise ValueError(f"quantization={quantize!r} is not supported for "
+                         "olmo_hybrid (only weight-only int8)")
+    return _assemble(_olmo_hybrid_leaf_source(model_dir, cfg),
+                     _make_put(cfg, None, dtype, quantize, tracer=tracer),
+                     tracer)
 
 
 def stream_llama_params(

@@ -43,6 +43,12 @@ from localai_tpu.ops.attention import (
 )
 
 
+# what the engine may do with this family (engine.py, Engine.__init__)
+CAPABILITIES = frozenset({"paged", "packed_prefill", "prefix_reuse",
+                          "speculation", "self_extend", "multimodal",
+                          "mesh"})
+
+
 def _decode_attn_mode() -> str:
     """LOCALAI_DECODE_ATTN: scatter (default) | append | pallas — the
     CONTIGUOUS layout's decode attention (the paged layout has one)."""
@@ -461,6 +467,45 @@ def ragged_kernel_shape_fallback(cache_k, N: int, cfg: LlamaConfig) -> bool:
     return _ragged_plan(cfg, cache_k, N) is None
 
 
+def ragged_attend_write(cfg, q, k, v, ck, cv, li, seg_of, seg_slots,
+                        seg_start, seg_off, seg_len, slot_of, positions,
+                        continued: bool):
+    """One layer of a packed prefill: attention for the [N] packed tokens
+    over their own pack and (``continued``) their slots' committed rows,
+    then the ragged scatter of the new K/V rows into layer ``li`` of the
+    whole cache. q [N, H, hd]; k, v [N, KV, hd] -> (attn [N, H, hd], ck,
+    cv). ``cfg`` gives ``attn``, ``num_kv_heads``, ``q_per_kv`` and
+    ``head_dim_`` (models/olmo_hybrid.py's full layers come here too)."""
+    from localai_tpu.ops.ragged_prefill import ragged_prefill_attention
+
+    N = q.shape[0]
+    # committed rows are read BEFORE this pack's scatter (the same
+    # no-read-after-write rule as every other attention path here)
+    if ragged_attn_impl(cfg, ck, N, continued).startswith("pallas"):
+        from localai_tpu.ops.pallas.ragged_prefill import (
+            ragged_prefill_attention_pallas)
+
+        # the kernel indexes the stacked pool by layer: slicing the
+        # layer out here would copy it out of the scan carry
+        qb, pkb = _ragged_plan(cfg, ck, N)
+        heads, rep = P(None, "tp", None), P(None)
+        attn = _on_mesh(
+            cfg, partial(ragged_prefill_attention_pallas,
+                         q_per_kv=cfg.q_per_kv, pkb=pkb, qb=qb),
+            (heads, heads, heads, _POOL, _POOL, P(None, None),
+             rep, rep, rep, rep, P()), heads)(
+            q, k, v, ck["pages"], cv["pages"], ck["ptab"],
+            seg_slots, seg_start, seg_off, seg_len, li)
+    else:
+        attn = ragged_prefill_attention(
+            q, k, v, seg_of, seg_slots, seg_start,
+            kvcache.layer(ck, li), kvcache.layer(cv, li),
+            cfg.q_per_kv, continued=continued)
+    ck = kvcache.scatter_ragged(ck, li, slot_of, positions, k)
+    cv = kvcache.scatter_ragged(cv, li, slot_of, positions, v)
+    return attn, ck, cv
+
+
 def ragged_prefill(
     params: dict,
     cfg: LlamaConfig,
@@ -507,7 +552,6 @@ def ragged_prefill(
     (position sentinel C drops the scatter) and their state is never
     sampled (slot sentinel drops the engine's key/mu writes).
     """
-    from localai_tpu.ops.ragged_prefill import ragged_prefill_attention
     from localai_tpu.parallel.sharding import overlap_halves
 
     N = tokens.shape[0]
@@ -529,7 +573,9 @@ def ragged_prefill(
             q = apply_rope(q, sin, cos)
             k = apply_rope(k, sin, cos)
         with _scope("layer/attn"):
-            attn, ck, cv = attend_write(q, k, v, ck, cv, li)
+            attn, ck, cv = ragged_attend_write(
+                cfg, q[0], k[0], v[0], ck, cv, li, seg_of, seg_slots,
+                seg_start, seg_off, seg_len, slot_of, positions, continued)
         attn_r = attn[None].reshape(1, N, -1)
 
         def out_proj(t):
@@ -549,33 +595,6 @@ def ragged_prefill(
             x = x + out_proj(attn_r)
             x = x + mlp_half(x)
         return (x, ck, cv), None
-
-    def attend_write(q, k, v, ck, cv, li):
-        # committed rows are read BEFORE this pack's scatter (the same
-        # no-read-after-write rule as every other attention path here)
-        if ragged_attn_impl(cfg, ck, N, continued).startswith("pallas"):
-            from localai_tpu.ops.pallas.ragged_prefill import (
-                ragged_prefill_attention_pallas)
-
-            # the kernel indexes the stacked pool by layer: slicing the
-            # layer out here would copy it out of the scan carry
-            qb, pkb = _ragged_plan(cfg, ck, N)
-            heads, rep = P(None, "tp", None), P(None)
-            attn = _on_mesh(
-                cfg, partial(ragged_prefill_attention_pallas,
-                             q_per_kv=cfg.q_per_kv, pkb=pkb, qb=qb),
-                (heads, heads, heads, _POOL, _POOL, P(None, None),
-                 rep, rep, rep, rep, P()), heads)(
-                q[0], k[0], v[0], ck["pages"], cv["pages"], ck["ptab"],
-                seg_slots, seg_start, seg_off, seg_len, li)
-        else:
-            attn = ragged_prefill_attention(
-                q[0], k[0], v[0], seg_of, seg_slots, seg_start,
-                kvcache.layer(ck, li), kvcache.layer(cv, li),
-                cfg.q_per_kv, continued=continued)
-        ck = kvcache.scatter_ragged(ck, li, slot_of, positions, k[0])
-        cv = kvcache.scatter_ragged(cv, li, slot_of, positions, v[0])
-        return attn, ck, cv
 
     layers = dict(params["layers"])
     layers["_idx"] = jnp.arange(cfg.num_layers, dtype=jnp.int32)

@@ -25,6 +25,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# what the engine may do with this family (engine.py, Engine.__init__): a
+# fixed-size state in the cache lanes on the contiguous layout, no more
+CAPABILITIES = frozenset({"mesh"})
+
 
 @dataclasses.dataclass(frozen=True)
 class MambaConfig:
@@ -139,7 +143,8 @@ def init_params(cfg: MambaConfig, key: jax.Array, dtype=None) -> dict:
     return params
 
 
-def load_hf_params(model_dir: str, cfg: MambaConfig, dtype=jnp.float32) -> dict:
+def load_hf_params(model_dir: str, cfg: MambaConfig, dtype=jnp.float32,
+                   quantize: str = "", tracer=None) -> dict:
     from localai_tpu.engine.weights import _open_shards
 
     shards = _open_shards(model_dir)
@@ -184,6 +189,8 @@ def load_hf_params(model_dir: str, cfg: MambaConfig, dtype=jnp.float32) -> dict:
     }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = jnp.asarray(get("lm_head.weight").T, dtype)
+    if quantize == "int8":
+        params = quantize_params(params)
     return params
 
 

@@ -30,6 +30,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# what the engine may do with this family (engine.py, Engine.__init__): a
+# fixed-size state in the cache lanes on the contiguous layout, no more
+CAPABILITIES = frozenset({"mesh"})
+
 _MAX_INIT = -1e38
 
 
@@ -137,7 +141,8 @@ def init_params(cfg: RwkvConfig, key: jax.Array, dtype=None) -> dict:
     return params
 
 
-def load_hf_params(model_dir: str, cfg: RwkvConfig, dtype=jnp.float32) -> dict:
+def load_hf_params(model_dir: str, cfg: RwkvConfig, dtype=jnp.float32,
+                   quantize: str = "", tracer=None) -> dict:
     """HF ``RwkvForCausalLM`` safetensors layout.
 
     HF's ``rescale_every`` machinery (output projections divided by
@@ -202,6 +207,8 @@ def load_hf_params(model_dir: str, cfg: RwkvConfig, dtype=jnp.float32) -> dict:
             "ffn_value": stack(ff + "value.weight", transpose=True),
         },
     }
+    if quantize == "int8":
+        params = quantize_params(params)
     return params
 
 

@@ -27,6 +27,22 @@ engine's jitted bodies stay shape-stable and donation-friendly:
    REF-COUNTED page sharing instead of a row copy (copy-on-write: the
    first divergent page is cloned, see clone_page / engine admission).
 
+3. RECURRENT STATE beside the pages (models/olmo_hybrid.py): a family in
+   which only some layers have K/V rows keeps, for the others, a
+   fixed-size state per slot as further leaves of the paged ``cache_k``
+   dict — ``STATE_LEAVES``: "delta" [L_lin, S, H, K, V] float32 and
+   "conv" [L_lin, S, 3, Ch] — with "pages" then holding the full-attention
+   layers alone. Every function here that rebuilds a paged dict from an
+   old one (with_page_table, scatter_*, clone_page) carries them through
+   untouched; ``layer`` and the gathers return K/V views without them.
+   They are written only by the family's own programs: zeroed by a
+   prefill segment that starts at position 0, untouched for an inactive
+   slot. No page helper reads them, which is why such a family declares
+   no prefix reuse (engine.py): a page of K/V without the state at its
+   boundary cannot be resumed from. There is no sharding rule for them:
+   such a family does not declare "mesh", and the runner and the engine
+   refuse it one.
+
 Quantized representation (int8, per-row-per-head scales).
 
 `kv_cache_dtype: int8` in the model YAML (reference analogue: llama.cpp's
@@ -99,6 +115,18 @@ def page_chain_hash(parent: bytes, token_ids, scope: bytes) -> bytes:
     h.update(parent)
     h.update(np.asarray(token_ids, np.int64).tobytes())
     return h.digest()
+
+
+STATE_LEAVES = ("delta", "conv")
+
+
+def state_bytes(cache: Any) -> int:
+    """Device bytes a cache holds as per-slot recurrent state (0 for a
+    cache of K/V rows alone)."""
+    if not isinstance(cache, dict):
+        return 0
+    return int(sum(cache[k].size * cache[k].dtype.itemsize
+                   for k in STATE_LEAVES if k in cache))
 
 
 def wants_quant(dtype) -> bool:

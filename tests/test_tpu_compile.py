@@ -345,3 +345,104 @@ def test_ragged_prefill_copies_no_layer_of_the_pool(topo, context):
         params, A((N,), i32), A((N,), i32), A((N,), i32),
         *_seg_tables(A), ck, cv).compile()
     _assert_no_layer_of_the_pool(compiled, ck)
+
+
+# ---------- the hybrid family: paged K/V beside a recurrent state ----------
+
+S_H, C_H, POOL_H = 32, 2048, 768     # the olmo-hybrid cell's cache geometry
+
+
+def _abstract_hybrid(A):
+    """(cfg, params, ck, cv): Olmo-Hybrid-7B widths, 12 layers, bf16
+    weights, the cell's slots and page pool, as ShapeDtypeStructs."""
+    from localai_tpu.models import olmo_hybrid as oh
+
+    cfg = oh.OlmoHybridConfig(num_layers=12,
+                              attn=llama.AttnTarget(pallas=True))
+
+    def place(tree):
+        return jax.tree.map(lambda x: A(x.shape, x.dtype), tree)
+
+    params = jax.eval_shape(
+        lambda: oh.init_params(cfg, jax.random.PRNGKey(0)))
+    ck, cv = jax.eval_shape(lambda: oh.init_cache(
+        cfg, S_H, C_H, jnp.bfloat16, page_size=PAGE, num_pages=POOL_H))
+    return cfg, place(params), place(ck), place(cv)
+
+
+def test_gated_delta_decode_kernel_compiles(topo):
+    """The in-place state update at the cell's shape: 9 x 32 slots x 30
+    heads x 96 x 192 float32, the layer traced."""
+    from localai_tpu.ops.pallas.gated_delta import gated_delta_decode_pallas
+
+    A = _on(SingleDeviceSharding(topo.devices[0]))
+    f32 = jnp.float32
+    H, K, V = 30, 96, 192
+    compiled = jax.jit(gated_delta_decode_pallas, donate_argnums=(0,)).lower(
+        A((9, S_H, H, K, V), f32), A((), jnp.int32), A((S_H, H, K), f32),
+        A((S_H, H, K), f32), A((S_H, H, V), f32), A((S_H, H), f32),
+        A((S_H, H), f32), A((S_H,), jnp.bool_)).compile()
+    # aliased onto its input: nothing the size of a layer of state is made
+    assert compiled.memory_analysis().temp_size_in_bytes < S_H * H * K * V * 4
+
+
+def _assert_state_and_pool_stay_in_place(compiled, ck):
+    """The program's temporaries are smaller than ONE layer of the
+    recurrent state as the chip lays it out (192 lanes pad to 256) plus one
+    layer of the K pool: neither is copied out of the layer loop's carry."""
+    import math
+
+    L, S, H, K, V = ck["delta"].shape
+    one_state = S * H * K * 256 * 4
+    one_pool = math.prod(ck["pages"].shape[1:]) * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    # W_g is relaid once at the program's edge (398 MB: XLA's choice for
+    # the matmul, outside the loop)
+    temp -= math.prod((L, 3840, 5760)) * 2
+    assert temp < one_state + one_pool, temp
+
+
+def test_hybrid_decode_step_compiles_in_place(topo):
+    """engine_decode at the cell's size with donated caches: the paged
+    kernel on a pool of 32 (30 padded) KV heads, the delta kernel on the
+    stacked state, every weight read through ONE fused dynamic slice."""
+    from localai_tpu.models import olmo_hybrid as oh
+
+    A = _on(SingleDeviceSharding(topo.devices[0]))
+    cfg, params, ck, cv = _abstract_hybrid(A)
+    assert ck["pages"].shape[-2:] == (32, 128)
+    assert llama.decode_attn_impl(cfg.attn_cfg, ck) == "pallas:paged_decode"
+
+    def decode(p, t, ln, act, ck, cv):
+        return oh.engine_decode(p, cfg, t, ln, act, ck, cv)
+
+    compiled = jax.jit(decode, donate_argnums=(4, 5)).lower(
+        params, A((S_H,), jnp.int32), A((S_H,), jnp.int32),
+        A((S_H,), jnp.bool_), ck, cv).compile()
+    assert "gated_delta_decode" in compiled.as_text()
+    _assert_state_and_pool_stay_in_place(compiled, ck)
+
+
+@pytest.mark.parametrize("N", [512, 1024])
+def test_hybrid_packed_prefill_compiles(topo, N):
+    """A continued pack through the chunked delta rule and the ragged
+    prefill kernel (30 heads of 128 over a padded pool)."""
+    from localai_tpu.models import olmo_hybrid as oh
+
+    A = _on(SingleDeviceSharding(topo.devices[0]))
+    cfg, params, ck, cv = _abstract_hybrid(A)
+    i32 = jnp.int32
+    assert llama.ragged_attn_impl(cfg.attn_cfg, ck, N, True) == \
+        "pallas:ragged_prefill"
+
+    def pack(p, t, pos, so, ss, st, off, ln, ck, cv):
+        return oh.ragged_prefill(p, cfg, t, pos, so, ss, st, off, ln, ck, cv,
+                                 continued=True)
+
+    compiled = jax.jit(pack, donate_argnums=(8, 9)).lower(
+        params, A((N,), i32), A((N,), i32), A((N,), i32),
+        *_seg_tables(A, S_H), ck, cv).compile()
+    # fits beside 9.8 GB of weights and caches on a 16 GB chip
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 << 30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
