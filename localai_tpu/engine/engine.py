@@ -917,6 +917,7 @@ class Engine:
                if self._paged else {}))
         # per-slot recurrent state beside the K/V rows (ops/kvcache.py)
         self._state_bytes = kvcache.state_bytes(self.ck)
+        self._kv_walk = {"pages_live": 0, "pages_grid": 0}   # _count_kv_walk
         # draft cache is allocated LAZILY at the first spec-eligible
         # admission (r2 allocated it up front, doubling per-slot KV HBM
         # even when no request could ever speculate)
@@ -3785,6 +3786,7 @@ class Engine:
             "family": self._fam_name,
             "capabilities": sorted(self._caps),
             "recurrent_state_bytes": self._state_bytes,
+            "kv_walk": dict(self._kv_walk),
             **self._device,
             "device_mem": sysobs.device_memory_stats(),
             "attention": self._attention_report(),
@@ -5955,6 +5957,7 @@ class Engine:
         spp = sampling.pack_slot_params(self.slot_params)
         ovp = self._pack_ov(ov_mask)
         self._tick_decode_tokens += K * len(included)
+        self._count_kv_walk(K, infl, [i for i, _ in included])
         with self._annot("prefill_pack_fused", steps=K,
                          slots=len(included)):
             pack, self.ck, self.cv, self.rng_keys, self._chain = fn(
@@ -6069,6 +6072,7 @@ class Engine:
         # still rides — it is current-host-truth every dispatch)
         burst_fn = self._get_burst_fn(K)
         self._tick_decode_tokens += K * len(included)
+        self._count_kv_walk(K, infl, [i for i, _ in included])
         with self._annot("decode_burst", steps=K, slots=len(included)):
             pack, self.ck, self.cv, self.rng_keys, self._chain = burst_fn(
                 self.params, chain[0], self.ck, self.cv, chain[1],
@@ -6170,6 +6174,7 @@ class Engine:
                            p_start=p_start)
         self._tick_prefill_tokens += sum(t for _g, t in group)
         self._tick_decode_tokens += K * len(included)
+        self._count_kv_walk(K, infl, [i for i, _ in included])
         with self._annot("prefill_fused", steps=K, slots=len(included)):
             pack, self.ck, self.cv, self.rng_keys, self._chain = fn(
                 self.params, chain[0], self.ck, self.cv, chain[1],
@@ -6745,6 +6750,20 @@ class Engine:
             k *= 2
         return k, mask
 
+    def _count_kv_walk(self, n_steps: int, infl, rows):
+        """/debug/state's kv_walk, at a dispatch of ``n_steps`` decode
+        steps whose plain rows are the slots ``rows``: the page-table
+        entries those steps span (num_slots x max_pages each: what the
+        paged decode kernel's first form walked) and those that hold a
+        live row (what it works on since PR 31: PERF.md section 6)."""
+        if not self._paged:
+            return
+        pg = self._pool.page_size
+        self._kv_walk["pages_live"] += n_steps * sum(
+            -(-(int(self.lengths[i]) + infl[i]) // pg) for i in rows)
+        self._kv_walk["pages_grid"] += (
+            n_steps * self.ecfg.num_slots * self._pool.max_pages)
+
     def _dispatch_decode(self) -> bool:
         """Dispatch the next decode burst — or, when spec-eligible slots
         are decoding, a FUSED SPEC TICK (ISSUE 13: draft-propose +
@@ -6834,6 +6853,9 @@ class Engine:
                            chain=chain if cold else None,
                            spp=spp, active=active, ovp=ovp)
         self._tick_decode_tokens += n_steps * len(included)
+        # a spec row attends in the verify pass, not in the decode step
+        self._count_kv_walk(n_steps, infl, [
+            i for i in included if spec_mask is None or not spec_mask[i]])
         with self._annot(
                 "decode_burst", steps=n_steps, slots=len(included),
                 **({"spec_slots": int(spec_mask.sum()), "spec_width": W}

@@ -629,7 +629,18 @@ def _decode_attend_write(q1, k1, v1, ck, cv, li, lengths, cfg: LlamaConfig):
     the layer out for the kernel and setting it back after the write
     made XLA copy a layer of the pool out of the carry and back, per
     layer per step: 17 and 24% of decode device time (PERF.md section 6,
-    PR 27)."""
+    PR 27).
+
+    ``lengths`` is the WRITE position: the engine hands an inactive slot
+    ``lengths == C`` so that its row write drops (engine_decode;
+    decode_step's invariant keeps active slots below C). The paged
+    kernels read by ``read_lengths`` of it, where such a slot has length
+    0: it fetches no page and computes nothing but its own token, one
+    grid step of about a microsecond, for an output the caller
+    discards. Given C it walked every entry of its page table, stale
+    ones too, and was the dearest slot of the step: a fifth of a
+    millisecond a layer at 4096 context (PERF.md section 6, PR 31). The
+    jnp forms mask instead of walking and keep ``lengths``."""
     S = q1.shape[0]
     slot = jnp.arange(S, dtype=jnp.int32)[:, None]
 
@@ -651,6 +662,10 @@ def _decode_attend_write(q1, k1, v1, ck, cv, li, lengths, cfg: LlamaConfig):
         return attn, ck, cv
     # every other form reads the cache BEFORE the write and appends the
     # current token's k/v from registers
+    if impl.startswith("pallas:paged_decode"):
+        from localai_tpu.ops.pallas.paged_attention import read_lengths
+
+        read = read_lengths(lengths, kvcache.shape(ck)[2])
     if impl == "pallas:paged_decode_int8":
         # int8 pages stay quantized in HBM: the {q, scales} kernel
         # variant folds the scales in VMEM
@@ -664,7 +679,7 @@ def _decode_attend_write(q1, k1, v1, ck, cv, li, lengths, cfg: LlamaConfig):
             (heads, heads, heads, _POOL, scales, _POOL, scales,
              P(None, None), P(None), P()), heads)(
             q1, k1, v1, ck["pages"], ck["scales"], cv["pages"],
-            cv["scales"], ck["ptab"], lengths, li)
+            cv["scales"], ck["ptab"], read, li)
     elif impl == "pallas:paged_decode":
         from localai_tpu.ops.pallas.paged_attention import (
             paged_decode_attention_append)
@@ -674,7 +689,7 @@ def _decode_attend_write(q1, k1, v1, ck, cv, li, lengths, cfg: LlamaConfig):
                          q_per_kv=cfg.q_per_kv),
             (heads, heads, heads, _POOL, _POOL, P(None, None), P(None),
              P()), heads)(
-            q1, k1, v1, ck["pages"], cv["pages"], ck["ptab"], lengths, li)
+            q1, k1, v1, ck["pages"], cv["pages"], ck["ptab"], read, li)
     elif impl == "pallas:decode_append":
         from localai_tpu.ops.pallas.decode_attention import (
             decode_attention_append_pallas)

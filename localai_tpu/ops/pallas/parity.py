@@ -1,8 +1,9 @@
 """On-device parity of every Pallas kernel against its jnp reference.
 
     python -m localai_tpu.ops.pallas.parity
+    python -m localai_tpu.ops.pallas.parity sweep
 
-runs each kernel of this package COMPILED, on whatever device jax finds
+The first runs each kernel of this package COMPILED, on whatever device jax finds
 (chip_smoke.py runs it on the chip before it boots the server; the
 process exits and releases the chip), at Llama-3.1-8B head shapes: 8 KV
 heads x 4 query groups of 128, page 64. Interpret-mode tests prove the
@@ -13,20 +14,33 @@ the layer coordinate of the stacked pool (_stacked) are where a layout
 surprise would show.
 
 Slot lengths are mixed on purpose: an empty slot, one row, a slot ending
-exactly on a page boundary, one just past it, a full slot (_lengths).
+exactly on a page boundary, one just past it, a full slot, and a slot
+the engine marks inactive (write position C, which the kernels' caller
+maps to a read length of 0) over stale page-table entries (_lengths).
+Every page no live slot holds is NaN: the paged kernels must neither
+fetch a row from it into their arithmetic nor compute on it. Besides
+the 8B shape the paged decode kernel runs at the benchmark cells' decode
+geometries (CELLS), which proves the Mosaic lowering and the VMEM fit
+of its page ring there (the int8 variant: of its page walk).
 Inputs are seeded and bf16-exact, so the kernel (bf16 in, f32 inside)
 and the reference (the same values in f32, matmuls at highest precision)
 see identical numbers, and what remains is the kernel's own error plus
 one bf16 rounding of the output.
 
 Prints one JSON line: the device and the largest error per kernel (see
-TOLERANCE for the measure). Exits 1 if any exceeds it.
+TOLERANCE for the measure). Exits 1 if an error exceeds the tolerance.
+
+``sweep`` is a measurement and decides nothing: the compiled paged
+decode kernel's microseconds a call at CELLS against live slots and
+context reserved (paged_decode_sweep: PERF.md section 6's table, PR 31;
+about 4 chip-minutes), one JSON line.
 """
 
 from __future__ import annotations
 
 import json
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -37,7 +51,8 @@ from localai_tpu.ops.attention import decode_attention_append
 from localai_tpu.ops.pallas.decode_attention import (
     decode_attention_append_pallas)
 from localai_tpu.ops.pallas.paged_attention import (
-    paged_decode_attention_append, paged_decode_attention_append_quant)
+    paged_decode_attention_append, paged_decode_attention_append_quant,
+    read_lengths)
 from localai_tpu.ops.pallas.ragged_prefill import (
     ragged_kernel_plan, ragged_prefill_attention_pallas)
 from localai_tpu.ops.ragged_prefill import ragged_prefill_attention
@@ -57,10 +72,23 @@ TOLERANCE = 2e-2
 KV, G, HD, PAGE = 8, 4, 128, 64     # Llama-3.1-8B heads, engine page size
 S, MP = 8, 8                        # slots, pages per slot (context 512)
 
+# The benchmark cells' decode geometries (PERF.md section 4): slots,
+# pages a slot, (KV heads, query heads a KV head, head size), and the
+# rows a live slot holds on a mean step there.
+CELLS = {
+    "8x16": (16, 16, (8, 4, HD), 300),      # mistral7b.chat_rate: 16 x 1024
+    "8x64": (16, 64, (8, 4, HD), 2500),     # the Nemo cells: 16 x 4096
+    "32x32": (32, 32, (32, 1, HD), 450),    # olmo-hybrid: 32 x 2048, padded
+}
 
-def _lengths(page: int):
-    return jnp.asarray((0, 1, page, page + 1, 3 * page, 3 * page + 8,
-                        MP * page - 1, page // 2 + 5), jnp.int32)
+
+def _lengths(page: int, slots: int = S, mp: int = MP):
+    """Write positions of ``slots`` slots of ``mp`` pages: the mix of the
+    module docstring, repeated; ``mp * page`` (= C) marks a slot
+    inactive."""
+    mix = (0, 1, page, page + 1, 3 * page, mp * page, mp * page - 1,
+           page // 2 + 5, 3 * page + 8)
+    return jnp.asarray([mix[i % len(mix)] for i in range(slots)], jnp.int32)
 
 
 def _bf16_exact(rng, shape):
@@ -68,14 +96,14 @@ def _bf16_exact(rng, shape):
     return np.asarray(jnp.asarray(x, jnp.bfloat16).astype(jnp.float32))
 
 
-def _paged_kv(rng, dtype, heads, hd, page):
+def _paged_kv(rng, dtype, heads, hd, page, slots: int = S, mp: int = MP):
     """One layer's paged K and V caches over ONE shuffled page table (a
     kernel that ignored the table would read the wrong rows). Returns
     ((k, v) in ``dtype``, (k, v) as the reference reads them: float32
     twins, or the same int8 + scales, which it folds itself)."""
-    n_pages = S * MP
+    n_pages = slots * mp
     ptab = jnp.asarray(rng.permutation(n_pages).astype(np.int32)
-                       .reshape(S, MP))
+                       .reshape(slots, mp))
 
     def layer():
         rows = jnp.asarray(_bf16_exact(rng, (n_pages, page, heads, hd)))
@@ -107,33 +135,95 @@ def _max_err(out, ref, keep=None):
     return float(err.max())
 
 
+def _unheld_nan(leaf, ptab, lengths, page: int):
+    """``leaf`` ([n_pages, ...] float) with every page no slot holds
+    (by the table and the read lengths) turned to NaN."""
+    held = np.zeros(leaf.shape[0], bool)
+    for row, n in zip(np.asarray(ptab), -(-np.asarray(lengths) // page)):
+        held[row[:n]] = True
+    return jnp.where(held.reshape((-1,) + (1,) * (leaf.ndim - 1)), leaf,
+                     jnp.nan)
+
+
 def check_paged_decode(quant: bool, interpret: bool = False,
-                       heads=(KV, G, HD), page: int = PAGE) -> float:
+                       heads=(KV, G, HD), page: int = PAGE,
+                       slots: int = S, mp: int = MP) -> float:
     kv, g, hd = heads
     rng = np.random.default_rng(1 + quant)
     (lc, lv), (lc32, lv32) = _paged_kv(
-        rng, jnp.int8 if quant else jnp.bfloat16, kv, hd, page)
-    q = _bf16_exact(rng, (S, kv * g, hd))
-    nk, nv = _bf16_exact(rng, (S, kv, hd)), _bf16_exact(rng, (S, kv, hd))
-    lengths = _lengths(page)
+        rng, jnp.int8 if quant else jnp.bfloat16, kv, hd, page, slots, mp)
+    q = _bf16_exact(rng, (slots, kv * g, hd))
+    nk, nv = (_bf16_exact(rng, (slots, kv, hd)),
+              _bf16_exact(rng, (slots, kv, hd)))
+    # the engine's write positions, read as the kernels' caller reads them
+    lengths = read_lengths(_lengths(page, slots, mp), mp * page)
+    nan = lambda leaf: _stacked(_unheld_nan(   # noqa: E731
+        leaf, lc["ptab"], lengths, page))
     bf = lambda a: jnp.asarray(a, jnp.bfloat16)   # noqa: E731
     if quant:
         out = paged_decode_attention_append_quant(
             bf(q), bf(nk), bf(nv), _stacked(lc["pages"]),
-            _stacked(lc["scales"]), _stacked(lv["pages"]),
-            _stacked(lv["scales"]), lc["ptab"], lengths, 1, q_per_kv=g,
-            interpret=interpret)
+            nan(lc["scales"]), _stacked(lv["pages"]), nan(lv["scales"]),
+            lc["ptab"], lengths, 1, q_per_kv=g, interpret=interpret)
     else:
         out = paged_decode_attention_append(
-            bf(q), bf(nk), bf(nv), _stacked(lc["pages"]),
-            _stacked(lv["pages"]), lc["ptab"], lengths, 1, q_per_kv=g,
-            interpret=interpret)
+            bf(q), bf(nk), bf(nv), nan(lc["pages"]), nan(lv["pages"]),
+            lc["ptab"], lengths, 1, q_per_kv=g, interpret=interpret)
     with jax.default_matmul_precision("highest"):
         ref = decode_attention_append(
             jnp.asarray(q), jnp.asarray(nk), jnp.asarray(nv),
             kvcache.gather_all_rows(lc32), kvcache.gather_all_rows(lv32),
             lengths, g)
     return _max_err(out, ref)
+
+
+def time_paged_decode(slots: int, mp: int, heads, rows: int, live: int,
+                      calls: int = 256, page: int = PAGE) -> float:
+    """Microseconds a call of the compiled paged decode kernel at a
+    cell's geometry with ``live`` of its slots holding ``rows`` rows and
+    the others inactive (write position C, alternating with the live
+    ones): ``calls`` calls chained in one program, each one's output the
+    next one's queries, the layer alternating; the best of three."""
+    kv, g, hd = heads
+    n_pages = slots * mp
+    rng = np.random.default_rng(5)
+    ptab = jnp.asarray(rng.permutation(n_pages).astype(np.int32)
+                       .reshape(slots, mp))
+    write = np.full((slots,), mp * page, np.int32)
+    if live:
+        write[np.linspace(0, slots - 1, live).round().astype(int)] = rows
+    lengths = read_lengths(jnp.asarray(write), mp * page)
+    pool = jnp.zeros((2, n_pages, page, kv, hd), jnp.bfloat16)
+    nk = jnp.asarray(_bf16_exact(rng, (slots, kv, hd)), jnp.bfloat16)
+    q = jnp.asarray(_bf16_exact(rng, (slots, kv * g, hd)), jnp.bfloat16)
+
+    @jax.jit
+    def chain(q, pool_k, pool_v):
+        return jax.lax.fori_loop(
+            0, calls, lambda i, q: paged_decode_attention_append(
+                q, nk, nk, pool_k, pool_v, ptab, lengths, i % 2,
+                q_per_kv=g), q)
+
+    chain(q, pool, pool).block_until_ready()
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        chain(q, pool, pool).block_until_ready()
+        best = min(best, time.perf_counter() - t0)
+    return round(best / calls * 1e6, 2)
+
+
+def paged_decode_sweep() -> dict:
+    """time_paged_decode at each of CELLS: no slot live, half and all at
+    the cell's rows, and all at full context (a live page must cost no
+    more there than before the kernel skipped the others)."""
+    out = {}
+    for name, (slots, mp, heads, rows) in CELLS.items():
+        out[name] = {
+            f"live{n}_rows{r}": time_paged_decode(slots, mp, heads, r, n)
+            for n, r in ((0, rows), (slots // 2, rows), (slots, rows),
+                         (slots, mp * PAGE - 1))}
+    return out
 
 
 def check_contiguous_decode(interpret: bool = False,
@@ -204,14 +294,28 @@ def main(argv=None) -> int:
     # --interpret: the CPU rehearsal of chip_smoke.py (Pallas interpreter,
     # one small pack); without it the kernels run compiled, which needs
     # the chip
-    interpret = "--interpret" in (sys.argv[1:] if argv is None else argv)
+    argv = sys.argv[1:] if argv is None else argv
+    interpret = "--interpret" in argv
     dev = jax.devices()[0]
     if not interpret and dev.platform != "tpu":
         print(f"no TPU: jax found {jax.devices()}", file=sys.stderr)
         return 2
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    if "sweep" in argv:
+        print(json.dumps({"paged_decode_us": paged_decode_sweep(),
+                          "device": device}))
+        return 0
     errors = {
         "paged_decode": check_paged_decode(False, interpret),
         "paged_decode_int8": check_paged_decode(True, interpret),
+        # the cells' geometries: compiled only (the interpreter walks a
+        # 1024-page pool for minutes)
+        **({} if interpret else {
+            f"paged_decode{'_int8' if quant else ''}[{name}]":
+            check_paged_decode(quant, heads=heads, slots=slots, mp=mp)
+            for name, (slots, mp, heads, _) in CELLS.items()
+            for quant in (False, True)}),
         "decode_append": check_contiguous_decode(interpret),
         # the pack buckets chip_smoke.py's engine builds
         **{f"ragged_prefill[{n}]": check_ragged_prefill(n, interpret)
@@ -220,9 +324,7 @@ def main(argv=None) -> int:
     ok = all(e <= TOLERANCE for e in errors.values())
     print(json.dumps({
         "ok": ok, "interpret": interpret, "tolerance": TOLERANCE,
-        "max_error": errors,
-        "device": {"platform": dev.platform, "kind": dev.device_kind,
-                   "count": len(jax.devices())}}))
+        "max_error": errors, "device": device}))
     return 0 if ok else 1
 
 

@@ -208,6 +208,184 @@ def test_paged_kernels_index_the_stacked_pool_by_layer(quant, layer):
                                atol=3e-4, rtol=3e-4)
 
 
+def _same(a, b):
+    """Elementwise a == b with NaN equal to NaN."""
+    a, b = np.asarray(a), np.asarray(b)
+    if a.dtype.kind != "f":
+        return a == b
+    return (a == b) | (np.isnan(a) & np.isnan(b))
+
+
+@pytest.mark.parametrize("quant,hd,rule", [
+    (False, 128, False), (False, 64, False), (False, 128, True),
+    (False, 64, True), (True, 128, False), (True, 128, True)],
+    ids=["float-ring", "float-walk", "float-ring-rule", "float-walk-rule",
+         "int8-walk", "int8-rule"])
+def test_paged_kernels_touch_only_pages_a_live_slot_holds(quant, hd, rule,
+                                                          monkeypatch):
+    """Both kernel variants (interpret mode) over a table of 6 pages (no
+    power of two), on the driver the shapes select: the ring for float
+    pages of 128 lanes, the walk for heads of 64 and for int8; called
+    directly or, for "rule", through
+    models/llama.py::_decode_attend_write. Every page no live slot holds
+    is NaN (for int8: its scales), page-table entries past a slot's
+    length are unallocated or stale, and two slots are inactive (write
+    position C). Live slots — empty, one row, exactly one turn of the
+    ring (the walk: one page), one row past it, C - 1 — equal
+    decode_attention_append over their gathered rows; an inactive slot's
+    output is finite and its K/V write drops."""
+    import dataclasses
+    from functools import partial
+
+    from localai_tpu.ops.attention import decode_attention_append
+    from localai_tpu.ops.pallas import paged_attention as pa
+
+    rng = np.random.default_rng(7)
+    L, KV, G, pgs, mp, li = 2, 2, 2, 8, 6, 1
+    C = mp * pgs
+    ring = pa.ring_takes(KV, hd, jnp.int8 if quant else jnp.float32)
+    assert ring == (hd == 128 and not quant)
+    turn = pgs * (2 if ring else 1)
+    write_at = np.asarray([0, 1, turn, turn + 1, C - 1, C, 21, C], np.int32)
+    S = len(write_at)
+    n_pages = S * mp + 3
+    read = np.where(write_at >= C, 0, write_at)
+    # held entries: a shuffled table; past them the sentinel or a stale id
+    ptab = rng.permutation(n_pages)[:S * mp].astype(np.int32).reshape(S, mp)
+    held = np.zeros((n_pages,), bool)
+    for s in range(S):
+        n = -(-int(read[s]) // pgs)
+        held[ptab[s, :n]] = True
+        ptab[s, n:] = np.where(rng.random(mp - n) < 0.5, n_pages,
+                               rng.integers(0, n_pages, mp - n))
+    ptab = jnp.asarray(ptab)
+    clean_k = _stacked_pool(rng, quant, L, n_pages, pgs, KV, hd, ptab)
+    clean_v = _stacked_pool(rng, quant, L, n_pages, pgs, KV, hd, ptab)
+
+    def unheld_nan(c):
+        leaf = "scales" if quant else "pages"
+        keep = held.reshape((1, -1) + (1,) * (c[leaf].ndim - 2))
+        return {**c, leaf: jnp.where(keep, c[leaf], jnp.nan)}
+
+    ck, cv = unheld_nan(clean_k), unheld_nan(clean_v)
+    q = jnp.asarray(rng.normal(size=(S, KV * G, hd)).astype(np.float32))
+    nk = jnp.asarray(rng.normal(size=(S, KV, hd)).astype(np.float32))
+    nv = jnp.asarray(rng.normal(size=(S, KV, hd)).astype(np.float32))
+    ref = decode_attention_append(
+        q, nk, nv, kvcache.gather_all_rows(kvcache.layer(clean_k, li)),
+        kvcache.gather_all_rows(kvcache.layer(clean_v, li)),
+        jnp.asarray(read), G)
+
+    if rule:
+        cfg = dataclasses.replace(
+            llama.LlamaConfig(
+                vocab_size=64, hidden_size=KV * G * hd, intermediate_size=64,
+                num_layers=L, num_heads=KV * G, num_kv_heads=KV,
+                max_position_embeddings=C),
+            attn=llama.AttnTarget(pallas=True))
+        for name in ("paged_decode_attention_append",
+                     "paged_decode_attention_append_quant"):
+            monkeypatch.setattr(pa, name,
+                                partial(getattr(pa, name), interpret=True))
+        out, wk, wv = jax.jit(
+            lambda *a: llama._decode_attend_write(*a, cfg))(
+            q, nk, nv, ck, cv, jnp.int32(li), jnp.asarray(write_at))
+        # the row write: one row a slot in range whose page the table
+        # names, in layer li alone
+        rows = {(li, int(ptab[s, w // pgs]), w % pgs)
+                for s, w in enumerate(write_at)
+                if w < C and ptab[s, w // pgs] < n_pages}
+        for before, after in ((ck, wk), (cv, wv)):
+            for leaf in [k for k in before if k != "ptab"]:
+                same = _same(before[leaf], after[leaf])
+                changed = set(zip(*np.nonzero(
+                    ~same.reshape(same.shape[:3] + (-1,)).all(-1))))
+                assert changed == rows, (leaf, changed ^ rows)
+    else:
+        tail = (ptab, jnp.asarray(pa.read_lengths(jnp.asarray(write_at), C)),
+                jnp.int32(li))
+        if quant:
+            out = jax.jit(partial(pa.paged_decode_attention_append_quant,
+                                  q_per_kv=G, interpret=True))(
+                q, nk, nv, ck["pages"], ck["scales"], cv["pages"],
+                cv["scales"], *tail)
+        else:
+            out = jax.jit(partial(pa.paged_decode_attention_append,
+                                  q_per_kv=G, interpret=True))(
+                q, nk, nv, ck["pages"], cv["pages"], *tail)
+    out = np.asarray(out)
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out, np.asarray(ref), atol=3e-4, rtol=3e-4)
+
+
+@pytest.mark.parametrize("kv_heads,head_dim,dtype,ring", [
+    # the cells: mistral7b / the Nemo cells, olmo-hybrid (30 heads
+    # stored as 32), and a tp=4 shard's share of each
+    (8, 128, jnp.bfloat16, True), (32, 128, jnp.bfloat16, True),
+    (2, 128, jnp.bfloat16, True), (4, 128, jnp.bfloat16, True),
+    (8, 128, jnp.float32, True), (16, 256, jnp.bfloat16, True),
+    # int8 pages, whole or a shard: their scales fill no tile
+    (8, 128, jnp.int8, False), (32, 128, jnp.int8, False),
+    (2, 128, jnp.int8, False),
+    (8, 64, jnp.bfloat16, False),       # TinyLlama's heads: half the lanes
+    (30, 128, jnp.bfloat16, False),     # the hybrid's heads unpadded
+    (1, 128, jnp.bfloat16, False),      # one KV head a shard
+    (8, 128, jnp.float16, False),
+])
+def test_ring_takes_what_mosaic_can_copy(kv_heads, head_dim, dtype, ring):
+    """The driver follows from the operands' shapes (no option, no
+    model's name): tests/test_tpu_compile.py compiles both sides of
+    this table for a v5e."""
+    from localai_tpu.ops.pallas import paged_attention as pa
+
+    assert pa.ring_takes(kv_heads, head_dim, dtype) == ring
+
+
+@pytest.mark.parametrize("tp", [2, 4])
+def test_ring_on_a_mesh_copies_its_shard_of_the_pool(tp):
+    """The ring's own DMA under shard_map (models/llama.py::_on_mesh),
+    through _decode_attend_write on a tp mesh of CPU devices, kernels in
+    the strict TPU interpreter: 8 KV heads, 4 or 2 a shard, the pool
+    split over tp and left in place; parity.py's data (shuffled table,
+    inactive slots, every page no live slot holds NaN)."""
+    import dataclasses
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    from localai_tpu.ops.attention import decode_attention_append
+    from localai_tpu.ops.pallas import paged_attention as pa, parity
+    from localai_tpu.parallel import mesh as meshlib
+
+    slots, mp, pgs, (KV, G, hd) = 4, 6, 8, (8, 2, 128)
+    rng = np.random.default_rng(31)
+    (lc, lv), (lc32, lv32) = parity._paged_kv(rng, jnp.float32, KV, hd, pgs,
+                                              slots, mp)
+    q, nk, nv = (jnp.asarray(parity._bf16_exact(rng, (slots, n, hd)))
+                 for n in (KV * G, KV, KV))
+    write_at = parity._lengths(pgs, slots, mp)
+    read = pa.read_lengths(write_at, mp * pgs)
+    assert pa.ring_takes(KV // tp, hd, jnp.float32)
+    mesh = meshlib.make_mesh(meshlib.MeshPlan(tp=tp), jax.devices()[:tp])
+    cfg = dataclasses.replace(
+        llama.LlamaConfig(vocab_size=64, hidden_size=KV * G * hd,
+                          intermediate_size=64, num_layers=2,
+                          num_heads=KV * G, num_kv_heads=KV,
+                          max_position_embeddings=mp * pgs),
+        attn=llama.AttnTarget(pallas=True, mesh=mesh))
+    ck, cv = (kvcache.device_put(
+        {"pages": parity._stacked(parity._unheld_nan(c["pages"], c["ptab"],
+                                                     read, pgs)),
+         "ptab": c["ptab"]}, mesh, (None, None, None, "tp", None))
+        for c in (lc, lv))
+    with pltpu.force_tpu_interpret_mode():
+        out = jax.jit(lambda *a: llama._decode_attend_write(*a, cfg)[0])(
+            q, nk, nv, ck, cv, jnp.int32(1), write_at)
+    ref = decode_attention_append(
+        q, nk, nv, kvcache.gather_all_rows(lc32),
+        kvcache.gather_all_rows(lv32), read, G)
+    assert parity._max_err(out, ref) <= 3e-4
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
                          ids=["bf16", "int8"])
 def test_decode_step_writes_only_its_rows(dtype):
@@ -339,6 +517,38 @@ def test_engine_paged_matches_contiguous_greedy(tiny_cfg_params):
     finally:
         e2.shutdown()
     assert got == ref
+
+
+@pytest.mark.parametrize("draft", ["0", "ngram"])
+def test_kv_walk_counts_the_pages_live_rows_hold(tiny_cfg_params, draft):
+    """/debug/state's kv_walk: pages_grid is num_slots x max_pages for
+    every decode step dispatched, pages_live the pages the plain rows'
+    lengths span. One request of 40 + 8 tokens holds 3 pages of 16 rows
+    on every step; under n-gram speculation it is a spec row on every
+    tick and no plain row is live."""
+    cfg, params = tiny_cfg_params
+    e = eng.Engine(
+        cfg, params, _Tok(),
+        eng.EngineConfig(num_slots=2, max_context=128,
+                         prefill_buckets=(16, 64), prefill_chunk=64,
+                         cache_dtype=jnp.float32, kv_layout="paged",
+                         kv_page_size=16, draft=draft))
+    e.start()
+    try:
+        assert e.state_snapshot()["kv_walk"] == {"pages_live": 0,
+                                                 "pages_grid": 0}
+        _greedy(e, [int(x) for x in
+                    np.random.default_rng(3).integers(1, 120, size=40)])
+        walk = e.state_snapshot()["kv_walk"]
+        spec = e.state_snapshot()["spec"]["dispatches"]
+    finally:
+        e.shutdown()
+    steps, rest = divmod(walk["pages_grid"], 2 * (128 // 16))
+    assert steps > 0 and rest == 0
+    if draft == "0":
+        assert spec == 0 and walk["pages_live"] == 3 * steps
+    else:
+        assert spec > 0 and walk["pages_live"] == 0
 
 
 def test_engine_paged_matches_contiguous_on_mesh(tiny_cfg_params):

@@ -60,24 +60,58 @@ def _seg_tables(A, B=S):
 
 
 @pytest.mark.parametrize("quant", [False, True])
-def test_paged_decode_kernels_compile(topo, quant):
+@pytest.mark.parametrize("slots,mp,kv,g", [
+    (S, MP, KV, G),       # chip_smoke.py's 8B; mistral7b.chat_rate
+    (16, 64, 8, 4),       # the Nemo cells: 16 x 4096
+    (32, 32, 32, 1),      # olmo-hybrid: 32 x 2048, one query head a KV head
+    (16, 64, 2, 4),       # a tp=4 shard of the Nemo geometry
+], ids=["8x16", "8x64", "32x32", "2x64"])
+def test_paged_decode_kernels_compile(topo, quant, slots, mp, kv, g):
+    """At the benchmark cells' decode geometries, with the driver the
+    kernel's own rule chooses: the page buffers and the body's
+    temporaries fit Mosaic's scoped VMEM."""
     from localai_tpu.ops.pallas import paged_attention as pa
 
     A = _on(SingleDeviceSharding(topo.devices[0]))
     bf, i32 = jnp.bfloat16, jnp.int32
-    q, nk = A((S, KV * G, HD), bf), A((S, KV, HD), bf)
+    n_pages = slots * mp
+    q, nk = A((slots, kv * g, HD), bf), A((slots, kv, HD), bf)
     # the stacked pool and a traced layer, as the layer scan hands them over
-    tail = (A((S, MP), i32), A((S,), i32), A((), i32))
+    tail = (A((slots, mp), i32), A((slots,), i32), A((), i32))
     if quant:
-        pages, scales = A((3, NP, PAGE, KV, HD), jnp.int8), \
-            A((3, NP, PAGE, KV), jnp.float32)
+        pages, scales = A((3, n_pages, PAGE, kv, HD), jnp.int8), \
+            A((3, n_pages, PAGE, kv), jnp.float32)
         pa.paged_decode_attention_append_quant.lower(
             q, nk, nk, pages, scales, pages, scales, *tail,
-            q_per_kv=G).compile()
+            q_per_kv=g).compile()
     else:
-        pages = A((3, NP, PAGE, KV, HD), bf)
+        pages = A((3, n_pages, PAGE, kv, HD), bf)
         pa.paged_decode_attention_append.lower(
-            q, nk, nk, pages, pages, *tail, q_per_kv=G).compile()
+            q, nk, nk, pages, pages, *tail, q_per_kv=g).compile()
+
+
+@pytest.mark.parametrize("dtype,kv,hd,ring", [
+    (jnp.bfloat16, 2, 128, True), (jnp.bfloat16, 4, 128, True),
+    (jnp.bfloat16, 16, 256, True), (jnp.bfloat16, 24, 128, True),
+    (jnp.float32, 8, 128, True),
+    (jnp.bfloat16, 8, 64, False),       # TinyLlama's heads
+    (jnp.bfloat16, 30, 128, False),     # the hybrid's heads unpadded
+    (jnp.bfloat16, 1, 128, False),
+])
+def test_paged_decode_driver_compiles_wherever_the_rule_says(topo, dtype, kv,
+                                                             hd, ring):
+    """ring_takes answers for Mosaic: where it says so, the kernel's own
+    page copies out of the HBM pool compile (a page is whole tiles of
+    the pool's layout); everywhere else the walk runs, and compiles."""
+    from localai_tpu.ops.pallas import paged_attention as pa
+
+    assert pa.ring_takes(kv, hd, dtype) == ring
+    A = _on(SingleDeviceSharding(topo.devices[0]))
+    q, nk = A((S, kv * G, hd), dtype), A((S, kv, hd), dtype)
+    pages = A((3, NP, PAGE, kv, hd), dtype)
+    pa.paged_decode_attention_append.lower(
+        q, nk, nk, pages, pages, A((S, MP), jnp.int32), A((S,), jnp.int32),
+        A((), jnp.int32), q_per_kv=G).compile()
 
 
 def test_contiguous_decode_kernel_compiles(topo):
