@@ -1,15 +1,7 @@
 # Builder entry points (ISSUE 3 satellite: stop re-typing incantations).
 #   make tier1   - the canonical tier-1 verify (scripts/run_tier1.sh)
-#   make smoke   - budgeted bench smoke (engine-direct phases only)
-#   make ci      - tier1 + smoke, fail on either (scripts/ci.sh)
 
-.PHONY: ci tier1 smoke
-
-ci:
-	scripts/ci.sh
+.PHONY: tier1
 
 tier1:
 	scripts/run_tier1.sh
-
-smoke:
-	LOCALAI_BENCH_BUDGET_S=$${LOCALAI_BENCH_BUDGET_S:-300} python bench.py --smoke

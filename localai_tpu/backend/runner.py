@@ -424,12 +424,6 @@ class EngineServicer(BackendServicer):
                ("0", "false", "off", "no") else {}),
             **({"prefill_token_budget": ptb} if (ptb := int(
                 extra.get("prefill_token_budget", 0) or 0)) > 0 else {}),
-            # prefill_packed_fuse=auto|0|1|split: fuse the packed step
-            # with the decode burst (1 = monolithic program, split =
-            # early-emit pair, auto = split everywhere)
-            **({"prefill_packed_fuse": ppf} if (ppf := str(
-                extra.get("prefill_packed_fuse", "") or "")) in
-               ("auto", "0", "1", "split") else {}),
             # comm_overlap=auto|0|1 (ISSUE 11): TokenWeave-style halved-
             # pack overlap of per-layer collectives with compute
             # (auto = meshed backends only; bit-exact either way)
@@ -469,12 +463,8 @@ class EngineServicer(BackendServicer):
             # (path|stderr|off)
             **({"event_log": evl} if (evl := str(
                 extra.get("event_log", "") or "")) else {}),
-            # event-driven hot path (ISSUE 9): emitter=0 restores in-loop
-            # emission; event_log_max_mb bounds the file sink (0 disables
+            # event_log_max_mb bounds the file sink (0 disables
             # rotation, so isdigit passes the explicit 0 through)
-            **({"emitter": False} if str(
-                extra.get("emitter", "")).strip().lower() in
-               ("0", "false", "off", "no") else {}),
             **({"event_log_max_mb": int(v)} if (v := str(
                 extra.get("event_log_max_mb", "")).strip()).isdigit()
                else {}),

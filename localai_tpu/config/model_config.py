@@ -143,9 +143,6 @@ class ModelConfig:
     # packed-prefill knobs prefill_packed=0|1 (default on; 0 restores
     # per-slot bucketed prefill), prefill_token_budget=N (max packed
     # prompt tokens per scheduler tick, 0 = engine auto) and
-    # prefill_packed_fuse=auto|0|1|split (fuse the packed step with the
-    # decode burst; 1 = one monolithic program, split = early-emit
-    # back-to-back pair, auto = split everywhere) and
     # comm_overlap=auto|0|1 (TokenWeave-style halved-pack overlap of
     # per-layer collectives with compute; auto = meshed backends only,
     # bit-exact either way), or the
@@ -314,9 +311,6 @@ class ModelConfig:
                     f"(0 = engine default), got {v!r}")
             elif k in ("kv_prefix_cache", "kv_offload",
                        "prefill_packed", "trace",
-                       # dedicated emission worker (ISSUE 9); 0 restores
-                       # the in-loop path
-                       "emitter",
                        # preemptive scheduler (ISSUE 10); 0 restores
                        # strict-FIFO admission bit-for-bit
                        "preempt",
@@ -338,10 +332,6 @@ class ModelConfig:
                     parse_priority_weights(v)
                 except ValueError as e:
                     problems.append(str(e))
-            elif k == "prefill_packed_fuse" and v not in ("auto", "0", "1",
-                                                          "split"):
-                problems.append(
-                    f"prefill_packed_fuse must be auto|0|1|split, got {v!r}")
             elif k == "comm_overlap" and v not in ("auto", "0", "1"):
                 problems.append(
                     f"comm_overlap must be auto|0|1, got {v!r}")

@@ -570,11 +570,10 @@ class ClusterRouter:
             r = e.replica_id
             if r < len(host.pool._dead):
                 host.pool._dead[r] = True
-            if e._emitter is not None:
-                try:
-                    e._emitter.drain(2.0)
-                except Exception:
-                    pass
+            try:
+                e._emitter.drain(2.0)
+            except Exception:
+                pass
             for slot, s in enumerate(e.slots):
                 if s is None:
                     continue

@@ -8,13 +8,13 @@ release for engine-detected finishes — and hands this worker one
 immutable token batch per processed burst / prefill pass
 (``push_batch``). The worker owns all text-level state for a request:
 the slot snapshot's ``IncrementalDetokenizer`` and ``held_text`` are
-single-writer (this thread) while the emitter is on, and the worker is
+single-writer (this thread), and the worker is
 the ONLY writer of ``req.out`` for slotted requests, so per-slot FIFO
 order is simply the queue's FIFO order.
 
 Stop sequences are text-level, so they are DETECTED here — possibly
 after the engine has already dispatched further decode steps for the
-slot. The worker truncates byte-identically to the in-loop path, closes
+slot. The worker truncates the text before the stop, closes
 the stream, and feeds the finish back via ``note_finish``; the engine
 applies the note on its next tick (release the slot, pull a racing
 context-shift re-prefill back out of the queue, account goodput).
@@ -45,8 +45,7 @@ log = logging.getLogger(__name__)
 
 def check_stops(snap, delta):
     """If a stop sequence completes in emitted+delta text, return the
-    delta truncated before the stop; else None. Byte-for-byte mirror of
-    the in-loop ``Engine._check_stops``."""
+    delta truncated before the stop; else None."""
     total = snap.detok.text  # includes delta already
     for stop in snap.req.stop_sequences:
         idx = total.find(stop, max(0, len(total) - len(delta) - len(stop)))
@@ -57,8 +56,7 @@ def check_stops(snap, delta):
 
 
 def holdback(snap, delta):
-    """Withhold a suffix of delta that is a prefix of any stop sequence
-    (mirror of ``Engine._holdback``)."""
+    """Withhold a suffix of delta that is a prefix of any stop sequence."""
     total = snap.detok.text
     hold = 0
     for stop in snap.req.stop_sequences:
@@ -232,8 +230,8 @@ class EmitterWorker:
     def _fail_item(self, item, exc):
         """An item raised mid-processing: fail every affected stream with
         a structured error so no consumer hangs on a stream whose tokens
-        died with the exception (mirror of the engine loop's generic
-        handler), and tell the engine to release the slots. Must never
+        died with the exception (as the engine loop's generic handler
+        does), and tell the engine to release the slots. Must never
         raise — it runs inside the worker's exception handler."""
         try:
             if item[0] == "batch":
@@ -283,9 +281,9 @@ class EmitterWorker:
         t1 = time.monotonic()
         tr = self._tracer
         if tr.enabled:
-            # same emit-vs-flush split as the in-loop spans, recorded
-            # under the _bg names so the decomposition keeps this thread's
-            # walltime out of host_loop (it overlaps the engine loop)
+            # detok + stop-scan, then the queue puts: the decomposition
+            # keeps this thread's walltime out of host_loop (it overlaps
+            # the engine loop)
             tr.record("emit_bg", "emitter", t0, tput,
                       args={"entries": len(entries)})
             tr.record("stream_flush_bg", "emitter", tput, t1)
@@ -330,7 +328,7 @@ class EmitterWorker:
             timings = None   # set only for emitter-DETECTED stops
             if fin == "stop":
                 # engine-detected EOS: the token itself is never
-                # detokenized (in-loop parity)
+                # detokenized
                 delta = snap.held_text + snap.detok.flush()
                 snap.held_text = ""
             elif fin == "length":

@@ -97,18 +97,6 @@ class EngineConfig:
     # packed path (a tick's pack stalls decode for one pack's compute).
     # 0 = auto: 2 * prefill_chunk, clamped to max_context.
     prefill_token_budget: int = 0
-    # fuse the packed prefill step WITH the decode burst so a tick
-    # costs ONE dispatch chain. "split" (the "auto" default everywhere)
-    # dispatches the fused tick as an early-emit PAIR: the prefill head
-    # (ragged prefill + final-segment first tokens) and the burst body
-    # chained off its device outputs, back-to-back with no host sync
-    # between — the head's first tokens sync ahead of the burst, so the
-    # fused path no longer pays the burst's compute in TTFT (the
-    # tradeoff that used to keep "auto" real-chip-only: CPU measured
-    # 1.5x worse loaded TTFT with the monolithic fuse). "1" forces the
-    # monolithic single-program fuse (_fused_packed_body), "0" keeps
-    # prefill and burst as independent ticks.
-    prefill_packed_fuse: str = "auto"
     # TokenWeave-style compute/communication overlap (models/llama.py +
     # parallel/sharding.py): packed-prefill layers split the token axis
     # in two so the out-proj / down-proj all-reduce of half N overlaps
@@ -259,13 +247,6 @@ class EngineConfig:
     # ring-only (events are ALWAYS retained in the bounded in-memory
     # ring surfaced at /debug/events; this knob adds write-through).
     event_log: str = ""
-    # --- event-driven hot path (ISSUE 9) ---
-    # dedicated emitter worker: detok, stop-sequence scanning and stream
-    # queue puts run on a background thread instead of the engine loop;
-    # the loop hands over immutable token batches and keeps all id-level
-    # control (EOS/grammar/length/context-shift). False restores the
-    # in-loop emission path bit-for-bit.
-    emitter: bool = True
     # event-log file-sink rotation bound (MB): at this size the file
     # rotates to <path>.1, one generation kept. 0 disables rotation.
     event_log_max_mb: int = 64
@@ -1040,16 +1021,6 @@ class Engine:
         # packs never dispatches them, so they are not warmed either
         self._per_slot_prefill = (not self._packed or bool(
             caps & {"multimodal", "self_extend", "prefix_reuse"}))
-        fuse = str(self.ecfg.prefill_packed_fuse)
-        # fused-tick mode: "off" | "mono" (prefill + first tokens +
-        # burst as literally one program) | "split" (early-emit pair:
-        # the same work as two back-to-back dispatches with no host
-        # sync between, so the head's first tokens reach the stream
-        # while the decode half is still computing). auto = split on
-        # EVERY platform — the split recovers the first-token delay
-        # that kept the monolithic body real-chip-only.
-        self._pack_fuse = {"0": "off", "1": "mono",
-                           "split": "split"}.get(fuse, "split")
         co = str(self.ecfg.comm_overlap)
         # TokenWeave halved-pack overlap (models/llama.py): only ever a
         # win when per-layer collectives exist, so auto arms it on a
@@ -1106,8 +1077,6 @@ class Engine:
         self._lc = {"requests_shed": 0, "requests_timed_out": 0,
                     "stalls": 0, "stall_dumps": 0}
         self._lc_lock = threading.Lock()
-        # non-None while _process_burst coalesces per-slot events
-        self._sink_buf: Optional[dict] = None
         # in-flight prefill dedup: leader slot -> [(sib_slot, snap, leader
         # snap, ids)]; KV rows fork when the leader's prefill commits
         self._fork_waiters: dict = {}
@@ -1166,11 +1135,9 @@ class Engine:
         self._em_batch: dict = {}
         self._em_notes: list = []
         self._em_lock = threading.Lock()
-        self._emitter = self._make_emitter() if self.ecfg.emitter else None
-        # hot-path dispatch: bound once so _process_burst/_process_prefill
-        # don't branch per token
-        self._emit = (self._emit_token_ev if self._emitter is not None
-                      else self._emit_token)
+        # the emitter worker owns detok, stop-sequence scanning and every
+        # stream queue put; the loop keeps id-level control (_emit_token)
+        self._emitter = self._make_emitter()
         # reusable host-side staging for per-dispatch overrides and packed
         # segment tables: round-robin pools deep enough that no buffer is
         # rewritten while its async device transfer may still be reading
@@ -1469,8 +1436,7 @@ class Engine:
                     queue_wait_ms: float):
         """Feed one finished request into the SLO engine (ISSUE 12).
 
-        Called from BOTH finish paths (in-loop _emit_token branch and the
-        event-driven _finish_accounting_ev) with the same timings the
+        Called from _finish_accounting with the same timings the
         histograms see, so burn rates and latency buckets can never
         disagree about what happened. ITL is the per-request mean
         inter-token gap — (t_done - t_first)/(ndec-1) — which matches how
@@ -2578,13 +2544,7 @@ class Engine:
 
         Duplicate p_slots entries (pow2 batch padding repeats the last
         prompt) stay idempotent: every per-slot update is a .set() of
-        identical values (same inputs -> same sampled id).
-
-        A concatenated prefill+decode forward sharing weight reads
-        (models/llama.py:fused_prefill_decode) exists but is not wired
-        in: an earlier rig measured it slower than this sequential
-        prefill-then-burst body, and neither has been timed on the chip
-        (ROADMAP D4)."""
+        identical values (same inputs -> same sampled id)."""
         slot_params = sampling.unpack_slot_params(slot_params)
         tokens, lengths, ring, ring_pos, mu, pos_offset = \
             self._compose_overrides(tokens, lengths, ring, ring_pos, mu,
@@ -2720,112 +2680,22 @@ class Engine:
             self._final_fns[key] = fn
         return fn
 
-    def _fused_packed_body(self, params, tokens, ck, cv, lengths, ring,
-                           ring_pos, bias, keys, slot_params, active, mu,
-                           ov_pack, p_tokens, p_positions, seg_of, seg_slots,
-                           seg_start, seg_off, seg_len, final_mask,
-                           n_steps: int, continued: bool):
-        """FUSED packed admission — the packed generalization of
-        _fused_body: ragged-prefill EVERY queued segment (fresh or
-        continued), sample first tokens for the FINAL segments, and run
-        the decode burst with those slots already active — all in ONE
-        dispatch. This is the full llama_batch analogue (module doc):
-        under load one tick costs one dispatch for prompt ingestion AND
-        decode, so admission latency stops scaling with the number of
-        pending prompts. Pad / non-final segments are gated exactly as
-        in _packed_prefill_body (sentinel slots drop, finals-only state
-        writes)."""
-        sp = sampling.unpack_slot_params(slot_params)
-        tokens, lengths, ring, ring_pos, mu, pos_offset = \
-            self._compose_overrides(tokens, lengths, ring, ring_pos, mu,
-                                    ov_pack)
-
-        logits, ck, cv = self.family.ragged_prefill(
-            params, self.cfg, p_tokens, p_positions, seg_of, seg_slots,
-            seg_start, seg_off, seg_len, ck, cv, continued=continued,
-            comm_overlap=self._comm_overlap)
-        sp_rows = jax.tree.map(
-            lambda a: jnp.take(jnp.asarray(a), seg_slots, axis=0), sp)
-        ring_rows = jnp.take(ring, seg_slots, axis=0)
-        rpos_rows = jnp.take(ring_pos, seg_slots, axis=0)
-        ids_f, lps_f, new_keys, new_mu = sampling.sample(
-            logits, sp_rows, ring_rows, rpos_rows,
-            jnp.take(bias, seg_slots, axis=0),
-            jnp.take(keys, seg_slots, axis=0),
-            jnp.take(mu, seg_slots, axis=0))
-        gate = final_mask
-        keys = keys.at[seg_slots].set(
-            jnp.where(gate[:, None], new_keys,
-                      jnp.take(keys, seg_slots, axis=0)), mode="drop")
-        mu = mu.at[seg_slots].set(
-            jnp.where(gate, new_mu, jnp.take(mu, seg_slots, axis=0)),
-            mode="drop")
-        lengths = lengths.at[seg_slots].set(
-            jnp.where(gate, seg_start + seg_len,
-                      jnp.take(lengths, seg_slots, axis=0)), mode="drop")
-        tokens = tokens.at[seg_slots].set(
-            jnp.where(gate, ids_f, jnp.take(tokens, seg_slots, axis=0)),
-            mode="drop")
-        # the sampled first token enters the penalty ring (finals only)
-        rcol = rpos_rows % sampling.RING_N
-        ring = ring.at[seg_slots, rcol].set(
-            jnp.where(gate, ids_f, ring[seg_slots, rcol]), mode="drop")
-        ring_pos = ring_pos.at[seg_slots].set(
-            jnp.where(gate, rpos_rows + 1, rpos_rows), mode="drop")
-        active = jnp.asarray(active).at[seg_slots].set(
-            jnp.where(gate, True,
-                      jnp.take(jnp.asarray(active), seg_slots, axis=0)),
-            mode="drop")
-
-        step = self._make_scan_step(params, sp, bias, active,
-                                    (True, True, True), pos_offset)
-        carry = (tokens, ck, cv, lengths, ring, ring_pos, keys, mu)
-        carry, (ids_all, lps_all) = jax.lax.scan(step, carry, None,
-                                                 length=n_steps)
-        tokens, ck, cv, lengths, ring, ring_pos, keys, mu = carry
-        S = self.ecfg.num_slots
-        first_ids = jnp.zeros((S,), jnp.float32).at[seg_slots].set(
-            jnp.where(gate, ids_f.astype(jnp.float32), 0.0), mode="drop")
-        first_lps = jnp.zeros((S,), jnp.float32).at[seg_slots].set(
-            jnp.where(gate, lps_f, 0.0), mode="drop")
-        pack = jnp.concatenate(
-            [ids_all.astype(jnp.float32), lps_all, mu[None, :],
-             first_ids[None, :], first_lps[None, :]], axis=0)
-        return pack, ck, cv, keys, self._pin_chain(
-            tokens, lengths, ring, ring_pos, mu)
-
-    def _get_fused_packed_fn(self, bucket: int, continued: bool):
-        key = ("fused_packed", bucket, continued)
-        fn = self._burst_fns.get(key)
-        if fn is None:
-            fn = self._program(
-                "prefill_pack_fused", (bucket, continued),
-                f"{self._ragged_attn(bucket, continued)} + "
-                f"{self._decode_attn()}",
-                lambda *a: self._fused_packed_body(
-                    *a, n_steps=self.ecfg.decode_burst,
-                    continued=continued),
-                donate_argnums=(2, 3, 8))
-            self._burst_fns[key] = fn
-        return fn
-
     def _split_head_body(self, params, tokens, ck, cv, lengths, ring,
                          ring_pos, bias, keys, slot_params, active, mu,
                          ov_pack, p_tokens, p_positions, seg_of, seg_slots,
                          seg_start, seg_off, seg_len, final_mask,
                          continued: bool):
-        """EARLY-EMIT split, prefill half: exactly the state evolution of
-        _fused_packed_body up to (not including) the decode scan —
-        compose overrides, ragged-prefill every segment, sample the
-        FINAL segments' first tokens, fold them into the chain state —
+        """EARLY-EMIT admission, prefill head: compose overrides,
+        ragged-prefill every segment (fresh or continued), sample the
+        FINAL segments' first tokens, fold them into the chain state,
         and return the per-segment first tokens as their own device
-        outputs. The engine dispatches a plain decode burst chained off
-        the returned handles back-to-back (no host sync between), so the
-        device still sees one uninterrupted tick of work; but the sync
-        worker materializes THIS half first, so first tokens reach the
-        stream a whole decode burst earlier than the monolithic fused
-        body could deliver them — that delay is what kept fused auto
-        real-chip-only."""
+        outputs. Pad / non-final segments are gated exactly as in
+        _packed_prefill_body (sentinel slots drop, finals-only state
+        writes). The engine dispatches a plain decode burst chained off
+        the returned handles (_dispatch_packed_split), so under load one
+        tick ingests prompts AND decodes; the sync worker materializes
+        THIS half first, so first tokens reach the stream without
+        waiting for the burst's compute."""
         sp = sampling.unpack_slot_params(slot_params)
         tokens, lengths, ring, ring_pos, mu, _pos_offset = \
             self._compose_overrides(tokens, lengths, ring, ring_pos, mu,
@@ -3162,23 +3032,15 @@ class Engine:
                         self.params, *pack_args,
                         self.ck, self.cv, self.ring, self.ring_pos,
                         self.bias, self.rng_keys, spp, self.mu)
-                    if self._pack_fuse == "mono":
-                        ffn = self._get_fused_packed_fn(bucket, continued)
-                        _, self.ck, self.cv, self.rng_keys, _ = ffn(
-                            self.params, c_tok, self.ck, self.cv,
-                            c_len, c_ring, c_rpos, self.bias,
-                            self.rng_keys, spp, self.active_dev, c_mu,
-                            no_ov, *pack_args)
-                    elif self._pack_fuse == "split":
-                        # chain outputs are DISCARDED: the head donates
-                        # only ck/cv/keys, and the engine's host-side
-                        # tokens/lengths/ring/mu arrays must stay numpy
-                        hfn = self._get_split_head_fn(bucket, continued)
-                        _, _, self.ck, self.cv, self.rng_keys, _ = hfn(
-                            self.params, c_tok, self.ck, self.cv,
-                            c_len, c_ring, c_rpos, self.bias,
-                            self.rng_keys, spp, self.active_dev, c_mu,
-                            no_ov, *pack_args)
+                    # chain outputs are DISCARDED: the head donates
+                    # only ck/cv/keys, and the engine's host-side
+                    # tokens/lengths/ring/mu arrays must stay numpy
+                    hfn = self._get_split_head_fn(bucket, continued)
+                    _, _, self.ck, self.cv, self.rng_keys, _ = hfn(
+                        self.params, c_tok, self.ck, self.cv,
+                        c_len, c_ring, c_rpos, self.bias,
+                        self.rng_keys, spp, self.active_dev, c_mu,
+                        no_ov, *pack_args)
         if self._paged:
             # page-table commit (two op-by-op slices of the stacked
             # upload) and the copy-on-write page clone: both first run
@@ -3304,14 +3166,9 @@ class Engine:
                 self.slots[i] = None
                 ev = StreamEvent(token_id=-1, text="", logprob=0.0,
                                  finish_reason="stop", error="engine shut down")
-                if self._emitter is not None:
-                    # lands after any still-queued tokens for the stream
-                    self._emitter.push_final(i, s, [ev, None])
-                else:
-                    s.req.out.put(ev)
-                    s.req.out.put(None)
-        if self._emitter is not None:
-            self._emitter.stop(timeout=5.0)
+                # lands after any still-queued tokens for the stream
+                self._emitter.push_final(i, s, [ev, None])
+        self._emitter.stop(timeout=5.0)
 
     def _reset_device_state(self):
         if self._bus is not None:
@@ -3492,7 +3349,6 @@ class Engine:
             # per-dispatch packing efficiency (pad_tokens / tokens is
             # the bucket-pad waste the packing removed per-slot)
             "prefill_packed": self._packed,
-            "prefill_packed_fuse": self._pack_fuse,
             "prefill_token_budget": self._pack_budget,
             "packed_prefill": dict(self._pack_stats),
         }
@@ -3592,13 +3448,9 @@ class Engine:
         # pool overrides this with the co-scaled routable sum)
         out["queue_limit"] = self.maxq_effective
         # event-driven emission (ISSUE 9)
-        if self._emitter is not None:
-            out["emitter"] = {"enabled": True,
-                              "alive": self._emitter.alive,
-                              "queued": self._emitter.qsize(),
-                              "emitted": self._emitter.emitted}
-        else:
-            out["emitter"] = {"enabled": False}
+        out["emitter"] = {"alive": self._emitter.alive,
+                          "queued": self._emitter.qsize(),
+                          "emitted": self._emitter.emitted}
         # system observability (ISSUE 8): compile tracking + memory
         # watermarks + goodput/MFU, re-exposed per model on /metrics
         self._sample_watermarks()
@@ -4054,15 +3906,9 @@ class Engine:
                 # pick up whatever completed while the previous tick was
                 # packing/dispatching BEFORE spending this tick's host
                 # time — ready bursts otherwise pay a full tick of
-                # finish-detect each (ISSUE 9); never blocks. Only with
-                # the emitter on: in-loop emission makes burst pickup
-                # expensive enough that extra drain points would starve
-                # dispatch, so emitter=0 keeps the seed cadence.
-                ev_mode = self._emitter is not None
-                drained0 = False
-                if ev_mode:
-                    with span("tick_drain", "sched"):
-                        drained0 = self._drain_fifo(block=False)
+                # finish-detect each (ISSUE 9); never blocks
+                with span("tick_drain", "sched"):
+                    drained0 = self._drain_fifo(block=False)
                 with span("tick_admit", "sched"):
                     admitted = self._admit()
                 if self._prefetch is not None:
@@ -4076,9 +3922,8 @@ class Engine:
                 # prompt packing is the longest host stretch of the tick;
                 # collect anything that completed under it (no-op when
                 # nothing is ready)
-                if ev_mode:
-                    with span("tick_drain", "sched"):
-                        drained0 |= self._drain_fifo(block=False)
+                with span("tick_drain", "sched"):
+                    drained0 |= self._drain_fifo(block=False)
                 with span("tick_dispatch_decode", "sched"):
                     dispatched = self._dispatch_decode()
                 # the batch this tick's decode steps run with (a slot that
@@ -4142,12 +3987,8 @@ class Engine:
                     token_id=-1, text="", logprob=0.0,
                     finish_reason="stop", error=f"{type(e).__name__}: {e}",
                 )
-                if self._emitter is not None:
-                    # FIFO with any still-queued tokens (ISSUE 9)
-                    self._emitter.push_final(i, s, [ev, None])
-                else:
-                    s.req.out.put(ev)
-                    s.req.out.put(None)
+                # FIFO with any still-queued tokens (ISSUE 9)
+                self._emitter.push_final(i, s, [ev, None])
                 self._release_slot(i)
         # a failure inside a donated jitted call leaves ck/cv/ring/
         # keys pointing at deleted buffers — reinitialize device state
@@ -4650,11 +4491,8 @@ class Engine:
             if s is not None and s.req.request_id in self._cancelled:
                 self._cancelled.discard(s.req.request_id)
                 self._release_slot(i)
-                if self._emitter is not None:
-                    # close the stream AFTER queued tokens drain (ISSUE 9)
-                    self._emitter.push_final(i, s, [None])
-                else:
-                    s.req.out.put(None)
+                # close the stream AFTER queued tokens drain (ISSUE 9)
+                self._emitter.push_final(i, s, [None])
                 # a cancelled LEADER must not strand fork-waiting siblings
                 self._process_fork_waiters(i)
 
@@ -4691,12 +4529,9 @@ class Engine:
                     and s.req.request_id not in self._cancelled:
                 # decoding for a dead client: error event now, then the
                 # cancel path releases the slot and closes the stream
-                if self._emitter is not None:
-                    # no trailing None here — the cancel path routes the
-                    # stream close through the emitter queue itself
-                    self._emitter.push_final(i, s, [self._timeout_event(s.req)])
-                else:
-                    s.req.out.put(self._timeout_event(s.req))
+                # no trailing None here — the cancel path routes the
+                # stream close through the emitter queue itself
+                self._emitter.push_final(i, s, [self._timeout_event(s.req)])
                 self.cancel(s.req.request_id)
 
     def _check_parked_stall(self):
@@ -4784,13 +4619,9 @@ class Engine:
                 error=(f"device dispatch stalled > "
                        f"{self.ecfg.dispatch_stall_ms} ms; request aborted"),
                 error_kind="stall")
-            if self._emitter is not None:
-                # FIFO-ordered behind any tokens already handed over, so
-                # the abort reaches queued-but-unemitted tokens too
-                self._emitter.push_final(i, snap, [ev, None])
-            else:
-                snap.req.out.put(ev)
-                snap.req.out.put(None)
+            # FIFO-ordered behind any tokens already handed over, so
+            # the abort reaches queued-but-unemitted tokens too
+            self._emitter.push_final(i, snap, [ev, None])
             self._release_slot(i)
             self._process_fork_waiters(i)
 
@@ -5837,26 +5668,21 @@ class Engine:
             self.dck, self.dcv = self._get_draft_packed_fn(bucket)(
                 self.draft_params, *args, *meta[:4], self.dck, self.dcv)
 
-        # FUSED packed admission: when the pipeline has room and a
-        # full-size burst is runnable, ragged prefill + first tokens +
-        # the decode burst go out as ONE dispatch (_fused_packed_body)
-        # in "mono" mode, or as the early-emit back-to-back pair
-        # (_dispatch_packed_split) in "split" mode — the packed
-        # generalization of _dispatch_fused, covering continued
-        # segments too
+        # early-emit admission: when finals are present, the pipeline
+        # has room and a full-size burst is runnable, the pack goes out
+        # as the prefill head with the decode burst chained off its
+        # device outputs (_dispatch_packed_split); otherwise the plain
+        # packed program alone
         finals = [(slot, s, take) for slot, s, take, f in segs if f]
-        if (finals and self._pack_fuse != "off"
+        if (finals
                 and self._n_inflight_bursts() < self.ecfg.pipeline_depth
                 and self._pick_burst(
                     extra=[(s.written + t, s.req.max_new_tokens)
                            for _sl, s, t in finals],
                     infl_vec=infl_vec)
                 == self.ecfg.decode_burst):
-            if self._pack_fuse == "split":
-                return self._dispatch_packed_split(segs, args, meta,
-                                                   bucket, continued, t0)
-            return self._dispatch_packed_fused(segs, args, meta, bucket,
-                                               continued, t0)
+            return self._dispatch_packed_split(segs, args, meta,
+                                               bucket, continued, t0)
 
         fn = self._get_packed_fn(bucket, continued)
         # ring/ring_pos/mu copied: in-flight dispatches must not see
@@ -5893,102 +5719,19 @@ class Engine:
             self._sync_q.put(item)
         return True
 
-    def _dispatch_packed_fused(self, segs, args, meta, bucket: int,
-                               continued: bool, t0: float) -> bool:
-        """Dispatch ragged prefill + first-token sampling + a full decode
-        burst in ONE device call (_fused_packed_body). Final segments'
-        slots flip to decode NOW and their first tokens come back in the
-        burst's packed results (_process_burst group handling, identical
-        to the legacy fused path); non-final segments only advance their
-        prefill bookkeeping."""
-        S = self.ecfg.num_slots
-        C = self.ecfg.max_context
-        K = self.ecfg.decode_burst
-        group_snaps = []
-        t1 = time.monotonic()
-        for slot, s, take, final in segs:
-            s.pending = s.pending[take:]
-            s.written += take
-            if not final:
-                s.committed = s.written
-                s.t_prefill_ms += (t1 - t0) * 1e3
-                continue
-            s.phase = "decode"
-            # cache_len must reflect the prompt rows NOW (_pick_burst /
-            # _plan_spec cost capacity against in-flight steps)
-            s.cache_len = s.written
-            self.lengths[slot] = s.written
-            self.active_dev[slot] = True
-            self._override.add(slot)
-            if slot in self._prefill_queue:
-                self._prefill_queue.remove(slot)
-            group_snaps.append((slot, s))
-        # budget-mask other decoding slots exactly like _dispatch_decode
-        # (one FIFO pass for all slots' in-flight counts — ISSUE 9)
-        infl = self._inflight_vec()
-        active = self.active_dev.copy()
-        included = list(group_snaps)
-        for i, s in enumerate(self.slots):
-            if s is None or s.phase != "decode" \
-                    or any(g == i for g, _ in group_snaps):
-                continue
-            if s.req.max_new_tokens - s.n_decoded - infl[i] <= 0:
-                active[i] = False
-                continue
-            included.append((i, s))
-        for gslot, gs in group_snaps:
-            # pages for the prompt rows AND the K fused burst steps
-            self._ensure_pages(gslot, min(C, gs.written + K + 2))
-        for i, s in included:
-            if any(g == i for g, _ in group_snaps):
-                continue
-            self._ensure_pages(i, min(C, int(self.lengths[i])
-                                      + infl[i] + K + 2))
-        self._commit_ptab()
-        ov_mask = np.zeros((S,), np.bool_)
-        if self._chain is None:
-            chain = self._host_chain()
-        else:
-            chain = self._chain
-            for i in self._override:
-                ov_mask[i] = True
-        self._override.clear()
-        fn = self._get_fused_packed_fn(bucket, continued)
-        spp = sampling.pack_slot_params(self.slot_params)
-        ovp = self._pack_ov(ov_mask)
-        self._tick_decode_tokens += K * len(included)
-        self._count_kv_walk(K, infl, [i for i, _ in included])
-        with self._annot("prefill_pack_fused", steps=K,
-                         slots=len(included)):
-            pack, self.ck, self.cv, self.rng_keys, self._chain = fn(
-                self.params, chain[0], self.ck, self.cv, chain[1],
-                chain[2], chain[3], self.bias, self.rng_keys,
-                spp, active, chain[4], ovp, *args, *meta)
-        self._hobserve("prefill_dispatch_seconds", time.monotonic() - t0)
-        if self.tracer.enabled:
-            self.tracer.record("prefill_dispatch", "engine", t0,
-                               time.monotonic(),
-                               args={"segments": len(segs), "bucket": bucket,
-                                     "packed": True, "fused": True})
-        b = _Burst(K, included, pack, group=group_snaps, t_dispatch=t0)
-        self._fifo.append(b)
-        self._sync_q.put(b)
-        return True
-
     def _dispatch_packed_split(self, segs, args, meta, bucket: int,
                                continued: bool, t0: float) -> bool:
-        """EARLY-EMIT fused tick: the same one-tick work as
-        _dispatch_packed_fused, issued as TWO dispatches — the prefill
-        half (_split_head_body: ragged prefill + first-token sampling +
-        chain-state fold) and a plain decode burst chained off its
-        device outputs. Between them the head's first tokens are synced
-        and EMITTED (the only host round-trip; the device is computing
-        the head for its whole duration, so the pipeline bubble is just
-        the emit + dispatch latency) — finals' TTFT stops paying for the
-        decode half (the tradeoff that kept fused auto real-chip-only).
-        Host bookkeeping is the fused path's: finals flip to decode NOW,
-        the burst rides the FIFO with ``head`` linked for its
-        first-token rows."""
+        """EARLY-EMIT admission tick, issued as TWO dispatches: the
+        prefill head (_split_head_body: ragged prefill + first-token
+        sampling + chain-state fold) and a plain decode burst chained
+        off its device outputs. Between them the head's first tokens are
+        synced and EMITTED (the only host round-trip; the device is
+        computing the head for its whole duration, so the pipeline
+        bubble is just the emit + dispatch latency) — finals' TTFT does
+        not pay for the decode half. Final segments' slots flip to
+        decode NOW and the burst rides the FIFO with ``head`` linked for
+        its first-token rows; non-final segments only advance their
+        prefill bookkeeping."""
         S = self.ecfg.num_slots
         C = self.ecfg.max_context
         K = self.ecfg.decode_burst
@@ -6051,9 +5794,7 @@ class Engine:
         # just this host round-trip (the device is busy with the head
         # for the whole wait); on synchronous-dispatch backends (the CPU
         # smoke rig, where a jit call blocks for its own compute) the
-        # wait is free — so TTFT stops paying for the decode half, which
-        # is this mode's reason to exist ("mono" keeps the zero-bubble
-        # fully-fused tick for throughput-first deployments).
+        # wait is free — so TTFT stops paying for the decode half.
         head = _PendingPrefill(group_snaps, ids_f, lps_f, chain[4], t0,
                                split=True)
         self._fifo.append(head)      # discoverable for the stall handler
@@ -6253,7 +5994,7 @@ class Engine:
                     trc.record("prefill", f"slot{gslot}", t0, t1,
                                rid=gs.req.request_id,
                                args={"prompt_tokens": gs.prompt_len})
-            self._emit(gslot, first_id, float(lps_np[b]))
+            self._emit_token(gslot, first_id, float(lps_np[b]))
         # leaders just committed: fork their rows to any waiting siblings
         # (vanished leaders downgrade the siblings to full prefills)
         for gslot, _snap in group:
@@ -6300,7 +6041,7 @@ class Engine:
                                rid=gs.req.request_id,
                                args={"prompt_tokens": gs.prompt_len,
                                      "fused": "split"})
-            self._emit(gslot, int(ids_np[b]), float(lps_np[b]))
+            self._emit_token(gslot, int(ids_np[b]), float(lps_np[b]))
         for gslot, _snap in group:
             self._process_fork_waiters(gslot)
         self._flush_grammar_bias()
@@ -6452,9 +6193,7 @@ class Engine:
                 progressed = True
                 acted = True
                 break
-            if not acted or self._emitter is None:
-                # emitter=0: at most one burst per call (seed cadence —
-                # in-loop emission is too expensive to batch up)
+            if not acted:
                 break
         return progressed
 
@@ -7002,8 +6741,9 @@ class Engine:
     def _process_burst(self, b: "_Burst"):
         """Fold (if not already) then emit a burst's tokens (emission may
         release slots or trigger context shifts — both mark the device
-        chain dirty). Per-slot events are COALESCED into one queue put per
-        burst (see StreamEvent.token_ids)."""
+        chain dirty). The burst's tokens reach the emitter as ONE batch
+        (_flush_em_batch), which coalesces them per stream (see
+        StreamEvent.token_ids)."""
         if b.head is not None and not b.head.processed:
             # the pipeline block-synced this burst past its own
             # not-yet-processed head (_drain_fifo's burst walk passes
@@ -7023,8 +6763,7 @@ class Engine:
         if not b.group and b.t_dispatch:
             dt = (time.monotonic() - b.t_dispatch) * 1e3
             self._burst_ms_ema += 0.2 * (dt - self._burst_ms_ema)
-        t0 = time.monotonic()
-        t_proc = t0
+        t_proc = time.monotonic()
         tr = self.tracer
         if b.t_dispatch:
             t_rdy = b.t_ready or t_proc
@@ -7074,9 +6813,6 @@ class Engine:
                                         0, tot - b.n_steps
                                         * len(spec_idx))})
                 tr.record("finish_detect", "engine", t_rdy, t_proc)
-        # emitter mode hands tokens over as one immutable batch instead
-        # of coalescing events in-loop (ISSUE 9)
-        self._sink_buf = {} if self._emitter is None else None
         rolled: set = set()   # grammar slots rolled back mid-burst
         try:
             # fused-admission slots: emit the in-fn sampled first token
@@ -7109,8 +6845,8 @@ class Engine:
                                   rid=snap.req.request_id,
                                   args={"prompt_tokens": snap.prompt_len,
                                         "fused": True})
-                if not self._emit(i, int(b.first_ids[i]),
-                                  float(b.first_lps[i])):
+                if not self._emit_token(i, int(b.first_ids[i]),
+                                        float(b.first_lps[i])):
                     rolled.add(i)
             for i, _snap in b.group:
                 self._process_fork_waiters(i)
@@ -7128,7 +6864,7 @@ class Engine:
                                 break
                             snap.committed = min(snap.committed + 1,
                                                  snap.cache_len)
-                            if not self._emit(
+                            if not self._emit_token(
                                     i, int(b.ids_np[r * Wd + j, i]),
                                     float(b.lps_np[r * Wd + j, i])):
                                 rolled.add(i)
@@ -7143,180 +6879,24 @@ class Engine:
                         # token's KV row
                         snap.committed = min(snap.committed + 1,
                                              snap.cache_len)
-                        if not self._emit(i, int(b.ids_np[j, i]),
-                                          float(b.lps_np[j, i])):
+                        if not self._emit_token(i, int(b.ids_np[j, i]),
+                                                float(b.lps_np[j, i])):
                             rolled.add(i)
         finally:
-            buf, self._sink_buf = self._sink_buf, None
             self._flush_grammar_bias()
             self._flush_em_batch()
-            t0 = time.monotonic()
-            if tr.enabled:
-                # emit = detok + stop-scan walltime; flush is separate.
-                # With the emitter on this shrinks to id-level control +
-                # one queue put — the text work records under emit_bg on
-                # the emitter thread instead.
-                tr.record("emit", "engine", t_proc, t0,
-                          args={"steps": b.n_steps})
-            if buf:
-                for (_slot, out), evs in buf.items():
-                    out.put(evs[0] if len(evs) == 1 else _merge_events(evs))
-            if tr.enabled:
-                tr.record("stream_flush", "engine", t0, time.monotonic(),
-                          args={"streams": len(buf) if buf else 0})
 
     def _emit_token(self, slot: int, token_id: int, logprob: float) -> bool:
-        """Emit one token for a slot. Returns False when the token was a
-        grammar-invalid speculative sample and the slot rolled back (the
-        slot's remaining tokens in the current burst must be skipped)."""
-        s = self.slots[slot]
-        s.generated.append(token_id)
-        s.n_decoded += 1
-        self._total_tokens += 1
-        finish = None
-        shifted = False
-
-        if token_id in self.eos_ids and not (s.req.ignore_eos and s.grammar is None):
-            if s.grammar is not None and s.cur_penalty is not None \
-                    and s.cur_penalty[token_id] != 0.0:
-                # speculative EOS sampled under a STALE mask while the
-                # grammar cannot terminate yet — discard and resume
-                return self._rollback_grammar(slot, s)
-            finish = "stop"
-            delta = s.held_text + s.detok.flush()
-        elif s.grammar is not None and not self._advance_grammar(slot, s, token_id):
-            # speculative token fell outside the grammar (stale mask mid-
-            # burst) — roll back instead of emitting invalid output
-            return self._rollback_grammar(slot, s)
-        elif s.n_decoded >= s.req.max_new_tokens:
-            finish = "length"
-            delta = s.held_text + s.detok.push(token_id) + s.detok.flush()
-        elif s.win_off + s.cache_len + 1 >= self.ecfg.max_context - 1:
-            if self.ecfg.context_shift:
-                delta = s.held_text + s.detok.push(token_id)
-                s.held_text = ""
-                # stop sequences still apply at the shift-trigger token —
-                # a completing stop must finish, not leak past the shift
-                if s.req.stop_sequences:
-                    cut = self._check_stops(s, delta)
-                    if cut is not None:
-                        delta, finish = cut, "stop"
-                    elif delta:
-                        delta, s.held_text = self._holdback(s, delta)
-                if finish is None:
-                    self._context_shift(slot, s, token_id)
-                    shifted = True
-            else:
-                finish = "length"
-                delta = s.held_text + s.detok.push(token_id) + s.detok.flush()
-        else:
-            delta = s.held_text + s.detok.push(token_id)
-            s.held_text = ""
-            # stop-sequence handling with partial-match holdback
-            if s.req.stop_sequences:
-                cut = self._check_stops(s, delta)
-                if cut is not None:
-                    delta, finish = cut, "stop"
-                elif delta:
-                    delta, s.held_text = self._holdback(s, delta)
-
-        extended = False
-        if finish is None and not shifted:
-            # this token's KV is written by the next decode step
-            self._cache_tokens[slot].append(token_id)
-            s.cache_len += 1
-            if self.ecfg.ga_n > 1 and s.mm_pos is None:
-                extended = self._maybe_self_extend(slot, s)
-
-        ev = StreamEvent(
-            token_id=token_id, text=delta, logprob=logprob,
-            finish_reason=finish,
-            prompt_tokens=s.prompt_len, completion_tokens=s.n_decoded,
-        )
-        buf = self._sink_buf
-        if finish:
-            dt = time.monotonic() - s.t_first_token
-            # TTFT decomposition (VERDICT r4 #9): how long the request sat
-            # in the admission queue vs the admit->first-token span (which
-            # itself splits into prefill dispatch time, t_prefill_ms, and
-            # waiting on other slots' work)
-            queue_wait_ms = max(0.0, (s.t_start - s.req.t_submit) * 1e3) \
-                if s.req.t_submit else 0.0
-            admit_to_first_ms = max(0.0, (s.t_first_token - s.t_start) * 1e3) \
-                if s.t_first_token else 0.0
-            ev.timings = {
-                "prefill_ms": s.t_prefill_ms,
-                "queue_wait_ms": queue_wait_ms,
-                "admit_to_first_ms": admit_to_first_ms,
-                "reused_prompt_tokens": s.reused,
-                "decode_tokens_per_s": (s.n_decoded - 1) / dt if dt > 0 and s.n_decoded > 1 else 0.0,
-            }
-            with self._decomp_lock:
-                self._ttft_decomp.append(
-                    (queue_wait_ms, admit_to_first_ms, s.t_prefill_ms))
-            t_done = time.monotonic()
-            if self.tracer.enabled and s.req.t_submit:
-                self.tracer.record("request", f"slot{slot}",
-                                   s.req.t_submit, t_done,
-                                   rid=s.req.request_id,
-                                   args={"completion_tokens": s.n_decoded,
-                                         "finish": finish})
-            if self._slow_ms > 0:
-                ttft_ms = queue_wait_ms + admit_to_first_ms
-                e2e_ms = (t_done - s.req.t_submit) * 1e3 \
-                    if s.req.t_submit else 0.0
-                if ttft_ms > self._slow_ms or e2e_ms > self._slow_ms:
-                    import json as _json
-                    import logging as _logging
-
-                    _logging.getLogger(__name__).warning(
-                        "slow request %s: %s", s.req.request_id,
-                        _json.dumps({
-                            "threshold_ms": self._slow_ms,
-                            "e2e_ms": round(e2e_ms, 1),
-                            "ttft_ms": round(ttft_ms, 1),
-                            "completion_tokens": s.n_decoded,
-                            "spans": {k: (round(v, 1)
-                                          if isinstance(v, float) else v)
-                                      for k, v in ev.timings.items()},
-                        }, sort_keys=True))
-            # goodput (ISSUE 8): ONLY clean finishes count — sheds,
-            # timeouts and stall aborts never reach this branch
-            self._goodput.add(s.n_decoded)
-            self._slo_finish(s, s.n_decoded, t_done,
-                            queue_wait_ms + admit_to_first_ms,
-                            queue_wait_ms)
-            EVENTS.emit("complete", rid=s.req.request_id, finish=finish,
-                        completion_tokens=s.n_decoded,
-                        e2e_ms=round((t_done - s.req.t_submit) * 1e3, 1)
-                        if s.req.t_submit else None)
-            self._save_prompt_cache(slot, s)
-            self._release_slot(slot)
-            if buf is not None:
-                evs = buf.pop((slot, s.req.out), None)
-                if evs:
-                    s.req.out.put(evs[0] if len(evs) == 1 else _merge_events(evs))
-            s.req.out.put(ev)
-            s.req.out.put(None)
-        elif buf is not None:
-            buf.setdefault((slot, s.req.out), []).append(ev)
-        else:
-            s.req.out.put(ev)
-        # a self-extend compression invalidates the slot's remaining
-        # in-flight tokens (stale positions) — skip them like a rollback,
-        # but the token above was valid and HAS been emitted
-        return not extended
-
-    # ---------- event-driven emission (ISSUE 9) ----------
-
-    def _emit_token_ev(self, slot: int, token_id: int, logprob: float) -> bool:
-        """Event-driven twin of _emit_token: identical id-level control
-        flow (EOS, grammar advance/rollback, length, context shift, KV
-        bookkeeping), but NO text work — the token joins the per-tick
-        batch handed to the emitter worker, which owns detok, stop-scan
-        and every ``req.out`` put. Stop sequences are text-level, so in
-        this mode they are detected by the EMITTER and fed back via
-        ``_apply_emitter_notes``."""
+        """Emit one token for a slot: the id-level control flow (EOS,
+        grammar advance/rollback, length, context shift, KV bookkeeping)
+        and NO text work — the token joins the per-tick batch handed to
+        the emitter worker, which owns detok, stop-scan and every
+        ``req.out`` put. Stop sequences are text-level, so they are
+        detected by the EMITTER and fed back via
+        ``_apply_emitter_notes``. Returns False when the slot's remaining
+        tokens in the current burst must be skipped (a grammar-invalid
+        speculative sample rolled the slot back, or a self-extend
+        compression invalidated its in-flight positions)."""
         s = self.slots[slot]
         s.generated.append(token_id)
         s.n_decoded += 1
@@ -7342,7 +6922,7 @@ class Engine:
                 # the emitter still stop-scans this token; a stop that
                 # completes here aborts the shifted slot via the note
                 # channel — the re-prefill is wasted work, the emitted
-                # OUTPUT is identical to the in-loop path
+                # OUTPUT ends at the stop either way
                 self._context_shift(slot, s, token_id)
                 shifted = True
             else:
@@ -7365,12 +6945,12 @@ class Engine:
         # while the batch rides the queue
         e["tokens"].append((token_id, logprob, s.n_decoded))
         if finish:
-            timings = self._finish_timings_ev(s, s.n_decoded,
-                                              time.monotonic())
+            timings = self._finish_timings(s, s.n_decoded,
+                                           time.monotonic())
             e["finish"] = finish
             e["timings"] = timings
-            self._finish_accounting_ev(slot, s, finish, s.n_decoded,
-                                       timings)
+            self._finish_accounting(slot, s, finish, s.n_decoded,
+                                    timings)
         return not extended
 
     def _flush_em_batch(self):
@@ -7380,10 +6960,10 @@ class Engine:
             batch, self._em_batch = self._em_batch, {}
             self._emitter.push_batch(list(batch.values()))
 
-    def _finish_timings_ev(self, s: "_Slot", ndec: int, t_done: float) -> dict:
-        """Final-event timings for an engine-detected finish (same fields
-        _emit_token computes inline; the emitter mirrors this for the
-        stops it detects itself)."""
+    def _finish_timings(self, s: "_Slot", ndec: int, t_done: float) -> dict:
+        """Final-event timings for an engine-detected finish (the
+        emitter computes the same fields for the stops it detects
+        itself)."""
         dt = t_done - s.t_first_token
         queue_wait_ms = max(0.0, (s.t_start - s.req.t_submit) * 1e3) \
             if s.req.t_submit else 0.0
@@ -7398,12 +6978,12 @@ class Engine:
                 (ndec - 1) / dt if dt > 0 and ndec > 1 else 0.0,
         }
 
-    def _finish_accounting_ev(self, slot: int, s: "_Slot", finish: str,
-                              ndec: int, timings: dict):
-        """Everything _emit_token's finish branch does besides the stream
-        puts (those belong to the emitter): TTFT decomposition, request
-        span, slow-request log, goodput, completion event, prompt-cache
-        save, slot release."""
+    def _finish_accounting(self, slot: int, s: "_Slot", finish: str,
+                           ndec: int, timings: dict):
+        """Everything a finish does besides the stream puts (those
+        belong to the emitter): TTFT decomposition, request span,
+        slow-request log, goodput, completion event, prompt-cache save,
+        slot release."""
         with self._decomp_lock:
             self._ttft_decomp.append(
                 (timings["queue_wait_ms"], timings["admit_to_first_ms"],
@@ -7472,11 +7052,11 @@ class Engine:
         pulls a racing context-shift re-prefill back out of the queue,
         and accounts the completion. ``abort`` notes are emitter-side
         item failures (e.g. a detokenizer exception) whose streams the
-        emitter already failed — release only, no completion accounting
-        (mirrors the in-loop generic handler). Tokens decoded past the
+        emitter already failed — release only, no completion
+        accounting. Tokens decoded past the
         note are discarded with the slot (same rule as any other
         in-flight invalidation)."""
-        if self._emitter is None or not self._em_notes:
+        if not self._em_notes:
             return
         with self._em_lock:
             notes, self._em_notes = self._em_notes, []
@@ -7494,8 +7074,8 @@ class Engine:
                 if isinstance(b, _Burst):
                     b.skip_slots.add(slot)
             if kind == "stop":
-                self._finish_accounting_ev(slot, snap, "stop", ndec,
-                                           timings)
+                self._finish_accounting(slot, snap, "stop", ndec,
+                                        timings)
             else:
                 self._release_slot(slot)
             self._process_fork_waiters(slot)
@@ -7506,8 +7086,6 @@ class Engine:
         with work still queued), take over its queue, fail every affected
         stream directly, and build a fresh worker."""
         em = self._emitter
-        if em is None:
-            return
         stall_s = self.ecfg.dispatch_stall_ms / 1e3
         if stall_s <= 0:
             return
@@ -7615,30 +7193,6 @@ class Engine:
         for b in self._fifo:
             if isinstance(b, _Burst):
                 b.skip_slots.add(slot)
-
-    def _check_stops(self, s: _Slot, delta: str) -> Optional[str]:
-        """If a stop sequence completes in emitted+delta text, return the
-        delta truncated before the stop; else None."""
-        total = s.detok.text  # includes delta already
-        for stop in s.req.stop_sequences:
-            idx = total.find(stop, max(0, len(total) - len(delta) - len(stop)))
-            if idx != -1:
-                emitted_before = len(total) - len(delta)
-                return delta[: max(0, idx - emitted_before)]
-        return None
-
-    def _holdback(self, s: _Slot, delta: str) -> tuple[str, str]:
-        """Withhold a suffix of delta that is a prefix of any stop sequence."""
-        total = s.detok.text
-        hold = 0
-        for stop in s.req.stop_sequences:
-            for k in range(min(len(stop) - 1, len(total)), 0, -1):
-                if total.endswith(stop[:k]):
-                    hold = max(hold, min(k, len(delta)))
-                    break
-        if hold:
-            return delta[:-hold], delta[-hold:]
-        return delta, ""
 
     def _retire_window(self, slot: int, s: "_Slot") -> int:
         """Shared windowed-slot retirement (ISSUE 16): the table holds

@@ -526,8 +526,7 @@ class EnginePool:
                             if e._sched is not None else 0))
         log.warning("engine pool: replica %d loop died; recovering", i)
         # settle client streams + detok state: the emitter owns both
-        if e._emitter is not None:
-            e._emitter.drain(2.0)
+        e._emitter.drain(2.0)
         # its device pages are gone: forget them pool-wide
         self._shared.index.clear_replica(i)
         if self._shared.store is not None:

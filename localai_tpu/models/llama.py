@@ -787,102 +787,6 @@ def engine_decode(params, cfg, tokens, lengths, active, cache_k, cache_v,
                        pos_offset=pos_offset)
 
 
-def fused_prefill_decode(
-    params: dict,
-    cfg: LlamaConfig,
-    tokens: jax.Array,      # [S] int32 — pending decode token per slot
-    lengths: jax.Array,     # [S] int32 — context length per slot
-    active: jax.Array,      # [S] bool — slots advancing this step
-    cache_k: jax.Array,
-    cache_v: jax.Array,
-    pr_tokens: jax.Array,   # [B, T] int32 fresh prompts, right-padded
-    pr_seq: jax.Array,      # [B] int32 true lengths
-    pr_slots: jax.Array,    # [B] int32 target slots (disjoint from active)
-    pr_start: jax.Array,    # [B] int32 position offset
-    pos_offset: jax.Array = None,   # [S] self-extend offset for decode
-):
-    """One decode step for all active slots AND a fresh-prompt prefill
-    batch, in a SINGLE forward whose activations are concatenated along
-    the token axis — so the two workloads share every weight read.
-
-    Packing prompt tokens and decode tokens into one batch is the
-    reference's llama_batch design (grpc-server.cpp:1671+); the TPU form
-    is a static-shape concat feeding shared matmuls, with per-segment
-    RoPE/attention after the projections.
-
-    Not wired into the engine: an earlier rig measured it slower than
-    the sequential prefill-then-decode composition (engine.py
-    _fused_body), and it has not been timed on the chip (ROADMAP D4:
-    measure once, then wire in or delete). Parity-tested.
-
-    Semantics match engine_decode(active-masked) followed by
-    prefill(continued=False) on disjoint slots. Returns
-    (dec_logits [S, V], pr_logits [B, V], cache_k, cache_v)."""
-    S = tokens.shape[0]
-    B, T = pr_tokens.shape
-    D = cfg.hidden_size
-    hd = cfg.head_dim_
-    C = kvcache.shape(cache_k)[2]
-    write_lengths = jnp.where(active, lengths, C)   # inactive writes drop
-
-    dpos = write_lengths[:, None]                   # [S, 1]
-    if pos_offset is not None:
-        dpos = dpos - pos_offset[:, None]
-    ppos = pr_start[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-    pos_all = jnp.concatenate([dpos.reshape(1, S), ppos.reshape(1, B * T)],
-                              axis=1)               # [1, S+B*T]
-    sin, cos = rope_frequencies(cfg, pos_all)
-    with _scope("embed"):
-        xd = _embed_rows(params["embed"], tokens, cfg.dtype)        # [S, D]
-        xp = _embed_rows(params["embed"], pr_tokens, cfg.dtype)     # [B, T, D]
-        x = jnp.concatenate([xd, xp.reshape(B * T, D)], axis=0)[None]  # [1,N,D]
-    valid = jnp.arange(T, dtype=jnp.int32)[None, :] < pr_seq[:, None]
-    rows = pr_slots[:, None] * jnp.ones((1, T), jnp.int32)
-    cols = ppos
-
-    def layer_fn(carry, layer):
-        x, ck, cv = carry
-        li = layer.pop("_idx")
-        with _scope("layer/attn_proj"):
-            h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
-            q, k, v = _project_qkv(h, layer, cfg)   # ONE weight read each
-            q = apply_rope(q, sin, cos)
-            k = apply_rope(k, sin, cos)
-        with _scope("layer/attn"):
-            qd, qp = q[0, :S], q[0, S:].reshape(B, T, cfg.num_heads, hd)
-            kd, kp = k[0, :S], k[0, S:].reshape(B, T, cfg.num_kv_heads, hd)
-            vd, vp = v[0, :S], v[0, S:].reshape(B, T, cfg.num_kv_heads, hd)
-            attn_d, ck, cv = _decode_attend_write(qd, kd, vd, ck, cv, li,
-                                                  write_lengths, cfg)
-            attn_p = causal_attention(qp, kp, vp, valid, cfg.q_per_kv)
-            ck = kvcache.scatter_prefill(ck, li, rows, cols, kp)
-            cv = kvcache.scatter_prefill(cv, li, rows, cols, vp)
-            attn = jnp.concatenate([attn_d.reshape(S, -1),
-                                    attn_p.reshape(B * T, -1)], axis=0)[None]
-        with _scope("layer/attn_proj"):
-            x = x + jnp.einsum("bth,hd->btd", attn,
-                               _mat(layer["wo"], x.dtype))
-        with _scope("layer/mlp"):
-            h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
-            x = x + _mlp(h, layer)
-        return (x, ck, cv), None
-
-    layers = dict(params["layers"])
-    layers["_idx"] = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-    (x, cache_k, cache_v), _ = jax.lax.scan(layer_fn, (x, cache_k, cache_v),
-                                            layers)
-    with _scope("final_norm"):
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    with _scope("lm_head"):
-        xd = x[0, :S]                                   # [S, D]
-        xp = x[0, S:].reshape(B, T, D)
-        last = jnp.take_along_axis(
-            xp, (pr_seq - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-        both = jnp.concatenate([xd, last], axis=0)[None]   # [1, S+B, D]
-        logits = _unembed(both, params, cfg)[0]
-    return logits[:S], logits[S:], cache_k, cache_v
-
-
 def shift_cache_positions(cache_k: jax.Array, cfg: LlamaConfig,
                           slot: jax.Array, deltas: jax.Array) -> jax.Array:
     """Re-rotate ONE slot's cached keys by per-row position deltas [C].

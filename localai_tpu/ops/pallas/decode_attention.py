@@ -1,6 +1,6 @@
 """Pallas TPU kernel: batched decode (append-)attention over the KV cache.
 
-WHY A KERNEL (r3 HLO evidence, scripts/inspect_hlo.py): with the jnp
+WHY A KERNEL (r3 HLO evidence): with the jnp
 einsum formulation, XLA's layout assignment gives the attention dot a
 C-minor (transposed) cache operand layout while the scan carry holds the
 cache hd-minor — so every layer of every decode step materializes TWO

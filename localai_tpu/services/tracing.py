@@ -36,16 +36,15 @@ import threading
 import time
 
 # Span names counted as HOST loop work in the decomposition: time the
-# engine thread spends dispatching / detokenizing / flushing, measured
-# as plain walltime deltas on the engine thread. The ``tick_*`` phase
+# engine thread spends admitting, dispatching and moving KV pages,
+# measured as plain walltime deltas on the engine thread (detok and the
+# stream queue puts are the emitter thread's: EMITTER_SPANS). The ``tick_*`` phase
 # spans are NOT here: they contain these and would count them twice.
 HOST_SPANS = frozenset({
     "admission",
     "prefill_chunk",
     "prefill_dispatch",
     "decode_burst",
-    "emit",
-    "stream_flush",
     "kv_offload_gather",
     "kv_restore_scatter",
 })

@@ -48,7 +48,7 @@ if _ROOT not in sys.path:
 
 
 class _ByteTokenizer256:
-    """bench.py's tokenizer: raw utf-8 bytes, id 256 = EOS."""
+    """Raw utf-8 bytes, id 256 = EOS."""
     vocab_size = 257
     eos_token_id = 256
 

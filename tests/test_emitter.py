@@ -1,11 +1,11 @@
-"""Event-driven emission worker (ISSUE 9): parity, ordering, drain
-routing, wedge watchdog — hermetic CPU.
+"""Event-driven emission worker (ISSUE 9): stop-sequence text, ordering,
+drain routing, wedge watchdog — hermetic CPU.
 
 The emitter owns detok/stop-scan/queue-puts on its own thread; these
-tests pin the contract that made the refactor safe to ship: byte-for-
-byte greedy parity with the in-loop path (``emitter=0``), per-slot FIFO
-ordering under interleaved bursts, failure finals that land AFTER
-queued tokens, and watchdog replacement of a wedged worker.
+tests pin its contract: stop-sequence cuts and partial-match hold-back
+against hand-written text, per-slot FIFO ordering under interleaved
+bursts, failure finals that land AFTER queued tokens, and watchdog
+replacement of a wedged worker.
 """
 
 import queue
@@ -17,8 +17,11 @@ import pytest
 
 from localai_tpu.engine import engine as eng
 from localai_tpu.engine import sampling
+from localai_tpu.engine.detok import IncrementalDetokenizer
+from localai_tpu.engine.emitter import EmitterWorker
 from localai_tpu.models import llama
 from localai_tpu.services.faults import FAULTS
+from localai_tpu.services.tracing import NO_TRACER
 
 
 def _build(byte_tokenizer, **ecfg_kw):
@@ -37,8 +40,7 @@ def _build(byte_tokenizer, **ecfg_kw):
 
 @pytest.fixture(scope="module")
 def emitter_engine(byte_tokenizer):
-    e = _build(byte_tokenizer)          # emitter defaults ON
-    assert e._emitter is not None
+    e = _build(byte_tokenizer)
     yield e
     e.shutdown()
 
@@ -50,23 +52,121 @@ def _greedy(tok, prompt, n, **kw):
         max_new_tokens=n, ignore_eos=True, **kw)
 
 
-def test_greedy_byte_parity_vs_inloop(emitter_engine, byte_tokenizer):
-    """emitter=0 restores the in-loop path; both must be bit-for-bit
-    identical on greedy output (ids AND text deltas' concatenation)."""
-    off = _build(byte_tokenizer, emitter=False)
+class _PieceTokenizer:
+    """Token id -> a fixed byte string: deltas of several characters,
+    and multi-byte characters split across tokens, chosen by the test."""
+
+    def __init__(self, pieces):
+        self.pieces = pieces
+
+    def decode(self, ids, skip_special_tokens=True):
+        return b"".join(self.pieces[i] for i in ids).decode(
+            "utf-8", errors="replace")
+
+
+# (pieces, stop sequences, engine-detected finish on the LAST piece or
+#  None, the text delta of each event in order, the final finish reason)
+_STOP_CASES = {
+    # the stop lies inside one token's text: cut in the middle of it,
+    # and the token after it is never emitted
+    "stop_inside_one_delta": (
+        [b"Hello", b" wor", b"ld! END tail", b"more"], ["END"], None,
+        ["Hello", " wor", "ld! "], "stop"),
+    # "<|e" could be the start of the stop: held; the next token
+    # completes it, so the held text is cut, not released
+    "stop_spans_two_tokens": (
+        [b"abc", b"<|e", b"nd|>x"], ["<|end|>"], None,
+        ["abc", "", ""], "stop"),
+    # a partial match that never completes: the held text goes out with
+    # the next delta; what is still held at the finish is flushed there
+    "partial_match_released_and_flushed": (
+        [b"x<|e", b"no", b" <|", b"en"], ["<|end|>"], "length",
+        ["x", "<|eno", " ", "<|en"], "length"),
+    # an engine-detected EOS releases the held text (the EOS token
+    # itself has no text)
+    "held_text_flushed_at_eos": (
+        [b"ab<", b""], ["<|"], "stop",
+        ["ab", "<"], "stop"),
+    # e-acute arrives as two tokens: nothing until it is whole, and the
+    # cut is counted in characters, not bytes
+    "stop_after_split_multibyte_char": (
+        [b"caf", b"\xc3", b"\xa9\n", b"\nrest"], ["\n\n"], None,
+        ["caf", "", "\u00e9", ""], "stop"),
+    # "world" is listed first and would complete one token later; the
+    # later-listed "lo w" completes first and ends the stream
+    "later_listed_stop_matches_first": (
+        [b"hel", b"lo w", b"orld"], ["world", "lo w"], None,
+        ["he", "l"], "stop"),
+}
+
+
+def _emit_pieces(pieces, stops, finish, one_batch):
+    """Feed ``pieces`` as token ids 0..n-1 through an EmitterWorker built
+    the way the engine builds its own; one token per batch (a delta per
+    event) or all in one batch (one coalesced event). Returns (events up
+    to the stream-close sentinel, notes fed back to the engine, the slot
+    snapshot)."""
+    notes = []
+    em = EmitterWorker(
+        tracer=NO_TRACER, stream_event=eng.StreamEvent,
+        merge_events=eng._merge_events,
+        note_finish=lambda *a: notes.append(a))
     try:
-        assert off._emitter is None
-        for prompt, n in (("hello", 8), ("parity", 12)):
-            t_on, ev_on = emitter_engine.generate_text(
-                _greedy(byte_tokenizer, prompt, n))
-            t_off, ev_off = off.generate_text(
-                _greedy(byte_tokenizer, prompt, n))
-            assert t_on == t_off
-            assert eng.event_ids(ev_on) == eng.event_ids(ev_off)
-            assert ev_on[-1].finish_reason == ev_off[-1].finish_reason
-            assert ev_on[-1].completion_tokens == ev_off[-1].completion_tokens
+        req = eng.GenRequest(
+            prompt_ids=[0], params=sampling.SamplingParamsHost(),
+            max_new_tokens=len(pieces), stop_sequences=list(stops))
+        out = req.out
+        snap = eng._Slot(
+            req, IncrementalDetokenizer(_PieceTokenizer(pieces)), 1)
+        snap.t_first_token = time.monotonic()
+        toks = [(i, 0.0, i + 1) for i in range(len(pieces))]
+        timings = {"prefill_ms": 0.0} if finish else None
+        batches = [toks] if one_batch else [[t] for t in toks]
+        for j, batch in enumerate(batches):
+            last = j == len(batches) - 1
+            em.push_batch([{
+                "slot": 0, "snap": snap, "tokens": batch,
+                "finish": finish if last else None,
+                "timings": timings if last else None}])
+        assert em.drain(5.0)
+        evs = []
+        while True:
+            ev = out.get(timeout=5)
+            if ev is None:
+                break
+            evs.append(ev)
+        assert out.empty()      # nothing follows the sentinel
+        return evs, notes, snap
     finally:
-        off.shutdown()
+        em.stop()
+
+
+@pytest.mark.parametrize("case", sorted(_STOP_CASES))
+def test_stop_sequences_and_holdback_text(case):
+    """Stop-sequence truncation and partial-match hold-back through the
+    emitter worker, against text written out by hand."""
+    pieces, stops, finish, deltas, reason = _STOP_CASES[case]
+    evs, notes, snap = _emit_pieces(pieces, stops, finish, one_batch=False)
+    assert [e.text for e in evs] == deltas
+    assert [e.finish_reason for e in evs] == \
+        [None] * (len(deltas) - 1) + [reason]
+    assert evs[-1].completion_tokens == len(deltas)
+    for stop in stops:
+        assert stop not in "".join(deltas)
+    # a stop the emitter found itself is fed back so that the engine
+    # releases the slot; a finish the engine detected is not
+    if finish is None:
+        assert [(n[0], n[1], n[2]) for n in notes] == \
+            [(0, snap, len(deltas))]
+    else:
+        assert notes == []
+    # the same tokens as one burst's batch: one coalesced event, same text
+    evs1, _notes, _snap = _emit_pieces(pieces, stops, finish,
+                                       one_batch=True)
+    assert "".join(e.text for e in evs1) == "".join(deltas)
+    assert evs1[-1].finish_reason == reason
+    assert sum(len(e.token_ids or [e.token_id]) for e in evs1) == \
+        len(deltas)
 
 
 def test_per_slot_fifo_ordering_interleaved(emitter_engine, byte_tokenizer):
@@ -237,7 +337,7 @@ def test_emitter_metrics_surface(emitter_engine, byte_tokenizer):
     e = emitter_engine
     e.generate_text(_greedy(byte_tokenizer, "m", 4))
     m = e.metrics()["emitter"]
-    assert m["enabled"] is True and m["alive"] is True
+    assert m["alive"] is True
     assert m["emitted"] > 0
 
 

@@ -611,7 +611,7 @@ def test_offload_restore_parity_on_mesh(tiny_cfg_params):
     params: the parity compares restore-then-continue against a full
     prefill, whose forwards run at different shapes — mesh partitioning
     plus bf16 rounding flips greedy near-ties on noise unrelated to the
-    mechanism under test (same reasoning as bench.py --pressure)."""
+    mechanism under test."""
     import dataclasses as _dc
 
     from localai_tpu.parallel import mesh as meshlib

@@ -223,56 +223,6 @@ def test_int4_quantized_model_close_to_fp():
     assert np.all(cos_rows(q_d, ref_d) > 0.85), cos_rows(q_d, ref_d)
 
 
-def test_fused_prefill_decode_matches_sequential():
-    """fused_prefill_decode (ONE concatenated forward sharing every
-    weight read — the r5 serving hot path) must equal prefill followed by
-    the active-masked decode step, for bf16 and int8 KV caches."""
-    cfg = llama.LlamaConfig(vocab_size=128, hidden_size=64,
-                            intermediate_size=96, num_layers=2, num_heads=4,
-                            num_kv_heads=2, head_dim=16,
-                            max_position_embeddings=256, dtype=jnp.float32)
-    params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    S, C, B, T = 6, 64, 2, 16
-    rng = np.random.default_rng(0)
-    for kv_dtype in (None, jnp.int8):
-        ck, cv = llama.init_cache(cfg, S, C, kv_dtype)
-        warm_tokens = jnp.asarray(rng.integers(2, 100, (3, 8)), jnp.int32)
-        warm_lens = jnp.asarray([8, 5, 7], jnp.int32)
-        _, ck, cv = llama.prefill(params, cfg, warm_tokens, warm_lens, ck, cv,
-                                  jnp.asarray([0, 1, 2], jnp.int32),
-                                  jnp.zeros(3, jnp.int32))
-        tokens = jnp.asarray(rng.integers(2, 100, (S,)), jnp.int32)
-        lengths = jnp.asarray([8, 5, 7, 0, 0, 0], jnp.int32)
-        active = jnp.asarray([True, True, True, False, False, False])
-        pr_tokens = jnp.asarray(rng.integers(2, 100, (B, T)), jnp.int32)
-        pr_seq = jnp.asarray([16, 11], jnp.int32)
-        pr_slots = jnp.asarray([3, 4], jnp.int32)
-        pr_start = jnp.zeros(B, jnp.int32)
-
-        pr_ref, ck_r, cv_r = llama.prefill(params, cfg, pr_tokens, pr_seq,
-                                           ck, cv, pr_slots, pr_start)
-        dec_ref, ck_r, cv_r = llama.engine_decode(params, cfg, tokens,
-                                                  lengths, active, ck_r, cv_r)
-        dec_f, pr_f, ck_f, cv_f = llama.fused_prefill_decode(
-            params, cfg, tokens, lengths, active, ck, cv,
-            pr_tokens, pr_seq, pr_slots, pr_start)
-
-        np.testing.assert_allclose(np.asarray(dec_f)[:3],
-                                   np.asarray(dec_ref)[:3],
-                                   rtol=2e-4, atol=2e-4)
-        np.testing.assert_allclose(np.asarray(pr_f), np.asarray(pr_ref),
-                                   rtol=2e-4, atol=2e-4)
-
-        def flat(t):
-            return np.concatenate([np.asarray(x, np.float32).ravel()
-                                   for x in jax.tree.leaves(t)])
-
-        np.testing.assert_allclose(flat(ck_f), flat(ck_r), rtol=2e-4,
-                                   atol=2e-4)
-        np.testing.assert_allclose(flat(cv_f), flat(cv_r), rtol=2e-4,
-                                   atol=2e-4)
-
-
 def test_int4_quantization_wired_through_loadmodel(tmp_path):
     """YAML/proto quantization="int4" -> the DEVICE weights are actually
     jnp.int4 with grouped scales (w_down gets group 128; wq's in-axis 64

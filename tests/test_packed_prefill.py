@@ -285,25 +285,6 @@ def test_packed_context_shift_reprefill(engine_pair):
     assert got == ref and len(ref[0]) == 120
 
 
-def test_packed_fused_burst_greedy_parity(tiny_cfg_params, engine_pair):
-    """prefill_packed_fuse=1 (ragged prefill + first tokens + decode
-    burst in ONE dispatch — the real-chip default) stays byte-identical
-    to the per-slot path, and the fused variant actually dispatched
-    (_Burst group path, observable via the burst-fn cache key)."""
-    cfg, params = tiny_cfg_params
-    e0, _ = engine_pair
-    prompts = _mixed_prompts(np.random.default_rng(8))
-    ref = _run_wave(e0, prompts, n=24)
-    e1 = _engine(cfg, params, packed=True, prefill_packed_fuse="1")
-    try:
-        got = _run_wave(e1, prompts, n=24)
-        assert any(isinstance(k, tuple) and k[0] == "fused_packed"
-                   for k in e1._burst_fns), "fused packed variant never ran"
-    finally:
-        e1.shutdown()
-    assert got == ref
-
-
 def test_prefill_packed_off_restores_legacy(engine_pair, monkeypatch):
     """prefill_packed=0 must never reach the ragged forward."""
     e0, _ = engine_pair
@@ -461,22 +442,26 @@ def test_long_pack_parity_vs_per_slot(dtype):
 
 
 def test_split_early_emit_default_and_parity(tiny_cfg_params, engine_pair):
-    """prefill_packed_fuse=auto now resolves to the EARLY-EMIT split on
-    every platform: the head program actually ran on the shared packed
-    engine, an explicit split engine stays byte-identical to the
-    per-slot path, and the shape-fallback counter stays 0 (every CPU
-    test pack has a kernel plan)."""
+    """Packed admission dispatches both of its forms, the early-emit
+    head (finals present, room in the pipeline, a full burst runnable)
+    and the plain pack otherwise (a lone prompt longer than one chunk
+    starts with a pack that holds no final segment): a fresh engine
+    that ran both stays byte-identical to the per-slot path, and the
+    shape-fallback counter stays 0 (every CPU test pack has a kernel
+    plan)."""
     cfg, params = tiny_cfg_params
     e0, e1 = engine_pair
-    assert e1.metrics()["prefill_packed_fuse"] == "split"
-    assert any(isinstance(k, tuple) and k[0] == "packed_head"
-               for k in e1._final_fns), "split head never compiled"
     assert e1.metrics()["packed_prefill"]["kernel_fallback"] == 0
-    prompts = _mixed_prompts(np.random.default_rng(21))
-    ref = _run_wave(e0, prompts, n=24)
-    e2 = _engine(cfg, params, packed=True, prefill_packed_fuse="split")
+    waves = [_mixed_prompts(np.random.default_rng(seed))
+             for seed in (8, 21)]
+    waves.append(
+        [np.random.default_rng(2).integers(1, 120, size=80).tolist()])
+    ref = [_run_wave(e0, prompts, n=24) for prompts in waves]
+    e2 = _engine(cfg, params, packed=True)
     try:
-        got = _run_wave(e2, prompts, n=24)
+        got = [_run_wave(e2, prompts, n=24) for prompts in waves]
+        assert {"packed_head", "packed"} <= {
+            k[0] for k in e2._final_fns if isinstance(k, tuple)}
         assert e2.metrics()["packed_prefill"]["kernel_fallback"] == 0
     finally:
         e2.shutdown()
@@ -615,11 +600,8 @@ def test_packed_knobs_validate():
     assert any("prefill_packed" in p for p in bad.validate())
     bad2 = ModelConfig(name="m", options=["prefill_token_budget=-1"])
     assert any("prefill_token_budget" in p for p in bad2.validate())
-    ok2 = ModelConfig(name="m", options=["prefill_packed_fuse=split",
-                                         "comm_overlap=auto"])
+    ok2 = ModelConfig(name="m", options=["comm_overlap=auto"])
     assert ok2.validate() == []
-    bad3 = ModelConfig(name="m", options=["prefill_packed_fuse=both"])
-    assert any("prefill_packed_fuse" in p for p in bad3.validate())
     bad4 = ModelConfig(name="m", options=["comm_overlap=yes"])
     assert any("comm_overlap" in p for p in bad4.validate())
 
