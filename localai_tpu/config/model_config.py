@@ -147,7 +147,7 @@ class ModelConfig:
     # per-layer collectives with compute; auto = meshed backends only,
     # bit-exact either way), or the
     # observability knobs trace=0|1 (request-lifecycle span tracer,
-    # default on), trace_ring_size=N (retained spans, default 32768) and
+    # default on), trace_ring_size=N (retained spans, default 131072) and
     # slow_request_ms=N (log a span decomposition when TTFT or e2e
     # exceeds N ms; 0 = off), or the system-observability knobs (ISSUE 8)
     # event_log=path|stderr|off (structured JSON-lines event sink for the

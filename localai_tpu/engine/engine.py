@@ -216,7 +216,7 @@ class EngineConfig:
     # metrics()["trace"], Chrome trace export via trace_events().
     # trace=0 makes every record() call a no-op on the hot path.
     trace: bool = True
-    trace_ring_size: int = 32768   # tracing.DEFAULT_RING_SIZE
+    trace_ring_size: int = 131072   # tracing.DEFAULT_RING_SIZE
     # slow-request structured log: when a finished request's TTFT or
     # end-to-end wall exceeds this many ms, log one WARNING with the
     # span decomposition. 0 disables.
@@ -480,7 +480,7 @@ class _Burst:
                  "t_ready", "pack_np", "ids_np", "lps_np", "first_ids",
                  "first_lps", "folded", "skip_slots", "ready", "err",
                  "head", "spec_mask", "spec_width", "n_out_np",
-                 "spec_greedy")
+                 "drafted_np", "spec_greedy")
 
     def __init__(self, n_steps, slots, pack, group=(), t_dispatch=0.0,
                  head=None):
@@ -492,6 +492,7 @@ class _Burst:
         self.spec_mask = None
         self.spec_width = 0
         self.n_out_np = None        # [R, S] per-round emit counts
+        self.drafted_np = None      # [R, S] bool: the row had a draft
         self.spec_greedy = None     # [S] dispatch-time greedy snapshot
         self.group = list(group)    # fused-admission slots (subset of slots)
         # early-emit split: the _PendingPrefill head this burst is
@@ -955,18 +956,25 @@ class Engine:
         self._chunk_fns: dict[int, Callable] = {}
         self._final_fns: dict[tuple, Callable] = {}
         # fused spec-tick counters (ISSUE 13): dispatches = spec ticks
-        # issued, mixed_dispatches = ticks that carried BOTH spec rounds
-        # and plain-decode rows, rounds/proposed/accepted = per-slot
-        # round totals, tokens = emitted spec tokens (accepted + bonus).
-        # by_mode (ISSUE 18) splits the per-slot totals between greedy
-        # (accept_greedy) and sampled (accept_sampled) slots; the flat
-        # keys stay the cross-mode aggregates.
+        # issued, mixed_dispatches = ticks that carried BOTH spec-masked
+        # and plain-decode slots, rounds = per-slot round totals,
+        # rows_drafted = those of them in which the drafter had a draft
+        # (ISSUE 34: the others took the plain step), proposed /
+        # accepted = draft tokens of the drafted rows, tokens = tokens
+        # the spec slots emitted (one a plain round; accepted + bonus a
+        # drafted one), rounds_verified = rounds of a tick in which the
+        # verify pass ran at all (any row drafted; per tick, not per
+        # slot). by_mode (ISSUE 18) splits the per-slot totals between
+        # greedy (accept_greedy) and sampled (accept_sampled) slots; the
+        # flat keys stay the cross-mode aggregates.
         self._spec_stats = {"dispatches": 0, "mixed_dispatches": 0,
-                            "rounds": 0, "proposed": 0, "accepted": 0,
-                            "tokens": 0,
+                            "rounds": 0, "rows_drafted": 0,
+                            "proposed": 0, "accepted": 0, "tokens": 0,
+                            "rounds_verified": 0,
                             "by_mode": {
-                                m: {"rounds": 0, "proposed": 0,
-                                    "accepted": 0, "tokens": 0}
+                                m: {"rounds": 0, "rows_drafted": 0,
+                                    "proposed": 0, "accepted": 0,
+                                    "tokens": 0}
                                 for m in ("greedy", "sampled")}}
 
         # pipelined decode state (r4 redesign): bursts chain device-side
@@ -6272,30 +6280,35 @@ class Engine:
                         dcv=None, *, n_rounds: int,
                         flags: tuple = (True, True, True)):
         """The FUSED spec tick (ISSUE 13): n_rounds speculative rounds in
-        ONE dispatch, where spec-masked slots take a D-token
-        draft-propose + target-verify round and every other active slot
-        takes a plain decode+sample step. The plain rows run the exact
-        _make_scan_step ops (engine_decode + sampling.sample, spec rows
-        masked out of the KV write and the state folds) so their stream
-        stays bit-identical to a plain burst; spec rows verify through
-        the same continued-prefill forward spec_round uses, with plain
-        rows parked at the OOB row so the scatter drops them. Replaces
-        the r3 whole-engine spec/burst alternation (_spec_turn) — mixed
-        traffic no longer starves greedy slots of speculation, and spec
-        ticks ride the same pipelined device chain as plain bursts.
+        ONE dispatch. A round routes its rows by what the drafter found
+        (ISSUE 34): a spec-masked slot WITH a draft takes a D-token
+        target-verify pass; every other active row — a non-spec slot, or
+        a spec slot whose history offered no continuation this round —
+        takes a plain decode+sample step. Each pass sits under a
+        lax.cond on "has a row", so a round without a draft costs a
+        plain step and a round of drafted rows alone costs the verify
+        pass; a skipped branch hands ck, cv, keys and mu back untouched.
+        The plain rows run the exact _make_scan_step ops (engine_decode
+        + sampling.sample, the other rows masked out of the KV write
+        and the state folds) so their stream is bit-identical to a
+        plain burst; drafted rows verify through the same
+        continued-prefill forward spec_round uses, with the other rows
+        parked at the OOB row so the scatter drops them.
 
-        Spec rows accept greedily (accept_greedy, byte-identical to
+        Drafted rows accept greedily (accept_greedy, byte-identical to
         plain greedy) when the slot is greedy, and via rejection
         sampling against the filtered verify distribution
         (accept_sampled + sampling.verify_dist, ISSUE 18 —
         distribution-identical to plain sampling) when temperature > 0;
-        both modes share ONE compiled body so the precompile ladder and
-        the COMPILES_AFTER_WARMUP=0 gate are untouched.
+        an undrafted sampled row draws from the plain sampler, which IS
+        that law. Both modes share ONE compiled body so the precompile
+        ladder and the COMPILES_AFTER_WARMUP=0 gate are untouched.
 
-        Pack layout [2*R*W + R + 1, S] f32: ids (R*W rows, round-major),
-        logprobs (R*W), per-round emit counts (R), mu — where W =
-        n_draft + 1 tokens per spec round (accepted prefix + bonus) and
-        plain rows emit exactly 1 at position 0 of their round."""
+        Pack layout [2*R*W + 2*R + 1, S] f32: ids (R*W rows,
+        round-major), logprobs (R*W), per-round emit counts (R),
+        per-round "this row was drafted" (R), mu — where W = n_draft + 1
+        tokens per verified round (accepted prefix + bonus) and a plain
+        row emits exactly 1 at position 0 of its round."""
         from localai_tpu.engine import speculative
 
         sp = sampling.unpack_slot_params(slot_params)
@@ -6316,76 +6329,102 @@ class Engine:
              mu) = carry
             with jax.named_scope("spec_draft"):
                 if model_mode:
-                    drafts, dck, dcv = speculative.draft_propose(
+                    drafts, has, dck, dcv = speculative.draft_propose(
                         dparams, self.draft_cfg, tokens, lengths, dck, dcv,
                         spec_active, D)
                 else:
-                    drafts = speculative.ngram_propose(
+                    drafts, has = speculative.ngram_propose(
                         tokens, ring, ring_pos, D, self.ecfg.spec_ngram)
-            # plain decode step for the non-spec rows (bit-identical ops
-            # to _make_scan_step; spec rows masked out of the KV write)
-            logits, ck, cv = self.family.engine_decode(
-                params, self.cfg, tokens, lengths, plain_active, ck, cv,
-                pos_offset=pos_offset)
-            ids0, lps0, new_keys, new_mu = sampling.sample(
-                logits, sp, ring, ring_pos, bias, keys, mu,
-                use_penalties=flags[0], use_typical=flags[1],
-                use_mirostat=flags[2])
-            keys = jnp.where(plain_active[:, None], new_keys, keys)
-            mu = jnp.where(plain_active, new_mu, mu)
-            # verify forward for the spec rows: current token + D
-            # proposals scored in one continued prefill; plain rows park
-            # at the OOB start so their writes drop (their single KV
-            # write stays the decode step's above)
-            with jax.named_scope("spec_verify"):
-                tin = jnp.concatenate([tokens[:, None], drafts], axis=1)
-                seq = jnp.full((S,), W, jnp.int32)
-                start = jnp.where(spec_active, lengths, C)
-                all_logits, ck, cv = self.family.prefill(
-                    params, self.cfg, tin, seq, ck, cv, slot_ids, start,
-                    continued=True, return_all_logits=True)
-                # filtered verify distribution via the sampler's own code
-                # path (sampling.filter_window under verify_dist): idx[:,:,0]
-                # is approx_max_k's retained global argmax with the same
-                # tie-breaks as sampling.sample's greedy path, so the greedy
-                # spec stream matches plain greedy bit-for-bit — and the
-                # window probs ARE the law plain sampling draws from, so
-                # rejection acceptance against them is distribution-lossless
-                vidx, vprobs = sampling.verify_dist(all_logits, sp,
-                                                    use_typical=flags[1])
-                greedy = vidx[:, :, 0]
-                out_spec, n_spec, _k = speculative.accept_greedy(
-                    drafts, greedy, spec_active)
-                logp = jax.nn.log_softmax(all_logits, axis=-1)
-                lp_spec = jnp.take_along_axis(
-                    logp, out_spec[:, :, None], axis=2)[:, :, 0]
-                # ISSUE 18: sampled spec rows accept via rejection sampling.
-                # Scatter the window distribution to vocab for acceptance and
-                # residual resampling (n-gram/greedy-draft proposals are
-                # deterministic, so draft_probs=None one-hot degeneration)
-                samp_active = spec_active & ~jnp.asarray(sp["greedy"])
-                V = all_logits.shape[-1]
-                rows = jnp.arange(S * W, dtype=jnp.int32)[:, None]
-                tgt = jnp.zeros((S * W, V), jnp.float32).at[
-                    rows, vidx.reshape(S * W, -1)].set(
-                    vprobs.reshape(S * W, -1)).reshape(S, W, V)
-                out_ss, n_ss, _ks, keys_ss = speculative.accept_sampled(
-                    drafts, tgt, None, keys, samp_active)
-                lp_ss = jnp.log(jnp.clip(jnp.take_along_axis(
-                    tgt, out_ss[:, :, None], axis=2)[:, :, 0], 1e-20))
-                out_spec = jnp.where(samp_active[:, None], out_ss, out_spec)
-                n_spec = jnp.where(samp_active, n_ss, n_spec)
-                lp_spec = jnp.where(samp_active[:, None], lp_ss, lp_spec)
-                keys = jnp.where(samp_active[:, None], keys_ss, keys)
+            drafted = spec_active & has
+            plain_rows = plain_active | (spec_active & ~has)
+
+            def plain_step(ck, cv, keys, mu):
+                # bit-identical ops to _make_scan_step; drafted rows are
+                # masked out of the KV write and the state folds
+                logits, ck, cv = self.family.engine_decode(
+                    params, self.cfg, tokens, lengths, plain_rows, ck, cv,
+                    pos_offset=pos_offset)
+                ids0, lps0, new_keys, new_mu = sampling.sample(
+                    logits, sp, ring, ring_pos, bias, keys, mu,
+                    use_penalties=flags[0], use_typical=flags[1],
+                    use_mirostat=flags[2])
+                keys = jnp.where(plain_rows[:, None], new_keys, keys)
+                mu = jnp.where(plain_rows, new_mu, mu)
+                return ids0, lps0, ck, cv, keys, mu
+
+            def no_plain_row(ck, cv, keys, mu):
+                return (jnp.zeros((S,), jnp.int32),
+                        jnp.zeros((S,), jnp.float32), ck, cv, keys, mu)
+
+            ids0, lps0, ck, cv, keys, mu = jax.lax.cond(
+                plain_rows.any(), plain_step, no_plain_row, ck, cv, keys, mu)
+
+            def verify(ck, cv, keys):
+                # current token + D proposals scored in one continued
+                # prefill; undrafted rows park at the OOB start so their
+                # writes drop (their single KV write is the decode
+                # step's above)
+                with jax.named_scope("spec_verify"):
+                    tin = jnp.concatenate([tokens[:, None], drafts], axis=1)
+                    seq = jnp.full((S,), W, jnp.int32)
+                    start = jnp.where(drafted, lengths, C)
+                    all_logits, ck, cv = self.family.prefill(
+                        params, self.cfg, tin, seq, ck, cv, slot_ids, start,
+                        continued=True, return_all_logits=True)
+                    # filtered verify distribution via the sampler's own
+                    # code path (sampling.filter_window under
+                    # verify_dist): idx[:,:,0] is approx_max_k's retained
+                    # global argmax with the same tie-breaks as
+                    # sampling.sample's greedy path, so the greedy spec
+                    # stream matches plain greedy bit-for-bit — and the
+                    # window probs ARE the law plain sampling draws from,
+                    # so rejection acceptance against them is
+                    # distribution-lossless
+                    vidx, vprobs = sampling.verify_dist(
+                        all_logits, sp, use_typical=flags[1])
+                    greedy = vidx[:, :, 0]
+                    out_spec, n_spec, _k = speculative.accept_greedy(
+                        drafts, greedy, drafted)
+                    logp = jax.nn.log_softmax(all_logits, axis=-1)
+                    lp_spec = jnp.take_along_axis(
+                        logp, out_spec[:, :, None], axis=2)[:, :, 0]
+                    # ISSUE 18: sampled spec rows accept via rejection
+                    # sampling. Scatter the window distribution to vocab
+                    # for acceptance and residual resampling
+                    # (n-gram/greedy-draft proposals are deterministic,
+                    # so draft_probs=None one-hot degeneration)
+                    samp = drafted & ~jnp.asarray(sp["greedy"])
+                    V = all_logits.shape[-1]
+                    rows = jnp.arange(S * W, dtype=jnp.int32)[:, None]
+                    tgt = jnp.zeros((S * W, V), jnp.float32).at[
+                        rows, vidx.reshape(S * W, -1)].set(
+                        vprobs.reshape(S * W, -1)).reshape(S, W, V)
+                    out_ss, n_ss, _ks, keys_ss = speculative.accept_sampled(
+                        drafts, tgt, None, keys, samp)
+                    lp_ss = jnp.log(jnp.clip(jnp.take_along_axis(
+                        tgt, out_ss[:, :, None], axis=2)[:, :, 0], 1e-20))
+                    out_spec = jnp.where(samp[:, None], out_ss, out_spec)
+                    n_spec = jnp.where(samp, n_ss, n_spec)
+                    lp_spec = jnp.where(samp[:, None], lp_ss, lp_spec)
+                    keys = jnp.where(samp[:, None], keys_ss, keys)
+                return out_spec, lp_spec, n_spec, ck, cv, keys
+
+            def no_draft(ck, cv, keys):
+                return (jnp.zeros((S, W), jnp.int32),
+                        jnp.zeros((S, W), jnp.float32),
+                        jnp.zeros((S,), jnp.int32), ck, cv, keys)
+
+            out_spec, lp_spec, n_spec, ck, cv, keys = jax.lax.cond(
+                drafted.any(), verify, no_draft, ck, cv, keys)
             pad = jnp.zeros((S, D), jnp.int32)
-            out = jnp.where(spec_mask[:, None], out_spec,
+            out = jnp.where(drafted[:, None], out_spec,
                             jnp.concatenate([ids0[:, None], pad], axis=1))
-            lps = jnp.where(spec_mask[:, None], lp_spec,
+            lps = jnp.where(drafted[:, None], lp_spec,
                             jnp.concatenate(
                                 [lps0[:, None], pad.astype(jnp.float32)],
                                 axis=1))
-            n_out = jnp.where(spec_active, n_spec,
-                              plain_active.astype(jnp.int32))
+            n_out = jnp.where(drafted, n_spec,
+                              plain_rows.astype(jnp.int32))
             for j in range(W):   # W is static: unrolled ring pushes
                 ring, ring_pos = sampling.update_ring(
                     ring, ring_pos, out[:, j], active & (j < n_out))
@@ -6394,11 +6433,11 @@ class Engine:
                 out, jnp.maximum(n_out - 1, 0)[:, None], axis=1)[:, 0]
             tokens = jnp.where(active, last, tokens)
             return ((tokens, ck, cv, dck, dcv, lengths, ring, ring_pos,
-                     keys, mu), (out.T, lps.T, n_out))
+                     keys, mu), (out.T, lps.T, n_out, drafted))
 
         carry = (tokens, ck, cv, dck, dcv, lengths, ring, ring_pos, keys,
                  mu)
-        carry, (ids_all, lps_all, n_all) = jax.lax.scan(
+        carry, (ids_all, lps_all, n_all, drafted_all) = jax.lax.scan(
             round_step, carry, None, length=n_rounds)
         (tokens, ck, cv, dck, dcv, lengths, ring, ring_pos, keys,
          mu) = carry
@@ -6406,7 +6445,8 @@ class Engine:
         pack = jnp.concatenate(
             [ids_all.reshape(R * W, S).astype(jnp.float32),
              lps_all.reshape(R * W, S),
-             n_all.astype(jnp.float32), mu[None, :]], axis=0)
+             n_all.astype(jnp.float32), drafted_all.astype(jnp.float32),
+             mu[None, :]], axis=0)
         chain = self._pin_chain(tokens, lengths, ring, ring_pos, mu)
         if model_mode:
             return pack, ck, cv, keys, chain, dck, dcv
@@ -6436,8 +6476,9 @@ class Engine:
         past the steps already in flight; everyone else in ``included``
         rides the same tick as a plain-decode row. Round count follows
         _pick_burst's sizing discipline with spec slots charged W rows
-        and W tokens of budget per round, floored to a power of two so
-        only the precompiled ladder ever runs."""
+        and W tokens of budget per round (the device decides which
+        rounds draft, ISSUE 34), floored to a power of two so only the
+        precompiled ladder ever runs."""
         if self._spec_mode == "off" or self.ecfg.ga_n > 1:
             # spec rounds advance positions row=position; they are not
             # self-extend-aware — mutually exclusive features
@@ -6502,6 +6543,24 @@ class Engine:
             -(-(int(self.lengths[i]) + infl[i]) // pg) for i in rows)
         self._kv_walk["pages_grid"] += (
             n_steps * self.ecfg.num_slots * self._pool.max_pages)
+
+    def _count_spec_kv_walk(self, b: "_Burst", live_idx):
+        """kv_walk for a folded spec tick: the decode step ran in the
+        rounds in which some row was not drafted, for those rows, each
+        at the length its round began with (the mirrors still hold the
+        lengths the tick started from)."""
+        if not self._paged:
+            return
+        pg = self._pool.page_size
+        plain = (b.n_out_np > 0) & ~b.drafted_np            # [R, S]
+        self._kv_walk["pages_grid"] += (
+            int(plain.any(axis=1).sum()) * self.ecfg.num_slots
+            * self._pool.max_pages)
+        for i in live_idx:
+            before = int(self.lengths[i]) + np.cumsum(b.n_out_np[:, i]) \
+                - b.n_out_np[:, i]
+            self._kv_walk["pages_live"] += int(
+                (-(-before // pg))[plain[:, i]].sum())
 
     def _dispatch_decode(self) -> bool:
         """Dispatch the next decode burst — or, when spec-eligible slots
@@ -6592,9 +6651,10 @@ class Engine:
                            chain=chain if cold else None,
                            spp=spp, active=active, ovp=ovp)
         self._tick_decode_tokens += n_steps * len(included)
-        # a spec row attends in the verify pass, not in the decode step
-        self._count_kv_walk(n_steps, infl, [
-            i for i in included if spec_mask is None or not spec_mask[i]])
+        if plan is None:
+            # a spec tick's device decides, round by round, which rows
+            # take the decode step: _fold_burst counts those from the pack
+            self._count_kv_walk(n_steps, infl, included)
         with self._annot(
                 "decode_burst", steps=n_steps, slots=len(included),
                 **({"spec_slots": int(spec_mask.sum()), "spec_width": W}
@@ -6652,12 +6712,13 @@ class Engine:
         K = b.n_steps
         if b.spec_width:
             # spec tick pack: ids/lps are [R*W, S] round-major, then the
-            # [R, S] per-round emit counts, then mu
+            # [R, S] per-round emit counts and drafted bits, then mu
             KW = K * b.spec_width
             b.ids_np = packed[:KW].astype(np.int32)
             b.lps_np = packed[KW:2 * KW]
             b.n_out_np = packed[2 * KW:2 * KW + K].astype(np.int32)
-            mu_np = packed[2 * KW + K]
+            b.drafted_np = packed[2 * KW + K:2 * KW + 2 * K] > 0
+            mu_np = packed[2 * KW + 2 * K]
         else:
             b.ids_np = packed[:K].astype(np.int32)
             b.lps_np = packed[K:2 * K]
@@ -6689,11 +6750,14 @@ class Engine:
             self.mu[i] = mu_np[i]
         if b.spec_width:
             # fused spec tick: per-slot VARIABLE advance — each round
-            # emitted n_out tokens (spec rows: accepted prefix + bonus;
-            # plain rows: exactly 1 at position 0); the mirrors must
-            # replay the device's ring/length evolution token-by-token
+            # emitted n_out tokens (drafted rows: accepted prefix +
+            # bonus; every other row: exactly 1 at position 0); the
+            # mirrors must replay the device's ring/length evolution
+            # token-by-token
             Wd = b.spec_width
             st = self._spec_stats
+            st["rounds_verified"] += int(b.drafted_np.any(axis=1).sum())
+            self._count_spec_kv_walk(b, live_idx)
             for i in live_idx:
                 ns = b.n_out_np[:, i]
                 tot = int(ns.sum())
@@ -6710,20 +6774,21 @@ class Engine:
                         rp += 1
                 self.ring_pos[i] = rp
                 if b.spec_mask[i]:
-                    st["rounds"] += K
-                    st["proposed"] += K * (Wd - 1)
-                    st["accepted"] += tot - K
-                    st["tokens"] += tot
+                    # a drafted round emits its accepted prefix and the
+                    # bonus, any other round the plain step's one token
+                    nd = int(b.drafted_np[:, i].sum())
                     # ISSUE 18 per-mode split (greedy accept_greedy vs
                     # sampled rejection acceptance), attributed from the
                     # dispatch-time greedy snapshot
                     mode = ("greedy" if b.spec_greedy is None
                             or b.spec_greedy[i] else "sampled")
-                    bm = st["by_mode"][mode]
-                    bm["rounds"] += K
-                    bm["proposed"] += K * (Wd - 1)
-                    bm["accepted"] += tot - K
-                    bm["tokens"] += tot
+                    for c in (st, st["by_mode"][mode]):
+                        c["rounds"] += K
+                        c["rows_drafted"] += nd
+                        c["proposed"] += nd * (Wd - 1)
+                        c["accepted"] += int(
+                            ns[b.drafted_np[:, i]].sum()) - nd
+                        c["tokens"] += tot
             b.folded = True
             return
         for i in live_idx:
@@ -6800,18 +6865,20 @@ class Engine:
                     # the fused program has no host-visible boundary
                     # between drafting and verifying: on the device they
                     # are the named scopes spec_draft / spec_verify
-                    nsp = b.n_out_np
+                    nsp, dr = b.n_out_np, b.drafted_np
                     spec_idx = [i for i, _s in b.slots if b.spec_mask[i]]
-                    tot = int(sum(int(nsp[:, i].sum()) for i in spec_idx))
+                    rows_drafted = int(dr.sum())
                     tr.record("spec_round", "engine", b.t_dispatch, t_rdy,
                               args={"mode": self._spec_mode,
                                     "rounds": b.n_steps,
+                                    "rounds_verified": int(
+                                        dr.any(axis=1).sum()),
+                                    "rows_drafted": rows_drafted,
                                     "spec_slots": len(spec_idx),
-                                    "proposed": b.n_steps
-                                    * (b.spec_width - 1) * len(spec_idx),
-                                    "accepted": max(
-                                        0, tot - b.n_steps
-                                        * len(spec_idx))})
+                                    "proposed": rows_drafted
+                                    * (b.spec_width - 1),
+                                    "accepted": int(
+                                        nsp[dr].sum()) - rows_drafted})
                 tr.record("finish_detect", "engine", t_rdy, t_proc)
         rolled: set = set()   # grammar slots rolled back mid-burst
         try:

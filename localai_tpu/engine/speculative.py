@@ -21,8 +21,8 @@ Two drafters (engine knob ``draft``):
     history (the device-side penalty ring), and the continuation after
     the most recent match is proposed. No second model, no draft KV, so
     every llama-family greedy request can speculate by default. A miss
-    proposes a repeat of the current token — verification rejects it,
-    so the fallback costs nothing but the round's spare compute.
+    says so (``has`` false): the row has no draft, and the engine's
+    round gives it the plain decode step instead of a verify pass.
 
 Cache invariant (target and draft models alike): rows [0, length) hold
 the accepted context, and the CURRENT token (last emitted) is not yet
@@ -30,10 +30,12 @@ ingested; a round ingests it as its first input. Rows written for
 rejected proposals sit above the new length and are masked/overwritten.
 
 Since ISSUE 13 speculation is a packed citizen of the engine's fused
-decode tick (engine.py _spec_tick_body): spec-eligible slots take a
-propose+verify round while non-spec neighbors take a plain decode step
-through position 0 of the very same ragged verify forward — one chained
-dispatch, no whole-engine spec/burst alternation.
+decode tick (engine.py _spec_tick_body): spec-eligible slots whose
+drafter has a draft take a propose+verify round while every other row
+(non-spec neighbors, and spec rows in a round without a draft) takes a
+plain decode step — one chained dispatch, no whole-engine spec/burst
+alternation, and each of the round's two forward passes runs only if
+it has a row.
 
 Since ISSUE 18 sampled (temperature>0) slots speculate too, via
 rejection-sampling acceptance (accept_sampled, leviathan-style): draft
@@ -68,15 +70,17 @@ def ngram_propose(tokens, ring, ring_pos, n_draft: int, ngram: int):
     [S, RING_N] / ring_pos [S]: the penalty ring (engine/sampling.py) —
     prompt-seeded at admission and updated with every emitted token, so
     it IS the trailing prompt+generation history, already device-side.
-    Returns proposals [S, D] int32.
+    Returns (proposals [S, D] int32, has [S] bool): ``has`` is whether
+    the slot's history offered a continuation at all.
 
     The trailing ``ngram``-gram (current token last) is compared against
     every aligned window of the chronological history; the continuation
     after the MOST RECENT match is proposed, clipped at the history end
     (self-overlap is deliberate — repetitive continuations are exactly
     what prompt-lookup exploits). No valid match (including short
-    histories still holding -1 seed entries) proposes a repeat of the
-    current token, which the verify round rejects — lossless either way.
+    histories still holding -1 seed entries) is ``has`` false; the
+    proposal row is then a repeat of the current token, a filler no
+    verify pass should be spent on.
     """
     S, N = ring.shape
     D, G = n_draft, ngram
@@ -99,7 +103,7 @@ def ngram_propose(tokens, ring, ring_pos, n_draft: int, ngram: int):
         p_best[:, None] + G + jnp.arange(D, dtype=jnp.int32)[None, :], N - 1)
     props = jnp.take_along_axis(hist, cont, axis=1)              # [S, D]
     return jnp.where(has[:, None], props,
-                     jnp.asarray(tokens)[:, None]).astype(jnp.int32)
+                     jnp.asarray(tokens)[:, None]).astype(jnp.int32), has
 
 
 def draft_propose(dparams, dcfg: llama.LlamaConfig, tokens, lengths,
@@ -111,7 +115,8 @@ def draft_propose(dparams, dcfg: llama.LlamaConfig, tokens, lengths,
     draft cache carries a permanent hole inside the accepted context and
     acceptance quality decays). Inactive slots write at the OOB row so
     the scatter drops (contiguous and paged layouts alike).
-    Returns (drafts [S, D], dck, dcv).
+    Returns (drafts [S, D], has [S], dck, dcv); a draft model always
+    proposes, so ``has`` is all true.
     """
     from localai_tpu.ops import kvcache
 
@@ -126,7 +131,7 @@ def draft_propose(dparams, dcfg: llama.LlamaConfig, tokens, lengths,
 
     (_, _, dck, dcv), proposals = jax.lax.scan(
         dstep, (tokens, lengths, dck, dcv), None, length=n_draft + 1)
-    return proposals[:n_draft].T, dck, dcv
+    return proposals[:n_draft].T, jnp.ones_like(active), dck, dcv
 
 
 def accept_greedy(drafts, greedy, active):
@@ -274,8 +279,8 @@ def spec_round(params, dparams, cfg: llama.LlamaConfig, dcfg: llama.LlamaConfig,
     C = kvcache.shape(ck)[2]
 
     # 1. drafter proposes D tokens
-    drafts, dck, dcv = draft_propose(dparams, dcfg, tokens, lengths,
-                                     dck, dcv, active, D)
+    drafts, _has, dck, dcv = draft_propose(dparams, dcfg, tokens, lengths,
+                                           dck, dcv, active, D)
 
     # 2. target scores current + proposals in one forward
     tin = jnp.concatenate([tokens[:, None], drafts], axis=1)   # [S, D+1]

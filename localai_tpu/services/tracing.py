@@ -72,10 +72,12 @@ EMITTER_SPANS = frozenset({
 # finish-detection latency called out in the r5 verdict.
 FINISH_DETECT_SPAN = "finish_detect"
 
-# 60 s of a saturated 16-slot engine with a factor of two to spare, from
-# the span rate measured on the chip (PERF.md section 6, PR 25); a slot
-# costs one tuple, a few short strings and a small dict: about 0.3 KB.
-DEFAULT_RING_SIZE = 32768
+# 60 s of a 16-slot engine at its highest tick rate with a factor of
+# two to spare, from the span rate measured on the chip: 1,200 spans a
+# second at 90 speculative ticks a second (PERF.md section 6, PR 34; the
+# 32768 of PR 25 held 27 s of that); a slot costs one tuple, a few short
+# strings and a small dict: about 0.3 KB.
+DEFAULT_RING_SIZE = 131072
 
 class _NullSpan:
     """What ``span()`` returns with tracing off: one shared object, so the

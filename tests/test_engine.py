@@ -279,7 +279,7 @@ def test_concurrent_admission_does_not_corrupt_chunked_prefill(byte_tokenizer):
                              params=sampling.SamplingParamsHost(temperature=0.0),
                              max_new_tokens=6, ignore_eos=True)
         _, ev_idle = e.generate_text(req)
-        toks_idle = [x.token_id for x in ev_idle]
+        toks_idle = eng.event_ids(ev_idle)   # an event coalesces a tick
     finally:
         e.shutdown()
 
@@ -294,7 +294,7 @@ def test_concurrent_admission_does_not_corrupt_chunked_prefill(byte_tokenizer):
                              params=sampling.SamplingParamsHost(temperature=0.0),
                              max_new_tokens=6, ignore_eos=True)
         _, ev_busy = e.generate_text(req)
-        toks_busy = [x.token_id for x in ev_busy]
+        toks_busy = eng.event_ids(ev_busy)
         e.cancel(a.request_id)
     finally:
         e.shutdown()

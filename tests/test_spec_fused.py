@@ -2,10 +2,14 @@
 prompt-lookup self-drafting, and the paged draft KV riding the existing
 page lifecycle.
 
-Four layers of coverage:
+Five layers of coverage:
 
 * `ngram_propose` units — match / most-recent-match / no-match /
-  short-history / history-end clipping / ring-rotation invariance;
+  short-history / history-end clipping / ring-rotation invariance,
+  each with the `has` it returns beside the proposals;
+* the routing of a round (ISSUE 34) — a row without a draft takes the
+  plain step, the verify pass runs only where a draft exists, mixed
+  rows run both, and the two `cond`s stay in the round loop;
 * fused mixed tick — a greedy (speculating) and a sampled (plain) slot
   decode through ONE chained dispatch per tick, byte-identical to the
   spec-off engine, with the dispatch-count assertion
@@ -46,48 +50,51 @@ from .conftest import ByteTokenizer
 
 
 def _props(rows, tokens, ring_pos=None, n_draft=4, ngram=3):
+    """(proposals [S, D], has [S]) as lists."""
     ring = jnp.asarray(np.asarray(rows, np.int32))
     S = ring.shape[0]
     rp = (jnp.zeros((S,), jnp.int32) if ring_pos is None
           else jnp.asarray(np.asarray(ring_pos, np.int32)))
-    out = ngram_propose(jnp.asarray(np.asarray(tokens, np.int32)),
-                        ring, rp, n_draft, ngram)
-    return np.asarray(out)
+    props, has = ngram_propose(jnp.asarray(np.asarray(tokens, np.int32)),
+                               ring, rp, n_draft, ngram)
+    assert has.shape == (S,) and has.dtype == jnp.bool_
+    return np.asarray(props).tolist(), np.asarray(has).tolist()
 
 
 def test_ngram_match_proposes_continuation():
     # period-4 repetition: trailing gram [6,7,8] recurs, and the
     # continuation after the most recent match is the next period
     hist = [5, 6, 7, 8] * 4
-    assert _props([hist], [8]).tolist() == [[5, 6, 7, 8]]
+    assert _props([hist], [8]) == ([[5, 6, 7, 8]], [True])
 
 
 def test_ngram_most_recent_match_wins():
     # [1,2,3] occurs at chronological starts 0 and 8 with DIFFERENT
     # continuations; prompt-lookup proposes the most recent one's
     hist = [1, 2, 3, 9, 0, 0, 0, 0, 1, 2, 3, 7, 0, 1, 2, 3]
-    assert _props([hist], [3]).tolist() == [[7, 0, 1, 2]]
+    assert _props([hist], [3]) == ([[7, 0, 1, 2]], [True])
 
 
-def test_ngram_no_match_repeats_current():
+def test_ngram_no_match_has_no_draft():
     # strictly increasing history: the trailing gram never recurs, so
-    # the drafter falls back to repeating the current token (which the
-    # verify round rejects — lossless, just a wasted round)
+    # the drafter says it has nothing (the proposal row is a filler,
+    # the current token repeated) and the engine's round gives the row
+    # the plain step
     hist = list(range(16))
-    assert _props([hist], [15]).tolist() == [[15, 15, 15, 15]]
+    assert _props([hist], [15]) == ([[15, 15, 15, 15]], [False])
 
 
-def test_ngram_short_history_repeats_current():
+def test_ngram_short_history_has_no_draft():
     # -1 ring seeds still inside the trailing gram: no valid match
     hist = [-1] * 14 + [7, 9]
-    assert _props([hist], [9]).tolist() == [[9, 9, 9, 9]]
+    assert _props([hist], [9]) == ([[9, 9, 9, 9]], [False])
 
 
 def test_ngram_continuation_clips_at_history_end():
     # match near the end of history: the proposal is clipped at the
     # newest entry instead of reading past it
     hist = [0] * 10 + [1, 2, 3, 1, 2, 3]
-    assert _props([hist], [3]).tolist() == [[1, 2, 3, 3]]
+    assert _props([hist], [3]) == ([[1, 2, 3, 3]], [True])
 
 
 def test_ngram_ring_rotation_invariant():
@@ -95,16 +102,16 @@ def test_ngram_ring_rotation_invariant():
     # proposals must depend only on the chronological view
     hist = np.asarray([5, 6, 7, 8] * 4, np.int32)
     for p in (3, 7, 15):
-        out = _props([np.roll(hist, p)], [8], ring_pos=[p])
-        assert out.tolist() == [[5, 6, 7, 8]]
+        assert _props([np.roll(hist, p)], [8], ring_pos=[p]) == \
+            ([[5, 6, 7, 8]], [True])
 
 
 def test_ngram_batch_rows_independent():
     # one batched call, three regimes — per-slot masking means one
     # row's miss never perturbs its neighbors
     rows = [[5, 6, 7, 8] * 4, list(range(16)), [-1] * 14 + [7, 9]]
-    out = _props(rows, [8, 15, 9])
-    assert out.tolist() == [[5, 6, 7, 8], [15] * 4, [9] * 4]
+    assert _props(rows, [8, 15, 9]) == (
+        [[5, 6, 7, 8], [15] * 4, [9] * 4], [True, False, False])
 
 
 # ---------- fused mixed tick ----------
@@ -227,6 +234,174 @@ def test_ngram_self_speculation_needs_no_draft_model():
         assert e._spec_stats["rounds"] > 0
     finally:
         e.shutdown()
+
+
+# ---------- a round does only the work its rows need (ISSUE 34) ----------
+
+
+def _no_repeated_gram(history, n=3) -> bool:
+    grams = [tuple(history[i:i + n]) for i in range(len(history) - n + 1)]
+    return len(set(grams)) == len(grams)
+
+
+def _random_prompt(seed: int, n: int = 20) -> list:
+    return [int(x) for x in np.random.default_rng(seed).integers(1, 250, n)]
+
+
+def _greedy_ids(e, prompt_ids, n, **req_kw):
+    _, evs = e.generate_text(eng.GenRequest(
+        prompt_ids=list(prompt_ids),
+        params=sampling.SamplingParamsHost(temperature=0.0),
+        max_new_tokens=n, **{"ignore_eos": True, **req_kw}))
+    return eng.event_ids(evs)
+
+
+@pytest.fixture(scope="module")
+def f32_params():
+    return llama.init_params(_cfg(), jax.random.PRNGKey(0), dtype=jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def spec_off_ids(f32_params):
+    """Greedy ids of the `draft: 0` engine, one request at a time."""
+    e = _engine(f32_params, draft_mode="0", decode_burst=4)
+    memo = {}
+
+    def ids(prompt_ids, n, **req_kw):
+        key = (tuple(prompt_ids), n, tuple(sorted(req_kw)))
+        if key not in memo:
+            memo[key] = _greedy_ids(e, prompt_ids, n, **req_kw)
+        return memo[key]
+
+    yield ids
+    e.shutdown()
+
+
+REPETITIVE = ByteTokenizer().encode("abab abab ab")
+
+
+def test_round_without_a_draft_is_a_plain_step(f32_params, spec_off_ids):
+    """(a) Unshared random prompts whose history never repeats a 3-gram:
+    the drafter has nothing in any round, so no verify pass runs, nothing
+    is proposed, every round emits one token a row from the decode step a
+    plain burst runs, and the ids are the `draft: 0` engine's."""
+    prompts = [_random_prompt(1), _random_prompt(2)]
+    refs = [spec_off_ids(p, 24) for p in prompts]
+    for p, r in zip(prompts, refs):
+        assert _no_repeated_gram(p + r), "pick a prompt that does not loop"
+    e = _engine(f32_params, draft_mode="ngram", decode_burst=4)
+    try:
+        outs = [e.submit(eng.GenRequest(
+            prompt_ids=p, params=sampling.SamplingParamsHost(temperature=0.0),
+            max_new_tokens=24, ignore_eos=True)) for p in prompts]
+        got = [eng.event_ids(_collect(o)) for o in outs]
+        assert got == refs
+        sp = e.state_snapshot()["spec"]
+        assert sp["dispatches"] > 0 and sp["rounds"] > 0   # spec ticks ran
+        assert sp["rounds_verified"] == 0 and sp["rows_drafted"] == 0
+        assert sp["proposed"] == 0 and sp["accepted"] == 0
+        assert sp["tokens"] == sp["rounds"]        # one token a row a round
+        m = e.metrics()["spec"]
+        assert m["rounds_verified"] == 0 and m["acceptance_rate"] == 0.0
+        assert m["accept_per_dispatch"] == 1.0
+    finally:
+        e.shutdown()
+
+
+def test_speculation_is_alive_where_a_draft_exists(f32_params, spec_off_ids):
+    """(b) A prompt that repeats a block: drafts exist, the verify pass
+    runs and accepts, a round yields more than one token, and the ids
+    are still the `draft: 0` engine's."""
+    ref = spec_off_ids(REPETITIVE, 32)
+    e = _engine(f32_params, draft_mode="ngram", decode_burst=4)
+    try:
+        assert _greedy_ids(e, REPETITIVE, 32) == ref
+        sp = e.metrics()["spec"]
+        assert sp["rows_drafted"] > 0 and sp["rounds_verified"] > 0
+        assert sp["accepted"] > 0
+        assert sp["proposed"] == sp["rows_drafted"] * e.ecfg.n_draft
+        assert sp["tokens"] / sp["rounds"] > 1.0
+        assert sp["acceptance_rate"] == sp["accepted"] / sp["proposed"]
+    finally:
+        e.shutdown()
+
+
+def test_round_runs_both_passes_for_mixed_rows(f32_params, spec_off_ids):
+    """(c) One tick carries a drafted greedy row, an undrafted greedy row
+    and a grammared row (never a spec row): the verify pass and the plain
+    step both run in one round, and each row's ids are its solo run's."""
+    from localai_tpu.functions.grammars import json_schema
+
+    grammar = json_schema.schema_to_grammar(
+        {"type": "object", "properties": {"city": {"enum": ["sf", "nyc"]}},
+         "required": ["city"]})
+    call = ByteTokenizer().encode("call: call: call:")
+    unshared = _random_prompt(3)
+    refs = [spec_off_ids(REPETITIVE, 32), spec_off_ids(unshared, 32),
+            spec_off_ids(call, 32, grammar=grammar, ignore_eos=False)]
+    assert _no_repeated_gram(unshared + refs[1])
+    e = eng.Engine(
+        _cfg(), f32_params, ByteTokenizer(),
+        eng.EngineConfig(num_slots=3, max_context=128,
+                         prefill_buckets=(16, 32), prefill_chunk=32,
+                         cache_dtype=jnp.float32, draft="ngram",
+                         decode_burst=4, trace=True))    # ticks of one round
+    e.start()
+    try:
+        greedy = sampling.SamplingParamsHost(temperature=0.0)
+        outs = [e.submit(eng.GenRequest(prompt_ids=list(p), params=greedy,
+                                        max_new_tokens=32, **kw))
+                for p, kw in ((REPETITIVE, {"ignore_eos": True}),
+                              (unshared, {"ignore_eos": True}),
+                              (call, {"grammar": grammar}))]
+        got = [eng.event_ids(_collect(o)) for o in outs]
+        assert got == refs
+        sp = e.state_snapshot()["spec"]
+        assert sp["mixed_dispatches"] > 0
+        assert 0 < sp["rows_drafted"] < sp["rounds"]
+        spans = [s["args"] for s in e.tracer.spans()
+                 if s["name"] == "spec_round"]
+        assert sum(a["rounds_verified"] for a in spans) == \
+            sp["rounds_verified"]
+        # one round in which a spec row verified and another spec row
+        # took the plain step beside the grammared one
+        assert any(a["rounds"] == 1
+                   and 0 < a["rows_drafted"] < a["spec_slots"]
+                   for a in spans)
+    finally:
+        e.shutdown()
+
+
+def test_spec_tick_keeps_its_two_conditionals_in_the_round_loop(f32_params):
+    """(d) The traced `jit_spec_tick`: the round scan's body holds two
+    `cond`s, the plain step and the verify pass. Folding either back
+    into `where`s makes every round pay both forward passes again."""
+    e = _engine(f32_params, draft_mode="ngram", decode_burst=4)
+    try:
+        S = e.ecfg.num_slots
+        c_tok, c_len, c_ring, c_rpos, c_mu = e._host_chain()
+        jaxpr = jax.make_jaxpr(
+            lambda *a: e._spec_tick_body(*a, n_rounds=2))(
+            e.params, c_tok, e.ck, e.cv, c_len, c_ring, c_rpos, e.bias,
+            e.rng_keys, sampling.pack_slot_params(e.slot_params),
+            e.active_dev, c_mu, e._pack_ov(np.zeros((S,), np.bool_)),
+            np.zeros((S,), np.bool_))
+    finally:
+        e.shutdown()
+    scans = [q for q in jaxpr.jaxpr.eqns if q.primitive.name == "scan"]
+    assert len(scans) == 1 and scans[0].params["length"] == 2
+    body = scans[0].params["jaxpr"].jaxpr
+    conds = [q for q in body.eqns if q.primitive.name == "cond"]
+    assert len(conds) == 2
+
+    for c in conds:
+        skip, run = sorted(c.params["branches"],
+                           key=lambda br: len(br.jaxpr.eqns))
+        # a skipped pass makes a few zeros and hands the cache back; a
+        # pass that runs is a forward over every layer
+        assert len(skip.jaxpr.eqns) < 8
+        assert any(q.primitive.name == "scan" for q in run.jaxpr.eqns)
+        assert not any(q.primitive.name == "scan" for q in skip.jaxpr.eqns)
 
 
 # ---------- spec x preemption ----------

@@ -242,6 +242,11 @@ def test_sampled_slot_joins_spec_and_splits_mode_counters():
         assert st["dispatches"] > 0
         assert bm["rounds"] > 0                  # it actually speculated
         assert bm["tokens"] >= bm["rounds"]      # >= 1 token per round
+        # ISSUE 34: only a round with a draft proposes; the others took
+        # the plain sampler and count as rounds alone
+        assert 0 <= bm["rows_drafted"] <= bm["rounds"]
+        assert bm["proposed"] == bm["rows_drafted"] * e.ecfg.n_draft
+        assert bm == {k: st[k] for k in bm}      # the one mode is the total
         assert st["by_mode"]["greedy"]["rounds"] == 0
         sp = e.metrics()["spec"]
         assert sp["by_mode"]["sampled"]["rounds"] == bm["rounds"]
@@ -253,12 +258,48 @@ def test_sampled_slot_joins_spec_and_splits_mode_counters():
         e.shutdown()
 
 
-def test_spec_sampled_chi_square_distribution_parity():
+def test_sampled_row_without_a_draft_draws_from_the_plain_sampler():
+    """ISSUE 34: a sampled spec row whose history offers no continuation
+    takes the plain decode step and the plain sampler, one categorical a
+    token on the slot's own key: while no round drafts, the stream is
+    the `draft: 0` engine's byte for byte, not only in distribution."""
+    params = llama.init_params(_cfg(), jax.random.PRNGKey(0),
+                               dtype=jnp.float32)
+    prompt = "".join(chr(33 + (7 * i) % 90) for i in range(24))
+
+    def run(draft_mode):
+        e = _engine(params, draft_mode=draft_mode, decode_burst=4)
+        try:
+            _, evs = e.generate_text(_sampled_req(prompt, seed=3, n=24))
+            return eng.event_ids(evs), e.metrics()["spec"]
+        finally:
+            e.shutdown()
+
+    on, sp = run("ngram")
+    off, _ = run("0")
+    bm = sp["by_mode"]["sampled"]
+    assert bm["rounds"] > 0 and sp["dispatches"] > 0
+    assert bm["rows_drafted"] == 0 and bm["proposed"] == 0, \
+        "pick a seed whose sampled stream repeats no 3-gram"
+    assert sp["rounds_verified"] == 0
+    assert bm["tokens"] == bm["rounds"]
+    assert on == off
+
+
+@pytest.mark.parametrize("prompt,top_k,drafted_share", [
+    (PROMPT, 16, 0.0),          # a stream that rarely repeats: plain rounds
+    ("abab abab ab", 2, 0.1),   # a stream that loops: drafts, acceptances
+], ids=["rarely_drafted", "often_drafted"])
+def test_spec_sampled_chi_square_distribution_parity(prompt, top_k,
+                                                     drafted_share):
     """THE distribution-preservation contract: over a fixed seed ladder,
     spec-on sampled token frequencies are chi-square-indistinguishable
     from plain (spec-off) sampling. Both runs are fully deterministic
     (fixed seeds), so this does not flake — it fails only if the
-    acceptance/residual math biases the law."""
+    acceptance/residual math biases the law. Since ISSUE 34 a round
+    verifies only where a draft exists, so the second case is the one
+    that exercises rejection sampling: its stream loops, a tenth of the
+    rounds or more draft, and drafts are accepted."""
     params = llama.init_params(_cfg(), jax.random.PRNGKey(0),
                                dtype=jnp.float32)
     seeds = range(10)
@@ -270,7 +311,7 @@ def test_spec_sampled_chi_square_distribution_parity():
         try:
             for s in seeds:
                 _, evs = e.generate_text(
-                    _sampled_req(PROMPT, seed=s, top_k=16))
+                    _sampled_req(prompt, seed=s, top_k=top_k))
                 ids = eng.event_ids(evs)
                 assert len(ids) == 40
                 counts += np.bincount(ids, minlength=V)[:V]
@@ -281,6 +322,10 @@ def test_spec_sampled_chi_square_distribution_parity():
     on, bm = run("ngram")
     off, _ = run("0")
     assert bm["rounds"] > 0                      # spec path actually ran
+    assert bm["rows_drafted"] >= drafted_share * bm["rounds"]
+    assert bm["proposed"] == bm["rows_drafted"] * 4
+    if drafted_share:
+        assert bm["accepted"] > 0
     assert int(on.sum()) == int(off.sum()) == 10 * 40
     stat, dof, p = speculative.two_sample_chi2(on, off)
     assert dof >= 1

@@ -381,6 +381,58 @@ def test_ragged_prefill_copies_no_layer_of_the_pool(topo, context):
     _assert_no_layer_of_the_pool(compiled, ck)
 
 
+# ---------- the spec tick's conditionals alias the pool ----------
+
+
+@pytest.mark.parametrize("context", [1024, 4096])
+def test_spec_tick_passes_the_pool_through_its_conditionals(topo, context):
+    """`jit_spec_tick` (ISSUE 34) at the chat and the document cells'
+    cache geometry: the pool rides the round scan's carry through two
+    conditionals (the plain step, the verify pass), and a skipped branch
+    hands it back. XLA must alias it there: no instruction produces a
+    copy of a whole pool, the donated caches alias the outputs, and both
+    conditionals sit in the compiled round loop."""
+    import math
+    import re
+    import types
+
+    from localai_tpu.engine import engine as eng, sampling
+
+    A = _on(SingleDeviceSharding(topo.devices[0]))
+    params, ck, cv = _abstract_7b(A, context, jnp.bfloat16)
+    e = object.__new__(eng.Engine)      # the body reads these and no more
+    e.ecfg = types.SimpleNamespace(n_draft=4, num_slots=S, spec_ngram=3)
+    e.cfg, e.family, e.draft_cfg = CFG_7B_L12, llama, None
+    e._state_shardings = None
+    spp = sampling.pack_slot_params(sampling.make_slot_params(S))
+    i32, f32 = jnp.int32, jnp.float32
+    compiled = jax.jit(
+        lambda *a: e._spec_tick_body(*a, n_rounds=2,
+                                     flags=(False, False, False)),
+        donate_argnums=(2, 3, 8)).lower(
+        params, A((S,), i32), ck, cv, A((S,), i32),
+        A((S, sampling.RING_N), i32), A((S,), i32),
+        A((S, CFG_7B_L12.vocab_size), f32), A((S, 2), jnp.uint32),
+        A(spp.shape, spp.dtype), A((S,), jnp.bool_), A((S,), f32),
+        A((7 + sampling.RING_N, S), f32), A((S,), jnp.bool_)).compile()
+    hlo = compiled.as_text()
+    pool = ck["pages"].shape
+    pool_bytes = math.prod(pool) * 2
+    dims = ",".join(str(d) for d in pool)
+    copies = [ln.strip()[:160] for ln in hlo.splitlines()
+              if re.search(rf"= bf16\[{dims}\]\S* copy(-start|-done)?\(", ln)]
+    assert not copies, "\n".join(copies)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    if context == 4096:
+        # the verify pass's dense per-layer gather and the weights XLA
+        # relays for it are under one pool (PERF.md section 7); a pool
+        # copied out of a conditional would not be
+        assert mem.temp_size_in_bytes < pool_bytes
+    in_loop = "\n".join(_loop_instructions(hlo))
+    assert len(re.findall(r" conditional\(", in_loop)) == 2
+
+
 # ---------- the hybrid family: paged K/V beside a recurrent state ----------
 
 S_H, C_H, POOL_H = 32, 2048, 768     # the olmo-hybrid cell's cache geometry

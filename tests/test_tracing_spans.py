@@ -99,7 +99,7 @@ def test_span_is_a_trace_annotation_in_a_real_capture(tmp_path):
 
 def test_ring_holds_a_window_and_says_from_when():
     tr = RingTracer(size=8)
-    assert tracing.DEFAULT_RING_SIZE >= 32768
+    assert tracing.DEFAULT_RING_SIZE >= 131072
     base = tr.t0
     for i in range(8):
         tr.record("s", "sched", base + i, base + i + 0.5)
