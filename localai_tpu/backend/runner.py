@@ -23,18 +23,33 @@ import grpc
 import numpy as np
 
 from localai_tpu.backend import contract_pb2 as pb
-from localai_tpu.backend.service import (BackendServicer, make_server,
-                                         parse_options)
+from localai_tpu.backend.service import (BackendServicer, RpcPool,
+                                         make_server, parse_options)
 
 log = logging.getLogger("localai_tpu.backend.runner")
 
 # config.json model_type -> how tpu-llm serves it: the dense Llama block
-# (models/llama.py), or a family module localai_tpu/models/<model_type>.py
-# and its config class
+# (models/llama.py), or a family module localai_tpu/models/<module>.py and
+# its config class
 _LLAMA_TYPES = frozenset({"llama", "mistral", "qwen2", "qwen", "gemma",
                           "phi3"})
-_FAMILIES = {"mamba": "MambaConfig", "rwkv": "RwkvConfig",
-             "olmo_hybrid": "OlmoHybridConfig"}
+_FAMILIES = {"mamba": ("mamba", "MambaConfig"),
+             "rwkv": ("rwkv", "RwkvConfig"),
+             "olmo_hybrid": ("olmo_hybrid", "OlmoHybridConfig"),
+             "granitemoehybrid": ("granite_hybrid", "GraniteHybridConfig")}
+
+# Threads of the runner's gRPC server. A streaming request holds one for
+# its whole life (waiting on the engine's queue, then on its tokens), so
+# this caps the requests the ENGINE can see at once: its slots and its own
+# queue, whose scheduler, priorities and queue_wait spans cannot act on a
+# request still parked in gRPC's. The pool is built before LoadModel says
+# how many slots there are, at the contract's default; a model with more
+# slots than that raises it there: at 16 a model of 48 slots decoded 15 at
+# a time, its other slots empty behind a 35 s time to first token (PERF.md
+# section 6, PR 36). A model of up to 16 slots keeps the 16 it was measured
+# with: what its engine sees of the queue is part of its cells' traffic.
+RPC_WORKERS = 16
+RPC_WORKERS_MANY_SLOTS = 256
 
 # engine lifecycle failure kinds -> gRPC status codes, so the core can
 # distinguish shed (retry later) from timeout from stall without parsing
@@ -85,8 +100,10 @@ class EngineServicer(BackendServicer):
     """LLM serving: LoadModel/Predict/PredictStream/Embedding/Tokenize/
     Status/GetMetrics on top of the continuous-batching engine."""
 
-    def __init__(self):
+    def __init__(self, rpc_pool=None):
         from localai_tpu.services.tracing import RingTracer
+
+        self.rpc_pool = rpc_pool   # service.RpcPool of the server, if any
 
         # the process's one span ring, from process start: LoadModel's
         # spans go in first, then the Engine is handed the same ring
@@ -199,12 +216,14 @@ class EngineServicer(BackendServicer):
                 # engine slots through the family adapter: mamba / rwkv
                 # with a fixed-size recurrent state in the cache lanes
                 # (reference: backend/python/mamba, backend/go/llm/rwkv),
-                # olmo_hybrid with paged K/V and a recurrent state
+                # olmo_hybrid and granite_hybrid with paged K/V and a
+                # recurrent state
                 import importlib
 
+                module, cfg_class = _FAMILIES[mtype]
                 family = importlib.import_module(
-                    "localai_tpu.models." + mtype)
-                cfg = getattr(family, _FAMILIES[mtype]).from_hf_config(
+                    "localai_tpu.models." + module)
+                cfg = getattr(family, cfg_class).from_hf_config(
                     cfg_dict, dtype=dtype)
                 if request.lora_adapter:
                     raise ValueError("LoRA adapters are llama-family only")
@@ -609,6 +628,9 @@ class EngineServicer(BackendServicer):
         # plain Engine — no pool object anywhere on the path, so single-
         # engine behavior stays bit-for-bit.
         n_engines = max(1, int(extra.get("engines", 1) or 1))
+        if self.rpc_pool is not None \
+                and ecfg.num_slots * n_engines > RPC_WORKERS:
+            self.rpc_pool.grow(RPC_WORKERS_MANY_SLOTS)
         if n_engines > 1 or ecfg.autoscale:
             # autoscale=1 needs the pool even at engines=1: the pool IS
             # the actuator (resize), and its build-arg stash is what lets
@@ -1059,8 +1081,9 @@ def main(argv=None):
     from localai_tpu.utils.jaxtools import enable_compilation_cache
 
     enable_compilation_cache()
-    servicer = EngineServicer()
-    server = make_server(servicer, args.addr)
+    pool = RpcPool(max_workers=RPC_WORKERS)
+    servicer = EngineServicer(rpc_pool=pool)
+    server = make_server(servicer, args.addr, pool=pool)
     server.start()
     log.info("backend listening on %s", args.addr)
     print(f"gRPC Server listening at {args.addr}", flush=True)  # readiness marker

@@ -132,9 +132,23 @@ def _inject_faults(name: str, fn, streaming: bool):
     return wrapped
 
 
+class RpcPool(futures.ThreadPoolExecutor):
+    """A server's handler threads, whose number a servicer may raise once
+    it knows what it serves (a streamed request holds a thread for its
+    whole life). ``grow`` never lowers it."""
+
+    def grow(self, max_workers: int):
+        # ThreadPoolExecutor reads _max_workers at every submit and starts
+        # a thread while it has fewer (tests/test_granite_hybrid.py holds
+        # CPython to that)
+        self._max_workers = max(self._max_workers, max_workers)
+
+
 def make_server(servicer: BackendServicer, addr: str, max_workers: int = 16,
-                options: Optional[list] = None) -> grpc.Server:
-    """Build (not start) a grpc server for the contract bound to addr."""
+                options: Optional[list] = None,
+                pool: Optional[futures.Executor] = None) -> grpc.Server:
+    """Build (not start) a grpc server for the contract bound to addr;
+    ``pool`` in place of a fixed pool of ``max_workers`` threads."""
     handlers = {}
     for name, (req_cls, resp_cls, streaming) in METHODS.items():
         fn = _inject_faults(name, getattr(servicer, name), streaming)
@@ -148,7 +162,7 @@ def make_server(servicer: BackendServicer, addr: str, max_workers: int = 16,
                 response_serializer=resp_cls.SerializeToString)
         handlers[name] = h
     server = grpc.server(
-        futures.ThreadPoolExecutor(max_workers=max_workers),
+        pool or futures.ThreadPoolExecutor(max_workers=max_workers),
         options=options or [
             ("grpc.max_receive_message_length", 64 * 1024 * 1024),
             ("grpc.max_send_message_length", 64 * 1024 * 1024),

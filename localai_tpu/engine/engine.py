@@ -900,6 +900,8 @@ class Engine:
         # per-slot recurrent state beside the K/V rows (ops/kvcache.py)
         self._state_bytes = kvcache.state_bytes(self.ck)
         self._kv_walk = {"pages_live": 0, "pages_grid": 0}   # _count_kv_walk
+        self._state_layers = kvcache.state_layers(self.ck)
+        self._state_walk = {"slot_steps_live": 0, "slot_steps_grid": 0}
         # draft cache is allocated LAZILY at the first spec-eligible
         # admission (r2 allocated it up front, doubling per-slot KV HBM
         # even when no request could ever speculate)
@@ -3647,6 +3649,7 @@ class Engine:
             "capabilities": sorted(self._caps),
             "recurrent_state_bytes": self._state_bytes,
             "kv_walk": dict(self._kv_walk),
+            "state_walk": dict(self._state_walk),
             **self._device,
             "device_mem": sysobs.device_memory_stats(),
             "attention": self._attention_report(),
@@ -6535,9 +6538,16 @@ class Engine:
         steps whose plain rows are the slots ``rows``: the page-table
         entries those steps span (num_slots x max_pages each: what the
         paged decode kernel's first form walked) and those that hold a
-        live row (what it works on since PR 31: PERF.md section 6)."""
+        live row (what it works on since PR 31: PERF.md section 6).
+        ``state_walk`` likewise for a family with recurrent state: slots x
+        state layers for every step that ran, and the live slots' share of
+        it, which is all a state kernel that skips the others moves."""
         if not self._paged:
             return
+        self._state_walk["slot_steps_live"] += (
+            n_steps * len(rows) * self._state_layers)
+        self._state_walk["slot_steps_grid"] += (
+            n_steps * self.ecfg.num_slots * self._state_layers)
         pg = self._pool.page_size
         self._kv_walk["pages_live"] += n_steps * sum(
             -(-(int(self.lengths[i]) + infl[i]) // pg) for i in rows)

@@ -69,7 +69,9 @@ def find_gguf(model_dir: str) -> Optional[str]:
 _QUANT_NAMES = {"embed", "lm_head", "wq", "wk", "wv", "wo",
                 "w_gate", "w_up", "w_down",
                 # models/olmo_hybrid.py's linear-attention projections
-                "lin_qkv", "lin_g", "lin_o"}
+                "lin_qkv", "lin_g", "lin_o",
+                # models/granite_hybrid.py's mamba projections
+                "ssm_in_z", "ssm_in_xbc", "ssm_out"}
 
 
 def _make_put(cfg, mesh, dtype, quantize, adapter=None, pace=None,
@@ -362,6 +364,79 @@ def load_olmo_hybrid_params(model_dir: str, cfg, dtype=jnp.bfloat16,
         raise ValueError(f"quantization={quantize!r} is not supported for "
                          "olmo_hybrid (only weight-only int8)")
     return _assemble(_olmo_hybrid_leaf_source(model_dir, cfg),
+                     _make_put(cfg, None, dtype, quantize, tracer=tracer),
+                     tracer)
+
+
+def _granite_hybrid_leaf_source(model_dir: str, cfg):
+    """(spec_path, host array) for models/granite_hybrid.py's stacked
+    layout: every leaf ``[periods, n, ...]`` with n the layers of its kind
+    in a period. Linear weights become ``[in, out]``; ``mamba.in_proj`` is
+    split into its z | xBC | dt columns and ``shared_mlp.input_linear``
+    into gate | up, so that each is a matmul of its own width; the
+    depthwise convolution ``[ch, 1, W]`` becomes ``[W, ch]``. The head is
+    tied: the checkpoint holds no ``lm_head.weight``."""
+    tensors = _open_shards(model_dir)
+    n = len(cfg.period)
+    Di, Ch, F = cfg.d_inner, cfg.conv_channels, cfg.intermediate_size
+
+    def top(name: str) -> np.ndarray:
+        return tensors[name].get_tensor(name)
+
+    def stacked(kind, one) -> np.ndarray:
+        """one(layer index) over the layers of ``kind`` (None: every
+        layer), stacked [periods, layers of that kind a period, ...]."""
+        js = [j for j, k in enumerate(cfg.period) if kind in (None, k)]
+        return np.stack([np.stack([one(n * p + j) for j in js])
+                         for p in range(cfg.periods)])
+
+    def get(i: int, name: str) -> np.ndarray:
+        return top(f"model.layers.{i}.{name}")
+
+    def cols(name: str, lo: int, hi: int):
+        """Columns [lo, hi) of a linear weight as [in, out]."""
+        return lambda i: get(i, name)[lo:hi].T
+
+    in_proj, mlp_in = "mamba.in_proj.weight", "shared_mlp.input_linear.weight"
+    yield ("embed",), top("model.embed_tokens.weight")
+    for leaf, lo, hi in (("ssm_in_z", 0, Di), ("ssm_in_xbc", Di, Di + Ch),
+                         ("ssm_in_dt", Di + Ch, Di + Ch + cfg.ssm_heads)):
+        yield ("layers", leaf), stacked("mamba", cols(in_proj, lo, hi))
+    yield ("layers", "ssm_out"), stacked(
+        "mamba", lambda i: get(i, "mamba.out_proj.weight").T)
+    yield ("layers", "ssm_conv"), stacked(
+        "mamba", lambda i: get(i, "mamba.conv1d.weight")[:, 0, :].T)
+    for leaf, name in (("ssm_conv_b", "conv1d.bias"), ("ssm_A_log", "A_log"),
+                       ("ssm_D", "D"), ("ssm_dt_bias", "dt_bias"),
+                       ("ssm_norm", "norm.weight")):
+        yield ("layers", leaf), stacked(
+            "mamba", lambda i, name=name: get(i, "mamba." + name))
+    for leaf, name in (("wq", "q"), ("wk", "k"), ("wv", "v"), ("wo", "o")):
+        yield ("layers", leaf), stacked(
+            "attention",
+            lambda i, name=name: get(i, f"self_attn.{name}_proj.weight").T)
+    yield ("layers", "attn_norm"), stacked(
+        None, lambda i: get(i, "input_layernorm.weight"))
+    yield ("layers", "mlp_norm"), stacked(
+        None, lambda i: get(i, "post_attention_layernorm.weight"))
+    yield ("layers", "w_gate"), stacked(None, cols(mlp_in, 0, F))
+    yield ("layers", "w_up"), stacked(None, cols(mlp_in, F, 2 * F))
+    yield ("layers", "w_down"), stacked(
+        None, lambda i: get(i, "shared_mlp.output_linear.weight").T)
+    yield ("final_norm",), top("model.norm.weight")
+    if not cfg.tie_word_embeddings:
+        yield ("lm_head",), top("lm_head.weight").T
+
+
+def load_granite_hybrid_params(model_dir: str, cfg, dtype=jnp.bfloat16,
+                               quantize: str = "", tracer=None) -> dict:
+    """Load a ``granitemoehybrid`` checkpoint (HF safetensors) through the
+    same cast / int8 / placement path as ``load_llama_params``. No mesh:
+    the family refuses one (models/granite_hybrid.py)."""
+    if quantize not in ("", "int8"):
+        raise ValueError(f"quantization={quantize!r} is not supported for "
+                         "granite_hybrid (only weight-only int8)")
+    return _assemble(_granite_hybrid_leaf_source(model_dir, cfg),
                      _make_put(cfg, None, dtype, quantize, tracer=tracer),
                      tracer)
 

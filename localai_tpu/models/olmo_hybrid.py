@@ -49,6 +49,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from localai_tpu.models import llama
+from localai_tpu.models.hybrid_common import (new_tails, packed_conv,
+                                              prefill_as_pack, scan_periods,
+                                              unembed)
 from localai_tpu.models.llama import AttnTarget, _embed_rows, _mat, _mlp
 from localai_tpu.ops import gated_delta, kvcache
 from localai_tpu.ops.norms import rms_norm
@@ -318,19 +321,6 @@ def _full_qkv(x, layer, cfg):
             heads(k, cfg.num_kv_heads, pad), heads(v, cfg.num_kv_heads, pad))
 
 
-def _unembed(x, params, cfg):
-    w = _mat(params["embed"], x.dtype).T if cfg.tie_word_embeddings \
-        else _mat(params["lm_head"], x.dtype)
-    return (x @ w).astype(jnp.float32)
-
-
-def _scan_periods(cfg, period_fn, carry):
-    """``period_fn(carry, p)`` over the periods; it takes its weights from
-    the stacked leaves by layer index (``_layer``)."""
-    return jax.lax.scan(lambda c, p: (period_fn(c, p), None), carry,
-                        jnp.arange(cfg.periods, dtype=jnp.int32))[0]
-
-
 def decode_step(params, cfg: OlmoHybridConfig, tokens, lengths, active,
                 cache_k, cache_v):
     """One decode step for all slots. tokens [S]; ``lengths`` the position
@@ -382,12 +372,12 @@ def decode_step(params, cfg: OlmoHybridConfig, tokens, lengths, active,
         x = _mlp_block(x[:, None], e, cfg)[:, 0]
         return x, ck, cv
 
-    x, cache_k, cache_v = _scan_periods(cfg, period_fn,
+    x, cache_k, cache_v = scan_periods(cfg, period_fn,
                                         (x, cache_k, cache_v))
     with _scope("final_norm"):
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     with _scope("lm_head"):
-        logits = _unembed(x, params, cfg)
+        logits = unembed(x, params, cfg)
     return logits, cache_k, cache_v
 
 
@@ -412,18 +402,6 @@ def engine_decode(params, cfg, tokens, lengths, active, cache_k, cache_v,
     C = kvcache.shape(cache_k)[2]
     return decode_step(params, cfg, tokens, jnp.where(active, lengths, C),
                        active, cache_k, cache_v)
-
-
-def _prev_inputs(pre, init, seg, j, d: int):
-    """The convolution's input ``d`` tokens before each packed token:
-    the pack's own row where the segment reaches back that far, else the
-    segment's starting tail ``init`` [B, 3, Ch] (oldest first)."""
-    own = jnp.roll(pre, d, axis=0)
-    W1 = init.shape[1]
-    flat = init.reshape(-1, init.shape[-1])                  # [B*3, Ch]
-    idx = jnp.clip(seg * W1 + (W1 + j - d), 0, flat.shape[0] - 1)
-    return jnp.where((j >= d)[:, None], own,
-                     jnp.take(flat, idx, axis=0).astype(pre.dtype))
 
 
 def ragged_prefill(params, cfg: OlmoHybridConfig, tokens, positions, seg_of,
@@ -453,8 +431,6 @@ def ragged_prefill(params, cfg: OlmoHybridConfig, tokens, positions, seg_of,
     plan = gated_delta.chunk_plan(seg_off, seg_len, N)
     slots_c = jnp.minimum(seg_slots, S - 1)
     fresh = seg_start == 0
-    # rows of the pack that become each segment's new convolution tail
-    tail_j = seg_len[:, None] - W1 + jnp.arange(W1, dtype=jnp.int32)[None]
 
     def linear_attn(pre, g, beta, w, ck, li):
         f32 = jnp.float32
@@ -468,22 +444,12 @@ def ragged_prefill(params, cfg: OlmoHybridConfig, tokens, positions, seg_of,
         else:
             conv0 = jnp.zeros((B, W1, cfg.conv_channels), pre.dtype)
             s0 = jnp.zeros((B,) + ck["delta"].shape[2:], f32)
-        cw = w["lin_conv"].astype(f32)                       # [4, Ch]
-        acc = pre.astype(f32) * cw[W1][None]
-        for d in range(1, W1 + 1):
-            acc = acc + _prev_inputs(pre, conv0, seg, j, d).astype(f32) \
-                * cw[W1 - d][None]
+        acc = packed_conv(pre, conv0, w["lin_conv"].astype(f32), seg, j)
         q, k, v = _split_heads(jax.nn.silu(acc), cfg)
         with _scope("gated_delta_chunk"):
             o, finals = gated_delta.gated_delta_chunk(q, k, v, g, beta, s0,
                                                       plan)
-        # the new tail: the segment's last three inputs, reaching into the
-        # old tail where the segment is shorter than that
-        own = jnp.take(pre, jnp.clip(seg_off[:, None] + tail_j, 0, N - 1),
-                       axis=0)                               # [B, 3, Ch]
-        old = jnp.take_along_axis(
-            conv0, jnp.clip(tail_j + W1, 0, W1 - 1)[..., None], axis=1)
-        tail = jnp.where((tail_j >= 0)[..., None], own, old.astype(pre.dtype))
+        tail = new_tails(pre, conv0, seg_off, seg_len)      # [B, 3, Ch]
         ck = dict(ck,
                   conv=ck["conv"].at[li, seg_slots].set(
                       tail.astype(ck["conv"].dtype), mode="drop"),
@@ -521,34 +487,23 @@ def ragged_prefill(params, cfg: OlmoHybridConfig, tokens, positions, seg_of,
         x = _mlp_block(x[None], e, cfg)[0]
         return x, ck, cv
 
-    x, cache_k, cache_v = _scan_periods(cfg, period_fn,
+    x, cache_k, cache_v = scan_periods(cfg, period_fn,
                                         (x, cache_k, cache_v))
     with _scope("final_norm"):
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     with _scope("lm_head"):
         last = jnp.maximum(seg_off + seg_len - 1, 0)
-        logits = _unembed(jnp.take(x, last, axis=0), params, cfg)
+        logits = unembed(jnp.take(x, last, axis=0), params, cfg)
     return logits, cache_k, cache_v
 
 
 def prefill(params, cfg, tokens, seq_lens, cache_k, cache_v, slot_ids,
             start_pos, continued=False, mm_pos=None, mm_vec=None,
             return_all_logits=False, positions=None):
-    """The per-slot prefill of the adapter contract, as one pack of B
-    segments of T tokens each (the engine's packed path is what serves;
-    this is for callers that hold a [B, T] batch)."""
+    """The per-slot prefill of the adapter contract, as one pack (the
+    engine's packed path is what serves; this is for callers that hold a
+    [B, T] batch)."""
     assert mm_pos is None and positions is None and not return_all_logits, \
         "multimodal, explicit positions and all-logits are not declared"
-    B, T = tokens.shape
-    C = kvcache.shape(cache_k)[2]
-    t = jnp.arange(T, dtype=jnp.int32)[None]
-    seq_lens, start_pos = jnp.asarray(seq_lens), jnp.asarray(start_pos)
-    valid = t < seq_lens[:, None]
-    pos = jnp.where(valid, start_pos[:, None] + t, C).reshape(-1)
-    seg_of = jnp.where(valid, jnp.arange(B, dtype=jnp.int32)[:, None],
-                       B).reshape(-1)
-    return ragged_prefill(
-        params, cfg, jnp.asarray(tokens).reshape(-1), pos, seg_of,
-        jnp.asarray(slot_ids), start_pos,
-        jnp.arange(B, dtype=jnp.int32) * T, seq_lens, cache_k, cache_v,
-        continued=continued)
+    return prefill_as_pack(ragged_prefill, params, cfg, tokens, seq_lens,
+                           cache_k, cache_v, slot_ids, start_pos, continued)

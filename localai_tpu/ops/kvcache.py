@@ -27,21 +27,25 @@ engine's jitted bodies stay shape-stable and donation-friendly:
    REF-COUNTED page sharing instead of a row copy (copy-on-write: the
    first divergent page is cloned, see clone_page / engine admission).
 
-3. RECURRENT STATE beside the pages (models/olmo_hybrid.py): a family in
-   which only some layers have K/V rows keeps, for the others, a
-   fixed-size state per slot as further leaves of the paged ``cache_k``
-   dict — ``STATE_LEAVES``: "delta" [L_lin, S, H, K, V] float32 and
-   "conv" [L_lin, S, 3, Ch] — with "pages" then holding the full-attention
-   layers alone. Every function here that rebuilds a paged dict from an
-   old one (with_page_table, scatter_*, clone_page) carries them through
-   untouched; ``layer`` and the gathers return K/V views without them.
-   They are written only by the family's own programs: zeroed by a
-   prefill segment that starts at position 0, untouched for an inactive
-   slot. No page helper reads them, which is why such a family declares
-   no prefix reuse (engine.py): a page of K/V without the state at its
-   boundary cannot be resumed from. There is no sharding rule for them:
-   such a family does not declare "mesh", and the runner and the engine
-   refuse it one.
+3. RECURRENT STATE beside the pages (models/olmo_hybrid.py,
+   models/granite_hybrid.py): a family in which only some layers have K/V
+   rows keeps, for the others, a fixed-size state per slot as further
+   leaves of the paged ``cache_k`` dict, with "pages" then holding the
+   attention layers alone. WHICH leaves is the family's to say: whatever
+   its ``init_cache`` returns in ``cache_k`` beside "pages", "ptab" and
+   "scales" is a state leaf (``state_leaves``), shaped ``[layers of that
+   kind, S, ...]`` - olmo_hybrid's "delta" [L_lin, S, H, K, V] float32 and
+   "conv" [L_lin, S, 3, Ch], granite_hybrid's "ssm" [L_ssm, S, H, P, N]
+   float32 and "conv". Every function here that rebuilds a paged dict
+   from an old one (with_page_table, scatter_*, clone_page) carries any
+   such leaf through untouched; ``layer`` and the gathers return K/V views
+   without them; ``state_bytes`` sums them. They are written only by the
+   family's own programs: zeroed by a prefill segment that starts at
+   position 0, untouched for an inactive slot. No page helper reads them,
+   which is why such a family declares no prefix reuse (engine.py): a page
+   of K/V without the state at its boundary cannot be resumed from. There
+   is no sharding rule for them: such a family does not declare "mesh",
+   and the runner and the engine refuse it one.
 
 Quantized representation (int8, per-row-per-head scales).
 
@@ -117,16 +121,29 @@ def page_chain_hash(parent: bytes, token_ids, scope: bytes) -> bytes:
     return h.digest()
 
 
-STATE_LEAVES = ("delta", "conv")
+_PAGE_LEAVES = ("pages", "ptab", "scales")
+
+
+def state_leaves(cache: Any) -> dict:
+    """The per-slot recurrent-state leaves of a paged cache dict: what
+    the family's ``init_cache`` put there beside the page pool (module
+    doc, point 3). Empty for a cache of K/V rows alone."""
+    if not is_paged(cache):
+        return {}
+    return {k: v for k, v in cache.items() if k not in _PAGE_LEAVES}
 
 
 def state_bytes(cache: Any) -> int:
     """Device bytes a cache holds as per-slot recurrent state (0 for a
     cache of K/V rows alone)."""
-    if not isinstance(cache, dict):
-        return 0
-    return int(sum(cache[k].size * cache[k].dtype.itemsize
-                   for k in STATE_LEAVES if k in cache))
+    return int(sum(a.size * a.dtype.itemsize
+                   for a in state_leaves(cache).values()))
+
+
+def state_layers(cache: Any) -> int:
+    """Layers that keep a recurrent state a slot (their leaves' leading
+    axis; 0 for a cache of K/V rows alone)."""
+    return max((a.shape[0] for a in state_leaves(cache).values()), default=0)
 
 
 def wants_quant(dtype) -> bool:

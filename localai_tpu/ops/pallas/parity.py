@@ -30,10 +30,20 @@ one bf16 rounding of the output.
 Prints one JSON line: the device and the largest error per kernel (see
 TOLERANCE for the measure). Exits 1 if an error exceeds the tolerance.
 
+The Mamba-2 state kernel (ops/pallas/mamba2_decode.py) runs at the
+granite cell's geometry (SSM_CELL: 48 slots x 64 heads x 64 x 128 float32,
+two stacked layers) with 0, 8, 30 and 48 slots live: the state of every
+slot that is not live, and the whole of the other layer, is NaN going in
+and must come out the very bits it was, while the live slots' update and
+output match ops/ssd.py::ssd_decode.
+
 ``sweep`` is a measurement and decides nothing: the compiled paged
 decode kernel's microseconds a call at CELLS against live slots and
 context reserved (paged_decode_sweep: PERF.md section 6's table, PR 31;
-about 4 chip-minutes), one JSON line.
+about 4 chip-minutes), and the state kernel's at SSM_CELL against live
+slots (mamba2_decode_sweep, with ``live8_over_live48``: a kernel that
+moves only live slots reads well under 0.3 there), one JSON line.
+``sweep mamba2`` runs the second alone.
 """
 
 from __future__ import annotations
@@ -46,10 +56,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from localai_tpu.ops import kvcache
+from localai_tpu.ops import kvcache, ssd
 from localai_tpu.ops.attention import decode_attention_append
 from localai_tpu.ops.pallas.decode_attention import (
     decode_attention_append_pallas)
+from localai_tpu.ops.pallas.mamba2_decode import mamba2_decode_pallas
 from localai_tpu.ops.pallas.paged_attention import (
     paged_decode_attention_append, paged_decode_attention_append_quant,
     read_lengths)
@@ -80,6 +91,12 @@ CELLS = {
     "8x64": (16, 64, (8, 4, HD), 2500),     # the Nemo cells: 16 x 4096
     "32x32": (32, 32, (32, 1, HD), 450),    # olmo-hybrid: 32 x 2048, padded
 }
+
+
+# granite-h-micro.longgen_many's state geometry: slots, heads, head size,
+# state size; the live counts the kernel is checked and timed at
+SSM_CELL = (48, 64, 64, 128)
+SSM_LIVE = (0, 8, 30, 48)
 
 
 def _lengths(page: int, slots: int = S, mp: int = MP):
@@ -290,6 +307,87 @@ def check_ragged_prefill(N: int, interpret: bool = False,
     return _max_err(out, ref, keep=seg_of < B)    # pad rows are garbage
 
 
+def _live_mask(slots: int, live: int):
+    """``live`` of ``slots`` slots, spread evenly (the first and the last
+    among them when there are two or more)."""
+    mask = np.zeros((slots,), bool)
+    if live:
+        mask[np.linspace(0, slots - 1, live).round().astype(int)] = True
+    return mask
+
+
+def _ssm_inputs(rng, slots, heads, p, n):
+    f32 = np.float32
+    x = rng.standard_normal((slots, heads, p)).astype(f32)
+    dt = (0.01 + 0.1 * rng.random((slots, heads))).astype(f32)
+    la = (-dt * np.exp(rng.uniform(0, 2.7, (heads,)))).astype(f32)
+    B = rng.standard_normal((slots, n)).astype(f32)
+    C = rng.standard_normal((slots, n)).astype(f32)
+    return tuple(jnp.asarray(a) for a in (x, dt, la, B, C))
+
+
+def check_mamba2_decode(live: int, interpret: bool = False,
+                        cell=SSM_CELL) -> float:
+    """The state kernel with ``live`` slots live on layer 1 of a stacked
+    [2, ...] state. Everything no live slot holds is NaN going in; it has
+    to come out bit for bit, the live slots' state and output have to match
+    the jax.numpy form, and the output of the others has to be zero."""
+    slots, heads, p, n = cell
+    rng = np.random.default_rng(7 + live)
+    mask = _live_mask(slots, live)
+    state = rng.standard_normal((2, slots, heads, p, n)).astype(np.float32)
+    state[0] = np.nan
+    state[1, ~mask] = np.nan
+    args = _ssm_inputs(rng, slots, heads, p, n)
+    active = jnp.asarray(mask)
+    y, new = jax.jit(lambda s, *a: mamba2_decode_pallas(
+        s, jnp.int32(1), *a, active, interpret=interpret),
+        donate_argnums=0)(jnp.asarray(state), *args)
+    y_ref, new_ref = ssd.ssd_decode(jnp.asarray(state), 1, *args, active)
+    y, new, new_ref = np.asarray(y), np.asarray(new), np.asarray(new_ref)
+    untouched = np.isnan(new[0]).all() and np.isnan(new[1, ~mask]).all()
+    assert untouched, "a state no live slot holds was rewritten"
+    assert not y[~mask].any(), "output of a slot that is not live"
+    if not live:
+        return 0.0
+    return max(_max_err(y[mask], np.asarray(y_ref)[mask]),
+               _max_err(new[1, mask], new_ref[1, mask]))
+
+
+def time_mamba2_decode(live: int, calls: int = 256, cell=SSM_CELL) -> float:
+    """Microseconds a call of the compiled state kernel at the cell's
+    geometry with ``live`` slots live: ``calls`` calls chained in one
+    program on one state, the layer alternating; the best of three."""
+    slots, heads, p, n = cell
+    rng = np.random.default_rng(9)
+    args = _ssm_inputs(rng, slots, heads, p, n)
+    active = jnp.asarray(_live_mask(slots, live))
+
+    @jax.jit
+    def chain(state):
+        def body(i, carry):
+            state, acc = carry
+            y, state = mamba2_decode_pallas(state, i % 2, *args, active)
+            return state, acc + y
+        return jax.lax.fori_loop(
+            0, calls, body, (state, jnp.zeros((slots, heads, p))))
+
+    state = jnp.zeros((2, slots, heads, p, n), jnp.float32)
+    jax.block_until_ready(chain(state))
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        jax.block_until_ready(chain(state))
+        best = min(best, time.perf_counter() - t0)
+    return round(best / calls * 1e6, 2)
+
+
+def mamba2_decode_sweep() -> dict:
+    out = {f"live{n}": time_mamba2_decode(n) for n in SSM_LIVE}
+    out["live8_over_live48"] = round(out["live8"] / out["live48"], 4)
+    return out
+
+
 def main(argv=None) -> int:
     # --interpret: the CPU rehearsal of chip_smoke.py (Pallas interpreter,
     # one small pack); without it the kernels run compiled, which needs
@@ -303,8 +401,10 @@ def main(argv=None) -> int:
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices())}
     if "sweep" in argv:
-        print(json.dumps({"paged_decode_us": paged_decode_sweep(),
-                          "device": device}))
+        out = {"mamba2_decode_us": mamba2_decode_sweep()}
+        if "mamba2" not in argv:
+            out["paged_decode_us"] = paged_decode_sweep()
+        print(json.dumps({**out, "device": device}))
         return 0
     errors = {
         "paged_decode": check_paged_decode(False, interpret),
@@ -317,6 +417,11 @@ def main(argv=None) -> int:
             for name, (slots, mp, heads, _) in CELLS.items()
             for quant in (False, True)}),
         "decode_append": check_contiguous_decode(interpret),
+        # the granite cell's geometry compiled; a small one interpreted
+        **{f"mamba2_decode[live{n}]": check_mamba2_decode(n)
+           for n in (() if interpret else SSM_LIVE)},
+        **({"mamba2_decode": check_mamba2_decode(
+            3, True, cell=(6, 4, 8, 128))} if interpret else {}),
         # the pack buckets chip_smoke.py's engine builds
         **{f"ragged_prefill[{n}]": check_ragged_prefill(n, interpret)
            for n in ((128,) if interpret else (128, 512, 1024))},

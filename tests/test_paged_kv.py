@@ -664,3 +664,84 @@ def test_paged_pool_never_exceeds_contiguous_reservation(tiny_cfg_params):
                                        cfg.num_kv_heads, cfg.head_dim_)
     finally:
         e.shutdown()
+
+
+# ---- recurrent-state leaves beside the pages (module doc, point 3) ----
+
+def _state_caches(family):
+    """A family's own ``init_cache`` at toy width: (cache_k, its state
+    leaves' names)."""
+    if family == "olmo_hybrid":
+        from localai_tpu.models import olmo_hybrid as fam
+
+        cfg = fam.OlmoHybridConfig(
+            vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=4,
+            num_heads=2, num_kv_heads=2, linear_heads=2, linear_key_dim=8,
+            linear_value_dim=8, dtype=jnp.float32)
+        names = {"delta", "conv"}
+    else:
+        from localai_tpu.models import granite_hybrid as fam
+
+        cfg = fam.GraniteHybridConfig(
+            vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=3,
+            period=("mamba", "mamba", "attention"), num_heads=2,
+            num_kv_heads=2, ssm_heads=2, ssm_head_dim=32, ssm_state=16,
+            dtype=jnp.float32)
+        names = {"ssm", "conv"}
+    ck, _cv = fam.init_cache(cfg, 3, 32, jnp.float32, page_size=8)
+    return ck, names
+
+
+def _rebuilt(helper, ck):
+    pages = ck["pages"]
+    L, n_pages, pg, kv, hd = pages.shape
+    if helper == "with_page_table":
+        return kvcache.with_page_table(
+            ck, jnp.arange(12, dtype=jnp.int32).reshape(3, 4))
+    if helper == "scatter_prefill":
+        return kvcache.scatter_prefill(
+            ck, 0, jnp.zeros((1, 2), jnp.int32),
+            jnp.arange(2, dtype=jnp.int32)[None], jnp.ones((1, 2, kv, hd)))
+    if helper == "scatter_ragged":
+        return kvcache.scatter_ragged(
+            ck, 0, jnp.zeros((2,), jnp.int32), jnp.arange(2, dtype=jnp.int32),
+            jnp.ones((2, kv, hd)))
+    if helper == "scatter_pages":
+        return kvcache.scatter_pages(
+            ck, jnp.asarray([1], jnp.int32), jnp.ones((L, 1, pg, kv, hd)))
+    if helper == "tree_slot_update":
+        return kvcache.tree_slot_update(ck, 1, jnp.ones((L, 32, kv, hd)))
+    assert helper == "clone_page"
+    return kvcache.clone_page(ck, 0, 1)
+
+
+@pytest.mark.parametrize("helper", ["with_page_table", "scatter_prefill",
+                                    "scatter_ragged", "scatter_pages",
+                                    "tree_slot_update", "clone_page"])
+@pytest.mark.parametrize("family", ["olmo_hybrid", "granite_hybrid"])
+def test_page_helpers_carry_whatever_state_leaves_a_family_names(family,
+                                                                 helper):
+    """The state leaves are what the family's ``init_cache`` returned
+    beside pages / ptab; every helper that rebuilds the paged dict hands
+    the very same arrays on, and ``state_bytes`` sums them."""
+    ck, names = _state_caches(family)
+    ck = {k: (v + 1 if k in names else v) for k, v in ck.items()}
+    leaves = kvcache.state_leaves(ck)
+    assert set(leaves) == names
+    assert kvcache.state_bytes(ck) == sum(
+        a.size * a.dtype.itemsize for a in leaves.values()) > 0
+    assert kvcache.state_layers(ck) == leaves["conv"].shape[0]
+    out = _rebuilt(helper, ck)
+    assert set(kvcache.state_leaves(out)) == names
+    for k in names:
+        assert out[k] is ck[k]
+    assert kvcache.state_bytes(out) == kvcache.state_bytes(ck)
+    # K/V views come without them
+    assert set(kvcache.layer(out, 0)) == {"pages", "ptab"}
+
+
+def test_a_cache_of_rows_alone_has_no_state():
+    ck = kvcache.init_paged((2, 3, 32, 2, 8), jnp.float32, 8)
+    assert kvcache.state_leaves(ck) == {} and kvcache.state_bytes(ck) == 0
+    assert kvcache.state_layers(ck) == 0
+    assert kvcache.state_bytes(jnp.zeros((2, 3, 32, 2, 8))) == 0

@@ -532,3 +532,106 @@ def test_hybrid_packed_prefill_compiles(topo, N):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 << 30
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
+
+
+# ---------- granite_hybrid: Mamba-2 state beside a pool of 64-wide heads ----------
+
+S_G, C_G, POOL_G = 48, 2048, 1152    # granite-h-micro.longgen_many's geometry
+
+
+def _abstract_granite(A):
+    """(cfg, params, ck, cv): Granite-4.0-H-Micro as published, all 40
+    layers, bf16 weights, the cell's slots and page pool, as
+    ShapeDtypeStructs."""
+    from localai_tpu.models import granite_hybrid as gh
+
+    cfg = gh.GraniteHybridConfig(attn=llama.AttnTarget(pallas=True))
+
+    def place(tree):
+        return jax.tree.map(lambda x: A(x.shape, x.dtype), tree)
+
+    params = jax.eval_shape(
+        lambda: gh.init_params(cfg, jax.random.PRNGKey(0)))
+    ck, cv = jax.eval_shape(lambda: gh.init_cache(
+        cfg, S_G, C_G, jnp.bfloat16, page_size=PAGE, num_pages=POOL_G))
+    return cfg, place(params), place(ck), place(cv)
+
+
+def test_mamba2_decode_kernel_compiles(topo):
+    """The in-place state update at the cell's shape: 36 x 48 slots x 64
+    heads x 64 x 128 float32, the layer traced, a slot's whole state a
+    program (8.4 MB of VMEM double-buffered)."""
+    from localai_tpu.ops.pallas.mamba2_decode import mamba2_decode_pallas
+
+    A = _on(SingleDeviceSharding(topo.devices[0]))
+    f32 = jnp.float32
+    H, P_, N = 64, 64, 128
+    compiled = jax.jit(mamba2_decode_pallas, donate_argnums=(0,)).lower(
+        A((36, S_G, H, P_, N), f32), A((), jnp.int32), A((S_G, H, P_), f32),
+        A((S_G, H), f32), A((S_G, H), f32), A((S_G, N), f32),
+        A((S_G, N), f32), A((S_G,), jnp.bool_)).compile()
+    # aliased onto its input: nothing the size of a slot's state is made
+    assert compiled.memory_analysis().temp_size_in_bytes < H * P_ * N * 4
+
+
+def test_granite_decode_step_compiles_in_place(topo):
+    """engine_decode at the cell's size with donated caches: the state
+    kernel on the stacked state, the paged kernel on a pool whose heads are
+    padded from 64 to 128 columns. The program's temporaries are a few
+    megabytes: no layer of the state and no copy of the pool (at 64
+    columns the chip's own layout put the PAGE axis minor and the program
+    transposed both pools on its way in and out, 1.2 GB of temporaries),
+    and no instruction of the layer loop makes a layer of state."""
+    import math
+    import re
+
+    from localai_tpu.models import granite_hybrid as gh
+
+    A = _on(SingleDeviceSharding(topo.devices[0]))
+    cfg, params, ck, cv = _abstract_granite(A)
+    assert ck["pages"].shape == (4, POOL_G, PAGE, 8, 128)
+    assert ck["ssm"].shape == (36, S_G, 64, 64, 128)
+    assert llama.decode_attn_impl(cfg.attn_cfg, ck) == "pallas:paged_decode"
+
+    def decode(p, t, ln, act, ck, cv):
+        return gh.engine_decode(p, cfg, t, ln, act, ck, cv)
+
+    compiled = jax.jit(decode, donate_argnums=(4, 5)).lower(
+        params, A((S_G,), jnp.int32), A((S_G,), jnp.int32),
+        A((S_G,), jnp.bool_), ck, cv).compile()
+    hlo = compiled.as_text()
+    assert "mamba2_decode" in hlo and "paged_decode" in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    one_layer = math.prod(ck["ssm"].shape[1:])
+    made = []
+    for line in _loop_instructions(hlo):
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = f32\[([\d,]*)\]", line)
+        if m and math.prod(int(d) for d in m.group(1).split(",") if d) \
+                == one_layer:
+            made.append(line.strip()[:200])
+    assert not made, "\n".join(made)
+
+
+@pytest.mark.parametrize("N", [512, 1024])
+def test_granite_packed_prefill_compiles(topo, N):
+    """A continued pack through the chunked state-space dual (chunks of
+    256, one dynamic slice each) and the ragged prefill kernel."""
+    from localai_tpu.models import granite_hybrid as gh
+
+    A = _on(SingleDeviceSharding(topo.devices[0]))
+    cfg, params, ck, cv = _abstract_granite(A)
+    i32 = jnp.int32
+    assert llama.ragged_attn_impl(cfg.attn_cfg, ck, N, True) == \
+        "pallas:ragged_prefill"
+
+    def pack(p, t, pos, so, ss, st, off, ln, ck, cv):
+        return gh.ragged_prefill(p, cfg, t, pos, so, ss, st, off, ln, ck, cv,
+                                 continued=True)
+
+    compiled = jax.jit(pack, donate_argnums=(8, 9)).lower(
+        params, A((N,), i32), A((N,), i32), A((N,), i32),
+        *_seg_tables(A, S_G), ck, cv).compile()
+    # fits beside 11.3 GB of weights and caches on a 16 GB chip
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 512 << 20
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
