@@ -19,7 +19,7 @@ once and write it once, and to touch no other:
     moves slot ``ids[i]``'s state; a program past the live ones maps to the
     block the last live program held, which Pallas neither fetches again
     nor writes back before the grid ends, and its body does nothing: about
-    a third of a microsecond where a live slot costs five. (The other
+    a third of a microsecond where a live slot costs six. (The other
     choice, an index map that revisits the block last visited with the
     slots in place, has no block to revisit before the first live slot.)
     With no slot live every program maps to slot 0, whose state the first
@@ -34,12 +34,52 @@ once and write it once, and to touch no other:
     size on the sublanes, heads on the lanes; the wrapper transposes 16 KB
     a slot in XLA): a head's ``dt x`` is then lane ``h`` of that block
     broadcast along the lanes, and its ``y`` is laid into lane ``h`` of the
-    output block by a select. Handing columns over as ``[.., P, 1]`` would
-    pad each to 128 lanes in HBM, as much as the state itself; standing a
-    row up as a column inside the kernel (a masked lane reduction, and a
-    masked sublane reduction back: the first form of this kernel, as
-    ops/pallas/gated_delta.py does) cost 12.5 us a live slot where this
-    costs 7.9 and the copies alone 5.1 (PERF.md section 6, PR 36).
+    output block by a select. (Handing columns over as ``[.., P, 1]`` would
+    pad each to 128 lanes in HBM, as much as the state itself.)
+  * TWO REGIONS a live slot, and this is where the kernel's time was. The
+    lane broadcast of ``dt x`` and the lane reduction that gives ``y`` are
+    both work of the cross-lane unit, 512 of each a slot. Alone either is
+    cheap; what is dear is the unit going from one kind to the other, which
+    a body that takes a head at a time (broadcast, update, reduce, next
+    head) makes it do a thousand times a slot. So the body is two
+    ``pl.when`` regions, which the scheduler does not move operations
+    across: the first writes every head's new state (all the broadcasts),
+    the second reads the new state back from the output block in VMEM and
+    reduces it (all the reductions). The arithmetic is the one-region
+    form's to the bit, ``y`` included.
+
+What a live slot costs alone, in microseconds (TPU v5e; the slope of
+``parity sweep mamba2`` between 8 and 48 live; "compute" is the same body
+with every program on one resident block, so that nothing is copied;
+PERF.md section 6, PR 38):
+
+    form                                            kernel   compute
+    a head at a time, both kinds interleaved         7.55     7.48
+      (the kernel as PR 36 left it)
+    two regions (THIS FILE)                          6.39     2.10
+      regions of 32, 16, 8, 4 heads, not of 64                2.17, 2.31,
+                                                              2.66, 3.25
+    broadcasts only (wrong numbers)                  6.37     1.16
+    reductions only (wrong numbers)                  6.41     0.98
+    neither (wrong numbers)                          6.38     0.35
+    row sums on the MXU against a ones matrix,       6.40     2.24
+      ``new * C`` in three bfloat16 parts (exact)
+      the parts by masking float32 / 4 heads a dot   6.40     2.23
+      one pass at Mosaic's default precision         6.39     1.24
+        (rounds to bfloat16: y off by 1.7e-3, refused)
+    head pairs as [128, 128] tiles, transposed       6.38     3.23
+    ``dt x`` from SMEM scalars, MXU row sums         6.36
+    the state copied by hand (pl.ANY, a ring of      6.44-6.50
+      2 / 3 / 4 states in VMEM, 1 / 2 / 4 copies each way)
+    ... and read and write never at the same time    6.55-6.60
+
+Every form that does not interleave the two kinds lands on 6.4 us, whatever
+its compute costs and however the copies are issued: that is what moving
+4.19 MB costs on this chip when half of it is written (657 GB/s of the 819
+the data sheet gives, whose figure would be 5.1 us; XLA's own in-place
+update of the same array moves 663-669 GB/s). The kernel is copy-bound;
+the form kept is the one that leaves the arithmetic and the operand
+layout as they were.
 
 ``y`` of a slot that is not live is not written by the kernel; the wrapper
 zeroes it.
@@ -64,17 +104,24 @@ def _kernel(layer_ref, ids_ref, n_ref, s_ref, dxt_ref, a_ref, b_ref, c_ref,
     i = pl.program_id(0)
     n_live = n_ref[0]
 
-    @pl.when(i < n_live)
+    live = i < n_live
+
+    # two regions, not one: see the module's docstring
+    @pl.when(live)
     def _():
-        b, c = b_ref[...], c_ref[...]                        # [1, N]
+        b = b_ref[...]                                       # [1, N]
         dxt = dxt_ref[...]                                   # [P, Hp]
-        lane = jax.lax.broadcasted_iota(jnp.int32, dxt.shape, 1)
-        yt = jnp.zeros(dxt.shape, jnp.float32)
         for h in range(H):
-            new = s_ref[h] * a_ref[h:h + 1, :] + dxt[:, h:h + 1] * b
-            out_ref[h] = new                                 # [P, N]
+            out_ref[h] = s_ref[h] * a_ref[h:h + 1, :] + dxt[:, h:h + 1] * b
+
+    @pl.when(live)
+    def _():
+        c = c_ref[...]                                       # [1, N]
+        lane = jax.lax.broadcasted_iota(jnp.int32, yt_ref.shape, 1)
+        yt = jnp.zeros(yt_ref.shape, jnp.float32)
+        for h in range(H):
             yt = jnp.where(lane == h,
-                           jnp.sum(new * c, axis=1, keepdims=True), yt)
+                           jnp.sum(out_ref[h] * c, axis=1, keepdims=True), yt)
         yt_ref[...] = yt
 
     @pl.when((n_live == 0) & (i == 0))

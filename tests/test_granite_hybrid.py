@@ -118,30 +118,102 @@ def test_chunked_form_is_the_recurrence(segs):
         np.testing.assert_allclose(finals[b], hr, atol=2e-5, rtol=1e-4)
 
 
+def _kernel_inputs(kind, rng, S, H, P, Ns):
+    """-> state [2, S, H, P, Ns], (x, dt, la, B, C), three kinds:
+
+    ``random``: normal draws, where every operation rounds.
+
+    ``exact``: small multiples of powers of two and decays of 1, 1/2, 1/4,
+    1/8, so that every product, every sum and every partial sum of a row
+    of ``y`` is exact in float32, in any order, fused or not. XLA:CPU
+    contracts a multiply and an add into one rounding in one program and
+    not in another, so on random inputs the interpreted kernel and
+    ``ssd_decode`` differ in the last bit here (the compiled kernel on the
+    chip does not: ops/pallas/parity.py holds that at 0.0); on these
+    inputs "bit-identical" is a statement about the kernel alone.
+
+    ``wide``: a state and a ``C`` whose magnitudes each span 2^-10 .. 2^10
+    with full mantissas, so that the products of one row of ``y`` span
+    2^-20 .. 2^20, and ``x`` zero, so that the new state is one rounded
+    product and bit-identical whatever is fused: a reduction that passes
+    through bfloat16 anywhere misses ``y`` by 2^-9 of the largest term."""
+    f32 = np.float32
+    if kind == "random":
+        state = rng.standard_normal((2, S, H, P, Ns)).astype(f32)
+        return state, _ssd_inputs(rng, S, H, P, Ns)
+
+    def grid(most, step, shape):
+        return (rng.integers(-most, most + 1, shape) * step).astype(f32)
+
+    if kind == "exact":
+        state = grid(128, 2.0 ** -4, (2, S, H, P, Ns))
+        x = grid(32, 2.0 ** -3, (S, H, P))
+        dt = (2.0 ** -rng.integers(3, 7, (S, H))).astype(f32)
+        la = (-np.log(2.0) * rng.integers(0, 4, (S, H))).astype(f32)
+        assert set(np.asarray(jnp.exp(la)).ravel()) <= {1.0, 0.5, 0.25, 0.125}
+        return state, (x, dt, la, grid(32, 2.0 ** -3, (S, Ns)),
+                       grid(4, 0.5, (S, Ns)))
+
+    def spread(shape):
+        return (rng.uniform(1, 2, shape) * rng.choice([-1, 1], shape)
+                * 2.0 ** rng.integers(-10, 11, shape)).astype(f32)
+
+    x, dt, la, B, _ = _ssd_inputs(rng, S, H, P, Ns)
+    return spread((2, S, H, P, Ns)), (0 * x, dt, la, B, spread((S, Ns)))
+
+
+def _bits(a):
+    return np.ascontiguousarray(np.asarray(a, np.float32)).view(np.uint32)
+
+
+@pytest.mark.parametrize("kind", ["random", "exact", "wide"])
 @pytest.mark.parametrize("live", [[0] * 6, [1, 0, 1, 1, 0, 0],
                                   [0, 0, 0, 0, 0, 1], [1] * 6],
                          ids=["none", "some", "last", "all"])
-def test_decode_kernel_in_interpret_mode_is_the_one_token_form(live):
+def test_decode_kernel_in_interpret_mode_is_the_one_token_form(live, kind):
     """ops/pallas/mamba2_decode.py against ops/ssd.py::ssd_decode on a
-    stacked state: the live slots' update, every other block of the state
-    bit-identical (the other layer, the slots that are not live)."""
+    stacked state whose other layer and whose dead slots are NaN: the live
+    slots' update; every other block of the state the bits it was; ``y``
+    zero for a dead slot and, for a live one, within float32 rounding of
+    ``ssd_decode``'s. The tolerance: a row of ``y`` is a sum of Ns
+    products; summed in float32 in any order it lies within
+    Ns * 2^-24 * sum|products| of the exact sum, so two orders lie within
+    twice that of each other, and one more 2^-24 * sum|products| each pays
+    for a last bit of the state that differs (``_kernel_inputs``). One
+    bfloat16 rounding of a product is 2^-9 of it, 250 times the bound."""
     from localai_tpu.ops.pallas.mamba2_decode import mamba2_decode_pallas
 
     rng = np.random.default_rng(sum(live))
     S, H, P, Ns = 6, 4, 8, 128
-    x, dt, la, B, C = _ssd_inputs(rng, S, H, P, Ns)
-    state = rng.standard_normal((2, S, H, P, Ns)).astype(np.float32)
-    active = jnp.asarray(live, bool)
+    keep = np.asarray(live, bool)
+    state, (x, dt, la, B, C) = _kernel_inputs(kind, rng, S, H, P, Ns)
+    state[0] = np.nan
+    state[1][~keep] = np.nan
+    active = jnp.asarray(keep)
     y0, s0 = ssd.ssd_decode(jnp.asarray(state), 1, x, dt, la, B, C, active)
     y1, s1 = mamba2_decode_pallas(jnp.asarray(state), jnp.int32(1), x, dt,
                                   la, B, C, active, interpret=True)
-    keep = np.asarray(live, bool)
-    np.testing.assert_allclose(np.asarray(y1)[keep], np.asarray(y0)[keep],
-                               atol=1e-4, rtol=1e-5)
-    assert not np.asarray(y1)[~keep].any()
-    np.testing.assert_allclose(s1, s0, atol=1e-5, rtol=1e-6)
-    assert (np.asarray(s1)[0] == state[0]).all()
-    assert (np.asarray(s1)[1][~keep] == state[1][~keep]).all()
+    y0, s0, y1, s1 = (np.asarray(v) for v in (y0, s0, y1, s1))
+    # what no live slot holds: the other layer, the dead slots, NaN and all
+    assert (_bits(s1[0]) == _bits(state[0])).all()
+    assert (_bits(s1[1][~keep]) == _bits(state[1][~keep])).all()
+    assert not y1[~keep].any()
+    # the live slots' state
+    if kind == "random":
+        np.testing.assert_allclose(s1[1][keep], s0[1][keep],
+                                   atol=1e-5, rtol=1e-6)
+    else:
+        assert (_bits(s1[1][keep]) == _bits(s0[1][keep])).all()
+    # the live slots' output
+    terms = np.abs(s1[1].astype(np.float64) * np.asarray(C)[:, None, None, :])
+    bound = 2 * (Ns + 1) * 2.0 ** -24 * terms.sum(-1)
+    assert (np.abs(y1 - y0)[keep] <= bound[keep]).all()
+    if kind == "exact":
+        assert terms[keep].sum(-1).max(initial=0) < 2 ** 11    # 24 bits do
+        assert (_bits(y1[keep]) == _bits(y0[keep])).all()
+    if kind == "wide" and keep.any():
+        lo, hi = terms[keep].min(-1), terms[keep].max(-1)
+        assert (lo < 2.0 ** -12).any() and (hi > 2.0 ** 17).any()
 
 
 # ---- config gates ----

@@ -557,24 +557,93 @@ def _abstract_granite(A):
     return cfg, place(params), place(ck), place(cv)
 
 
-def test_mamba2_decode_kernel_compiles(topo):
+def _named(hlo: str, part: str):
+    """The instruction lines of an optimised module whose instruction's
+    name holds ``part``: the names a profiler capture shows, which is how
+    benchmark/layer_metrics/_ssm.py finds the state kernel's calls."""
+    import re
+
+    return [ln for ln in hlo.splitlines()
+            if re.match(rf"\s*(?:ROOT )?%[\w.\-]*{part}[\w.\-]* = ", ln)]
+
+
+@pytest.fixture(scope="module")
+def granite_decode(topo):
+    """(cfg, ck, compiled): engine_decode at the cell's size with donated
+    caches, compiled once for the tests that read it."""
+    from localai_tpu.models import granite_hybrid as gh
+
+    A = _on(SingleDeviceSharding(topo.devices[0]))
+    cfg, params, ck, cv = _abstract_granite(A)
+
+    def decode(p, t, ln, act, ck, cv):
+        return gh.engine_decode(p, cfg, t, ln, act, ck, cv)
+
+    return cfg, ck, jax.jit(decode, donate_argnums=(4, 5)).lower(
+        params, A((S_G,), jnp.int32), A((S_G,), jnp.int32),
+        A((S_G,), jnp.bool_), ck, cv).compile()
+
+
+def test_mamba2_decode_kernel_compiles(topo, monkeypatch, granite_decode):
     """The in-place state update at the cell's shape: 36 x 48 slots x 64
     heads x 64 x 128 float32, the layer traced, a slot's whole state a
-    program (8.4 MB of VMEM double-buffered)."""
-    from localai_tpu.ops.pallas.mamba2_decode import mamba2_decode_pallas
+    program (8.4 MB of VMEM double-buffered). And what the benchmark's
+    mamba2_decode_roofline stands on: the state goes through ONE custom
+    call whose name holds ``mamba2_decode``, aliased onto itself, and the
+    decode program has one such call a run of mamba layers and no other
+    instruction of that name (a second one, or state traffic moved out of
+    the kernel, is how a sound change reads over 100%)."""
+    import re
+
+    from localai_tpu.ops.pallas import mamba2_decode as md
 
     A = _on(SingleDeviceSharding(topo.devices[0]))
     f32 = jnp.float32
     H, P_, N = 64, 64, 128
-    compiled = jax.jit(mamba2_decode_pallas, donate_argnums=(0,)).lower(
-        A((36, S_G, H, P_, N), f32), A((), jnp.int32), A((S_G, H, P_), f32),
-        A((S_G, H), f32), A((S_G, H), f32), A((S_G, N), f32),
-        A((S_G, N), f32), A((S_G,), jnp.bool_)).compile()
+
+    def compile_kernel():
+        # a function of its own each time: a patched limit is traced anew
+        return jax.jit(lambda *a: md.mamba2_decode_pallas(*a),
+                       donate_argnums=(0,)).lower(
+            A((36, S_G, H, P_, N), f32), A((), jnp.int32),
+            A((S_G, H, P_), f32), A((S_G, H), f32), A((S_G, H), f32),
+            A((S_G, N), f32), A((S_G, N), f32),
+            A((S_G,), jnp.bool_)).compile()
+
+    compiled = compile_kernel()
     # aliased onto its input: nothing the size of a slot's state is made
-    assert compiled.memory_analysis().temp_size_in_bytes < H * P_ * N * 4
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < H * P_ * N * 4
+    assert mem.alias_size_in_bytes == 36 * S_G * H * P_ * N * 4
+    hlo = compiled.as_text()
+    calls = _named(hlo, "mamba2_decode")
+    assert len(calls) == 1 and " custom-call(" in calls[0], calls
+    # operand 3 is the state (0-2 the scalar-prefetch arguments)
+    assert "output_to_operand_aliasing={{1}: (3, {})}" in calls[0]
+
+    # the decode program: one call a run of mamba layers (the layer scan
+    # runs each run's body once a layer), nothing else of that name
+    cfg, _ck, decode = granite_decode
+    runs = sum(k == "mamba" and (i == 0 or cfg.period[i - 1] != "mamba")
+               for i, k in enumerate(cfg.period))
+    calls = _named(decode.as_text(), "mamba2_decode")
+    assert runs and len(calls) == runs, calls
+    assert all(" custom-call(" in c and "output_to_operand_aliasing" in c
+               for c in calls), calls
+
+    # VMEM: under a limit nothing fits, the compiler says what it needs
+    limit = md._VMEM_LIMIT
+    monkeypatch.setattr(md, "_VMEM_LIMIT", 1 << 20)
+    with pytest.raises(Exception, match="vmem") as refused:
+        compile_kernel()
+    need = re.search(r"Scoped allocation with size ([\d.]+)M",
+                     str(refused.value))
+    # two blocks in, two out, and under a megabyte of operands
+    assert need and 8 << 20 <= float(need.group(1)) * 2 ** 20 < 10 << 20
+    assert 10 << 20 < limit
 
 
-def test_granite_decode_step_compiles_in_place(topo):
+def test_granite_decode_step_compiles_in_place(granite_decode):
     """engine_decode at the cell's size with donated caches: the state
     kernel on the stacked state, the paged kernel on a pool whose heads are
     padded from 64 to 128 columns. The program's temporaries are a few
@@ -585,20 +654,10 @@ def test_granite_decode_step_compiles_in_place(topo):
     import math
     import re
 
-    from localai_tpu.models import granite_hybrid as gh
-
-    A = _on(SingleDeviceSharding(topo.devices[0]))
-    cfg, params, ck, cv = _abstract_granite(A)
+    cfg, ck, compiled = granite_decode
     assert ck["pages"].shape == (4, POOL_G, PAGE, 8, 128)
     assert ck["ssm"].shape == (36, S_G, 64, 64, 128)
     assert llama.decode_attn_impl(cfg.attn_cfg, ck) == "pallas:paged_decode"
-
-    def decode(p, t, ln, act, ck, cv):
-        return gh.engine_decode(p, cfg, t, ln, act, ck, cv)
-
-    compiled = jax.jit(decode, donate_argnums=(4, 5)).lower(
-        params, A((S_G,), jnp.int32), A((S_G,), jnp.int32),
-        A((S_G,), jnp.bool_), ck, cv).compile()
     hlo = compiled.as_text()
     assert "mamba2_decode" in hlo and "paged_decode" in hlo
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
