@@ -155,6 +155,9 @@ def make_cors_middleware(allow_origins: str = "*"):
     return cors_middleware
 
 
+EXECUTOR_WORKERS = 256
+
+
 class AppState:
     """Shared server state hung off the aiohttp app."""
 
@@ -162,7 +165,13 @@ class AppState:
         self.caps = caps
         self.config = app_config
         self.gallery_service = gallery_service
-        self.executor = ThreadPoolExecutor(max_workers=64, thread_name_prefix="cap")
+        # one worker a streamed request for its whole life: as many as the
+        # runner's gRPC pool gives a model of many slots
+        # (backend/runner.py::RPC_WORKERS_MANY_SLOTS), so that the engine's
+        # queue, and not this pool's, is where a request waits (threads
+        # start on demand)
+        self.executor = ThreadPoolExecutor(max_workers=EXECUTOR_WORKERS,
+                                           thread_name_prefix="cap")
         self.started_at = time.time()
 
     async def run_blocking(self, fn, *args, **kwargs):

@@ -36,7 +36,8 @@ _LLAMA_TYPES = frozenset({"llama", "mistral", "qwen2", "qwen", "gemma",
 _FAMILIES = {"mamba": ("mamba", "MambaConfig"),
              "rwkv": ("rwkv", "RwkvConfig"),
              "olmo_hybrid": ("olmo_hybrid", "OlmoHybridConfig"),
-             "granitemoehybrid": ("granite_hybrid", "GraniteHybridConfig")}
+             "granitemoehybrid": ("granite_hybrid", "GraniteHybridConfig"),
+             "lfm2_moe": ("lfm2_moe", "Lfm2MoeConfig")}
 
 # Threads of the runner's gRPC server. A streaming request holds one for
 # its whole life (waiting on the engine's queue, then on its tokens), so
@@ -216,8 +217,8 @@ class EngineServicer(BackendServicer):
                 # engine slots through the family adapter: mamba / rwkv
                 # with a fixed-size recurrent state in the cache lanes
                 # (reference: backend/python/mamba, backend/go/llm/rwkv),
-                # olmo_hybrid and granite_hybrid with paged K/V and a
-                # recurrent state
+                # olmo_hybrid, granite_hybrid and lfm2_moe with paged K/V
+                # and a recurrent state
                 import importlib
 
                 module, cfg_class = _FAMILIES[mtype]

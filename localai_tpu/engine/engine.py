@@ -480,7 +480,7 @@ class _Burst:
                  "t_ready", "pack_np", "ids_np", "lps_np", "first_ids",
                  "first_lps", "folded", "skip_slots", "ready", "err",
                  "head", "spec_mask", "spec_width", "n_out_np",
-                 "drafted_np", "spec_greedy")
+                 "drafted_np", "spec_greedy", "experts_touched")
 
     def __init__(self, n_steps, slots, pack, group=(), t_dispatch=0.0,
                  head=None):
@@ -494,6 +494,9 @@ class _Burst:
         self.n_out_np = None        # [R, S] per-round emit counts
         self.drafted_np = None      # [R, S] bool: the row had a draft
         self.spec_greedy = None     # [S] dispatch-time greedy snapshot
+        # distinct experts touched, summed over steps and expert layers
+        # (a family that reports its routing; _fold_burst)
+        self.experts_touched = None
         self.group = list(group)    # fused-admission slots (subset of slots)
         # early-emit split: the _PendingPrefill head this burst is
         # chained off on-device. The sync worker readies the head FIRST
@@ -525,9 +528,10 @@ class _PendingPrefill:
     decode bursts."""
     __slots__ = ("group", "out_ids", "logprobs", "mu_out", "t0",
                  "t_ready", "ids_np", "lps_np", "mu_np", "ready", "err",
-                 "split", "processed")
+                 "split", "processed", "routed")
 
-    def __init__(self, group, out_ids, logprobs, mu_out, t0, split=False):
+    def __init__(self, group, out_ids, logprobs, mu_out, t0, split=False,
+                 routed=False):
         self.group = group
         self.out_ids = out_ids
         self.logprobs = logprobs
@@ -544,6 +548,9 @@ class _PendingPrefill:
         # block-syncs the burst past a not-yet-processed head.
         self.split = split
         self.processed = False
+        # a packed prefill of a family that reports its routing: the
+        # pack's route stats follow the segments' logprobs
+        self.routed = routed
 
 
 class _PendingOffload:
@@ -711,6 +718,20 @@ class Engine:
                 model_cfg, attn=llama.attn_target(model_cfg, mesh))
         self._fam_name = getattr(self.family, "__name__",
                                  "llama").rsplit(".", 1)[-1]
+        # "route_stats": the family's steps report their routing (a routed
+        # expert feed-forward: ops/moe.py). The numbers ride the
+        # results a dispatch brings back anyway - a burst's pack, a prefill
+        # pack's logprobs - and are folded into /debug/state's "moe"
+        # (its stats: per expert layer the pairs each of E experts got,
+        # then the experts touched: [L, E + 1], flat)
+        L_r, E_r = self.family.route_stats_shape(model_cfg) \
+            if "route_stats" in caps else (0, 0)
+        self._n_route = L_r * (E_r + 1)
+        self._route_rows_n = -(-self._n_route // self.ecfg.num_slots)
+        self._moe = {k: {"steps": 0,
+                         "experts_touched": np.zeros((L_r,), np.int64),
+                         "pairs": np.zeros((L_r, E_r), np.int64)}
+                     for k in ("decode", "prefill")} if self._n_route else None
         assert mesh is None or "mesh" in caps, \
             f"a mesh is not declared by {self._fam_name}"
         assert draft is None or "speculation" in caps, \
@@ -2442,6 +2463,44 @@ class Engine:
 
     # ---------- jitted step bodies ----------
 
+    def _family_step(self, fn, *a, **kw):
+        """``fn`` (the family's engine_decode or ragged_prefill) -> (logits,
+        ck, cv, route stats [n] float32, or None for a family that reports
+        none)."""
+        if self._n_route:
+            return fn(*a, **kw, route_stats=True)
+        return (*fn(*a, **kw), None)
+
+    def _route_rows(self, stats):
+        """Route stats [n] as whole rows of a burst's [*, S] pack."""
+        S, rows = self.ecfg.num_slots, self._route_rows_n
+        return jnp.pad(stats, (0, rows * S - self._n_route)).reshape(rows, S)
+
+    def _fold_route(self, kind: str, flat, steps: int) -> int:
+        """Fold the route stats a dispatch brought back (``flat``: the sum
+        over its ``steps`` decode steps, or one prefill pack's) into
+        /debug/state's "moe"; -> the experts touched, summed over steps and
+        layers."""
+        c = self._moe[kind]
+        st = np.rint(flat[:self._n_route]).astype(np.int64).reshape(
+            len(c["pairs"]), -1)
+        c["steps"] += steps
+        c["pairs"] += st[:, :-1]
+        c["experts_touched"] += st[:, -1]
+        return int(st[:, -1].sum())
+
+    def _moe_snapshot(self) -> dict:
+        """/debug/state's "moe", since start: for the decode steps and for
+        the prefill packs apart, how many there were (``steps``), an expert
+        layer the distinct experts touched summed over them
+        (``experts_touched`` [L_moe]) and the (row, expert) pairs each
+        expert got (``pairs`` [L_moe][E])."""
+        return {"experts": self._moe["decode"]["pairs"].shape[1],
+                **{k: {"steps": c["steps"],
+                       "experts_touched": c["experts_touched"].tolist(),
+                       "pairs": c["pairs"].tolist()}
+                   for k, c in self._moe.items()}}
+
     def _compose_overrides(self, tokens, lengths, ring, ring_pos, mu, ov_pack):
         """Merge host override rows (ONE packed [7+RING_N, S] f32 upload:
         mask, tokens, lengths, ring_pos, mu, pos_offset, win_delta,
@@ -2487,16 +2546,20 @@ class Engine:
         step = self._make_scan_step(params, slot_params, bias, active, flags,
                                     pos_offset)
         carry = (tokens, ck, cv, lengths, ring, ring_pos, keys, mu)
-        carry, (ids_all, lps_all) = jax.lax.scan(step, carry, None, length=n_steps)
+        carry, (ids_all, lps_all, route) = jax.lax.scan(
+            step, carry, None, length=n_steps)
         tokens, ck, cv, lengths, ring, ring_pos, keys, mu = carry
         # tokens/lengths/ring/mu are returned as DEVICE handles so the next
         # burst can chain off them without a host round-trip (pipelined
         # decode). Everything the host needs (ids, logprobs, post-burst mu)
         # is PACKED into one [2K+1, S] float32 array: one device->host
         # transfer per burst instead of three tiny ones.
-        # float32 holds token ids exactly (vocab << 2^24).
+        # float32 holds token ids exactly (vocab << 2^24). A family that
+        # reports its routing adds the burst's sum as further rows.
         pack = jnp.concatenate(
-            [ids_all.astype(jnp.float32), lps_all, mu[None, :]], axis=0)
+            [ids_all.astype(jnp.float32), lps_all, mu[None, :]]
+            + ([] if route is None
+               else [self._route_rows(route.sum(axis=0))]), axis=0)
         return pack, ck, cv, keys, self._pin_chain(
             tokens, lengths, ring, ring_pos, mu)
 
@@ -2511,7 +2574,8 @@ class Engine:
 
         def step(carry, _):
             tokens, ck, cv, lengths, ring, ring_pos, keys, mu = carry
-            logits, ck, cv = self.family.engine_decode(
+            logits, ck, cv, route = self._family_step(
+                self.family.engine_decode,
                 params, self.cfg, tokens, lengths, active, ck, cv,
                 pos_offset=pos_offset)
             ids, logprobs, new_keys, new_mu = sampling.sample(
@@ -2523,7 +2587,8 @@ class Engine:
             ring, ring_pos = sampling.update_ring(ring, ring_pos, ids, active)
             lengths = lengths + active.astype(jnp.int32)
             tokens = jnp.where(active, ids, tokens)
-            return (tokens, ck, cv, lengths, ring, ring_pos, keys, mu), (ids, logprobs)
+            return ((tokens, ck, cv, lengths, ring, ring_pos, keys, mu),
+                    (ids, logprobs, route))
 
         return step
 
@@ -2587,8 +2652,8 @@ class Engine:
         step = self._make_scan_step(params, slot_params, bias, active,
                                     (True, True, True), pos_offset)
         carry = (tokens, ck, cv, lengths, ring, ring_pos, keys, mu)
-        carry, (ids_all, lps_all) = jax.lax.scan(step, carry, None,
-                                                 length=n_steps)
+        carry, (ids_all, lps_all, _route) = jax.lax.scan(step, carry, None,
+                                                         length=n_steps)
         tokens, ck, cv, lengths, ring, ring_pos, keys, mu = carry
         S = self.ecfg.num_slots
         first_ids = jnp.zeros((S,), jnp.float32).at[p_slots].set(
@@ -2654,7 +2719,8 @@ class Engine:
         real non-final segment's gated write puts its OWN old value
         back (slots are unique per pack, so the scatter stays
         well-defined)."""
-        logits, ck, cv = self.family.ragged_prefill(
+        logits, ck, cv, route = self._family_step(
+            self.family.ragged_prefill,
             params, self.cfg, tokens, positions, seg_of, seg_slots,
             seg_start, seg_off, seg_len, ck, cv, continued=continued,
             comm_overlap=self._comm_overlap)
@@ -2675,6 +2741,9 @@ class Engine:
             mode="drop")
         mu = jnp.asarray(mu).at[seg_slots].set(
             jnp.where(final_mask, new_mu, mu_rows), mode="drop")
+        if route is not None:
+            # the pack's routing rides home behind the segments' logprobs
+            logprobs = jnp.concatenate([logprobs, route])
         return ids, logprobs, ck, cv, keys, mu
 
     def _get_packed_fn(self, bucket: int, continued: bool):
@@ -2711,7 +2780,8 @@ class Engine:
             self._compose_overrides(tokens, lengths, ring, ring_pos, mu,
                                     ov_pack)
 
-        logits, ck, cv = self.family.ragged_prefill(
+        logits, ck, cv, route = self._family_step(
+            self.family.ragged_prefill,
             params, self.cfg, p_tokens, p_positions, seg_of, seg_slots,
             seg_start, seg_off, seg_len, ck, cv, continued=continued,
             comm_overlap=self._comm_overlap)
@@ -2743,6 +2813,8 @@ class Engine:
             jnp.where(gate, ids_f, ring[seg_slots, rcol]), mode="drop")
         ring_pos = ring_pos.at[seg_slots].set(
             jnp.where(gate, rpos_rows + 1, rpos_rows), mode="drop")
+        if route is not None:
+            lps_f = jnp.concatenate([lps_f, route])
         return (ids_f, lps_f, ck, cv, keys,
                 self._pin_chain(tokens, lengths, ring, ring_pos, mu))
 
@@ -3650,6 +3722,7 @@ class Engine:
             "recurrent_state_bytes": self._state_bytes,
             "kv_walk": dict(self._kv_walk),
             "state_walk": dict(self._state_walk),
+            **({} if self._moe is None else {"moe": self._moe_snapshot()}),
             **self._device,
             "device_mem": sysobs.device_memory_stats(),
             "attention": self._attention_report(),
@@ -5724,8 +5797,11 @@ class Engine:
             self.tracer.record("prefill_dispatch", "engine", t0, t1,
                                args={"tokens": total, "segments": len(segs),
                                      "bucket": bucket, "packed": True})
-        if group:
-            item = _PendingPrefill(group, out_ids, logprobs, mu_out, t0)
+        if group or self._n_route:
+            # (a pack with no final segment brings nothing back but its
+            # routing, where the family reports one)
+            item = _PendingPrefill(group, out_ids, logprobs, mu_out, t0,
+                                   routed=bool(self._n_route))
             self._fifo.append(item)
             self._sync_q.put(item)
         return True
@@ -5807,7 +5883,7 @@ class Engine:
         # smoke rig, where a jit call blocks for its own compute) the
         # wait is free — so TTFT stops paying for the decode half.
         head = _PendingPrefill(group_snaps, ids_f, lps_f, chain[4], t0,
-                               split=True)
+                               split=True, routed=bool(self._n_route))
         self._fifo.append(head)      # discoverable for the stall handler
         self._sync_q.put(head)
         self._wait_ready(head, t0)
@@ -5960,6 +6036,8 @@ class Engine:
             raise item.err
         if item.split:
             return self._process_split_head(item)
+        if item.routed:
+            self._fold_route("prefill", item.lps_np[-self._n_route:], 1)
         group = item.group
         ids_np, lps_np, mu_np, t0 = item.ids_np, item.lps_np, item.mu_np, item.t0
         # scatter ONLY the group's mu entries — and only where the slot
@@ -6028,6 +6106,8 @@ class Engine:
         if item.processed:
             return
         item.processed = True
+        if item.routed:
+            self._fold_route("prefill", item.lps_np[-self._n_route:], 1)
         group = item.group
         ids_np, lps_np, t0 = item.ids_np, item.lps_np, item.t0
         t1 = time.monotonic()
@@ -6720,6 +6800,11 @@ class Engine:
             raise b.err
         packed = b.pack_np                  # [2K+1(+2), S] f32
         K = b.n_steps
+        if self._n_route and (not b.group or b.head is not None):
+            # the plain burst's last rows: its route stats, summed over
+            # its steps (a fused per-slot admission carries none)
+            b.experts_touched = self._fold_route(
+                "decode", packed[-self._route_rows_n:].reshape(-1), K)
         if b.spec_width:
             # spec tick pack: ids/lps are [R*W, S] round-major, then the
             # [R, S] per-round emit counts and drafted bits, then mu
@@ -6870,7 +6955,9 @@ class Engine:
                                 "fused": bool(b.group),
                                 "spec": bool(b.spec_width),
                                 "slot_ids": [i for i, _ in live],
-                                "rids": [r for _, r in live]})
+                                "rids": [r for _, r in live],
+                                **({} if b.experts_touched is None else
+                                   {"experts_touched": b.experts_touched})})
                 if b.spec_width:
                     # the fused program has no host-visible boundary
                     # between drafting and verifying: on the device they

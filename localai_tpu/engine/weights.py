@@ -16,6 +16,7 @@ import logging
 import os
 import threading
 import time
+from functools import partial
 from typing import Optional
 
 import jax
@@ -71,7 +72,9 @@ _QUANT_NAMES = {"embed", "lm_head", "wq", "wk", "wv", "wo",
                 # models/olmo_hybrid.py's linear-attention projections
                 "lin_qkv", "lin_g", "lin_o",
                 # models/granite_hybrid.py's mamba projections
-                "ssm_in_z", "ssm_in_xbc", "ssm_out"}
+                "ssm_in_z", "ssm_in_xbc", "ssm_out",
+                # models/lfm2_moe.py's convolution projections
+                "conv_in", "conv_out"}
 
 
 def _make_put(cfg, mesh, dtype, quantize, adapter=None, pace=None,
@@ -439,6 +442,124 @@ def load_granite_hybrid_params(model_dir: str, cfg, dtype=jnp.bfloat16,
     return _assemble(_granite_hybrid_leaf_source(model_dir, cfg),
                      _make_put(cfg, None, dtype, quantize, tracer=tracer),
                      tracer)
+
+
+def _lfm2_moe_leaf_source(model_dir: str, cfg):
+    """(spec_path, host array) for models/lfm2_moe.py's stacked layout, all
+    but the expert stacks: every leaf stacked over the layers that hold
+    one (conv layers, attention layers, dense layers, expert layers, every
+    layer). Linear weights become ``[in, out]``; the depthwise convolution
+    ``[D, 1, W]`` becomes ``[W, D]``; the router and its bias stay
+    float32. The head is tied: no ``lm_head.weight``."""
+    tensors = _open_shards(model_dir)
+    nd = cfg.num_dense_layers
+
+    def top(name: str) -> np.ndarray:
+        return tensors[name].get_tensor(name)
+
+    def stacked(which, one) -> np.ndarray:
+        return np.stack([one(i) for i in which])
+
+    def get(name: str, transpose=False, dtype=None):
+        def one(i):
+            a = top(f"model.layers.{i}.{name}")
+            a = a.T if transpose else a
+            return a.astype(dtype) if dtype else a
+        return one
+
+    every = range(cfg.num_layers)
+    conv = [i for i, k in enumerate(cfg.kinds) if k == "conv"]
+    attn = [i for i, k in enumerate(cfg.kinds) if k == "attention"]
+    dense, routed = range(nd), range(nd, cfg.num_layers)
+    yield ("embed",), top("model.embed_tokens.weight")
+    yield ("layers", "op_norm"), stacked(every, get("operator_norm.weight"))
+    yield ("layers", "ff_norm"), stacked(every, get("ffn_norm.weight"))
+    yield ("layers", "conv_in"), stacked(
+        conv, get("conv.in_proj.weight", True))
+    yield ("layers", "conv_w"), stacked(
+        conv, lambda i: top(f"model.layers.{i}.conv.conv.weight")[:, 0, :].T)
+    yield ("layers", "conv_out"), stacked(
+        conv, get("conv.out_proj.weight", True))
+    for leaf, name in (("wq", "q_proj"), ("wk", "k_proj"), ("wv", "v_proj"),
+                       ("wo", "out_proj")):
+        yield ("layers", leaf), stacked(
+            attn, get(f"self_attn.{name}.weight", True))
+    yield ("layers", "q_norm"), stacked(
+        attn, get("self_attn.q_layernorm.weight"))
+    yield ("layers", "k_norm"), stacked(
+        attn, get("self_attn.k_layernorm.weight"))
+    for leaf, name in (("w_gate", "w1"), ("w_up", "w3"), ("w_down", "w2")):
+        yield ("layers", leaf), stacked(
+            dense, get(f"feed_forward.{name}.weight", True))
+    yield ("layers", "router"), stacked(
+        routed, get("feed_forward.gate.weight", True, np.float32))
+    yield ("layers", "expert_bias"), stacked(
+        routed, get("feed_forward.expert_bias", False, np.float32))
+    yield ("final_norm",), top("model.embedding_norm.weight")
+
+
+def _lfm2_moe_expert_stack(tensors, cfg, name: str, dtype, span):
+    """One projection of every expert of every expert layer as ONE device
+    leaf ``[L_moe, E, in, out]``, streamed a layer at a time: the host
+    holds one layer's 64 tensors (HF ``[out, in]``), the device casts and
+    transposes them into the leaf in place (the leaf is donated to its own
+    update), so neither side ever holds a second copy of the stack."""
+    E, nd = cfg.num_experts, cfg.num_dense_layers
+
+    @partial(jax.jit, donate_argnums=0)
+    def set_layer(leaf, rows, mi):
+        return jax.lax.dynamic_update_index_in_dim(
+            leaf, rows.astype(dtype).swapaxes(-1, -2), mi, 0)
+
+    leaf = None
+    for mi in range(cfg.moe_layers):
+        with span("load_source", "load", leaf=name):
+            rows = np.stack([tensors[n].get_tensor(n) for n in (
+                f"model.layers.{nd + mi}.feed_forward.experts.{e}."
+                f"{name}.weight" for e in range(E))])
+        with span("load_cast", "load", leaf=name):
+            rows = jnp.asarray(rows)
+            if leaf is None:
+                leaf = jnp.zeros((cfg.moe_layers, E) + rows.shape[:0:-1],
+                                 dtype)
+        with span("load_put", "load", leaf=name):
+            leaf = set_layer(leaf, rows, mi)
+        del rows
+    return leaf
+
+
+def load_lfm2_moe_params(model_dir: str, cfg, dtype=jnp.bfloat16,
+                         quantize: str = "", tracer=None) -> dict:
+    """Load an ``lfm2_moe`` checkpoint (HF safetensors): every leaf but
+    the expert stacks through the cast / placement path of
+    ``load_llama_params``, the three expert stacks a layer at a time
+    (``_lfm2_moe_expert_stack``). No mesh: the family refuses one.
+    ``quantize="int8"`` takes the leaves ops/quant.py's quantizer takes (a
+    whole host leaf: the operators' projections, the dense feed-forwards,
+    the embedding); the expert stacks, nine tenths of the bytes, stay in
+    ``dtype``: quantized expert leaves are not built."""
+    if quantize not in ("", "int8"):
+        raise ValueError(f"quantization={quantize!r} is not supported for "
+                         "lfm2_moe (only weight-only int8)")
+    if quantize:
+        log.warning("lfm2_moe: quantization=int8 leaves the expert stacks "
+                    "in %s (quantized expert leaves are not built)",
+                    jnp.dtype(dtype).name)
+    span = (tracer or NO_TRACER).span
+    cast = _make_put(cfg, None, dtype, quantize, tracer=tracer)
+
+    def put(arr, spec_path):
+        # the router computes its scores in float32: its leaves are not cast
+        if spec_path[-1] in ("router", "expert_bias"):
+            return jnp.asarray(arr, jnp.float32)
+        return cast(arr, spec_path)
+
+    params = _assemble(_lfm2_moe_leaf_source(model_dir, cfg), put, tracer)
+    tensors = _open_shards(model_dir)
+    for name in ("w1", "w3", "w2"):
+        params["layers"][name] = _lfm2_moe_expert_stack(
+            tensors, cfg, name, dtype, span)
+    return params
 
 
 def stream_llama_params(

@@ -63,8 +63,8 @@ import numpy as np
 
 from localai_tpu.models import llama
 from localai_tpu.models.hybrid_common import (new_tails, packed_conv,
-                                              prefill_as_pack, scan_periods,
-                                              unembed)
+                                              prefill_as_pack,
+                                              scan_layer_runs, unembed)
 from localai_tpu.models.llama import AttnTarget, _embed_rows, _mat, _mlp
 from localai_tpu.ops import kvcache, ssd
 from localai_tpu.ops.norms import rms_norm
@@ -173,7 +173,8 @@ class GraniteHybridConfig:
                 f"{cfg.get('num_local_experts')} asks for a routed expert "
                 "(mixture-of-experts) feed-forward, which is not built for "
                 "this family: only the dense siblings (num_local_experts 0, "
-                "the shared MLP alone) are served")
+                "the shared MLP alone) are served (the layer such a sibling "
+                "would call is ops/moe.py)")
         kinds = tuple(cfg["layer_types"])[:L]
         if len(kinds) != L or set(kinds) - {"mamba", "attention"}:
             raise ValueError("granitemoehybrid: layer_types must name "
@@ -405,40 +406,12 @@ def _embed(params, tokens, cfg):
 
 
 def _scan_layers(cfg, carry, layer_fns):
-    """The layers over ``carry``: a scan over the periods, and inside a
-    period one scan over each RUN of layers of one kind (as published:
-    five mamba layers, the attention layer inline, four mamba layers), so
-    that a program holds a layer's body once a run and not once a layer.
-    Traced ten layers to the period, the cell's 25 programs took 240 s of
-    tracing and lowering at every start-up, whatever the compile cache
-    held (PERF.md section 6, PR 36). ``layer_fns[kind](carry, ki, i)``
-    runs layer ``i`` (traced), the ``ki``-th of its kind."""
-    n = len(cfg.period)
-    per = {"mamba": cfg.ssm_per_period, "attention": cfg.attn_per_period}
-    runs, seen = [], {"mamba": 0, "attention": 0}
-    for j, kind in enumerate(cfg.period):
-        if runs and runs[-1][0] == kind:
-            runs[-1][3] += 1
-        else:
-            runs.append([kind, seen[kind], j, 1])   # kind, first of kind, at
-        seen[kind] += 1
-
-    def period_fn(carry, p):
-        for kind, k0, j0, count in runs:
-            fn = layer_fns[kind]
-
-            def one(c, r, fn=fn, k0=k0, j0=j0, kind=kind):
-                return fn(c, per[kind] * p + k0 + r, n * p + j0 + r)
-
-            if count == 1:
-                carry = one(carry, 0)
-            else:
-                carry = jax.lax.scan(
-                    lambda c, r, one=one: (one(c, r), None), carry,
-                    jnp.arange(count, dtype=jnp.int32))[0]
-        return carry
-
-    return scan_periods(cfg, period_fn, carry)
+    """The layers over ``carry``: a scan over the periods and, inside one,
+    over each run of layers of one kind (as published: five mamba layers,
+    the attention layer inline, four mamba layers).
+    ``layer_fns[kind](carry, ki, i)`` runs layer ``i`` (traced), the
+    ``ki``-th of its kind."""
+    return scan_layer_runs(cfg.period * cfg.periods, carry, layer_fns)
 
 
 def mamba2_decode(cfg, state, li, xs, dt, la, B, C, active):

@@ -1,5 +1,6 @@
 """What the hybrid families share (``olmo_hybrid``: gated delta rule,
-``granite_hybrid``: Mamba-2): the scan over periods, the head, and the
+``granite_hybrid``: Mamba-2, ``lfm2_moe``: gated short convolution): the
+scan over periods and over runs of same-kind layers, the head, and the
 causal depthwise convolution over a PACK of segments with each slot's tail
 carried in and out. A family imports these; none imports another family."""
 
@@ -23,6 +24,70 @@ def scan_periods(cfg, period_fn, carry):
     the stacked leaves by layer index (each family's ``_layer``)."""
     return jax.lax.scan(lambda c, p: (period_fn(c, p), None), carry,
                         jnp.arange(cfg.periods, dtype=jnp.int32))[0]
+
+
+def _runs(kinds):
+    """[(kind, its index among the layers of its kind in ``kinds``, its
+    position in ``kinds``, count)] a run of consecutive layers of one
+    kind."""
+    runs, seen = [], {}
+    for j, kind in enumerate(kinds):
+        if runs and runs[-1][0] == kind:
+            runs[-1][3] += 1
+        else:
+            runs.append([kind, seen.get(kind, 0), j, 1])
+        seen[kind] = seen.get(kind, 0) + 1
+    return runs
+
+
+def scan_layer_runs(kinds, carry, layer_fns, lead: int = 0):
+    """The layers ``kinds`` (one kind name a layer) over ``carry``. Past
+    the first ``lead`` layers the list is taken as PERIODS (the shortest
+    prefix whose repetition gives it, the last one possibly cut short): a
+    scan over the whole periods, and inside a period - as among the leading
+    layers and in a cut last period - one scan over each RUN of layers of
+    one kind, so that a program holds a layer's body once a run and not
+    once a layer. Traced ten layers to the period, one cell's 25 programs
+    took 240 s of tracing and lowering at every start-up, whatever the
+    compile cache held (PERF.md section 6, PR 36). ``layer_fns[kind](carry,
+    ki, i)`` runs layer ``i`` (traced), the ``ki``-th of its kind."""
+    kinds = tuple(kinds)
+    body = kinds[lead:]
+    n = next((p for p in range(1, len(body) + 1)
+              if all(body[j] == body[j % p] for j in range(len(body)))), 1)
+    periods = len(body) // n
+    if periods < 2:
+        n, periods = len(body), 1
+    period, rest = body[:n], body[n * periods:]
+
+    def run_all(carry, some, k_base, j_base):
+        for kind, k0, j0, count in _runs(some):
+            def one(c, r, fn=layer_fns[kind], k=k_base(kind) + k0,
+                    j=j_base + j0):
+                return fn(c, k + r, j + r)
+
+            if count == 1:
+                carry = one(carry, 0)
+            else:
+                carry = jax.lax.scan(
+                    lambda c, r, one=one: (one(c, r), None), carry,
+                    jnp.arange(count, dtype=jnp.int32))[0]
+        return carry
+
+    carry = run_all(carry, kinds[:lead], lambda kind: 0, 0)
+    before = {k: kinds[:lead].count(k) for k in set(kinds)}
+    if periods > 1:
+        carry = jax.lax.scan(
+            lambda c, p: (run_all(
+                c, period, lambda kind: before[kind] + period.count(kind) * p,
+                lead + n * p), None),
+            carry, jnp.arange(periods, dtype=jnp.int32))[0]
+    else:
+        carry = run_all(carry, period, lambda kind: before[kind], lead)
+    done = lead + n * periods
+    return run_all(carry, rest,
+                   lambda kind: before[kind] + period.count(kind) * periods,
+                   done)
 
 
 def _prev_inputs(pre, init, seg, j, d: int):

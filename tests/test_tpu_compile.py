@@ -694,3 +694,80 @@ def test_granite_packed_prefill_compiles(topo, N):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 512 << 20
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
+
+
+# ---------- lfm2_moe: expert stacks of 1.6 GB a projection, never copied ----------
+
+S_L, C_L, POOL_L = 64, 2048, 1536    # lfm2-24b-a2b.longgen_wide's geometry
+
+
+def _abstract_lfm2(A):
+    """(cfg, params, ck, cv): the first ten layers of LFM2-24B-A2B at its
+    published widths, all 64 experts, bf16 weights, the cell's slots and
+    page pool, as ShapeDtypeStructs."""
+    from localai_tpu.models import lfm2_moe as lm
+
+    cfg = lm.Lfm2MoeConfig(
+        num_layers=10, kinds=("conv", "conv")
+        + ("attention", "conv", "conv", "conv") * 2,
+        attn=llama.AttnTarget(pallas=True))
+
+    def place(tree):
+        return jax.tree.map(lambda x: A(x.shape, x.dtype), tree)
+
+    params = jax.eval_shape(
+        lambda: lm.init_params(cfg, jax.random.PRNGKey(0)))
+    ck, cv = jax.eval_shape(lambda: lm.init_cache(
+        cfg, S_L, C_L, jnp.bfloat16, page_size=PAGE, num_pages=POOL_L))
+    return cfg, place(params), place(ck), place(cv)
+
+
+def test_lfm2_decode_step_compiles_without_a_copy_of_an_expert_layer(topo):
+    """engine_decode at the cell's size with donated caches, routing
+    counters and all: 10.5 GB of arguments, and temporaries far under the
+    0.4 GB that ONE projection of one layer's 64 experts is (a layer
+    sliced out of the stack for the grouped product was such a copy)."""
+    from localai_tpu.models import lfm2_moe as lm
+
+    A = _on(SingleDeviceSharding(topo.devices[0]))
+    cfg, params, ck, cv = _abstract_lfm2(A)
+    assert params["layers"]["w1"].shape == (8, 64, 2048, 1536)
+    assert ck["pages"].shape == (2, POOL_L, PAGE, 8, 128)
+    assert ck["conv"].shape == (8, S_L, 2, 2048)
+    assert llama.decode_attn_impl(cfg.attn_cfg, ck) == "pallas:paged_decode"
+
+    def decode(p, t, ln, act, ck, cv):
+        return lm.engine_decode(p, cfg, t, ln, act, ck, cv, route_stats=True)
+
+    compiled = jax.jit(decode, donate_argnums=(4, 5)).lower(
+        params, A((S_L,), jnp.int32), A((S_L,), jnp.int32),
+        A((S_L,), jnp.bool_), ck, cv).compile()
+    mem = compiled.memory_analysis()
+    assert 10.5e9 < mem.argument_size_in_bytes < 12.5e9
+    assert mem.temp_size_in_bytes < 64 << 20
+    assert "paged_decode" in compiled.as_text()
+
+
+@pytest.mark.parametrize("N", [512, 1024])
+def test_lfm2_packed_prefill_compiles(topo, N):
+    """A continued pack through the grouped expert form (4 N pairs sorted
+    by expert, three ragged products a layer over the whole stacks) and
+    the ragged prefill kernel."""
+    from localai_tpu.models import lfm2_moe as lm
+
+    A = _on(SingleDeviceSharding(topo.devices[0]))
+    cfg, params, ck, cv = _abstract_lfm2(A)
+    i32 = jnp.int32
+    assert llama.ragged_attn_impl(cfg.attn_cfg, ck, N, True) == \
+        "pallas:ragged_prefill"
+
+    def pack(p, t, pos, so, ss, st, off, ln, ck, cv):
+        return lm.ragged_prefill(p, cfg, t, pos, so, ss, st, off, ln, ck, cv,
+                                 continued=True, route_stats=True)
+
+    compiled = jax.jit(pack, donate_argnums=(8, 9)).lower(
+        params, A((N,), i32), A((N,), i32), A((N,), i32),
+        *_seg_tables(A, S_L), ck, cv).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 256 << 20
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
