@@ -86,10 +86,15 @@ def _make_put(cfg, mesh, dtype, quantize, adapter=None, pace=None,
 
     ``tracer`` (the runner's RingTracer) gets, per leaf, ``load_quantize``
     (ops/quant.py::quantize_weight*, which also hands the int8 array to
-    the device), ``load_cast`` (``jnp.asarray(arr, dtype)``: the cast and,
-    off a mesh, the hand-over to the device in one call) and ``load_put``
-    (the mesh placement). Host calls only: nothing here waits for the
-    device (the runner waits once, ``load_device_wait``)."""
+    the device), ``load_cast`` (ops/hostblocks.py::cast_leaf, then
+    ``jnp.asarray`` of the finished array: off a mesh, the hand-over to
+    the device) and ``load_put`` (the mesh placement). The first two carry
+    ``threads`` and ``blocks``: both passes run on the host, by blocks on
+    a pool of threads that lives for the leaf (1 and 1: a small leaf,
+    worked inline), and give the bits the one-thread whole-array form
+    gives. Each leaf's output is a fresh host array: the hand-over
+    may still be reading it when ``put`` returns, and nothing here waits
+    for the device (the runner waits once, ``load_device_wait``)."""
     span = (tracer or NO_TRACER).span
 
     def leaf_spec(spec_path: tuple):
@@ -130,14 +135,18 @@ def _make_put(cfg, mesh, dtype, quantize, adapter=None, pace=None,
                     if axis is not None:
                         divisor = mesh.shape[axis]
                 with span("load_quantize", "load", leaf=leaf_name,
-                          bits=4):
-                    leaf = quantize_weight_int4(arr, shard_divisor=divisor)
+                          bits=4) as sp:
+                    leaf = quantize_weight_int4(arr, shard_divisor=divisor,
+                                                ran=sp.args)
             else:
-                with span("load_quantize", "load", leaf=leaf_name, bits=8):
-                    leaf = quantize_weight(arr)
+                with span("load_quantize", "load", leaf=leaf_name,
+                          bits=8) as sp:
+                    leaf = quantize_weight(arr, ran=sp.args)
         else:
-            with span("load_cast", "load", leaf=leaf_name):
-                leaf = jnp.asarray(arr, dtype)
+            from localai_tpu.ops.hostblocks import cast_leaf
+
+            with span("load_cast", "load", leaf=leaf_name) as sp:
+                leaf = jnp.asarray(cast_leaf(arr, dtype, ran=sp.args), dtype)
         if mesh is not None:
             from jax.sharding import NamedSharding
             from localai_tpu.ops.quant import scale_spec
