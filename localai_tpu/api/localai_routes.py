@@ -203,7 +203,12 @@ _LATENCY_HISTOGRAMS = ("ttft_seconds", "itl_seconds",
 _LIFECYCLE_COUNTERS = (("requests_shed", "requests_shed_total"),
                        ("requests_timed_out", "requests_timed_out_total"),
                        ("stalls", "engine_stalls_total"),
-                       ("stall_dumps", "stall_dumps_total"))
+                       ("stall_dumps", "stall_dumps_total"),
+                       # dispatches that overran their own pace without
+                       # reaching the stall abort (engine.py LATE_FACTOR);
+                       # the seconds they were overdue are a gauge below
+                       # (set_counter truncates a float)
+                       ("late_dispatches", "late_dispatches_total"))
 # preemptive priority scheduler (ISSUE 10): preempt/resume totals +
 # per-class depth gauges, from engine metrics()["scheduler"]
 _SCHED_COUNTERS = (("preemptions", "preemptions_total"),
@@ -224,7 +229,11 @@ _SYSOBS_WATERMARKS = ("peak_queued", "peak_slots_active",
                       "peak_tokens_total", "peak_pool_active_pages",
                       "peak_pool_retained_pages", "peak_pool_pages_in_use",
                       "peak_host_offloaded_pages", "peak_host_bytes",
-                      "peak_device_bytes_in_use")
+                      "peak_device_bytes_in_use", "peak_host_rss_bytes")
+# the runner process's resident memory now and at its high-water mark:
+# engine sysobs.host_memory (services/sysobs.py::host_memory)
+_HOST_MEM_GAUGES = (("rss_bytes", "runner_rss_bytes"),
+                    ("rss_peak_bytes", "runner_rss_peak_bytes"))
 # device allocator stats: engine sysobs.device_mem, one entry per local
 # device -> localai_mem_device_<key>{device=}; no counters on CPU
 _DEVICE_MEM_GAUGES = ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
@@ -275,11 +284,13 @@ def _refresh_engine_metrics(state):
               *(f"kv_prefetch_{m}_total" for _k, m in _PREFETCH_COUNTERS),
               "kv_prefetch_inflight",
               *(m for _k, m in _LIFECYCLE_COUNTERS),
+              "late_dispatch_seconds_total",
               *(m for _k, m in _SCHED_COUNTERS),
               "queue_depth_class", "resume_queue_depth",
               *_SYSOBS_COUNTERS, *_SYSOBS_GAUGES,
               *(f"mem_{k}" for k in _SYSOBS_WATERMARKS),
               *(f"mem_device_{k}" for k in _DEVICE_MEM_GAUGES),
+              *(m for _k, m in _HOST_MEM_GAUGES),
               "slo_burn_rate", "slo_objective_ms", "slo_violations_total",
               "slo_error_budget", "flight_dumps_total",
               "flight_dumps_suppressed_total",
@@ -357,6 +368,9 @@ def _refresh_engine_metrics(state):
             for skey, mkey in _LIFECYCLE_COUNTERS:
                 METRICS.set_counter(mkey, lc.get(skey, 0),
                                     label_str(model=name))
+            METRICS.set_gauge("late_dispatch_seconds_total",
+                              lc.get("late_dispatch_s", 0.0),
+                              label_str(model=name))
         # preemptive priority scheduler (ISSUE 10): preempt/resume
         # totals + per-class queue depth (queued + parked-for-resume)
         sch = stats.get("scheduler")
@@ -487,6 +501,10 @@ def _refresh_engine_metrics(state):
                         METRICS.set_gauge(
                             f"mem_device_{key}", dm[key],
                             label_str(model=name, device=str(dm["id"])))
+            hm = so.get("host_memory") or {}
+            for key, metric in _HOST_MEM_GAUGES:
+                if key in hm:
+                    METRICS.set_gauge(metric, hm[key], label_str(model=name))
         # per-class SLO engine (ISSUE 12): burn-rate gauges + violation
         # counters per (priority class, metric); the flight recorder's
         # dump/suppression totals ride the same pull

@@ -25,6 +25,7 @@ import numpy as np
 from localai_tpu.backend import contract_pb2 as pb
 from localai_tpu.backend.service import (BackendServicer, RpcPool,
                                          make_server, parse_options)
+from localai_tpu.services import sysobs
 
 log = logging.getLogger("localai_tpu.backend.runner")
 
@@ -150,6 +151,7 @@ class EngineServicer(BackendServicer):
                 with self.tracer.span("load_model", "load",
                                       model=request.model):
                     self._load(request)
+                sysobs.mark_warm()
                 self._state = pb.StatusResponse.READY
                 # clock handshake (ISSUE 12): Result.message carries this
                 # process's wall/monotonic clocks and the tracer epoch so
@@ -183,6 +185,10 @@ class EngineServicer(BackendServicer):
             from localai_tpu.parallel import sharding as shardlib
 
             require_accelerator()
+            # hear compiles from here on, whatever thread runs them: the
+            # loader's own (before any Engine binds a tracker) are the
+            # process record's unowned ones
+            sysobs.install_listener()
         # the model's trace / trace_ring_size options, applied to the
         # process's ring before the load records into it
         extra = parse_options(request.options)
@@ -895,16 +901,12 @@ class EngineServicer(BackendServicer):
     # ---- observability ----
 
     def Status(self, request, context) -> pb.StatusResponse:
-        breakdown = {}
-        total = 0
-        try:
-            import resource
-
-            rss = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
-            breakdown["rss"] = rss
-            total = rss
-        except Exception:
-            pass
+        # what the process holds NOW is what /backend/monitor and the
+        # watchdog's memory reading compare; the peak rides beside it
+        hm = sysobs.host_memory()
+        breakdown = {k: hm[k + "_bytes"] for k in ("rss", "rss_peak")
+                     if k + "_bytes" in hm}
+        total = breakdown.get("rss", 0)
         state = self._state
         if state == pb.StatusResponse.READY and self.engine and self.engine.num_active > 0:
             state = pb.StatusResponse.BUSY

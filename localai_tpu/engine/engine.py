@@ -41,9 +41,12 @@ compilation model:
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import os
 import queue
+import resource
+import statistics
 import threading
 import time
 import uuid
@@ -460,6 +463,36 @@ class _DispatchStall(Exception):
         self.item = item
 
 
+# A dispatched item was LATE when it waited, from _overdue_ref to ready,
+# more than LATE_MIN_S and LATE_FACTOR times the median pace (seconds a
+# step) of its program kind's last PACE_ITEMS items. 3x: the slowest
+# healthy burst of any cell of the ledger is under 1.5x its median, the
+# stall of PERF.md section 6 (ROADMAP S13) 5-11x. 0.25 s: under it a
+# stream's reader sees no hiccup.
+LATE_FACTOR = 3.0
+LATE_MIN_S = 0.25
+PACE_ITEMS = 64
+
+_RU_NAMES = ("majflt", "minflt", "nvcsw", "nivcsw",
+             "proc_user_ms", "proc_sys_ms")
+
+
+def _wait_rusage():
+    """What a wait is charged with (_RU_NAMES): the calling thread's
+    faults and context switches, and the whole process's CPU time, user
+    and system - a wait in which the process burns system time is the
+    kernel's (memory handed back, page tables), not the device's."""
+    ru = resource.getrusage(resource.RUSAGE_THREAD)
+    cpu = resource.getrusage(resource.RUSAGE_SELF)
+    return (ru.ru_majflt, ru.ru_minflt, ru.ru_nvcsw, ru.ru_nivcsw,
+            cpu.ru_utime * 1e3, cpu.ru_stime * 1e3)
+
+
+def _riders(item) -> list:
+    """[(slot index, _Slot snapshot)] of a dispatched item."""
+    return item.slots if isinstance(item, _Burst) else item.group
+
+
 class _ReplicaDead(BaseException):
     """Chaos-only (ISSUE 14): the ``replica<N>_die`` fault kills this
     replica's loop thread the way a lost host would — BaseException so
@@ -480,7 +513,8 @@ class _Burst:
                  "t_ready", "pack_np", "ids_np", "lps_np", "first_ids",
                  "first_lps", "folded", "skip_slots", "ready", "err",
                  "head", "spec_mask", "spec_width", "n_out_np",
-                 "drafted_np", "spec_greedy", "experts_touched")
+                 "drafted_np", "spec_greedy", "experts_touched",
+                 "kind", "t_ref", "ru")
 
     def __init__(self, n_steps, slots, pack, group=(), t_dispatch=0.0,
                  head=None):
@@ -505,6 +539,10 @@ class _Burst:
         self.head = head
         self.t_dispatch = t_dispatch
         self.t_ready = 0.0          # sync-worker completion stamp
+        # pace record (_enqueue, _sync_worker, _note_ready): the program
+        # kind, the reference point the wait is counted from, the sync
+        # worker's rusage over the wait
+        self.kind, self.t_ref, self.ru = "", 0.0, None
         self.pack_np = None
         self.ids_np = None
         self.lps_np = None
@@ -528,7 +566,10 @@ class _PendingPrefill:
     decode bursts."""
     __slots__ = ("group", "out_ids", "logprobs", "mu_out", "t0",
                  "t_ready", "ids_np", "lps_np", "mu_np", "ready", "err",
-                 "split", "processed", "routed")
+                 "split", "processed", "routed",
+                 "kind", "t_ref", "ru")
+    n_steps = 1     # a prefill is paced as a whole item
+    t_dispatch = property(lambda self: self.t0)
 
     def __init__(self, group, out_ids, logprobs, mu_out, t0, split=False,
                  routed=False):
@@ -538,6 +579,7 @@ class _PendingPrefill:
         self.mu_out = mu_out
         self.t0 = t0
         self.t_ready = 0.0          # sync-worker completion stamp
+        self.kind, self.t_ref, self.ru = "", 0.0, None
         self.ids_np = self.lps_np = self.mu_np = None
         self.ready = threading.Event()
         self.err = None
@@ -1010,8 +1052,6 @@ class Engine:
         # lives in one FIFO mirroring the device's execution order; the
         # loop keeps up to pipeline_depth bursts in flight and only
         # block-syncs the FIFO head, which by then is (nearly) computed.
-        import collections
-
         self._chain = None                    # device handles or None
         self._override: set = set()           # slots whose chain rows are stale
         self._fifo = collections.deque()      # _Burst | _PendingPrefill
@@ -1101,12 +1141,17 @@ class Engine:
                        for name, b in _HIST_BUCKETS.items()}
         self._t_last_burst = 0.0
         # lifecycle telemetry + watchdog state (ISSUE 7). _t_last_ready is
-        # the last sync-worker ready-set stamp: the stall watchdog measures
-        # from max(item.t_dispatch, _t_last_ready) so a busy-but-progressing
-        # pipeline never false-triggers.
+        # the last sync-worker ready-set stamp (_overdue_ref reads it).
         self._t_last_ready = 0.0
         self._lc = {"requests_shed": 0, "requests_timed_out": 0,
-                    "stalls": 0, "stall_dumps": 0}
+                    "stalls": 0, "stall_dumps": 0,
+                    "late_dispatches": 0, "late_dispatch_s": 0.0}
+        # program kind -> seconds a step of its last PACE_ITEMS items,
+        # each counted from its own _overdue_ref (engine thread only)
+        self._pace: dict = {}
+        # (monotonic, sysobs.host_memory()) at the loop's half-second
+        # folds, a minute of them: what a late wait began with
+        self._rss = collections.deque(maxlen=128)
         self._lc_lock = threading.Lock()
         # in-flight prefill dedup: leader slot -> [(sib_slot, snap, leader
         # snap, ids)]; KV rows fork when the leader's prefill commits
@@ -1287,6 +1332,7 @@ class Engine:
                     time.sleep(int(d) / 1e3)
                 if FAULTS.take("sync_fail") is not None:
                     item.err = RuntimeError("injected fault: sync_fail")
+                    item.t_ref = self._overdue_ref(item)
                     item.t_ready = self._t_last_ready = time.monotonic()
                     item.ready.set()
                     self._wake.set()
@@ -1294,8 +1340,17 @@ class Engine:
             # sync_wait: this thread blocked on the device (and the copy
             # back) for one dispatched item; with an idle device under it,
             # the host is what the device waits for
-            with self.tracer.span("sync_wait", "sync"):
+            paced = not isinstance(item, _PendingOffload)
+            with self.tracer.span(
+                    "sync_wait", "sync",
+                    **({"kind": item.kind, "steps": item.n_steps}
+                       if paced else {"kind": "kv_offload"})):
                 try:
+                    # what this thread's wait cost it (a copy back that
+                    # faults or is descheduled shows here only), for the
+                    # late_dispatch span: sampled only while spans are kept
+                    ru0 = _wait_rusage() \
+                        if paced and self.tracer.enabled else None
                     if isinstance(item, _Burst):
                         item.pack_np = np.asarray(item.pack)
                     elif isinstance(item, _PendingOffload):
@@ -1316,10 +1371,14 @@ class Engine:
                             "kv page offload failed")
                         continue
                     item.err = e
+            if ru0 is not None:
+                item.ru = [round(b - a, 1)
+                           for a, b in zip(ru0, _wait_rusage())]
             # the ready-set stamp IS the device-completion observation
             # point (the np.asarray above returned): span
             # t_dispatch->t_ready is device time, t_ready->process
             # pickup is finish-detection latency
+            item.t_ref = self._overdue_ref(item)
             item.t_ready = self._t_last_ready = time.monotonic()
             item.ready.set()
             self._wake.set()
@@ -3543,6 +3602,7 @@ class Engine:
         if self._paged:
             sys_obs["fragmentation"] = self._pool.fragmentation()
         sys_obs["device_mem"] = self._device_mem
+        sys_obs["host_memory"] = sysobs.host_memory()
         out["sysobs"] = sys_obs
         # SLO engine (ISSUE 12): per-class burn rates + violation totals,
         # re-exposed as localai_slo_* gauges; short-window burns > 1 also
@@ -3708,6 +3768,14 @@ class Engine:
             "compiles": self._cobs.snapshot(),
             "last_compiles": self._cobs.last_compiles(),
             "compiles_by_kind": self._cobs.by_kind(),
+            # every compile of the PROCESS, whatever thread it ran on
+            # (one record for all engines of a pool), and apart those no
+            # engine's tracker above could hear
+            "compiles_process": sysobs.PROCESS.snapshot(),
+            "gc_full": sysobs.GC_FULL.snapshot(),
+            # what the runner holds resident: now, when LoadModel
+            # returned, and the load span that left the peak
+            "host_memory": sysobs.HOST.snapshot(),
             # per-span totals since process start (they survive the
             # ring's wrap) and from when the ring is complete
             "trace": self.tracer.summary(),
@@ -3971,6 +4039,11 @@ class Engine:
                         # lost
                         t_wm = t_tick
                         self._sample_watermarks()
+                        # the one host gauge: here only, not at every
+                        # admission's and /metrics pull's fold
+                        hm = sysobs.host_memory()
+                        self._rss.append((t_tick, hm))
+                        self._wm.sample(host_rss_bytes=hm.get("rss_bytes"))
                         if self.kv_checkpoint:
                             # cluster mode (ISSUE 17): stream active
                             # slots' warm chains to the host tier so a
@@ -4618,6 +4691,23 @@ class Engine:
                 self._emitter.push_final(i, s, [self._timeout_event(s.req)])
                 self.cancel(s.req.request_id)
 
+    def _enqueue(self, item, kind: str):
+        """Hand a dispatched item to the FIFO (where the loop and the
+        stall handler find it) and to the sync worker, named by the
+        program kind its pace is kept under."""
+        item.kind = kind
+        self._fifo.append(item)
+        self._sync_q.put(item)
+
+    def _overdue_ref(self, item) -> float:
+        """The point a dispatched item's wait is counted from, for the
+        late record and the stall abort alike: its own dispatch, or the
+        LAST ready transition of any item where that came later — a deep
+        pipeline whose head is slow while the worker visibly progresses
+        is load, not a stall. jax compiles inside the dispatch call on
+        the loop's thread, so compile time eats neither budget."""
+        return max(item.t_dispatch, self._t_last_ready)
+
     def _check_parked_stall(self):
         """Stall detection for the idle branch of the loop: the oldest
         dispatched-but-unready FIFO item is the one the sync worker
@@ -4627,31 +4717,74 @@ class Engine:
         if stall_s <= 0 or not self._fifo:
             return
         head = self._fifo[0]
-        if head.ready.is_set():
-            return
-        t_dispatch = getattr(head, "t_dispatch", 0.0) or getattr(
-            head, "t0", 0.0)
-        if time.monotonic() - max(t_dispatch, self._t_last_ready) > stall_s:
+        if not head.ready.is_set() and \
+                time.monotonic() - self._overdue_ref(head) > stall_s:
             raise _DispatchStall(head)
 
-    def _wait_ready(self, item, t_dispatch: float):
+    def _wait_ready(self, item):
         """Block until the sync worker marks ``item`` ready — with the
-        stall watchdog armed (dispatch_stall_ms > 0), never forever.
-
-        The reference point is max(this item's dispatch, the LAST ready
-        transition of any item): a deep pipeline where the head is slow
-        but the worker is visibly progressing is load, not a stall. jax
-        compilation happens inside the dispatch call on this thread, so
-        compile time never eats the stall budget."""
+        stall watchdog armed (dispatch_stall_ms > 0), never forever."""
         stall_s = self.ecfg.dispatch_stall_ms / 1e3
         if stall_s <= 0:
             item.ready.wait()
             return
         step = min(stall_s / 2, 0.5)
         while not item.ready.wait(timeout=step):
-            ref = max(t_dispatch, self._t_last_ready)
-            if time.monotonic() - ref > stall_s:
+            if time.monotonic() - self._overdue_ref(item) > stall_s:
                 raise _DispatchStall(item)
+
+    def _note_ready(self, item):
+        """A dispatched item came home (engine thread, once an item): its
+        pace sample and, where it overran its kind's pace (LATE_FACTOR,
+        LATE_MIN_S), ONE ``late_dispatch`` span from the final numbers.
+        Nothing polls while an item is overdue: in the stall this records
+        no Python thread of the process runs."""
+        if not item.t_ready:
+            return
+        waited = item.t_ready - item.t_ref
+        pace = self._pace.get(item.kind)
+        if pace is None:
+            pace = self._pace[item.kind] = collections.deque(
+                maxlen=PACE_ITEMS)
+        elif waited > LATE_MIN_S:
+            exp = statistics.median(pace) * item.n_steps
+            if waited > LATE_FACTOR * exp:
+                self._record_late(item, waited, exp)
+        pace.append(waited / item.n_steps)
+
+    def _record_late(self, item, waited: float, exp: float):
+        overdue = waited - exp
+        with self._lc_lock:
+            self._lc["late_dispatches"] += 1
+            self._lc["late_dispatch_s"] += overdue
+        if not self.tracer.enabled:
+            return
+        # memory now less the last half-second sample before the wait
+        # began: which kind of memory the process gave back inside it
+        # (as far as the kernel splits it: sysobs.parse_proc_status)
+        now = sysobs.host_memory()
+        before = next((hm for t, hm in reversed(self._rss)
+                       if t <= item.t_ref), {})
+        gave = {k + "_mb": round((now[k + "_bytes"] - before[k + "_bytes"])
+                                 / 1e6, 1)
+                for k in ("rss", "rss_anon", "rss_file", "vm_size", "vm_data")
+                if k + "_bytes" in now and k + "_bytes" in before}
+        riders = _riders(item)
+        self.tracer.record(
+            "late_dispatch", "sync", item.t_ref, item.t_ready,
+            rid=riders[0][1].req.request_id if riders else "",
+            args={"kind": item.kind, "steps": item.n_steps,
+                  "slots": len(riders),
+                  "expected_ms": round(exp * 1e3, 3),
+                  "overdue_ms": round(overdue * 1e3, 3),
+                  **dict(zip(_RU_NAMES, item.ru or ())), **gave,
+                  # the collector's full passes inside the wait: they
+                  # stop every Python thread too
+                  "gc_ms": round(1e3 * sysobs.GC_FULL.seconds_within(
+                      item.t_ref, item.t_ready), 3),
+                  # from the last compile heard to the wait's start:
+                  # negative where something compiled DURING the wait
+                  "since_compile_s": sysobs.PROCESS.since_last(item.t_ref)})
 
     def _handle_stall(self, item):
         """Abort ONLY the stalled item's requests: structured error events,
@@ -4663,8 +4796,8 @@ class Engine:
         import logging
 
         log = logging.getLogger(__name__)
-        pairs = item.slots if isinstance(item, _Burst) else item.group
-        stalled = [(i, snap) for i, snap in pairs if self.slots[i] is snap]
+        stalled = [(i, snap) for i, snap in _riders(item)
+                   if self.slots[i] is snap]
         with self._lc_lock:
             self._lc["stalls"] += 1
         dump_path = ""
@@ -5326,8 +5459,7 @@ class Engine:
         if slot in self._prefill_queue:
             self._prefill_queue.remove(slot)
         item = _PendingPrefill([(slot, s)], out_ids, logprobs, mu_out, t0)
-        self._fifo.append(item)
-        self._sync_q.put(item)
+        self._enqueue(item, f"prefill_final:{bucket}")
         return True
 
     def _prefill_win_piece(self, slot: int, s: "_Slot") -> bool:
@@ -5374,8 +5506,7 @@ class Engine:
         if slot in self._prefill_queue:
             self._prefill_queue.remove(slot)
         item = _PendingPrefill([(slot, s)], out_ids, logprobs, mu_out, t0)
-        self._fifo.append(item)
-        self._sync_q.put(item)
+        self._enqueue(item, f"prefill_final:{bucket}")
         return True
 
     def _init_ga(self, slot: int, s: "_Slot", P: int):
@@ -5626,8 +5757,7 @@ class Engine:
         item = _PendingPrefill(
             [(gslot, self.slots[gslot]) for gslot, _ in group],
             out_ids, logprobs, mu_out, t0)
-        self._fifo.append(item)
-        self._sync_q.put(item)
+        self._enqueue(item, f"prefill_final:{bucket}")
         t1 = time.monotonic()
         self._hobserve("prefill_dispatch_seconds", t1 - t0)
         if self.tracer.enabled:
@@ -5802,8 +5932,7 @@ class Engine:
             # routing, where the family reports one)
             item = _PendingPrefill(group, out_ids, logprobs, mu_out, t0,
                                    routed=bool(self._n_route))
-            self._fifo.append(item)
-            self._sync_q.put(item)
+            self._enqueue(item, f"prefill_pack:{bucket}")
         return True
 
     def _dispatch_packed_split(self, segs, args, meta, bucket: int,
@@ -5884,9 +6013,8 @@ class Engine:
         # wait is free — so TTFT stops paying for the decode half.
         head = _PendingPrefill(group_snaps, ids_f, lps_f, chain[4], t0,
                                split=True, routed=bool(self._n_route))
-        self._fifo.append(head)      # discoverable for the stall handler
-        self._sync_q.put(head)
-        self._wait_ready(head, t0)
+        self._enqueue(head, f"prefill_pack_head:{bucket}")
+        self._wait_ready(head)
         self._fifo.remove(head)
         self._process_prefill(head)
         # a grammar rollback / context shift inside the head's emission
@@ -5915,8 +6043,7 @@ class Engine:
         b = _Burst(K, included, pack, group=group_snaps, t_dispatch=t0,
                    head=head)
         b.skip_slots |= poisoned
-        self._fifo.append(b)
-        self._sync_q.put(b)
+        self._enqueue(b, "decode_burst")
         return True
 
     def _dispatch_fused(self, group, bucket: int) -> bool:
@@ -6021,8 +6148,7 @@ class Engine:
                                args={"slots": len(group_snaps),
                                      "bucket": bucket, "fused": True})
         b = _Burst(K, included, pack, group=group_snaps, t_dispatch=t_d)
-        self._fifo.append(b)
-        self._sync_q.put(b)
+        self._enqueue(b, f"prefill_fused:{bucket}")
         return True
 
     def _process_prefill(self, item: "_PendingPrefill"):
@@ -6031,11 +6157,12 @@ class Engine:
         them as chain OVERRIDES so the next burst dispatch picks their
         state from the host mirrors without a chain rebuild."""
         if not item.ready.is_set():
-            self._wait_ready(item, item.t0)
+            self._wait_ready(item)
         if item.err is not None:
             raise item.err
         if item.split:
             return self._process_split_head(item)
+        self._note_ready(item)
         if item.routed:
             self._fold_route("prefill", item.lps_np[-self._n_route:], 1)
         group = item.group
@@ -6106,6 +6233,7 @@ class Engine:
         if item.processed:
             return
         item.processed = True
+        self._note_ready(item)
         if item.routed:
             self._fold_route("prefill", item.lps_np[-self._n_route:], 1)
         group = item.group
@@ -6780,8 +6908,7 @@ class Engine:
             st["dispatches"] += 1
             if any(not spec_mask[i] for i in included):
                 st["mixed_dispatches"] += 1
-        self._fifo.append(b)
-        self._sync_q.put(b)
+        self._enqueue(b, "spec_tick" if plan is not None else "decode_burst")
         return True
 
     def _live(self, i, snap):
@@ -6795,7 +6922,7 @@ class Engine:
         if b.folded:
             return
         if not b.ready.is_set():
-            self._wait_ready(b, b.t_dispatch)   # worker-side sync in flight
+            self._wait_ready(b)   # worker-side sync in flight
         if b.err is not None:
             raise b.err
         packed = b.pack_np                  # [2K+1(+2), S] f32
@@ -6827,7 +6954,7 @@ class Engine:
                 # (no first-token rows).
                 h = b.head
                 if not h.ready.is_set():
-                    self._wait_ready(h, h.t0)
+                    self._wait_ready(h)
                 if h.err is not None:
                     raise h.err
                 S = self.ecfg.num_slots
@@ -6920,6 +7047,7 @@ class Engine:
             finally:
                 self._fifo.remove(b)
         self._fold_burst(b)
+        self._note_ready(b)
         if not b.group and b.t_dispatch:
             dt = (time.monotonic() - b.t_dispatch) * 1e3
             self._burst_ms_ema += 0.2 * (dt - self._burst_ms_ema)

@@ -944,7 +944,9 @@ class EnginePool:
         out = {
             # replicas share one process, so one set of devices
             **{k: replicas[0][k] for k in ("platform", "device_kind",
-                                           "device_count", "device_mem")},
+                                           "device_count", "device_mem",
+                                           "compiles_process", "gc_full",
+                                           "host_memory")},
             "engine_replicas": len(self._engines),
             "pool": {
                 "replicas_alive": sum(1 for d in self._dead if not d),

@@ -12,10 +12,15 @@ compiler ran or the persistent compilation cache served it; the latter
 also emits ``/jax/compilation_cache/cache_hits``, counted separately
 (``compiles_from_cache``) so a warm restart can be told from a cold one.
 Executions of an already-built program emit neither. We register ONE module-level
-listener and dispatch to the engine whose thread is compiling via a
-thread-local registration: the engine loop thread registers its
+listener. It gives every event to the process's record (``PROCESS``:
+whatever thread compiled), and to the engine whose thread is compiling
+via a thread-local registration: the engine loop thread registers its
 CompileTracker at startup, and ``precompile()`` (which runs on the
-loader/caller thread) wraps itself in :func:`activated`. Program
+loader/caller thread) wraps itself in :func:`activated`. An event on a
+thread that bound no tracker (the sync worker, the emitter, a gRPC
+handler, ``LoadModel`` outside ``precompile()``) is *unowned*: no engine
+counter hears it, so ``PROCESS`` keeps it apart with the thread's name
+and the line that compiled. Program
 attribution rides the same thread-local — ``Engine._program`` brackets
 every call of a jitted program with ``note_program(kind, key)`` /
 ``note_program(None)``, so whatever compiles inside the call (the first
@@ -34,6 +39,12 @@ latency cliff the bucket tables were supposed to prevent.
 retained / offloaded pages, host bytes, …) — cheap max() folds sampled
 from the engine loop so peaks between /metrics scrapes are not lost.
 
+**Host memory.** ``host_memory()`` reads the process's resident sizes
+from ``/proc/self/status``; ``HOST`` keeps them as every ``load`` span
+left them and as they stood when ``LoadModel`` returned. ``GC_FULL``
+times the collector's full passes from the collector's own callback: a
+pass stops every Python thread, the loop that would notice included.
+
 **Goodput / MFU.** Analytic FLOPs-per-token from the model config
 (matmul params ×2 + attention term) and achieved tokens/s over a
 rolling window → model FLOPs utilization against the device's peak
@@ -46,9 +57,12 @@ no goodput even though they burned FLOPs.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import json
 import logging
 import os
+import resource
+import sys
 import threading
 import time
 from collections import deque
@@ -73,6 +87,7 @@ def _on_event_duration(name: str, secs: float, **kw):
     tracker = getattr(_tl, "tracker", None)
     if tracker is not None:
         tracker.on_compile(secs)
+    PROCESS.on_compile(secs, owned=tracker is not None)
 
 
 def _on_event(name: str, **kw):
@@ -81,6 +96,8 @@ def _on_event(name: str, **kw):
     tracker = getattr(_tl, "tracker", None)
     if tracker is not None:
         tracker.on_cache_hit()
+    else:
+        PROCESS.on_unowned_cache_hit()
 
 
 def install_listener():
@@ -91,6 +108,8 @@ def install_listener():
     with _listener_lock:
         if _listener_installed:
             return
+        if GC_FULL.on_gc not in gc.callbacks:
+            gc.callbacks.append(GC_FULL.on_gc)
         try:
             from jax import monitoring
             monitoring.register_event_duration_secs_listener(_on_event_duration)
@@ -201,6 +220,230 @@ class CompileTracker:
                     "compile_seconds_total": round(self.compile_seconds, 4),
                     "compiles_after_warmup": self.compiles_after_warmup,
                     "warm": self.warm}
+
+
+_UNOWNED_RING = 64      # last unowned compile events kept
+_NOT_THE_CALLER = (os.sep + "jax" + os.sep, os.sep + "jaxlib" + os.sep)
+
+
+def _compiling_frame() -> str:
+    """``file:line function`` of the innermost frame of THIS thread that
+    is neither jax's nor this module's: the listener runs synchronously
+    on the compiling thread, so that frame is the call that compiled."""
+    f = sys._getframe(1)
+    while f is not None:
+        fn = f.f_code.co_filename
+        if fn != __file__ and not any(p in fn for p in _NOT_THE_CALLER):
+            return f"{fn}:{f.f_lineno} {f.f_code.co_name}"
+        f = f.f_back
+    return "?"
+
+
+class ProcessCompiles:
+    """Every compile event of the process, whatever thread it fired on,
+    and apart those no thread-bound CompileTracker took ("unowned").
+    One instance (``PROCESS``): a pool of engines has one record and one
+    warm mark, which the runner sets when ``LoadModel`` returns."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.compiles = 0
+        self.compile_seconds = 0.0
+        self.unowned = 0
+        self.unowned_seconds = 0.0
+        self.unowned_from_cache = 0
+        self.unowned_after_warmup = 0
+        self.warm = False
+        self._by_thread: dict = {}   # thread name -> [seconds, compiles]
+        self._ring: deque = deque(maxlen=_UNOWNED_RING)
+        self._last = None            # monotonic of the last compile event
+
+    def mark_warm(self):
+        with self._lock:
+            self.warm = True
+
+    def on_unowned_cache_hit(self):
+        with self._lock:
+            self.unowned_from_cache += 1
+
+    def on_compile(self, secs: float, owned: bool):
+        rec = None if owned else {
+            "t": round(time.time(), 3), "seconds": round(secs, 4),
+            "thread": threading.current_thread().name,
+            "where": _compiling_frame()}
+        with self._lock:
+            self.compiles += 1
+            self.compile_seconds += secs
+            self._last = time.monotonic()
+            if owned:
+                return
+            self.unowned += 1
+            self.unowned_seconds += secs
+            t = self._by_thread.setdefault(rec["thread"], [0.0, 0])
+            t[0] += secs
+            t[1] += 1
+            late = rec["after_warmup"] = self.warm
+            self._ring.append(rec)
+            if late:
+                self.unowned_after_warmup += 1
+        if late:
+            # the latency cliff a storm is, on a thread whose compiles
+            # the engine's compiles_after_warmup cannot count
+            log.warning(json.dumps({
+                "event": "compile_after_warmup_unowned", **rec,
+                "unowned_after_warmup": self.unowned_after_warmup}))
+
+    def since_last(self, at: float):
+        """Seconds from the last compile event heard to ``at`` (a
+        time.monotonic(); negative: it compiled after ``at``), None
+        before the first."""
+        last = self._last
+        return None if last is None else round(at - last, 3)
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {
+                "compiles_total": self.compiles,
+                "compile_seconds_total": round(self.compile_seconds, 4),
+                "unowned_compiles": self.unowned,
+                "unowned_seconds": round(self.unowned_seconds, 4),
+                "unowned_from_cache": self.unowned_from_cache,
+                "unowned_after_warmup": self.unowned_after_warmup,
+                "warm": self.warm,
+                "unowned_by_thread": {
+                    k: [round(v[0], 4), v[1]]
+                    for k, v in sorted(self._by_thread.items())},
+                "unowned_last": list(self._ring)}
+
+
+PROCESS = ProcessCompiles()
+
+
+class FullCollections:
+    """The collector's full passes (generation 2), timed. A full pass
+    walks every tracked object with the GIL held: every Python thread of
+    the process waits for it, the loop that would notice included, so it
+    is recorded from the collector's own callback. One instance
+    (``GC_FULL``), hooked in by :func:`install_listener`."""
+
+    def __init__(self):
+        self.passes = 0
+        self.seconds = 0.0
+        self._t0 = 0.0
+        self._last: deque = deque(maxlen=16)   # (t0, t1, collected)
+
+    def on_gc(self, phase, info):
+        if info.get("generation") != 2:
+            return
+        if phase == "start":
+            self._t0 = time.monotonic()
+            return
+        t1 = time.monotonic()
+        self.passes += 1
+        self.seconds += t1 - self._t0
+        self._last.append((self._t0, t1, info.get("collected", 0)))
+
+    def seconds_within(self, a: float, b: float) -> float:
+        """Seconds of full passes inside [a, b] (time.monotonic())."""
+        return sum(max(0.0, min(b, t1) - max(a, t0))
+                   for t0, t1, _n in list(self._last))
+
+    def snapshot(self) -> dict:
+        now = time.monotonic()
+        return {"passes": self.passes, "seconds": round(self.seconds, 4),
+                "last": [{"ago_s": round(now - t1, 3),
+                          "seconds": round(t1 - t0, 4), "collected": n}
+                         for t0, t1, n in list(self._last)[-4:]]}
+
+
+GC_FULL = FullCollections()
+
+
+_STATUS_KEYS = {"VmRSS": "rss_bytes", "VmHWM": "rss_peak_bytes",
+                "RssAnon": "rss_anon_bytes", "RssFile": "rss_file_bytes",
+                "RssShmem": "rss_shmem_bytes",
+                "VmSize": "vm_size_bytes", "VmData": "vm_data_bytes"}
+
+
+def parse_proc_status(text: str) -> dict:
+    """The memory sizes of a ``/proc/<pid>/status`` text, in bytes
+    (_STATUS_KEYS): resident now, its high-water mark, resident split
+    into anonymous, file-backed and shared memory, and the mapped sizes
+    (all of it, and the private writable part: what a sandbox kernel that
+    gives no split - gVisor gives VmSize, VmRSS and VmData only - still
+    tells of WHICH memory went: an unmapped arena takes both down with
+    the resident size, a purge neither, an unmapped file VmSize alone).
+    A line that is absent or does not parse is left out."""
+    out = {}
+    for ln in text.splitlines():
+        key, _, rest = ln.partition(":")
+        name = _STATUS_KEYS.get(key)
+        if name is not None:
+            try:
+                out[name] = int(rest.split()[0]) * 1024     # "<n> kB"
+            except (ValueError, IndexError):
+                pass
+    return out
+
+
+def host_memory(path: str = "/proc/self/status") -> dict:
+    """What the process holds now (parse_proc_status): one read, 20-50
+    us; {} where the file cannot be read. Where the kernel keeps no
+    VmHWM the peak is getrusage's ``ru_maxrss``, the same counter."""
+    try:
+        with open(path) as f:
+            out = parse_proc_status(f.read())
+    except OSError:
+        return {}
+    if "rss_bytes" in out and "rss_peak_bytes" not in out:
+        out["rss_peak_bytes"] = max(out["rss_bytes"], resource.getrusage(
+            resource.RUSAGE_SELF).ru_maxrss * 1024)
+    return out
+
+
+def _mb(n_bytes) -> float:
+    return round(n_bytes / 1e6, 1)
+
+
+class HostMemory:
+    """The runner's resident memory through a load and after it. Every
+    span of the track ``load`` reads it at its exit (tracing.py), so the
+    ring says at which phase and leaf the peak stood; the runner marks
+    what is left when ``LoadModel`` returns. One instance (``HOST``)."""
+
+    def __init__(self):
+        self.at_warm: dict = {}
+        self.peak_in_load: dict = {}
+
+    def on_load_span(self, name: str, args: dict):
+        """A ``load`` span ends: its ``rss_mb`` / ``rss_peak_mb``, and the
+        last span at whose exit the high-water mark had risen."""
+        hm = host_memory()
+        if "rss_bytes" not in hm:
+            return
+        args["rss_mb"] = _mb(hm["rss_bytes"])
+        args["rss_peak_mb"] = _mb(hm["rss_peak_bytes"])
+        if hm["rss_peak_bytes"] > self.peak_in_load.get("bytes", 0):
+            self.peak_in_load = {"bytes": hm["rss_peak_bytes"], "span": name,
+                                 "leaf": args.get("leaf", "")}
+
+    def mark_warm(self):
+        self.at_warm = host_memory()
+
+    def snapshot(self) -> dict:
+        return {**host_memory(), "at_warm": self.at_warm,
+                "peak_in_load": self.peak_in_load}
+
+
+HOST = HostMemory()
+
+
+def mark_warm():
+    """``LoadModel`` returned: a compile from here on that no engine's
+    tracker hears is ``unowned_after_warmup`` (one mark for a pool of
+    engines), and what the process holds now is ``at_warm``."""
+    PROCESS.mark_warm()
+    HOST.mark_warm()
 
 
 class Watermarks:

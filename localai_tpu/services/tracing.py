@@ -14,7 +14,12 @@ profiler capture is running (``capturing``, set and cleared by the
 runner's ``Profile`` RPC), also enters a ``jax.profiler.TraceAnnotation``
 of the same name, so the capture's host plane shows the same spans as the
 ring on the profiler's own clock. ``record()`` stays for spans whose ends
-are observed on different threads (dispatch -> sync-worker ready).
+are observed on different threads (dispatch -> sync-worker ready). The
+sync worker's own span is ``sync_wait`` (track ``sync``): one per
+dispatched item, the ``np.asarray`` that blocks on the device and the copy
+back, with the ``kind`` and ``steps`` it waited for; an item that overran
+its kind's pace also leaves ``late_dispatch`` there (engine.py,
+``LATE_FACTOR``).
 
 ``chrome_trace()`` renders the ring as Chrome trace-event JSON
 (https://ui.perfetto.dev loads it directly): one track per slot plus
@@ -34,6 +39,8 @@ import os
 import tempfile
 import threading
 import time
+
+from localai_tpu.services import sysobs
 
 # Span names counted as HOST loop work in the decomposition: time the
 # engine thread spends admitting, dispatching and moving KV pages,
@@ -131,6 +138,10 @@ class _Span:
         t1 = time.monotonic()
         if self._ann is not None:
             self._ann.__exit__(*exc)
+        if self.track == "load":
+            # the runner's resident memory as every phase and leaf of a
+            # load left it (rss_mb, rss_peak_mb)
+            sysobs.HOST.on_load_span(self.name, self.args)
         self._tr.record(self.name, self.track, self.t0, t1, self.rid,
                         self.args or None)
         return False

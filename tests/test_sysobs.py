@@ -111,6 +111,244 @@ def test_tracker_thread_isolation():
     assert b.snapshot()["compiles_total"] == 0
 
 
+# --------------------------------------- compiles on every thread (PR 43)
+
+_X3 = None
+
+
+def _compile_on_bare_thread(name):
+    """One real compile on a thread that binds no tracker; -> the line
+    of this file that called the jitted function."""
+    x = _X3
+    line = []
+
+    def run():
+        f = jax.jit(lambda y: y * 5 - 2)        # fresh lambda: a compile
+        line.append(run.__code__.co_firstlineno + 3)
+        f(x)
+
+    t = threading.Thread(target=run, name=name)
+    t.start()
+    t.join(timeout=120)
+    assert not t.is_alive()
+    return line[0]
+
+
+def test_process_record_hears_a_bare_thread_and_says_where():
+    global _X3
+    import time
+
+    # (the ones-fill is itself a tiny compile, on this thread: first)
+    _X3 = jnp.ones((3,), jnp.float32)
+    sysobs.install_listener()
+    before = sysobs.PROCESS.snapshot()
+    line = _compile_on_bare_thread("bare-compiler")
+    after = sysobs.PROCESS.snapshot()
+    assert after["compiles_total"] == before["compiles_total"] + 1
+    assert after["unowned_compiles"] == before["unowned_compiles"] + 1
+    assert after["unowned_seconds"] > before["unowned_seconds"]
+    assert after["compile_seconds_total"] > before["compile_seconds_total"]
+    secs, n = after["unowned_by_thread"]["bare-compiler"]
+    assert n == 1 and secs > 0
+    rec = after["unowned_last"][-1]
+    assert rec["thread"] == "bare-compiler" and rec["after_warmup"] is False
+    # the innermost frame that is not jax's: the call that compiled, as a
+    # string (a frame object would keep its locals alive)
+    assert rec["where"] == f"{__file__}:{line} run", rec["where"]
+    assert 0 <= sysobs.PROCESS.since_last(time.monotonic()) < 60
+    json.dumps(after)
+
+
+def test_owned_compile_is_the_trackers_and_counted_once():
+    x = jnp.ones((5,), jnp.float32)
+    tr = sysobs.CompileTracker(model="owner")
+    before = sysobs.PROCESS.snapshot()
+    with sysobs.activated(tr):
+        tr.note_program("owned_kind", 7)
+        jax.jit(lambda y: y * 7 + 3)(x)
+        tr.note_program(None)
+    after = sysobs.PROCESS.snapshot()
+    snap = tr.snapshot()
+    assert snap["compiles_total"] == 1
+    assert tr.by_kind()["owned_kind"]["compiles"] == 1
+    # the process record counts it once, and not as unowned
+    assert after["compiles_total"] == before["compiles_total"] + 1
+    assert after["unowned_compiles"] == before["unowned_compiles"]
+    assert after["unowned_last"] == before["unowned_last"]
+    # seconds agree: one event, two records
+    assert after["compile_seconds_total"] - before["compile_seconds_total"] \
+        == pytest.approx(snap["compile_seconds_total"], abs=2e-4)
+
+
+def test_unowned_compile_after_the_warm_mark_leaves_the_gate_alone(
+        warm_engine, caplog, monkeypatch):
+    """What `correct` reads (the engine tracker's compiles_after_warmup)
+    does not move; the process record says what it could not hear."""
+    gate = warm_engine._cobs.snapshot()
+    before = sysobs.PROCESS.snapshot()
+    monkeypatch.setattr(sysobs.HOST, "at_warm", {})
+    monkeypatch.setattr(sysobs.PROCESS, "warm", before["warm"])
+    sysobs.mark_warm()                   # what LoadModel does as it returns
+    assert sysobs.HOST.at_warm["rss_bytes"] > 0
+    with caplog.at_level("WARNING", logger="localai_tpu.sysobs"):
+        _compile_on_bare_thread("late-compiler")
+    after = sysobs.PROCESS.snapshot()
+    assert after["warm"] is True
+    assert after["unowned_after_warmup"] == before["unowned_after_warmup"] + 1
+    assert after["unowned_last"][-1]["after_warmup"] is True
+    assert warm_engine._cobs.snapshot() == gate
+    warned = [json.loads(r.getMessage()) for r in caplog.records
+              if "compile_after_warmup_unowned" in r.getMessage()]
+    assert len(warned) == 1 and warned[0]["thread"] == "late-compiler"
+    assert warned[0]["where"].startswith(__file__)
+    # /debug/state carries the same record, the gate's counters beside it
+    st = warm_engine.state_snapshot()
+    assert st["compiles_process"]["unowned_after_warmup"] \
+        == after["unowned_after_warmup"]
+    assert st["compiles"]["compiles_after_warmup"] \
+        == gate["compiles_after_warmup"]
+    assert st["host_memory"]["at_warm"] == sysobs.HOST.at_warm
+
+
+def test_full_collections_are_timed_from_the_collectors_own_callback():
+    import gc
+    import time
+
+    sysobs.install_listener()
+    assert sysobs.GC_FULL.on_gc in gc.callbacks
+    sysobs.install_listener()                    # idempotent: hooked once
+    assert gc.callbacks.count(sysobs.GC_FULL.on_gc) == 1
+    gc.disable()                 # only the passes this test asks for
+    try:
+        before = sysobs.GC_FULL.snapshot()
+        gc.collect(0)                            # a young pass: not counted
+        assert sysobs.GC_FULL.snapshot()["passes"] == before["passes"]
+        junk = [[i] for i in range(200000)]      # something to walk
+        t0 = time.monotonic()
+        gc.collect()
+        t1 = time.monotonic()
+        after = sysobs.GC_FULL.snapshot()
+    finally:
+        gc.enable()
+    assert after["passes"] == before["passes"] + 1
+    took = after["seconds"] - before["seconds"]
+    assert 0 < took <= t1 - t0 + 1e-3
+    assert after["last"][-1]["seconds"] == pytest.approx(took, abs=2e-4)
+    assert 0 <= after["last"][-1]["ago_s"] < 5 and len(after["last"]) <= 4
+    # the seconds of it that fall inside a wait: all, none, the first half
+    within = sysobs.GC_FULL.seconds_within
+    assert within(t0, t1) == pytest.approx(took, abs=2e-4)
+    assert within(t1 + 1, t1 + 2) == 0.0
+    p0, p1, _n = sysobs.GC_FULL._last[-1]
+    assert within(t0, (p0 + p1) / 2) == pytest.approx(took / 2, abs=2e-4)
+    del junk
+
+
+# -------------------------------------------- the runner's resident memory
+
+_STATUS_TEXT = """Name:\tpython3
+Umask:\t0022
+State:\tS (sleeping)
+VmPeak:\t 40123456 kB
+VmSize:\t 39000000 kB
+VmHWM:\t 24023436 kB
+VmRSS:\t  9876544 kB
+RssAnon:\t  8000000 kB
+RssFile:\t  1876000 kB
+RssShmem:\t      544 kB
+VmData:\t 30000000 kB
+Threads:\t97
+"""
+
+
+def test_host_memory_parses_a_proc_status_text():
+    assert sysobs.parse_proc_status(_STATUS_TEXT) == {
+        "rss_bytes": 9876544 * 1024, "rss_peak_bytes": 24023436 * 1024,
+        "rss_anon_bytes": 8000000 * 1024, "rss_file_bytes": 1876000 * 1024,
+        "rss_shmem_bytes": 544 * 1024, "vm_size_bytes": 39000000 * 1024,
+        "vm_data_bytes": 30000000 * 1024}
+    # an older kernel's text has no split; a torn line is left out
+    assert sysobs.parse_proc_status("VmRSS:\t 12 kB\nVmHWM:\nRssAnon") \
+        == {"rss_bytes": 12 * 1024}
+    assert sysobs.parse_proc_status("") == {}
+
+
+# what the benchmark machine's kernel (gVisor) gives: no high-water mark,
+# no split (the first chip run of PR 43 died of a KeyError on it)
+_SANDBOX_STATUS_TEXT = """Name:\tpython3
+VmSize:\t  779144 kB
+VmRSS:\t  300908 kB
+VmData:\t  514472 kB
+Threads:\t13
+"""
+
+
+def test_host_memory_on_a_kernel_without_a_high_water_mark(tmp_path):
+    import resource
+
+    path = tmp_path / "status"
+    path.write_text(_SANDBOX_STATUS_TEXT)
+    hm = sysobs.host_memory(str(path))
+    assert set(hm) == {"rss_bytes", "rss_peak_bytes", "vm_size_bytes",
+                       "vm_data_bytes"}
+    # getrusage's ru_maxrss, the same counter, and never under the reading
+    assert hm["rss_peak_bytes"] == max(300908 * 1024, resource.getrusage(
+        resource.RUSAGE_SELF).ru_maxrss * 1024)
+    args = {}
+    rec = sysobs.HostMemory()
+    real = sysobs.host_memory
+    sysobs.host_memory = lambda: real(str(path))
+    try:
+        rec.on_load_span("load_cast", args)
+    finally:
+        sysobs.host_memory = real
+    assert args == {"rss_mb": 308.1, "rss_peak_mb": round(
+        hm["rss_peak_bytes"] / 1e6, 1)}
+    assert rec.peak_in_load["span"] == "load_cast"
+
+
+def test_host_memory_reads_this_process_and_gives_nothing_where_it_cannot(
+        tmp_path, monkeypatch):
+    hm = sysobs.host_memory()
+    assert set(hm) == {"rss_bytes", "rss_peak_bytes", "rss_anon_bytes",
+                       "rss_file_bytes", "rss_shmem_bytes", "vm_size_bytes",
+                       "vm_data_bytes"}
+    assert 0 < hm["rss_bytes"] <= hm["rss_peak_bytes"]
+    assert hm["rss_anon_bytes"] + hm["rss_file_bytes"] \
+        + hm["rss_shmem_bytes"] == pytest.approx(hm["rss_bytes"], rel=0.05)
+    assert sysobs.host_memory(str(tmp_path / "no-such-file")) == {}
+    # a load span on a system without /proc records no number, no error
+    args = {}
+    rec = sysobs.HostMemory()
+    monkeypatch.setattr(sysobs, "host_memory", lambda: {})
+    rec.on_load_span("load_cast", args)
+    rec.mark_warm()
+    assert args == {}
+    assert rec.snapshot() == {"at_warm": {}, "peak_in_load": {}}
+
+
+def test_load_spans_say_where_the_peak_rose(monkeypatch):
+    readings = iter([(5, 5), (9, 9), (4, 9), (6, 9)])
+
+    def fake():
+        now, peak = next(readings)
+        return {"rss_bytes": now * 10**6, "rss_peak_bytes": peak * 10**6}
+
+    monkeypatch.setattr(sysobs, "host_memory", fake)
+    rec = sysobs.HostMemory()
+    spans = []
+    for name, leaf in (("load_source", "wq"), ("load_cast", "w_down"),
+                       ("load_source", "embed"), ("load_precompile", None)):
+        args = {"leaf": leaf} if leaf else {}
+        rec.on_load_span(name, args)
+        spans.append(args)
+    assert [a["rss_mb"] for a in spans] == [5.0, 9.0, 4.0, 6.0]
+    assert [a["rss_peak_mb"] for a in spans] == [5.0, 9.0, 9.0, 9.0]
+    # the span at whose exit the high-water mark LAST rose
+    assert rec.peak_in_load == {"bytes": 9 * 10**6, "span": "load_cast",
+                                "leaf": "w_down"}
+
+
 # ------------------------------------------------------------- watermarks
 
 def test_watermarks_max_fold():
@@ -123,7 +361,8 @@ def test_watermarks_max_fold():
 
 
 def test_engine_watermarks_match_pool_accounting(warm_engine,
-                                                 byte_tokenizer):
+                                                 byte_tokenizer,
+                                                 monkeypatch):
     _gen(warm_engine, byte_tokenizer)
     m = warm_engine.metrics()
     so = m["sysobs"]
@@ -133,6 +372,22 @@ def test_engine_watermarks_match_pool_accounting(warm_engine,
     # exceed the physical pool
     assert wm["peak_pool_pages_in_use"] >= 1
     assert wm["peak_pool_pages_in_use"] <= pool.num_pages
+    # the one host gauge, folded by the loop every half second and NOT by
+    # the fold a /metrics pull or an admission runs
+    import time
+
+    t_end = time.monotonic() + 30
+    while "peak_host_rss_bytes" not in warm_engine._wm.snapshot() \
+            and time.monotonic() < t_end:
+        time.sleep(0.05)
+    rss = sysobs.host_memory()["rss_bytes"]
+    assert 0.5 * rss < warm_engine._wm.peak("host_rss_bytes") < 2 * rss
+    calls = []
+    monkeypatch.setattr(sysobs, "host_memory", lambda: calls.append(
+        threading.current_thread().name) or {})
+    warm_engine._sample_watermarks()
+    assert threading.current_thread().name not in calls   # the loop's only
+    assert so["host_memory"]["rss_bytes"] > 0       # what /metrics exports
     assert wm["peak_slots_active"] >= 1
     assert wm["peak_tokens_total"] >= 1
     # weight bytes: computed from the actual param tree, so > 0

@@ -255,6 +255,256 @@ def test_default_ring_drops_nothing_over_200_requests_of_bursts(
         small.shutdown()
 
 
+# --------------------------------- a dispatch that overruns its pace (PR 43)
+
+from localai_tpu.services.faults import FAULTS        # noqa: E402
+
+
+def _late(e):
+    return [s for s in e.tracer.spans() if s["name"] == "late_dispatch"]
+
+
+def _gen_within(e, tok, prompt, n=8, limit_s=120):
+    """_gen on a thread of its own: a wedged engine fails the test at
+    ``limit_s`` and does not hang the run. -> (events, wall ms)"""
+    import threading
+
+    out = []
+    t0 = time.monotonic()
+    t = threading.Thread(target=lambda: out.append(_gen(e, tok, prompt, n)))
+    t.start()
+    t.join(timeout=limit_s)
+    assert not t.is_alive(), f"no answer in {limit_s} s"
+    return out[0][1], (time.monotonic() - t0) * 1e3
+
+
+@pytest.fixture()
+def paced_engine(byte_tokenizer):
+    """A toy engine whose every program kind has a pace: a handful of
+    requests after the first ones' compiles."""
+    e = _tiny(byte_tokenizer)
+    e.start(precompile=False)
+    for i in range(6):
+        _gen_within(e, byte_tokenizer, f"pace {i}")
+    yield e
+    FAULTS.reset()
+    e.shutdown()
+
+
+def test_sync_wait_says_what_it_waited_for(paced_engine):
+    waits = [s for s in paced_engine.tracer.spans()
+             if s["name"] == "sync_wait"]
+    assert waits and all(s["track"] == "sync" for s in waits)
+    kinds = {s["args"]["kind"].split(":")[0] for s in waits}
+    assert kinds >= {"prefill_pack_head"} and kinds & {"decode_burst",
+                                                      "spec_tick"}
+    assert all(s["args"]["steps"] >= 1 for s in waits)
+    # each kind's pace is kept, at most PACE_ITEMS items of it
+    assert set(paced_engine._pace) == {s["args"]["kind"] for s in waits}
+    assert all(0 < len(p) <= eng.PACE_ITEMS
+               for p in paced_engine._pace.values())
+
+
+def test_a_held_dispatch_leaves_exactly_one_late_dispatch(
+        paced_engine, byte_tokenizer, tmp_path):
+    import gc
+    import threading
+
+    e = paced_engine
+    e.ecfg.stall_dump_dir = str(tmp_path)
+    delay_ms = 600
+    assert delay_ms / 1e3 > eng.LATE_FACTOR * max(
+        np.median(p) * 2 for p in e._pace.values())
+    assert delay_ms / 1e3 > 2 * eng.LATE_MIN_S
+    lc0 = e.metrics()["lifecycle"]
+    flights0 = e._flight.snapshot()["dumps"]
+    # full passes of the collector all through the request: the record
+    # says how much of the wait was theirs
+    done = threading.Event()
+
+    def collect():
+        while not done.wait(0.05):
+            gc.collect()
+
+    collector = threading.Thread(target=collect)
+    collector.start()
+    FAULTS.arm("sync_delay_ms", str(delay_ms), count=1)
+    try:
+        events, wall_ms = _gen_within(e, byte_tokenizer, "held dispatch")
+    finally:
+        done.set()
+        collector.join(timeout=30)
+    # completed, no abort
+    assert all(ev.error is None for ev in events)
+    assert events[-1].completion_tokens == 8
+    # (a loaded machine may add a true hiccup of its own: the held item's
+    # record is the one as long as the injected delay, and no wait of the
+    # request can be longer than the request)
+    late = [s for s in _late(e) if s["args"]["overdue_ms"] >= 0.5 * delay_ms]
+    assert len(late) == 1
+    sp, a = late[0], late[0]["args"]
+    assert sp["track"] == "sync" and sp["rid"] != ""
+    assert 0.5 * delay_ms <= a["overdue_ms"] <= wall_ms
+    assert a["expected_ms"] < delay_ms / eng.LATE_FACTOR
+    assert (sp["t1"] - sp["t0"]) * 1e3 == pytest.approx(
+        a["overdue_ms"] + a["expected_ms"], abs=1.0)
+    assert a["kind"] in e._pace and a["steps"] >= 1 and a["slots"] >= 1
+    # the sync worker's faults and switches over its wait, the process's
+    # CPU time over the same wait, what its resident memory did since the
+    # last half-second sample before the wait, the collector's share
+    assert {"majflt", "minflt", "nvcsw", "nivcsw"} <= set(a)
+    assert a["proc_user_ms"] >= 0 and a["proc_sys_ms"] >= 0
+    assert all(abs(a[k]) < 4096 for k in (
+        "rss_mb", "rss_anon_mb", "rss_file_mb", "vm_size_mb", "vm_data_mb"))
+    assert 0 < a["gc_ms"] < a["overdue_ms"] + a["expected_ms"]
+    assert a["since_compile_s"] >= 0
+    lc = e.metrics()["lifecycle"]
+    assert lc["late_dispatches"] == lc0["late_dispatches"] + len(_late(e))
+    assert lc["late_dispatch_s"] - lc0["late_dispatch_s"] == pytest.approx(
+        sum(s["args"]["overdue_ms"] for s in _late(e)) / 1e3, abs=1e-3)
+    assert lc["stalls"] == lc0["stalls"]
+    # no ring dump, no flight dump, no event while it was overdue
+    assert e._flight.snapshot()["dumps"] == flights0
+    assert list(tmp_path.iterdir()) == []
+    assert e.state_snapshot()["lifecycle"]["late_dispatches"] \
+        == lc["late_dispatches"]
+
+
+def test_fifty_healthy_bursts_record_no_late_dispatch(
+        paced_engine, byte_tokenizer, monkeypatch):
+    # (the toy's device is this machine's CPU, which the other tests
+    # share: a quarter second's hiccup there would be a true record, so
+    # the floor is raised and the factor alone is what is tested)
+    monkeypatch.setattr(eng, "LATE_MIN_S", 2.0)
+    e = paced_engine
+    n0 = e.tracer.summary()["by_span_ms"]["sync_wait"]["count"]
+    for i in range(5):
+        _gen_within(e, byte_tokenizer, f"healthy {i}", n=24)
+    assert e.tracer.summary()["by_span_ms"]["sync_wait"]["count"] - n0 >= 50
+    assert _late(e) == []
+    assert e.metrics()["lifecycle"]["late_dispatches"] == 0
+
+
+def test_with_the_ring_off_a_late_dispatch_is_counted_and_not_sampled(
+        byte_tokenizer, monkeypatch):
+    """The counters need no ring; the rusage and /proc samples exist for
+    the span's arguments alone and are not taken."""
+    sampled = []
+    real = eng._wait_rusage
+    monkeypatch.setattr(eng, "_wait_rusage",
+                        lambda: sampled.append(1) or real())
+    e = _tiny(byte_tokenizer, tracer=RingTracer(size=64, enabled=False))
+    e.start(precompile=False)
+    try:
+        for i in range(4):
+            _gen_within(e, byte_tokenizer, f"pace {i}")
+        FAULTS.arm("sync_delay_ms", "600", count=1)
+        _gen_within(e, byte_tokenizer, "held, ring off")
+        lc = e.metrics()["lifecycle"]
+        assert lc["late_dispatches"] >= 1 and lc["late_dispatch_s"] > 0.3
+        assert sampled == [] and e.tracer.spans() == []
+    finally:
+        FAULTS.reset()
+        e.shutdown()
+
+
+def test_the_stall_abort_behaves_as_before(paced_engine, byte_tokenizer,
+                                           tmp_path):
+    e = paced_engine
+    e.ecfg.dispatch_stall_ms = 700
+    e.ecfg.stall_dump_dir = str(tmp_path)
+    FAULTS.arm("sync_delay_ms", "1500", count=1)
+    t0 = time.monotonic()
+    events = list(e.generate(eng.GenRequest(
+        prompt_ids=byte_tokenizer.encode("wedged dispatch"),
+        params=sampling.SamplingParamsHost(temperature=0.0),
+        max_new_tokens=8, ignore_eos=True)))
+    # aborted at the budget, not when the delayed item came home
+    assert events[-1].error_kind == "stall"
+    assert 0.7 <= time.monotonic() - t0 < 1.5
+    assert e.metrics()["lifecycle"]["stalls"] == 1
+    time.sleep(1.0)                      # let the delayed item drain
+    e.ecfg.dispatch_stall_ms = 30000
+
+
+def _homecoming(e, item, kind, t_dispatch, t_ready):
+    """What the sync worker does to an item that comes home at
+    ``t_ready``, then the loop's note of it."""
+    item.kind = kind
+    item.t_ref = e._overdue_ref(item)
+    item.t_ready = e._t_last_ready = t_ready
+    e._note_ready(item)
+
+
+def test_an_item_behind_an_unready_one_has_not_begun_its_wait(
+        byte_tokenizer):
+    """The sync worker syncs in dispatch order: a prefill head queued
+    behind a burst that is still computing is not late by its own few
+    milliseconds of pace (the chip's first cold granite run of PR 42
+    read 35 such records of 36 before this rule), and the parked loop's
+    abort counts from the same point."""
+    import collections
+
+    e = _tiny(byte_tokenizer)            # never started: the items are ours
+    now = time.monotonic()
+    e._pace = {"decode_burst": collections.deque([0.2]),       # s a step
+               "prefill_pack_head:16": collections.deque([0.01])}
+    burst = eng._Burst(2, [], None, t_dispatch=now - 1.0)
+    head = eng._PendingPrefill([], None, None, None, now - 1.0, split=True)
+    # the burst took 0.5 s of an expected 0.4; the head, dispatched with
+    # it, came home 10 ms after it: 1 s after its dispatch, 10 ms of wait
+    _homecoming(e, burst, "decode_burst", now - 1.0, now - 0.5)
+    _homecoming(e, head, "prefill_pack_head:16", now - 1.0, now - 0.49)
+    assert head.t_ref == now - 0.5
+    assert _late(e) == [] and e._lc["late_dispatches"] == 0
+    assert len(e._pace["prefill_pack_head:16"]) == 2
+    # the stall abort's reference is the same: 0.49 s of quiet, not 1 s
+    e.ecfg.dispatch_stall_ms = 700
+    parked = eng._Burst(2, [], None, t_dispatch=now - 1.0)
+    e._fifo.append(parked)
+    e._check_parked_stall()              # 0.49 s since the last ready stamp
+    e._t_last_ready = now - 0.8
+    with pytest.raises(eng._DispatchStall):
+        e._check_parked_stall()
+    e._fifo.clear()
+    # ... and a head that itself waits a second, a hundred times its pace,
+    # is late: one span from its own reference point
+    e._t_last_ready = now - 0.49
+    slow = eng._PendingPrefill([], None, None, None, now - 1.0, split=True)
+    _homecoming(e, slow, "prefill_pack_head:16", now - 1.0, now + 0.51)
+    (sp,) = _late(e)
+    assert sp["args"]["kind"] == "prefill_pack_head:16"
+    assert sp["args"]["overdue_ms"] == pytest.approx(1000 - 10, abs=1.0)
+    assert (sp["t0"], sp["t1"]) == (now - 0.49, now + 0.51)
+    assert e._lc["late_dispatches"] == 1
+
+
+def test_a_kind_with_no_history_leaves_no_record(byte_tokenizer):
+    e = _tiny(byte_tokenizer)
+    now = time.monotonic()
+    first = eng._PendingPrefill([], None, None, None, now - 5.0)
+    _homecoming(e, first, "prefill_final:16", now - 5.0, now)
+    assert _late(e) == [] and e._lc["late_dispatches"] == 0
+    assert list(e._pace["prefill_final:16"]) == [pytest.approx(5.0)]
+    # an item that errored before the device (no ready stamp) is no sample
+    e._note_ready(eng._PendingPrefill([], None, None, None, now))
+    assert set(e._pace) == {"prefill_final:16"}
+
+
+def test_late_counters_and_runner_memory_reach_the_metrics_route():
+    from localai_tpu.api import localai_routes as routes
+
+    names = dict(routes._LIFECYCLE_COUNTERS)
+    assert names["late_dispatches"] == "late_dispatches_total"
+    assert names["stalls"] == "engine_stalls_total"
+    src = open(routes.__file__).read()
+    assert src.count('"late_dispatch_seconds_total"') == 2   # cleared, set
+    assert dict(routes._HOST_MEM_GAUGES) == {
+        "rss_bytes": "runner_rss_bytes",
+        "rss_peak_bytes": "runner_rss_peak_bytes"}
+    assert "peak_host_rss_bytes" in routes._SYSOBS_WATERMARKS
+
+
 # ------------------------------------------------- names on what the device runs
 
 @pytest.fixture(scope="module")
@@ -417,6 +667,48 @@ def test_debug_state_carries_profile_and_trace(loaded_servicer, tmp_path):
                for e in ln.events if e.name == "clock_anchor"]
     assert len(anchors) == 1
     assert dict(anchors[0].stats)["monotonic_ns"] == prof["monotonic_ns"]
+
+
+def test_every_load_span_carries_the_runners_resident_memory(loaded_servicer):
+    sv, ring = loaded_servicer
+    loads = [s for s in ring.spans() if s["track"] == "load"]
+    assert {s["name"] for s in loads} >= {
+        "load_model", "load_imports", "load_tokenizer", "load_source",
+        "load_quantize", "load_cast", "load_device_wait", "load_engine_init",
+        "load_precompile"}
+    for s in loads:
+        assert 0 < s["args"]["rss_mb"] <= s["args"]["rss_peak_mb"], s
+    # spans enter the ring as they end: the high-water mark never falls
+    # (but by the kernel's per-thread counting, a fraction of a MB)
+    peaks = [s["args"]["rss_peak_mb"] for s in loads]
+    assert all(b >= a - 1.0 for a, b in zip(peaks, peaks[1:]))
+    hm = sv.engine.state_snapshot()["host_memory"]
+    assert {"rss_bytes", "rss_peak_bytes", "rss_anon_bytes", "rss_file_bytes",
+            "rss_shmem_bytes", "vm_size_bytes", "vm_data_bytes", "at_warm",
+            "peak_in_load"} == set(hm)
+    # as LoadModel returned: after its last span, before anything served
+    assert hm["at_warm"]["rss_peak_bytes"] / 1e6 >= peaks[-1] - 1.0
+    assert hm["at_warm"]["rss_bytes"] > 0
+    pk = hm["peak_in_load"]
+    assert pk["bytes"] / 1e6 == pytest.approx(max(peaks), abs=0.1)
+    assert any(s["args"]["rss_peak_mb"] == pytest.approx(max(peaks), abs=.1)
+               for s in loads if s["name"] == pk["span"]
+               and s["args"].get("leaf", "") == pk["leaf"])
+    # the process record heard the load's own compiles (the listener is
+    # installed when LoadModel starts) and is warm now
+    cp = sv.engine.state_snapshot()["compiles_process"]
+    assert cp["warm"] is True and cp["unowned_after_warmup"] == 0
+    assert cp["compiles_total"] >= cp["unowned_compiles"] >= 0
+
+
+def test_status_reports_what_the_runner_holds_now(loaded_servicer):
+    sv, _ring = loaded_servicer
+    st = sv.Status(None, None)
+    b = dict(st.memory.breakdown)
+    now = sysobs.host_memory()
+    assert set(b) == {"rss", "rss_peak"} and st.memory.total == b["rss"]
+    assert b["rss"] <= b["rss_peak"] == now["rss_peak_bytes"]
+    assert b["rss"] == pytest.approx(now["rss_bytes"], rel=0.2)
 
 
 # ----------------------------------------------------------- one clock
