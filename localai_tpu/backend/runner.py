@@ -38,7 +38,8 @@ _FAMILIES = {"mamba": ("mamba", "MambaConfig"),
              "rwkv": ("rwkv", "RwkvConfig"),
              "olmo_hybrid": ("olmo_hybrid", "OlmoHybridConfig"),
              "granitemoehybrid": ("granite_hybrid", "GraniteHybridConfig"),
-             "lfm2_moe": ("lfm2_moe", "Lfm2MoeConfig")}
+             "lfm2_moe": ("lfm2_moe", "Lfm2MoeConfig"),
+             "ling_hybrid": ("ling_hybrid", "LingHybridConfig")}
 
 # Threads of the runner's gRPC server. A streaming request holds one for
 # its whole life (waiting on the engine's queue, then on its tokens), so
@@ -223,8 +224,8 @@ class EngineServicer(BackendServicer):
                 # engine slots through the family adapter: mamba / rwkv
                 # with a fixed-size recurrent state in the cache lanes
                 # (reference: backend/python/mamba, backend/go/llm/rwkv),
-                # olmo_hybrid, granite_hybrid and lfm2_moe with paged K/V
-                # and a recurrent state
+                # olmo_hybrid, granite_hybrid, lfm2_moe and ling_hybrid with
+                # paged K/V (or latent) rows and a recurrent state
                 import importlib
 
                 module, cfg_class = _FAMILIES[mtype]
@@ -234,6 +235,10 @@ class EngineServicer(BackendServicer):
                     cfg_dict, dtype=dtype)
                 if request.lora_adapter:
                     raise ValueError("LoRA adapters are llama-family only")
+                if request.mmproj and "multimodal" not in family.CAPABILITIES:
+                    raise ValueError(
+                        f"mmproj: a vision tower's injection is not built "
+                        f"for {mtype} (image parts are llama-family only)")
                 if request.draft_model:
                     raise ValueError(
                         "speculative draft models are llama-family only")

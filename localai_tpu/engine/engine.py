@@ -764,14 +764,16 @@ class Engine:
         # expert feed-forward: ops/moe.py). The numbers ride the
         # results a dispatch brings back anyway - a burst's pack, a prefill
         # pack's logprobs - and are folded into /debug/state's "moe"
-        # (its stats: per expert layer the pairs each of E experts got,
-        # then the experts touched: [L, E + 1], flat)
+        # (its stats: per expert layer the pairs each of the E experts held
+        # here got, the experts touched, and the pairs routed in all, held
+        # here or not: [L, E + 2], flat)
         L_r, E_r = self.family.route_stats_shape(model_cfg) \
             if "route_stats" in caps else (0, 0)
-        self._n_route = L_r * (E_r + 1)
+        self._n_route = L_r * (E_r + 2)
         self._route_rows_n = -(-self._n_route // self.ecfg.num_slots)
         self._moe = {k: {"steps": 0,
                          "experts_touched": np.zeros((L_r,), np.int64),
+                         "pairs_routed": np.zeros((L_r,), np.int64),
                          "pairs": np.zeros((L_r, E_r), np.int64)}
                      for k in ("decode", "prefill")} if self._n_route else None
         assert mesh is None or "mesh" in caps, \
@@ -962,6 +964,10 @@ class Engine:
                if self._paged else {}))
         # per-slot recurrent state beside the K/V rows (ops/kvcache.py)
         self._state_bytes = kvcache.state_bytes(self.ck)
+        # a family whose rows are latent (one plane: ops/mla.py) says how
+        # many bytes its pool holds; None for K/V planes
+        latent = getattr(self.family, "latent_cache_bytes", None)
+        self._latent_bytes = latent(self.ck) if latent else None
         self._kv_walk = {"pages_live": 0, "pages_grid": 0}   # _count_kv_walk
         self._state_layers = kvcache.state_layers(self.ck)
         self._state_walk = {"slot_steps_live": 0, "slot_steps_grid": 0}
@@ -1598,10 +1604,14 @@ class Engine:
     def _decode_attn(self) -> str:
         if "paged" not in self._caps:
             return f"{self._fam_name}:recurrent"
-        return llama.decode_attn_impl(self.cfg, self.ck)
+        # a family whose attention is not llama's kernels names its own
+        return getattr(self.family, "decode_attn_impl",
+                       llama.decode_attn_impl)(self.cfg, self.ck)
 
     def _ragged_attn(self, bucket: int, continued: bool) -> str:
-        return llama.ragged_attn_impl(self.cfg, self.ck, bucket, continued)
+        return getattr(self.family, "ragged_attn_impl",
+                       llama.ragged_attn_impl)(self.cfg, self.ck, bucket,
+                                               continued)
 
     def _attention_report(self) -> dict:
         """Where this engine's attention runs, and per compiled program
@@ -2544,19 +2554,24 @@ class Engine:
         st = np.rint(flat[:self._n_route]).astype(np.int64).reshape(
             len(c["pairs"]), -1)
         c["steps"] += steps
-        c["pairs"] += st[:, :-1]
-        c["experts_touched"] += st[:, -1]
-        return int(st[:, -1].sum())
+        c["pairs"] += st[:, :-2]
+        c["experts_touched"] += st[:, -2]
+        c["pairs_routed"] += st[:, -1]
+        return int(st[:, -2].sum())
 
     def _moe_snapshot(self) -> dict:
         """/debug/state's "moe", since start: for the decode steps and for
         the prefill packs apart, how many there were (``steps``), an expert
         layer the distinct experts touched summed over them
-        (``experts_touched`` [L_moe]) and the (row, expert) pairs each
-        expert got (``pairs`` [L_moe][E])."""
+        (``experts_touched`` [L_moe]), the (row, expert) pairs each expert
+        held here got (``pairs`` [L_moe][E]; ``experts`` is that E) and the
+        pairs the rows routed in all, to experts held here or on other
+        chips (``pairs_routed`` [L_moe]: the sum of ``pairs`` where every
+        expert is held)."""
         return {"experts": self._moe["decode"]["pairs"].shape[1],
                 **{k: {"steps": c["steps"],
                        "experts_touched": c["experts_touched"].tolist(),
+                       "pairs_routed": c["pairs_routed"].tolist(),
                        "pairs": c["pairs"].tolist()}
                    for k, c in self._moe.items()}}
 
@@ -3788,6 +3803,8 @@ class Engine:
             "family": self._fam_name,
             "capabilities": sorted(self._caps),
             "recurrent_state_bytes": self._state_bytes,
+            **({} if self._latent_bytes is None else
+               {"latent_cache_bytes": self._latent_bytes}),
             "kv_walk": dict(self._kv_walk),
             "state_walk": dict(self._state_walk),
             **({} if self._moe is None else {"moe": self._moe_snapshot()}),
@@ -7085,7 +7102,14 @@ class Engine:
                                 "slot_ids": [i for i, _ in live],
                                 "rids": [r for _, r in live],
                                 **({} if b.experts_touched is None else
-                                   {"experts_touched": b.experts_touched})})
+                                   {"experts_touched": b.experts_touched}),
+                                # a latent pool: the rows the live slots
+                                # held when the burst began, at the least
+                                **({"ctx_rows": int(sum(
+                                    max(0, int(self.lengths[i]) - b.n_steps)
+                                    for i, _ in live))}
+                                   if self._latent_bytes is not None
+                                   else {})})
                 if b.spec_width:
                     # the fused program has no host-visible boundary
                     # between drafting and verifying: on the device they

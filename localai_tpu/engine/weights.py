@@ -74,7 +74,11 @@ _QUANT_NAMES = {"embed", "lm_head", "wq", "wk", "wv", "wo",
                 # models/granite_hybrid.py's mamba projections
                 "ssm_in_z", "ssm_in_xbc", "ssm_out",
                 # models/lfm2_moe.py's convolution projections
-                "conv_in", "conv_out"}
+                "conv_in", "conv_out",
+                # models/ling_hybrid.py's KDA and MLA projections and its
+                # shared expert
+                "kda_qkv", "kda_f", "kda_g", "kda_o", "mla_q", "mla_kva",
+                "mla_kvb", "mla_o", "sh_w1", "sh_w3", "sh_w2"}
 
 
 def _make_put(cfg, mesh, dtype, quantize, adapter=None, pace=None,
@@ -507,13 +511,18 @@ def _lfm2_moe_leaf_source(model_dir: str, cfg):
     yield ("final_norm",), top("model.embedding_norm.weight")
 
 
-def _lfm2_moe_expert_stack(tensors, cfg, name: str, dtype, span):
-    """One projection of every expert of every expert layer as ONE device
-    leaf ``[L_moe, E, in, out]``, streamed a layer at a time: the host
-    holds one layer's 64 tensors (HF ``[out, in]``), the device casts and
-    transposes them into the leaf in place (the leaf is donated to its own
-    update), so neither side ever holds a second copy of the stack."""
-    E, nd = cfg.num_experts, cfg.num_dense_layers
+def _expert_stack(tensors, tensor_name, n_layers: int, ids, leaf_name: str,
+                  dtype, span):
+    """One projection of the experts ``ids`` of every expert layer as ONE
+    device leaf ``[n_layers, len(ids), in, out]``, streamed a layer at a
+    time: ``tensor_name(mi, e)`` is expert ``e``'s tensor (HF ``[out,
+    in]``) in expert layer ``mi``. The host holds one layer's tensors, the
+    device casts and transposes them into the leaf in place (the leaf is
+    donated to its own update), so neither side ever holds a second copy of
+    the stack. ``ids`` are the experts THIS CHIP holds (all of them, or a
+    share's sorted global ids): no other expert's tensor is read, and a
+    checkpoint need not have it."""
+    ids = list(ids)
 
     @partial(jax.jit, donate_argnums=0)
     def set_layer(leaf, rows, mi):
@@ -521,17 +530,16 @@ def _lfm2_moe_expert_stack(tensors, cfg, name: str, dtype, span):
             leaf, rows.astype(dtype).swapaxes(-1, -2), mi, 0)
 
     leaf = None
-    for mi in range(cfg.moe_layers):
-        with span("load_source", "load", leaf=name):
+    for mi in range(n_layers):
+        with span("load_source", "load", leaf=leaf_name):
             rows = np.stack([tensors[n].get_tensor(n) for n in (
-                f"model.layers.{nd + mi}.feed_forward.experts.{e}."
-                f"{name}.weight" for e in range(E))])
-        with span("load_cast", "load", leaf=name):
+                tensor_name(mi, e) for e in ids)])
+        with span("load_cast", "load", leaf=leaf_name):
             rows = jnp.asarray(rows)
             if leaf is None:
-                leaf = jnp.zeros((cfg.moe_layers, E) + rows.shape[:0:-1],
+                leaf = jnp.zeros((n_layers, len(ids)) + rows.shape[:0:-1],
                                  dtype)
-        with span("load_put", "load", leaf=name):
+        with span("load_put", "load", leaf=leaf_name):
             leaf = set_layer(leaf, rows, mi)
         del rows
     return leaf
@@ -542,7 +550,7 @@ def load_lfm2_moe_params(model_dir: str, cfg, dtype=jnp.bfloat16,
     """Load an ``lfm2_moe`` checkpoint (HF safetensors): every leaf but
     the expert stacks through the cast / placement path of
     ``load_llama_params``, the three expert stacks a layer at a time
-    (``_lfm2_moe_expert_stack``). No mesh: the family refuses one.
+    (``_expert_stack``). No mesh: the family refuses one.
     ``quantize="int8"`` takes the leaves ops/quant.py's quantizer takes (a
     whole host leaf: the operators' projections, the dense feed-forwards,
     the embedding); the expert stacks, nine tenths of the bytes, stay in
@@ -565,9 +573,128 @@ def load_lfm2_moe_params(model_dir: str, cfg, dtype=jnp.bfloat16,
 
     params = _assemble(_lfm2_moe_leaf_source(model_dir, cfg), put, tracer)
     tensors = _open_shards(model_dir)
+    nd = cfg.num_dense_layers
     for name in ("w1", "w3", "w2"):
-        params["layers"][name] = _lfm2_moe_expert_stack(
-            tensors, cfg, name, dtype, span)
+        params["layers"][name] = _expert_stack(
+            tensors, lambda mi, e, name=name: (
+                f"model.layers.{nd + mi}.feed_forward.experts.{e}."
+                f"{name}.weight"),
+            cfg.moe_layers, range(cfg.num_experts), name, dtype, span)
+    return params
+
+
+def _ling_hybrid_leaf_source(model_dir: str, cfg):
+    """(spec_path, host array) for models/ling_hybrid.py's stacked layout,
+    all but the expert stacks: every leaf stacked over the layers that hold
+    one (KDA layers, MLA layers, dense layers, expert layers, every layer).
+    Linear weights become ``[in, out]``; KDA's three depthwise convolutions
+    ``[HK, 1, W]`` become one ``[W, 3HK]`` (q | k | v channels, as the
+    fused projection has them); the router, its bias, ``A_log`` and
+    ``dt_bias`` stay float32."""
+    tensors = _open_shards(model_dir)
+    nd = cfg.num_dense_layers
+
+    def top(name: str) -> np.ndarray:
+        return tensors[name].get_tensor(name)
+
+    def stacked(which, one) -> np.ndarray:
+        return np.stack([one(i) for i in which])
+
+    def get(name: str, transpose=False, dtype=None):
+        def one(i):
+            a = top(f"model.layers.{i}.{name}")
+            a = a.T if transpose else a
+            return a.astype(dtype) if dtype else a
+        return one
+
+    def fused(names, one_of):
+        return lambda i: np.concatenate([one_of(n)(i) for n in names], -1)
+
+    every = range(cfg.num_layers)
+    kda = [i for i, m in enumerate(cfg.mixers) if m == "kda"]
+    mla = [i for i, m in enumerate(cfg.mixers) if m == "mla"]
+    dense, routed = range(nd), range(nd, cfg.num_layers)
+    f32 = np.float32
+    yield ("embed",), top("model.embed_tokens.weight")
+    yield ("layers", "mix_norm"), stacked(every, get("input_layernorm.weight"))
+    yield ("layers", "ff_norm"), stacked(
+        every, get("post_attention_layernorm.weight"))
+    a = "linear_attn."
+    yield ("layers", "kda_qkv"), stacked(kda, fused(
+        ("q", "k", "v"), lambda n: get(f"{a}{n}_proj.weight", True)))
+    yield ("layers", "kda_conv"), stacked(kda, fused(
+        ("q", "k", "v"),
+        lambda n: lambda i: top(
+            f"model.layers.{i}.{a}{n}_conv1d.weight")[:, 0, :].T))
+    yield ("layers", "kda_f"), stacked(kda, get(a + "f_proj.weight", True))
+    yield ("layers", "kda_dt_bias"), stacked(kda, get(a + "dt_bias", False,
+                                                      f32))
+    yield ("layers", "kda_A_log"), stacked(kda, get(a + "A_log", False, f32))
+    yield ("layers", "kda_b"), stacked(kda, get(a + "b_proj.weight", True))
+    yield ("layers", "kda_g"), stacked(kda, get(a + "g_proj.weight", True))
+    yield ("layers", "kda_o_norm"), stacked(kda, get(a + "o_norm.weight"))
+    yield ("layers", "kda_o"), stacked(kda, get(a + "o_proj.weight", True))
+    a = "self_attn."
+    for leaf, name, t in (("mla_q", "q_proj.weight", True),
+                          ("mla_q_norm", "q_norm.weight", False),
+                          ("mla_kva", "kv_a_proj_with_mqa.weight", True),
+                          ("mla_kv_norm", "kv_a_layernorm.weight", False),
+                          ("mla_kvb", "kv_b_proj.weight", True),
+                          ("mla_gate", "g_proj.weight", True),
+                          ("mla_o", "o_proj.weight", True)):
+        yield ("layers", leaf), stacked(mla, get(a + name, t))
+    for leaf, name in (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                       ("w_down", "down_proj")):
+        yield ("layers", leaf), stacked(dense, get(f"mlp.{name}.weight", True))
+    yield ("layers", "router"), stacked(
+        routed, get("mlp.gate.weight", True, f32))
+    yield ("layers", "expert_bias"), stacked(
+        routed, get("mlp.gate.expert_bias", False, f32))
+    for leaf, name in (("sh_w1", "gate_proj"), ("sh_w3", "up_proj"),
+                       ("sh_w2", "down_proj")):
+        yield ("layers", leaf), stacked(
+            routed, get(f"mlp.shared_experts.{name}.weight", True))
+    yield ("final_norm",), top("model.norm.weight")
+    if not cfg.tie_word_embeddings:
+        yield ("lm_head",), top("lm_head.weight").T
+
+
+def load_ling_hybrid_params(model_dir: str, cfg, dtype=jnp.bfloat16,
+                            quantize: str = "", tracer=None) -> dict:
+    """Load a ``ling_hybrid`` checkpoint (HF safetensors): every leaf but
+    the expert stacks through the cast / placement path of
+    ``load_llama_params``, the three expert stacks a layer at a time and
+    only the experts this chip holds (``_expert_stack`` over ``cfg.held``).
+    No mesh: the family refuses one. ``quantize="int8"`` takes the leaves
+    ops/quant.py's quantizer takes (the mixers' projections, the dense and
+    shared feed-forwards, the embedding and the head); the expert stacks
+    stay in ``dtype``: quantized expert leaves are not built."""
+    if quantize not in ("", "int8"):
+        raise ValueError(f"quantization={quantize!r} is not supported for "
+                         "ling_hybrid (only weight-only int8)")
+    if quantize:
+        log.warning("ling_hybrid: quantization=int8 leaves the expert "
+                    "stacks in %s (quantized expert leaves are not built)",
+                    jnp.dtype(dtype).name)
+    span = (tracer or NO_TRACER).span
+    cast = _make_put(cfg, None, dtype, quantize, tracer=tracer)
+    keep_f32 = ("router", "expert_bias", "kda_A_log", "kda_dt_bias")
+
+    def put(arr, spec_path):
+        if spec_path[-1] in keep_f32:
+            return jnp.asarray(arr, jnp.float32)
+        return cast(arr, spec_path)
+
+    params = _assemble(_ling_hybrid_leaf_source(model_dir, cfg), put, tracer)
+    tensors = _open_shards(model_dir)
+    nd = cfg.num_dense_layers
+    held = range(cfg.num_experts) if cfg.held is None else cfg.held
+    for leaf, name in (("w1", "gate_proj"), ("w3", "up_proj"),
+                       ("w2", "down_proj")):
+        params["layers"][leaf] = _expert_stack(
+            tensors, lambda mi, e, name=name: (
+                f"model.layers.{nd + mi}.mlp.experts.{e}.{name}.weight"),
+            cfg.moe_layers, held, leaf, dtype, span)
     return params
 
 

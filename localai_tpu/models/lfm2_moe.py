@@ -203,15 +203,16 @@ class Lfm2MoeConfig:
 
 def route_stats_shape(cfg: Lfm2MoeConfig) -> Tuple[int, int]:
     """(expert layers L, experts E): a step's route stats are, per expert
-    layer, the pairs each of the E experts got and then the experts touched,
-    ``[L, E + 1]`` laid flat (the engine asks by this name where a family
-    declares ``route_stats``)."""
+    layer, the pairs each of the E experts got, the experts touched and the
+    pairs routed in all (ops/moe.py::route_stats), ``[L, E + 2]`` laid flat
+    (the engine asks by this name where a family declares
+    ``route_stats``)."""
     return cfg.moe_layers, cfg.num_experts
 
 
 def _stats(choices, cfg):
     """choices [L_moe, rows, k] -> ``route_stats`` of each layer, laid end
-    to end [L_moe * (E + 1)] float32."""
+    to end [L_moe * (E + 2)] float32."""
     return jax.vmap(lambda c: moe.route_stats(c, cfg.num_experts))(
         choices).reshape(-1)
 

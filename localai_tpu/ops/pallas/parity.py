@@ -45,6 +45,15 @@ about 4 chip-minutes), and the state kernel's at SSM_CELL against live
 slots (mamba2_decode_sweep, with ``live8_over_live48``: a kernel that
 moves only live slots reads well under 0.3 there), one JSON line.
 ``sweep mamba2`` runs the second alone.
+
+The two kernels of ``ling_hybrid`` run at its cell's geometry (KDA_CELL: 96
+slots x 32 heads x 128 x 128 float32; MLA_CELL: 96 slots x 64 pages of 64
+rows x 640 bfloat16, 32 query heads): ``kda_decode`` as the Mamba-2 kernel
+does (NaN wherever no live slot holds, against ops/kda.py::kda_decode), and
+``mla_paged_decode`` with the mixed lengths over NaN pages, against
+ops/mla.py::decode_attention's page gather. ``ling`` runs those alone;
+``sweep kda`` times the state kernel against live slots
+(kda_decode_sweep).
 """
 
 from __future__ import annotations
@@ -57,10 +66,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from localai_tpu.ops import kvcache, ssd
+from localai_tpu.ops import kda, kvcache, mla, ssd
 from localai_tpu.ops.attention import decode_attention_append
 from localai_tpu.ops.pallas.decode_attention import (
     decode_attention_append_pallas)
+from localai_tpu.ops.pallas.kda_decode import kda_decode_pallas
 from localai_tpu.ops.pallas.mamba2_decode import mamba2_decode_pallas
 from localai_tpu.ops.pallas.paged_attention import (
     paged_decode_attention_append, paged_decode_attention_append_quant,
@@ -98,6 +108,13 @@ CELLS = {
 # state size; the live counts the kernel is checked and timed at
 SSM_CELL = (48, 64, 64, 128)
 SSM_LIVE = (0, 8, 30, 48)
+
+
+# ling-flash-vl.reason_wide's geometries: slots, heads, key and value size;
+# slots, pages a slot, query heads, the pool's row width, the latent's rank
+KDA_CELL = (96, 32, 128, 128)
+KDA_LIVE = (0, 8, 64, 96)
+MLA_CELL = (96, 64, 32, 640, 512)
 
 
 def _lengths(page: int, slots: int = S, mp: int = MP):
@@ -396,6 +413,119 @@ def mamba2_decode_sweep() -> dict:
     return out
 
 
+def _kda_inputs(rng, slots, heads, k, v):
+    f32 = np.float32
+
+    def unit(x):
+        return x / np.sqrt((x * x).sum(-1, keepdims=True) + 1e-6)
+
+    q = unit(rng.standard_normal((slots, heads, k))) * k ** -0.5
+    kk = unit(rng.standard_normal((slots, heads, k)))
+    vv = rng.standard_normal((slots, heads, v))
+    g = -5.0 / (1.0 + np.exp(-rng.normal(-3.0, 2.0, (slots, heads, k))))
+    beta = 1.0 / (1.0 + np.exp(-rng.standard_normal((slots, heads))))
+    return tuple(jnp.asarray(a.astype(f32)) for a in (q, kk, vv, g, beta))
+
+
+def check_kda_decode(live: int, interpret: bool = False,
+                     cell=KDA_CELL) -> tuple:
+    """``kda_decode`` with ``live`` slots live on layer 1 of a stacked
+    [2, ...] state -> (the error of the live slots' state, that of their
+    output) against ops/kda.py::kda_decode. Everything no live slot holds is
+    NaN going in and has to come out bit for bit, and the output of a slot
+    that is not live has to be zero."""
+    slots, heads, k, v = cell
+    rng = np.random.default_rng(17 + live)
+    mask = _live_mask(slots, live)
+    state = rng.standard_normal((2, slots, heads, k, v)).astype(np.float32)
+    state[0] = np.nan
+    state[1, ~mask] = np.nan
+    args = _kda_inputs(rng, slots, heads, k, v)
+    active = jnp.asarray(mask)
+    o, new = jax.jit(lambda s, *a: kda_decode_pallas(
+        s, jnp.int32(1), *a, active, interpret=interpret),
+        donate_argnums=0)(jnp.asarray(state), *args)
+    o_ref, new_ref = kda.kda_decode(jnp.asarray(state), 1, *args, active)
+    o, new, new_ref = np.asarray(o), np.asarray(new), np.asarray(new_ref)
+    untouched = np.isnan(new[0]).all() and np.isnan(new[1, ~mask]).all()
+    assert untouched, "a state no live slot holds was rewritten"
+    assert not o[~mask].any(), "output of a slot that is not live"
+    if not live:
+        return 0.0, 0.0
+    return (_max_err(new[1, mask], new_ref[1, mask]),
+            _max_err(o[mask], np.asarray(o_ref)[mask]))
+
+
+def time_kda_decode(live: int, calls: int = 256, cell=KDA_CELL) -> float:
+    """Microseconds a call of the compiled KDA state kernel at the cell's
+    geometry with ``live`` slots live (as ``time_mamba2_decode``)."""
+    slots, heads, k, v = cell
+    args = _kda_inputs(np.random.default_rng(9), slots, heads, k, v)
+    active = jnp.asarray(_live_mask(slots, live))
+
+    @jax.jit
+    def chain(state):
+        def body(i, carry):
+            state, acc = carry
+            o, state = kda_decode_pallas(state, i % 2, *args, active)
+            return state, acc + o
+        return jax.lax.fori_loop(
+            0, calls, body, (state, jnp.zeros((slots, heads, v))))
+
+    state = jnp.zeros((2, slots, heads, k, v), jnp.float32)
+    jax.block_until_ready(chain(state))
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        jax.block_until_ready(chain(state))
+        best = min(best, time.perf_counter() - t0)
+    return round(best / calls * 1e6, 2)
+
+
+def kda_decode_sweep() -> dict:
+    out = {f"live{n}": time_kda_decode(n) for n in KDA_LIVE}
+    out["live8_over_live96"] = round(out["live8"] / out["live96"], 4)
+    return out
+
+
+def check_mla_decode(interpret: bool = False, cell=MLA_CELL) -> float:
+    """``mla_paged_decode`` over layer 1 of a stacked latent pool, mixed
+    lengths through a shuffled page table, every page no live slot holds
+    NaN, against the page gather of ops/mla.py::decode_attention."""
+    slots, mp, heads, wd, rank = cell
+    rng = np.random.default_rng(23)
+    n_pages = slots * mp
+    pool = np.full((2, n_pages, PAGE, 1, wd), np.nan, np.float32)
+    ptab = rng.permutation(n_pages).astype(np.int32).reshape(slots, mp)
+    write = np.asarray(_lengths(PAGE, slots, mp))
+    read = np.asarray(read_lengths(jnp.asarray(write), mp * PAGE))
+    for s in range(slots):
+        for pg in range(-(-int(read[s]) // PAGE)):
+            pool[1, ptab[s, pg]] = _bf16_exact(rng, (PAGE, 1, wd))
+    q = jnp.asarray(_bf16_exact(rng, (slots, heads, wd)) * wd ** -0.5)
+    new = jnp.asarray(_bf16_exact(rng, (slots, 1, wd)), jnp.bfloat16)
+    ck = {"pages": jnp.asarray(pool, jnp.bfloat16), "ptab": jnp.asarray(ptab)}
+    out = mla.decode_attention(q, new, ck, jnp.int32(1), jnp.asarray(read),
+                               rank, pallas=True, interpret=interpret)
+    # the reference reads zeros where the kernel may read nothing
+    ck32 = {"pages": jnp.asarray(np.nan_to_num(pool)), "ptab": ck["ptab"]}
+    with jax.default_matmul_precision("highest"):
+        ref = mla.decode_attention(q, new, ck32, jnp.int32(1),
+                                   jnp.asarray(read), rank)
+    return _max_err(out, ref)
+
+
+def ling_errors(interpret: bool) -> dict:
+    return {
+        **{"kda_decode" + part + name: err
+           for name, args in (
+               [("", (3, True, (6, 4, 16, 128)))] if interpret else
+               [(f"[live{n}]", (n,)) for n in KDA_LIVE])
+           for part, err in zip(("", "_o"), check_kda_decode(*args))},
+        "mla_paged_decode": check_mla_decode(
+            interpret, (6, 4, 4, 128, 64) if interpret else MLA_CELL)}
+
+
 def main(argv=None) -> int:
     # --interpret: the CPU rehearsal of chip_smoke.py (Pallas interpreter,
     # one small pack); without it the kernels run compiled, which needs
@@ -408,13 +538,18 @@ def main(argv=None) -> int:
         return 2
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices())}
+    if "sweep" in argv and "kda" in argv:
+        print(json.dumps({"kda_decode_us": kda_decode_sweep(),
+                          "device": device}))
+        return 0
     if "sweep" in argv:
         out = {"mamba2_decode_us": mamba2_decode_sweep()}
         if "mamba2" not in argv:
             out["paged_decode_us"] = paged_decode_sweep()
         print(json.dumps({**out, "device": device}))
         return 0
-    errors = {
+    errors = ling_errors(interpret) if "ling" in argv else {
+        **ling_errors(interpret),
         "paged_decode": check_paged_decode(False, interpret),
         "paged_decode_int8": check_paged_decode(True, interpret),
         # the cells' geometries: compiled only (the interpreter walks a

@@ -168,6 +168,7 @@ def test_a_row_that_is_not_live_routes_nowhere():
     stats = np.asarray(moe.route_stats(experts, 8))
     assert stats[:8].sum() == 2 * 3             # k pairs a live row
     assert stats[8] == len(set(np.asarray(experts)[np.asarray(live)].ravel()))
+    assert stats[9] == 2 * 3                    # every pair, held or not
 
 
 # ---- the expert products ----
@@ -250,8 +251,9 @@ def test_a_slot_that_does_not_decode_changes_no_state_and_counts_nowhere():
         assert (np.asarray(a["pages"]) != np.asarray(b["pages"])
                 ).any(axis=(-1, -2)).sum() == 2 * CFG.attn_layers
     st = np.asarray(stats).reshape(CFG.moe_layers, -1)
-    assert (st[:, :-1].sum(1) == 2 * 2).all()       # k x live rows a layer
-    assert ((st[:, -1] >= 2) & (st[:, -1] <= 4)).all()
+    assert (st[:, :-2].sum(1) == 2 * 2).all()       # k x live rows a layer
+    assert ((st[:, -2] >= 2) & (st[:, -2] <= 4)).all()
+    assert (st[:, -1] == 2 * 2).all()       # every expert held: all routed
     # and the idle slots' tokens do not matter
     _, _, _, stats2 = jax.jit(
         lambda *a: lm.engine_decode(params, CFG, *a, route_stats=True))(

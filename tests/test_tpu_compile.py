@@ -771,3 +771,105 @@ def test_lfm2_packed_prefill_compiles(topo, N):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 256 << 20
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
+
+
+# ---------- ling_hybrid: KDA states, a latent pool, a share of the experts ----------
+
+S_K, C_K, POOL_K = 96, 4096, 4608    # ling-flash-vl.reason_wide's geometry
+
+
+def _abstract_ling(A):
+    """(cfg, params, ck, cv): the first six layers of Ling-3.0-flash at its
+    published widths, a strided 128 of 512 experts and a quarter of the
+    vocabulary, bf16 weights, the cell's slots and page pool, as
+    ShapeDtypeStructs."""
+    from localai_tpu.models import ling_hybrid as lh
+
+    cfg = lh.LingHybridConfig(
+        vocab_size=39296, num_layers=6, held=tuple(range(0, 512, 4)),
+        attn=llama.AttnTarget(pallas=True))
+
+    def place(tree):
+        return jax.tree.map(lambda x: A(x.shape, x.dtype), tree)
+
+    params = jax.eval_shape(
+        lambda: lh.init_params(cfg, jax.random.PRNGKey(0)))
+    ck, cv = jax.eval_shape(lambda: lh.init_cache(
+        cfg, S_K, C_K, jnp.bfloat16, page_size=PAGE, num_pages=POOL_K))
+    return cfg, place(params), place(ck), place(cv)
+
+
+def test_ling_kernels_compile_at_the_cells_geometry(topo):
+    """``kda_decode`` on the stacked state (a slot's 2.1 MB block in and
+    out, double-buffered, under the kernel's own VMEM limit) and
+    ``mla_paged_decode`` over the latent pool (pages of 64 x 640 bfloat16
+    copied whole out of HBM)."""
+    from localai_tpu.ops.pallas.kda_decode import kda_decode_pallas
+    from localai_tpu.ops.pallas.mla_decode import mla_paged_decode
+
+    A = _on(SingleDeviceSharding(topo.devices[0]))
+    f32, bf, i32 = jnp.float32, jnp.bfloat16, jnp.int32
+    hk = A((S_K, 32, 128), f32)
+    kda = jax.jit(kda_decode_pallas, donate_argnums=0).lower(
+        A((5, S_K, 32, 128, 128), f32), A((), i32), hk, hk, hk, hk,
+        A((S_K, 32), f32), A((S_K,), jnp.bool_)).compile()
+    assert "kda_decode" in kda.as_text()
+    assert kda.memory_analysis().temp_size_in_bytes < 64 << 20
+    mla = jax.jit(lambda *a: mla_paged_decode(*a, rank=512)).lower(
+        A((S_K, 32, 640), f32), A((S_K, 1, 640), bf),
+        A((1, POOL_K, PAGE, 1, 640), bf), A((S_K, C_K // PAGE), i32),
+        A((S_K,), i32), A((), i32)).compile()
+    assert "mla_paged_decode" in mla.as_text()
+    # the pool goes to the kernel as it lies: no copy of it
+    assert mla.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+def test_ling_decode_step_compiles_in_place(topo):
+    """engine_decode at the cell's size with donated caches, routing
+    counters and all: 8.8 GB of arguments, and temporaries far under a
+    layer of state (1 GB over 5 layers), a layer's held experts (0.25 GB a
+    projection) or the latent pool (0.38 GB)."""
+    from localai_tpu.models import ling_hybrid as lh
+
+    A = _on(SingleDeviceSharding(topo.devices[0]))
+    cfg, params, ck, cv = _abstract_ling(A)
+    assert params["layers"]["w1"].shape == (4, 128, 2560, 768)
+    assert params["layers"]["router"].shape == (4, 2560, 512)
+    assert ck["pages"].shape == (1, POOL_K, PAGE, 1, 640)
+    assert cv["pages"].shape == (0, POOL_K, PAGE, 1, 640)
+    assert ck["kda"].shape == (5, S_K, 32, 128, 128)
+    assert lh.decode_attn_impl(cfg, ck) == "pallas:mla_paged_decode"
+
+    def decode(p, t, ln, act, ck, cv):
+        return lh.engine_decode(p, cfg, t, ln, act, ck, cv, route_stats=True)
+
+    compiled = jax.jit(decode, donate_argnums=(4, 5)).lower(
+        params, A((S_K,), jnp.int32), A((S_K,), jnp.int32),
+        A((S_K,), jnp.bool_), ck, cv).compile()
+    mem = compiled.memory_analysis()
+    assert 8.5e9 < mem.argument_size_in_bytes < 9.5e9
+    assert mem.temp_size_in_bytes < 128 << 20
+    hlo = compiled.as_text()
+    assert "kda_decode" in hlo and "mla_paged_decode" in hlo
+
+
+@pytest.mark.parametrize("N", [512, 1024])
+def test_ling_packed_prefill_compiles(topo, N):
+    """A continued pack: the chunked KDA rule in chunks of 16, the
+    materialised MLA form walking the slots' committed latent rows, the
+    grouped expert products over the held stacks."""
+    from localai_tpu.models import ling_hybrid as lh
+
+    A = _on(SingleDeviceSharding(topo.devices[0]))
+    cfg, params, ck, cv = _abstract_ling(A)
+    i32 = jnp.int32
+
+    def pack(p, t, pos, so, ss, st, off, ln, ck, cv):
+        return lh.ragged_prefill(p, cfg, t, pos, so, ss, st, off, ln, ck, cv,
+                                 continued=True, route_stats=True)
+
+    compiled = jax.jit(pack, donate_argnums=(8, 9)).lower(
+        params, A((N,), i32), A((N,), i32), A((N,), i32),
+        *_seg_tables(A, S_K), ck, cv).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
