@@ -278,7 +278,7 @@ def _refresh_engine_metrics(state):
               *_LATENCY_HISTOGRAMS,
               *(f"ttft_{m}_p50_ms" for _k, m in _TTFT_GAUGES),
               *(f"prefill_packed_{k}_total" for k in _PACKED_COUNTERS),
-              "prefill_kernel_fallback_total",
+              "prefill_kernel_fallback_total", "decode_bursts_total",
               *(f"prefix_cache_{k}_total" for k in _PCACHE_COUNTERS),
               *(f"kv_offload_{m}_total" for _k, m in _OFFLOAD_COUNTERS),
               *(f"kv_prefetch_{m}_total" for _k, m in _PREFETCH_COUNTERS),
@@ -363,6 +363,11 @@ def _refresh_engine_metrics(state):
             METRICS.set_counter("prefill_kernel_fallback_total",
                                 pp.get("kernel_fallback", 0),
                                 label_str(model=name))
+        # decode bursts by the sampler's branch: all live rows plain
+        # greedy (argmax, no candidate window) or the window
+        for branch, n in (stats.get("sampler_bursts") or {}).items():
+            METRICS.set_counter("decode_bursts_total", n,
+                                label_str(model=name, sampler=branch))
         lc = stats.get("lifecycle")
         if lc:
             for skey, mkey in _LIFECYCLE_COUNTERS:

@@ -971,6 +971,9 @@ class Engine:
         self._kv_walk = {"pages_live": 0, "pages_grid": 0}   # _count_kv_walk
         self._state_layers = kvcache.state_layers(self.ck)
         self._state_walk = {"slot_steps_live": 0, "slot_steps_grid": 0}
+        # bursts by the branch of the sampler their steps take
+        # (_sampler_branch): metrics()["sampler_bursts"]
+        self._sampler_bursts = {"plain_greedy": 0, "window": 0}
         # draft cache is allocated LAZILY at the first spec-eligible
         # admission (r2 allocated it up front, doubling per-slot KV HBM
         # even when no request could ever speculate)
@@ -2644,7 +2647,10 @@ class Engine:
         Inactive slots (free / mid-prefill) must NOT advance their cache
         state (the family adapter masks KV writes / state updates), and
         only active slots consume RNG/mirostat/ring state: a prefilling
-        slot's seeded state must not advance with others' decode steps."""
+        slot's seeded state must not advance with others' decode steps.
+        Which branch of the sampler a step takes is constant over the
+        burst, so the predicate is computed here, outside the scan."""
+        all_plain = sampling.all_plain_greedy(slot_params, active)
 
         def step(carry, _):
             tokens, ck, cv, lengths, ring, ring_pos, keys, mu = carry
@@ -2655,7 +2661,7 @@ class Engine:
             ids, logprobs, new_keys, new_mu = sampling.sample(
                 logits, slot_params, ring, ring_pos, bias, keys, mu,
                 use_penalties=flags[0], use_typical=flags[1],
-                use_mirostat=flags[2])
+                use_mirostat=flags[2], all_plain=all_plain)
             keys = jnp.where(active[:, None], new_keys, keys)
             mu = jnp.where(active, new_mu, mu)
             ring, ring_pos = sampling.update_ring(ring, ring_pos, ids, active)
@@ -2809,7 +2815,7 @@ class Engine:
         mu_rows = jnp.take(jnp.asarray(mu), seg_slots, axis=0)
         ids, logprobs, new_keys, new_mu = sampling.sample(
             logits, sp_rows, ring_rows, rpos_rows, bias_rows, key_rows,
-            mu_rows)
+            mu_rows, active=final_mask)
         keys = keys.at[seg_slots].set(
             jnp.where(final_mask[:, None], new_keys, key_rows),
             mode="drop")
@@ -2868,7 +2874,7 @@ class Engine:
             ring_rows, rpos_rows,
             jnp.take(bias, seg_slots, axis=0),
             jnp.take(keys, seg_slots, axis=0),
-            jnp.take(mu, seg_slots, axis=0))
+            jnp.take(mu, seg_slots, axis=0), active=final_mask)
         gate = final_mask
         keys = keys.at[seg_slots].set(
             jnp.where(gate[:, None], new_keys,
@@ -3507,6 +3513,8 @@ class Engine:
             "prefill_packed": self._packed,
             "prefill_token_budget": self._pack_budget,
             "packed_prefill": dict(self._pack_stats),
+            # decode bursts by the sampler's branch (_sampler_branch)
+            "sampler_bursts": dict(self._sampler_bursts),
         }
         # speculative decoding (ISSUE 13): per-round counters + the two
         # derived rates the bench/CI gate on — acceptance (accepted /
@@ -6046,7 +6054,8 @@ class Engine:
         burst_fn = self._get_burst_fn(K)
         self._tick_decode_tokens += K * len(included)
         self._count_kv_walk(K, infl, [i for i, _ in included])
-        with self._annot("decode_burst", steps=K, slots=len(included)):
+        with self._annot("decode_burst", steps=K, slots=len(included),
+                         plain_greedy=self._sampler_branch(active)):
             pack, self.ck, self.cv, self.rng_keys, self._chain = burst_fn(
                 self.params, chain[0], self.ck, self.cv, chain[1],
                 chain[2], chain[3], self.bias, self.rng_keys, spp,
@@ -6147,7 +6156,8 @@ class Engine:
         self._tick_prefill_tokens += sum(t for _g, t in group)
         self._tick_decode_tokens += K * len(included)
         self._count_kv_walk(K, infl, [i for i, _ in included])
-        with self._annot("prefill_fused", steps=K, slots=len(included)):
+        with self._annot("prefill_fused", steps=K, slots=len(included),
+                         plain_greedy=self._sampler_branch(active)):
             pack, self.ck, self.cv, self.rng_keys, self._chain = fn(
                 self.params, chain[0], self.ck, self.cv, chain[1],
                 chain[2], chain[3], self.bias, self.rng_keys,
@@ -6575,7 +6585,7 @@ class Engine:
                 ids0, lps0, new_keys, new_mu = sampling.sample(
                     logits, sp, ring, ring_pos, bias, keys, mu,
                     use_penalties=flags[0], use_typical=flags[1],
-                    use_mirostat=flags[2])
+                    use_mirostat=flags[2], active=plain_rows)
                 keys = jnp.where(plain_rows[:, None], new_keys, keys)
                 mu = jnp.where(plain_rows, new_mu, mu)
                 return ids0, lps0, ck, cv, keys, mu
@@ -6602,12 +6612,13 @@ class Engine:
                     # filtered verify distribution via the sampler's own
                     # code path (sampling.filter_window under
                     # verify_dist): idx[:,:,0] is approx_max_k's retained
-                    # global argmax with the same tie-breaks as
-                    # sampling.sample's greedy path, so the greedy spec
-                    # stream matches plain greedy bit-for-bit — and the
-                    # window probs ARE the law plain sampling draws from,
-                    # so rejection acceptance against them is
-                    # distribution-lossless
+                    # global argmax, so the greedy spec stream is plain
+                    # greedy's wherever a row's maximum is single (among
+                    # equal maxima the window's rank 0 is its sort's pick
+                    # and sampling's greedy branch takes the lowest
+                    # index: both are a maximum) — and the window probs
+                    # ARE the law plain sampling draws from, so rejection
+                    # acceptance against them is distribution-lossless
                     vidx, vprobs = sampling.verify_dist(
                         all_logits, sp, use_typical=flags[1])
                     greedy = vidx[:, :, 0]
@@ -6779,6 +6790,17 @@ class Engine:
         self._kv_walk["pages_grid"] += (
             n_steps * self.ecfg.num_slots * self._pool.max_pages)
 
+    def _sampler_branch(self, active) -> int:
+        """1 where every step of the burst about to go out with ``active``
+        takes the sampler's greedy branch, 0 where the window runs: the
+        predicate the device evaluates (sampling.all_plain_greedy), on
+        the host's vectors, for the burst's span and the counters. A spec
+        tick's rounds ask it of their undrafted rows alone, so a 0 there
+        says a round MAY take the window."""
+        plain = int(sampling.all_plain_greedy(self.slot_params, active))
+        self._sampler_bursts["plain_greedy" if plain else "window"] += 1
+        return plain
+
     def _count_spec_kv_walk(self, b: "_Burst", live_idx):
         """kv_walk for a folded spec tick: the decode step ran in the
         rounds in which some row was not drafted, for those rows, each
@@ -6892,6 +6914,7 @@ class Engine:
             self._count_kv_walk(n_steps, infl, included)
         with self._annot(
                 "decode_burst", steps=n_steps, slots=len(included),
+                plain_greedy=self._sampler_branch(active),
                 **({"spec_slots": int(spec_mask.sum()), "spec_width": W}
                    if plan is not None else {})):
             if plan is None:

@@ -10,12 +10,21 @@ per-token host roundtrip.
 
 TPU-first design (round 2 rework, measured on the serving chip):
   * Full-vocab [S, V] passes are the dominant sampling cost on the target
-    device (each costs ~2-6 ms regardless of FLOPs). The sampler therefore
-    touches the full vocab exactly ONCE — an ``approx_max_k`` that reduces
-    [S, V] to a [S, SORT_K] candidate window — and does all other work
-    (penalties, temperature, top-k/p/min-p/typical-p, categorical, logprobs)
-    on the window. approx_max_k's bin-max algorithm always retains the
-    global argmax, so greedy decoding stays exact.
+    device (each costs ~2-6 ms regardless of FLOPs). ``sample`` takes one of
+    two branches of ONE ``lax.cond``, chosen on the device from the rows'
+    own parameters (``all_plain_greedy``):
+      - the WINDOW branch, for a batch in which some row that counts
+        samples or is penalised, touches the full vocab exactly ONCE — an
+        ``approx_max_k`` that reduces [S, V] to a [S, SORT_K] candidate
+        window, whose aggregation is a sort that grows with rows x
+        candidates — and does all other work (penalties, temperature,
+        top-k/p/min-p/typical-p, categorical, logprobs) on the window.
+        approx_max_k's bin-max algorithm always retains the global argmax,
+        so greedy decoding stays exact.
+      - the GREEDY branch, for a batch whose rows that count are all plain
+        greedy (temperature 0, neutral penalties), is row reductions over
+        (logits + bias) and nothing else: a max, an argmax and a
+        logsumexp. No window, no sort, no key split, no draw.
   * Repetition penalties use a per-slot RING BUFFER of the last
     ``RING_N`` context tokens instead of a [S, V] histogram. This matches
     llama.cpp's semantics (penalty_last_n window, default 64 — the r1
@@ -34,8 +43,19 @@ Exactness notes:
     (repeat_last_n=256 and 256 distinct recent tokens filling the entire
     top-256), greedy can pick a penalized token over an unpenalized
     rank-257 one.
-  * Logprobs are normalized over the candidate window (tail mass beyond
-    SORT_K is dropped); for real model logits the tail holds <~2% mass.
+  * Window-branch logprobs are normalized over the candidate window (tail
+    mass beyond SORT_K is dropped); for real model logits the tail holds
+    <~2% mass.
+  * The greedy branch: the pick is ``argmax(logits + bias)``, the LOWEST
+    index among equal maxima (logits are bfloat16 values cast to float32 —
+    models/llama.py, models/hybrid_common.py — so equal maxima happen; the
+    window's rank 0 among equals is whatever approx_max_k's sort leaves
+    first). Its logprob is normalized over the WHOLE vocabulary, so it is
+    the more exact of the two and never above the window's. Keys and mu
+    come back as they went in: a row that never draws does not advance its
+    key (the window branch splits every row's key, greedy rows' too, and
+    its callers keep the new key for the rows that count) — a greedy row's
+    stream never reads its key, so no token depends on which branch ran.
 """
 
 from __future__ import annotations
@@ -251,6 +271,14 @@ def _window_counts(ring, pos, idx, repeat_last_n):
     return jnp.sum(match & in_window[:, None, :], axis=-1).astype(jnp.int32)
 
 
+def penalised(slot_params):
+    """Rows with a penalty off its neutral value ([S] bool; host numpy
+    vectors and traced ones alike)."""
+    return ((slot_params["repeat_penalty"] != 1.0)
+            | (slot_params["presence_penalty"] != 0.0)
+            | (slot_params["frequency_penalty"] != 0.0))
+
+
 def feature_flags(slot_params, active=None) -> dict:
     """Host-side: which sampler features any (active) slot actually uses.
 
@@ -260,14 +288,33 @@ def feature_flags(slot_params, active=None) -> dict:
     window counts, the typical-p double argsort, and the mirostat math.
     """
     sel = slice(None) if active is None else active
-    pen = (np.any(slot_params["repeat_penalty"][sel] != 1.0)
-           or np.any(slot_params["presence_penalty"][sel] != 0.0)
-           or np.any(slot_params["frequency_penalty"][sel] != 0.0))
     return {
-        "use_penalties": bool(pen),
+        "use_penalties": bool(np.any(penalised(slot_params)[sel])),
         "use_typical": bool(np.any(slot_params["typical_p"][sel] < 1.0)),
         "use_mirostat": bool(np.any(slot_params["mirostat"][sel] > 0)),
     }
+
+
+def plain_greedy(slot_params):
+    """Rows that need an argmax and nothing else: greedy with neutral
+    penalties ([S] bool). Mirostat, typical-p, top-k/p and the temperature
+    never reach a greedy row's pick or logprob; a logit bias does, and the
+    greedy branch of `sample` adds it. Host numpy vectors and traced ones
+    alike."""
+    return slot_params["greedy"] & ~penalised(slot_params)
+
+
+def all_plain_greedy(slot_params, active=None):
+    """Scalar bool: every row that counts is plain greedy — the predicate
+    `sample` branches on. A free slot keeps its last request's parameters
+    (set_slot is the only writer), so the rows that do not count
+    (~active) must not vote: one finished sampling request would
+    otherwise pin a server to the window for good. The engine evaluates
+    the same expression on its host vectors for the burst's span."""
+    plain = plain_greedy(slot_params)
+    if active is not None:
+        plain = plain | ~active
+    return plain.all()
 
 
 def filter_window(logits, slot_params, ring, ring_pos, logit_bias, mu=None,
@@ -357,10 +404,20 @@ def filter_window(logits, slot_params, ring, ring_pos, logit_bias, mu=None,
     return idx, masked, vals
 
 
+def _greedy_rows(logits, logit_bias):
+    """The greedy branch's whole work: (argmax ids [S] int32, their
+    full-vocabulary logprobs [S]). argmax returns the lowest index among
+    equal maxima."""
+    x = logits + logit_bias
+    ids = jnp.argmax(x, axis=-1).astype(jnp.int32)
+    logprobs = jnp.max(x, axis=-1) - jax.nn.logsumexp(x, axis=-1)
+    return ids, logprobs
+
+
 @jax.named_scope("sample")   # the scope device time is sorted by (PERF.md 3)
 def sample(logits, slot_params, ring, ring_pos, logit_bias, rng_keys, mu=None,
            use_penalties: bool = True, use_typical: bool = True,
-           use_mirostat: bool = True):
+           use_mirostat: bool = True, active=None, all_plain=None):
     """Sample one token per slot.
 
     logits: [S, V] fp32; ring/ring_pos: penalty state from make_ring;
@@ -369,13 +426,42 @@ def sample(logits, slot_params, ring, ring_pos, logit_bias, rng_keys, mu=None,
     use_*: STATIC feature gates (see feature_flags) — False traces the
     block out entirely; semantics are unchanged when the corresponding
     per-slot parameters are at their neutral values.
+    active: [S] bool, the rows whose result the caller keeps (None = all).
+    all_plain: `all_plain_greedy(slot_params, active)` where the caller has
+    it already (a burst computes it once, outside its scan).
     Returns (token_ids [S] int32, logprobs [S] fp32, new_rng_keys, new_mu).
+
+    ONE lax.cond on all_plain: true runs `_greedy_rows` and hands keys and
+    mu back as they came (module docstring, exactness notes); false runs
+    `_sample_window`, the full sampler, for every row.
 
     Mirostat (llama.cpp mirostat v2 semantics, sample_token_mirostat_v2:
     truncate candidates whose surprise exceeds mu, sample, then
     mu -= eta * (observed_surprise - tau)) replaces the top-k/p/min-p
     chain for slots with slot_params["mirostat"] > 0.
     """
+    if all_plain is None:
+        all_plain = all_plain_greedy(slot_params, active)
+    mu = None if mu is None else jnp.asarray(mu)
+
+    def greedy_branch():
+        return (*_greedy_rows(logits, logit_bias), rng_keys, mu)
+
+    def window_branch():
+        return _sample_window(
+            logits, slot_params, ring, ring_pos, logit_bias, rng_keys, mu,
+            use_penalties=use_penalties, use_typical=use_typical,
+            use_mirostat=use_mirostat)
+
+    return jax.lax.cond(all_plain, greedy_branch, window_branch)
+
+
+def _sample_window(logits, slot_params, ring, ring_pos, logit_bias, rng_keys,
+                   mu, use_penalties: bool, use_typical: bool,
+                   use_mirostat: bool):
+    """The window branch of `sample`: every row through the candidate
+    window, a key split and a categorical draw; a greedy row takes the
+    window's rank 0."""
     use_mirostat = use_mirostat and mu is not None
     idx, masked, vals = filter_window(
         logits, slot_params, ring, ring_pos, logit_bias, mu=mu,

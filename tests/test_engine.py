@@ -135,6 +135,47 @@ def test_queue_overflow_queues_requests(running_engine, byte_tokenizer):
     assert done == 6
 
 
+def test_greedy_text_is_the_same_beside_a_sampling_request(running_engine,
+                                                          byte_tokenizer):
+    """A batch of plain greedy rows takes the sampler's greedy branch, one
+    live sampling row moves the batch to the window, and the greedy
+    request reads the same either way; the slot the sampling request
+    leaves behind does not hold later bursts on the window."""
+    e = running_engine
+
+    def greedy():
+        return eng.GenRequest(
+            prompt_ids=byte_tokenizer.encode("the same answer twice"),
+            params=sampling.SamplingParamsHost(temperature=0.0),
+            max_new_tokens=24, ignore_eos=True)
+
+    def bursts(since):
+        return [s["args"]["plain_greedy"] for s in e.tracer.spans()[since:]
+                if s["name"] in ("decode_burst", "prefill_fused")]
+
+    n0 = len(e.tracer.spans())
+    alone, _ = e.generate_text(greedy())
+    assert set(bursts(n0)) == {1}
+    n1 = len(e.tracer.spans())
+    sampled = eng.GenRequest(
+        prompt_ids=byte_tokenizer.encode("something else"),
+        params=sampling.SamplingParamsHost(temperature=0.8, seed=5),
+        max_new_tokens=80, ignore_eos=True)
+    out = e.submit(sampled)
+    out.get(timeout=60)                  # the sampling row is decoding
+    beside, _ = e.generate_text(greedy())
+    assert beside == alone
+    assert 0 in bursts(n1)
+    while out.get(timeout=60).finish_reason is None:
+        pass
+    n2 = len(e.tracer.spans())
+    again, _ = e.generate_text(greedy())
+    assert again == alone
+    assert set(bursts(n2)) == {1}
+    kinds = e.metrics()["sampler_bursts"]
+    assert kinds["plain_greedy"] >= 2 and kinds["window"] >= 1
+
+
 def test_metrics_surface(running_engine):
     m = running_engine.metrics()
     assert m["slots_total"] == 4

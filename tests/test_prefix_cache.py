@@ -247,7 +247,10 @@ def test_no_false_reuse_on_hash_chain_divergence(tiny_cfg_params):
     may be reused; the divergent tail never matches, and the output
     equals a cold prefill of the divergent prompt."""
     cfg, params = tiny_cfg_params
-    rng = np.random.default_rng(11)
+    # (seed 11's prompt ends, in the cold engine alone, on two equal
+    # bfloat16 maxima, tokens 50 and 51: which of two equal maxima a
+    # greedy row takes is the sampler's tie rule, not this test's subject)
+    rng = np.random.default_rng(12)
     pgs = 16
     a = _prompt(rng, 48)
     div = list(a)

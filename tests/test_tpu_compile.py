@@ -279,10 +279,8 @@ def _abstract_7b(A, context, kv_dtype):
 _HLO_DTYPE = {"bf16": jnp.bfloat16, "s8": jnp.int8, "f32": jnp.float32}
 
 
-def _loop_instructions(hlo: str):
-    """The instruction lines of every computation a ``while`` of the
-    optimised module runs: loop bodies and conditions, and the fusions
-    and calls under them."""
+def _computations(hlo: str) -> dict:
+    """name -> instruction lines of every computation of the module."""
     import re
 
     comps, cur = {}, None
@@ -294,17 +292,35 @@ def _loop_instructions(hlo: str):
             cur = None
         elif cur is not None:
             cur.append(line)
+    return comps
+
+
+def _reachable(comps: dict, roots) -> list:
+    """The instruction lines of the computations ``roots`` name and of
+    every fusion, call, loop body and condition under them."""
+    import re
+
     callee = re.compile(r"(?:body|condition|calls|to_apply)=%?([\w.\-]+)")
-    todo = [c for lines in comps.values() for ln in lines if " while(" in ln
-            for c in re.findall(r"(?:body|condition)=%?([\w.\-]+)", ln)]
-    assert todo, "no while loop in the module: the layer scan is gone"
-    seen = set()
+    seen, todo = set(), list(roots)
     while todo:
         name = todo.pop()
         if name not in seen:
             seen.add(name)
             todo += [c for ln in comps[name] for c in callee.findall(ln)]
     return [ln for name in sorted(seen) for ln in comps[name]]
+
+
+def _loop_instructions(hlo: str):
+    """The instruction lines of every computation a ``while`` of the
+    optimised module runs: loop bodies and conditions, and the fusions
+    and calls under them."""
+    import re
+
+    comps = _computations(hlo)
+    loops = [c for lines in comps.values() for ln in lines if " while(" in ln
+             for c in re.findall(r"(?:body|condition)=%?([\w.\-]+)", ln)]
+    assert loops, "no while loop in the module: the layer scan is gone"
+    return _reachable(comps, loops)
 
 
 def _assert_no_layer_of_the_pool(compiled, ck):
@@ -431,6 +447,47 @@ def test_spec_tick_passes_the_pool_through_its_conditionals(topo, context):
         assert mem.temp_size_in_bytes < pool_bytes
     in_loop = "\n".join(_loop_instructions(hlo))
     assert len(re.findall(r" conditional\(", in_loop)) == 2
+
+
+def test_sampler_greedy_branch_compiles_without_a_sort(topo):
+    """`sampling.sample` at the Ling cell's geometry ([96, 39296] float32
+    logits): ONE conditional, whose true branch - every row that counts
+    plain greedy - holds no sort and no ApproxTopK, and whose false
+    branch is the window with both (ISSUE 48)."""
+    import re
+
+    from localai_tpu.engine import sampling
+
+    A = _on(SingleDeviceSharding(topo.devices[0]))
+    S_L, V_L = 96, 39296
+    spp = sampling.pack_slot_params(sampling.make_slot_params(S_L))
+    i32, f32 = jnp.int32, jnp.float32
+
+    def sample(logits, spp, ring, pos, bias, keys, mu, active):
+        return sampling.sample(
+            logits, sampling.unpack_slot_params(spp), ring, pos, bias, keys,
+            mu, use_penalties=False, use_typical=False, use_mirostat=False,
+            active=active)
+
+    hlo = jax.jit(sample).lower(
+        A((S_L, V_L), f32), A(spp.shape, spp.dtype),
+        A((S_L, sampling.RING_N), i32), A((S_L,), i32), A((S_L, V_L), f32),
+        A((S_L, 2), jnp.uint32), A((S_L,), f32),
+        A((S_L,), jnp.bool_)).compile().as_text()
+    comps = _computations(hlo)
+    conds = [ln for lines in comps.values() for ln in lines
+             if " conditional(" in ln]
+    assert len(conds) == 1, conds
+    # lax.cond lowers to a case on the predicate as an index: branch 0 is
+    # the false function, branch 1 the true one
+    m = re.search(r"branch_computations=\{%?([\w.\-]+), %?([\w.\-]+)\}",
+                  conds[0])
+    assert m, conds[0]
+    window, greedy = ("\n".join(_reachable(comps, [b])) for b in m.groups())
+    wanted = re.compile(r" sort\(|ApproxTopK|approx_top_k", re.I)
+    assert not wanted.findall(greedy)
+    assert re.search(r" sort\(", window)
+    assert re.search(r"ApproxTopK|approx_top_k", window, re.I)
 
 
 # ---------- the hybrid family: paged K/V beside a recurrent state ----------
