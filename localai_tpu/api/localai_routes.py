@@ -851,9 +851,12 @@ async def debug_profile(request):
             continue
         try:
             # the capture itself, then stop_trace: 7-8 s per captured
-            # second of a busy device, measured (PERF.md section 6, PR 25)
+            # second of a busy device whose steps are a few hundred
+            # operations (PERF.md section 6, PR 25), and 25 s where a
+            # step is 1500 small ones (models/xing4.py: section 6, PR 50,
+            # where 30 + 12 x seconds gave up on a capture of 2 s)
             r = await state.run_blocking(
-                lm.client.profile, seconds, 30.0 + 12.0 * seconds)
+                lm.client.profile, seconds, 60.0 + 60.0 * seconds)
         except Exception as e:
             return api_error(f"profile RPC failed: {e}", 502)
         return web.json_response({

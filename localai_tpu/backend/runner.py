@@ -39,7 +39,13 @@ _FAMILIES = {"mamba": ("mamba", "MambaConfig"),
              "olmo_hybrid": ("olmo_hybrid", "OlmoHybridConfig"),
              "granitemoehybrid": ("granite_hybrid", "GraniteHybridConfig"),
              "lfm2_moe": ("lfm2_moe", "Lfm2MoeConfig"),
-             "ling_hybrid": ("ling_hybrid", "LingHybridConfig")}
+             "ling_hybrid": ("ling_hybrid", "LingHybridConfig"),
+             "xing4_0": ("xing4", "Xing4Config")}
+
+# options that ask for the host tier under the page pool or for what stands
+# on it (engine/kv_offload.py, the snap-back window, services/kv_wire.py)
+_HOST_TIER_OPTIONS = ("kv_offload", "kv_host_store", "kv_host_pool_mb",
+                      "kv_window_pages", "kv_serve", "kv_peers")
 
 # Threads of the runner's gRPC server. A streaming request holds one for
 # its whole life (waiting on the engine's queue, then on its tokens), so
@@ -225,7 +231,8 @@ class EngineServicer(BackendServicer):
                 # with a fixed-size recurrent state in the cache lanes
                 # (reference: backend/python/mamba, backend/go/llm/rwkv),
                 # olmo_hybrid, granite_hybrid, lfm2_moe and ling_hybrid with
-                # paged K/V (or latent) rows and a recurrent state
+                # paged K/V (or latent) rows and a recurrent state,
+                # xing4_0 with latent rows alone
                 import importlib
 
                 module, cfg_class = _FAMILIES[mtype]
@@ -242,6 +249,16 @@ class EngineServicer(BackendServicer):
                 if request.draft_model:
                     raise ValueError(
                         "speculative draft models are llama-family only")
+                asked = [k for k in _HOST_TIER_OPTIONS if str(
+                    extra.get(k, "")).strip().lower() not in (
+                        "", "0", "false", "off", "no")]
+                if asked and "prefix_reuse" in family.CAPABILITIES \
+                        and "kv_offload" not in family.CAPABILITIES:
+                    raise ValueError(
+                        f"{asked[0]}: a host tier under {mtype}'s pool is "
+                        "not built (kv_offload, the snap-back window and "
+                        "the page wire hold a K and a V plane; this "
+                        "family's pages are one latent plane)")
                 if "ga_n" in (request.options or ""):
                     raise ValueError(
                         "self-extend (group_attn_n) is llama-family only")

@@ -740,8 +740,11 @@ class Engine:
         # the family DECLARES (its CAPABILITIES): "paged" (K/V rows in the
         # page pool, through llama's attention kernels), "packed_prefill"
         # (a ragged_prefill forward), "prefix_reuse" (a slot can resume
-        # from cached K/V pages alone: prefix cache, COW sharing, fork
-        # dedup, prompt-cache files, offload), "speculation",
+        # from cached pages alone: prefix cache, COW sharing, fork
+        # dedup, prompt-cache files), "kv_offload" (its pages are a K and
+        # a V plane, which is what the host tier, the snap-back window
+        # over it and the page wire hold: models/xing4.py's one latent
+        # plane reuses prefixes and declares no host tier), "speculation",
         # "self_extend", "multimodal", and "mesh" (the family's params
         # and cache have sharding rules). models/llama.py declares all;
         # mamba / rwkv only "mesh" (a fixed-size state in the cache
@@ -889,7 +892,7 @@ class Engine:
                          if shared_kv is not None else {})
                 self._pcache = prefix_cache.PrefixPageCache(
                     scope, pg, **hooks)
-                if self.ecfg.kv_offload:
+                if self.ecfg.kv_offload and "kv_offload" in caps:
                     # the host-RAM tier under the pool (the scope doubles
                     # as the persisted file's model/geometry check)
                     from localai_tpu.engine.kv_offload import (
@@ -923,6 +926,10 @@ class Engine:
         self._prefetch = None
         if self.ecfg.kv_window_pages > 0:
             W = int(self.ecfg.kv_window_pages)
+            if "kv_offload" not in caps:
+                raise ValueError(
+                    "kv_window_pages: the snap-back window is not declared "
+                    f"by {self._fam_name}")
             if not self._paged or self._pcache is None:
                 raise ValueError(
                     "kv_window_pages requires the paged KV layout with the "
@@ -1097,10 +1104,11 @@ class Engine:
                         and "packed_prefill" in caps and bus is None)
         # the per-slot prefill programs serve what cannot ride a pack
         # (multimodal shapes, compressed self-extend positions, the
-        # snap-back window): a family that declares none of those and
-        # packs never dispatches them, so they are not warmed either
+        # snap-back window over the host tier): a family that declares
+        # none of those and packs never dispatches them, so they are not
+        # warmed either
         self._per_slot_prefill = (not self._packed or bool(
-            caps & {"multimodal", "self_extend", "prefix_reuse"}))
+            caps & {"multimodal", "self_extend", "kv_offload"}))
         co = str(self.ecfg.comm_overlap)
         # TokenWeave halved-pack overlap (models/llama.py): only ever a
         # win when per-layer collectives exist, so auto arms it on a
@@ -5266,6 +5274,7 @@ class Engine:
         Shared by the leader's restore path and the lockstep follower's
         cache_restore replay (both must build IDENTICAL inputs)."""
         L, _, C, KV, hd = kvcache.shape(self.ck)
+        Lv = kvcache.shape(self.cv)[0]   # 0: one latent plane, no V rows
         try:
             data = np.load(path)
             ctoks = data["tokens"].tolist()
@@ -5276,7 +5285,7 @@ class Engine:
             # shape-mismatch ValueError here, and must degrade to
             # no-reuse, not fail the engine loop / kill a follower
             kfull = np.zeros((L, C, KV, hd), np.float16)
-            vfull = np.zeros((L, C, KV, hd), np.float16)
+            vfull = np.zeros((Lv, C, KV, hd), np.float16)
             kfull[:, :m] = data["k"][:, :m]
             vfull[:, :m] = data["v"][:, :m]
         except Exception:

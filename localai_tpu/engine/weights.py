@@ -78,7 +78,9 @@ _QUANT_NAMES = {"embed", "lm_head", "wq", "wk", "wv", "wo",
                 # models/ling_hybrid.py's KDA and MLA projections and its
                 # shared expert
                 "kda_qkv", "kda_f", "kda_g", "kda_o", "mla_q", "mla_kva",
-                "mla_kvb", "mla_o", "sh_w1", "sh_w3", "sh_w2"}
+                "mla_kvb", "mla_o", "sh_w1", "sh_w3", "sh_w2",
+                # models/xing4.py's query pair
+                "mla_qa", "mla_qb"}
 
 
 def _make_put(cfg, mesh, dtype, quantize, adapter=None, pace=None,
@@ -939,3 +941,132 @@ def save_llama_params(params: dict, cfg, model_dir: str):
         out[p + "mlp.up_proj.weight"] = np32(ly["w_up"][i]).T
         out[p + "mlp.down_proj.weight"] = np32(ly["w_down"][i]).T
     save_file(out, os.path.join(model_dir, "model.safetensors"))
+
+
+def xing4_mtp_tensors(names, cfg) -> list:
+    """The checkpoint's tensors that belong to the multi-token prediction
+    module: DeepSeek-V3's layout puts it after the last layer, as
+    ``model.layers.<num_hidden_layers>.*`` and beyond. The next-token model
+    reads none of them."""
+    out = []
+    for name in names:
+        parts = name.split(".")
+        if parts[:2] == ["model", "layers"] and parts[2].isdigit() \
+                and int(parts[2]) >= cfg.num_layers:
+            out.append(name)
+    return out
+
+
+def _xing4_leaf_source(model_dir: str, cfg):
+    """(spec_path, host array) for models/xing4.py's stacked layout, all
+    but the expert stacks: every leaf stacked over the layers that hold one
+    (every layer, dense layers, expert layers). Linear weights become
+    ``[in, out]``; a layer's two hyper-connections (mixer, feed-forward)
+    stack as ``[2, ...]``; the router, its bias and the hyper-connections'
+    scales and biases stay float32."""
+    tensors = _open_shards(model_dir)
+    nd = cfg.num_dense_layers
+
+    def top(name: str) -> np.ndarray:
+        return tensors[name].get_tensor(name)
+
+    def stacked(which, one) -> np.ndarray:
+        return np.stack([one(i) for i in which])
+
+    def get(name: str, transpose=False, dtype=None):
+        def one(i):
+            a = top(f"model.layers.{i}.{name}")
+            a = a.T if transpose else a
+            return a.astype(dtype) if dtype else a
+        return one
+
+    def pair(name, transpose=False, dtype=None):
+        """The mixer's and the feed-forward's hyper-connection of a layer."""
+        return lambda i: np.stack([get(f"{sub}_hc.{name}", transpose,
+                                       dtype)(i) for sub in ("attn", "mlp")])
+
+    every = range(cfg.num_layers)
+    dense, routed = range(nd), range(nd, cfg.num_layers)
+    f32 = np.float32
+    yield ("embed",), top("model.embed_tokens.weight")
+    yield ("layers", "mix_norm"), stacked(every, get("input_layernorm.weight"))
+    yield ("layers", "ff_norm"), stacked(
+        every, get("post_attention_layernorm.weight"))
+    a = "self_attn."
+    for leaf, name, t in (("mla_qa", "q_a_proj.weight", True),
+                          ("mla_q_norm", "q_a_layernorm.weight", False),
+                          ("mla_qb", "q_b_proj.weight", True),
+                          ("mla_kva", "kv_a_proj_with_mqa.weight", True),
+                          ("mla_kv_norm", "kv_a_layernorm.weight", False),
+                          ("mla_kvb", "kv_b_proj.weight", True),
+                          ("mla_o", "o_proj.weight", True)):
+        yield ("layers", leaf), stacked(every, get(a + name, t))
+    if cfg.hc_mult > 1:
+        yield ("layers", "hc_w"), stacked(every, pair("weight", True))
+        yield ("layers", "hc_s"), stacked(every, pair("scale", False, f32))
+        yield ("layers", "hc_b"), stacked(every, pair("bias", False, f32))
+        yield ("hc_head_w",), top("model.hc_head.weight").T
+        yield ("hc_head_s",), top("model.hc_head.scale").astype(f32)
+        yield ("hc_head_b",), top("model.hc_head.bias").astype(f32)
+    for leaf, name in (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                       ("w_down", "down_proj")) if nd else ():
+        yield ("layers", leaf), stacked(dense, get(f"mlp.{name}.weight", True))
+    if routed:
+        yield ("layers", "router"), stacked(
+            routed, get("mlp.gate.weight", True, f32))
+        yield ("layers", "expert_bias"), stacked(
+            routed, get("mlp.gate.e_score_correction_bias", False, f32))
+    for leaf, name in (("sh_w1", "gate_proj"), ("sh_w3", "up_proj"),
+                       ("sh_w2", "down_proj")) if routed else ():
+        yield ("layers", leaf), stacked(
+            routed, get(f"mlp.shared_experts.{name}.weight", True))
+    yield ("final_norm",), top("model.norm.weight")
+    if not cfg.tie_word_embeddings:
+        yield ("lm_head",), top("lm_head.weight").T
+
+
+def load_xing4_params(model_dir: str, cfg, dtype=jnp.bfloat16,
+                      quantize: str = "", tracer=None) -> dict:
+    """Load a ``xing4_0`` checkpoint (HF safetensors): every leaf but the
+    expert stacks through the cast / placement path of
+    ``load_llama_params``, the three expert stacks a layer at a time, all
+    ``cfg.num_experts`` held (``_expert_stack``, as lfm2_moe's). No mesh:
+    the family refuses one. The multi-token prediction module's tensors
+    are skipped by name and counted (``xing4_mtp_tensors``).
+    ``quantize="int8"`` takes the leaves ops/quant.py's quantizer takes (the
+    mixers' projections, the dense and shared feed-forwards, the embedding
+    and the head); the expert stacks and the hyper-connections stay in
+    ``dtype``."""
+    if quantize not in ("", "int8"):
+        raise ValueError(f"quantization={quantize!r} is not supported for "
+                         "xing4_0 (only weight-only int8)")
+    if quantize:
+        log.warning("xing4_0: quantization=int8 leaves the expert stacks "
+                    "in %s (quantized expert leaves are not built)",
+                    jnp.dtype(dtype).name)
+    span = (tracer or NO_TRACER).span
+    cast = _make_put(cfg, None, dtype, quantize, tracer=tracer)
+    keep_f32 = ("router", "expert_bias", "hc_s", "hc_b", "hc_head_s",
+                "hc_head_b")
+
+    def put(arr, spec_path):
+        if spec_path[-1] in keep_f32:
+            return jnp.asarray(arr, jnp.float32)
+        return cast(arr, spec_path)
+
+    tensors = _open_shards(model_dir)
+    skipped = xing4_mtp_tensors(tensors, cfg)
+    if skipped:
+        log.info("xing4_0: skipped %d tensors of the multi-token prediction "
+                 "module (model.layers.%d.* and beyond): the next-token "
+                 "model reads none", len(skipped), cfg.num_layers)
+    params = _assemble(_xing4_leaf_source(model_dir, cfg), put, tracer)
+    nd = cfg.num_dense_layers
+    if cfg.moe_layers:
+        for leaf, name in (("w1", "gate_proj"), ("w3", "up_proj"),
+                           ("w2", "down_proj")):
+            params["layers"][leaf] = _expert_stack(
+                tensors, lambda mi, e, name=name: (
+                    f"model.layers.{nd + mi}.mlp.experts.{e}.{name}.weight"),
+                cfg.moe_layers, range(cfg.num_experts), leaf, dtype, span)
+    return params

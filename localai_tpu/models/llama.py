@@ -45,8 +45,8 @@ from localai_tpu.ops.attention import (
 
 # what the engine may do with this family (engine.py, Engine.__init__)
 CAPABILITIES = frozenset({"paged", "packed_prefill", "prefix_reuse",
-                          "speculation", "self_extend", "multimodal",
-                          "mesh"})
+                          "kv_offload", "speculation", "self_extend",
+                          "multimodal", "mesh"})
 
 
 def _decode_attn_mode() -> str:

@@ -450,12 +450,15 @@ def slot_rows(cache: Cache, slot) -> Cache:
     if is_paged(cache):
         pg = cache["pages"].shape[-3]
         idx = _row_index(cache["ptab"][slot], pg)               # [C]
+        # the pool's rows counted out, not inferred: a plane of no layers
+        # (ops/mla.py's cache_v) has no size to infer them from
+        L, n_rows = cache["pages"].shape[0], cache["pages"].shape[1] * pg
         flat = cache["pages"].reshape(
-            (cache["pages"].shape[0], -1) + cache["pages"].shape[-2:])
+            (L, n_rows) + cache["pages"].shape[-2:])
         rows = jnp.take(flat, idx, axis=1, mode="fill", fill_value=0)
         if "scales" in cache:
             sflat = cache["scales"].reshape(
-                cache["scales"].shape[0], -1, cache["scales"].shape[-1])
+                L, n_rows, cache["scales"].shape[-1])
             return {"q": rows,
                     "s": jnp.take(sflat, idx, axis=1, mode="fill",
                                   fill_value=0)}

@@ -42,9 +42,11 @@ import jax
 import jax.numpy as jnp
 
 from localai_tpu.ops import kvcache
+from localai_tpu.ops.norms import rms_norm
 
 _NEG_INF = -1e30
 _BLOCK = 512            # committed rows expanded at a time (prefill)
+WALK_SCOPE = "mla_walk"  # prefill_attention's walk over committed rows
 
 
 def pool_width(kv_lora_rank: int, rope_dim: int) -> int:
@@ -52,13 +54,45 @@ def pool_width(kv_lora_rank: int, rope_dim: int) -> int:
     return -(-(kv_lora_rank + rope_dim) // 128) * 128
 
 
-def rope_terms(positions, dim: int, theta: float):
+def rope_terms(positions, dim: int, theta: float, inv_freq=None,
+               mscale: float = 1.0):
     """positions [...] -> (sin, cos) [..., dim], the half-split convention
-    of ops/rope.py."""
-    inv = 1.0 / theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    of ops/rope.py. ``inv_freq`` [dim / 2]: scaled frequencies in place of
+    ``theta``'s own (ops/rope.py::yarn_inv_freq), ``mscale`` their
+    magnitude (YaRN's ``mscale / mscale_all_dim``)."""
+    inv = 1.0 / theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim) \
+        if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
     ang = positions.astype(jnp.float32)[..., None] * inv
     ang = jnp.concatenate([ang, ang], axis=-1)
+    if mscale != 1.0:
+        return jnp.sin(ang) * mscale, jnp.cos(ang) * mscale
     return jnp.sin(ang), jnp.cos(ang)
+
+
+def lora_query(h, w_qa, q_norm, w_qb, eps: float):
+    """The query through its own low-rank pair: ``W_qb RMSNorm(W_qa h)``.
+    h [N, D]; w_qa [D, r], q_norm [r], w_qb [r, H dq] -> [N, H dq]."""
+    return rms_norm(h @ w_qa, q_norm, eps) @ w_qb
+
+
+def split_kvb(kvb, rank: int, heads: int, nope: int):
+    """``W_kvb`` [R, H (nope + v)] -> its key part [R, H, nope] and its
+    value part [R, H, v]."""
+    kvb = kvb.reshape(rank, heads, -1)
+    return kvb[..., :nope], kvb[..., nope:]
+
+
+def expand_rows(lat, w_k, w_v, rope_dim: int):
+    """Latent rows [n, Wd] (``[c | r | 0]``) -> the per-head keys
+    ``[k_nope | r]`` [n, H, nope + rope] and values [n, H, v] they stand
+    for: the materialised form's ``expand``."""
+    R, H = w_k.shape[:2]
+    lat = lat.astype(w_k.dtype)      # (a pool held lower: a control)
+    c, r = lat[:, :R], lat[:, R:R + rope_dim]
+    k_nope = jnp.einsum("nr,rhd->nhd", c, w_k)
+    r = jnp.broadcast_to(r[:, None], (lat.shape[0], H, rope_dim))
+    return (jnp.concatenate([k_nope, r.astype(k_nope.dtype)], -1),
+            jnp.einsum("nr,rhd->nhd", c, w_v))
 
 
 def latent_rows(c, r, width: int):
@@ -178,8 +212,12 @@ def prefill_attention(q, k, v, seg_of, seg_slots, seg_start, ck, li, expand,
     B = seg_slots.shape[0]
     init = (jnp.full((H, N), _NEG_INF, f32), jnp.zeros((H, N), f32),
             jnp.zeros((H, N, dv), f32))
-    (m_c, l_c, a_c), _ = jax.lax.scan(
-        segment, init, (jnp.arange(B, dtype=jnp.int32), seg_slots, seg_start))
+    # the walk over committed rows under a name of its own: a device trace
+    # can tell it from the pack's own products
+    with jax.named_scope(WALK_SCOPE):
+        (m_c, l_c, a_c), _ = jax.lax.scan(
+            segment, init,
+            (jnp.arange(B, dtype=jnp.int32), seg_slots, seg_start))
     # the joint softmax over [committed rows, pack]: every token sees at
     # least itself in the pack, so the total is finite
     m_tot = jnp.maximum(m_c, jnp.max(sc_pack, axis=-1))

@@ -51,7 +51,8 @@ slots x 32 heads x 128 x 128 float32; MLA_CELL: 96 slots x 64 pages of 64
 rows x 640 bfloat16, 32 query heads): ``kda_decode`` as the Mamba-2 kernel
 does (NaN wherever no live slot holds, against ops/kda.py::kda_decode), and
 ``mla_paged_decode`` with the mixed lengths over NaN pages, against
-ops/mla.py::decode_attention's page gather. ``ling`` runs those alone;
+ops/mla.py::decode_attention's page gather, and again at MLA_LONG (256
+pages a slot: ``xing4``'s context of 16384). ``ling`` runs those alone;
 ``sweep kda`` times the state kernel against live slots
 (kda_decode_sweep).
 """
@@ -115,6 +116,10 @@ SSM_LIVE = (0, 8, 30, 48)
 KDA_CELL = (96, 32, 128, 128)
 KDA_LIVE = (0, 8, 64, 96)
 MLA_CELL = (96, 64, 32, 640, 512)
+# xing4-29b-a4b.docqa_long's: a context of 16384 is 256 pages a slot (half
+# the cell's 32 slots: the walk is a slot's own, and the pool is laid out on
+# the host first)
+MLA_LONG = (16, 256, 32, 640, 512)
 
 
 def _lengths(page: int, slots: int = S, mp: int = MP):
@@ -523,7 +528,10 @@ def ling_errors(interpret: bool) -> dict:
                [(f"[live{n}]", (n,)) for n in KDA_LIVE])
            for part, err in zip(("", "_o"), check_kda_decode(*args))},
         "mla_paged_decode": check_mla_decode(
-            interpret, (6, 4, 4, 128, 64) if interpret else MLA_CELL)}
+            interpret, (6, 4, 4, 128, 64) if interpret else MLA_CELL),
+        **({} if interpret else {
+            "mla_paged_decode[256 pages]": check_mla_decode(
+                cell=MLA_LONG)})}
 
 
 def main(argv=None) -> int:

@@ -18,6 +18,32 @@ def _base_inv_freq(cfg) -> np.ndarray:
     return 1.0 / (cfg.rope_theta ** (np.arange(0, hd, 2, dtype=np.float64) / hd))
 
 
+def yarn_inv_freq(dim: int, theta: float, factor: float, orig: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0
+                  ) -> np.ndarray:
+    """YaRN's ``dim / 2`` inverse frequencies: interpolate the low-frequency
+    dimensions (divide by ``factor``), keep the high-frequency ones, a linear
+    ramp between the dimensions that make ``beta_fast`` and ``beta_slow``
+    rotations over the ``orig`` positions."""
+    inv_freq = 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim))
+
+    def correction_dim(num_rot):
+        return dim * np.log(orig / (num_rot * 2 * np.pi)) / (2 * np.log(theta))
+
+    low = np.floor(correction_dim(beta_fast))
+    high = np.ceil(correction_dim(beta_slow))
+    low, high = max(low, 0), min(high, dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / max(high - low, 1e-3), 0, 1)
+    mask = 1 - ramp
+    return inv_freq / factor * (1 - mask) + inv_freq * mask
+
+
+def yarn_mscale(factor: float, a: float = 1.0) -> float:
+    """YaRN's attention temperature ``0.1 a ln(factor) + 1`` (1 where
+    nothing is scaled)."""
+    return 1.0 if factor <= 1.0 else 0.1 * a * float(np.log(factor)) + 1.0
+
+
 def _scaled_inv_freq(cfg) -> np.ndarray:
     """Static (trace-time) inverse frequencies with scaling applied."""
     inv_freq = _base_inv_freq(cfg)
@@ -40,21 +66,9 @@ def _scaled_inv_freq(cfg) -> np.ndarray:
         out = np.where(wavelen < high_wl, inv_freq, np.where(wavelen > low_wl, scaled, mid))
         return out
     if t == "yarn":
-        # YaRN: interpolate low-freq dims, keep high-freq dims (beta ramp).
-        hd = cfg.head_dim_
-        factor = cfg.rope_scaling_factor
-        beta_fast, beta_slow = 32.0, 1.0
-        orig = cfg.rope_original_max_position
-
-        def correction_dim(num_rot):
-            return hd * np.log(orig / (num_rot * 2 * np.pi)) / (2 * np.log(cfg.rope_theta))
-
-        low = np.floor(correction_dim(beta_fast))
-        high = np.ceil(correction_dim(beta_slow))
-        low, high = max(low, 0), min(high, hd - 1)
-        ramp = np.clip((np.arange(hd // 2, dtype=np.float64) - low) / max(high - low, 1e-3), 0, 1)
-        mask = 1 - ramp
-        return inv_freq / factor * (1 - mask) + inv_freq * mask
+        return yarn_inv_freq(cfg.head_dim_, cfg.rope_theta,
+                             cfg.rope_scaling_factor,
+                             cfg.rope_original_max_position)
     raise ValueError(f"unknown rope scaling type: {t}")
 
 

@@ -149,8 +149,8 @@ def _collect(out):
 
 def test_engine_gates_follow_what_the_family_declares(byte_tokenizer):
     assert llama.CAPABILITIES == {"paged", "packed_prefill", "prefix_reuse",
-                                  "speculation", "self_extend", "multimodal",
-                                  "mesh"}
+                                  "kv_offload", "speculation", "self_extend",
+                                  "multimodal", "mesh"}
     assert mamba.CAPABILITIES == rwkv.CAPABILITIES == {"mesh"}
     assert oh.CAPABILITIES == {"paged", "packed_prefill"}
     e = _engine(byte_tokenizer, num_slots=2)

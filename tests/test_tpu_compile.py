@@ -930,3 +930,153 @@ def test_ling_packed_prefill_compiles(topo, N):
         *_seg_tables(A, S_K), ck, cv).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
+
+
+# ---------- xing4: MLA on every layer, hyper-connections, a latent pool alone ----------
+
+S_X, C_X, POOL_X = 32, 16384, 6144    # xing4-29b-a4b.docqa_long's geometry
+
+
+def _abstract_xing4(A):
+    """(cfg, params, ck, cv): the first six layers of Xing4.0-29B-A4B at its
+    published widths, all 64 experts and the whole vocabulary, bf16 weights,
+    the cell's slots and its default pool of three quarters, as
+    ShapeDtypeStructs."""
+    from localai_tpu.models import xing4
+
+    cfg = xing4.Xing4Config(
+        num_layers=6, rope_scaling_factor=64.0, rope_mscale=1.0,
+        rope_mscale_all_dim=1.0, attn=llama.AttnTarget(pallas=True))
+
+    def place(tree):
+        return jax.tree.map(lambda x: A(x.shape, x.dtype), tree)
+
+    params = jax.eval_shape(
+        lambda: xing4.init_params(cfg, jax.random.PRNGKey(0)))
+    ck, cv = jax.eval_shape(lambda: xing4.init_cache(
+        cfg, S_X, C_X, jnp.bfloat16, page_size=PAGE, num_pages=POOL_X))
+    return cfg, place(params), place(ck), place(cv)
+
+
+_NO_OP = {"parameter", "get-tuple-element", "tuple", "constant", "bitcast",
+          "after-all"}
+
+
+def _executed_ops(text: str, scope: str):
+    """(operations one run of a compiled program executes, those whose
+    ``op_name`` lies under ``scope``): the entry computation's instructions,
+    a fusion as one, a loop's body times its trip count."""
+    import re
+
+    comps, cur, entry = {}, None, None
+    for line in text.splitlines():
+        m = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if m:
+            cur = m.group(2)
+            comps[cur] = []
+            entry = cur if m.group(1) else entry
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and " = " in line:
+            comps[cur].append(line)
+
+    def trips(line):
+        m = re.search(r'known_trip_count":\{"n":"(\d+)"', line)
+        if m:
+            return int(m.group(1))
+        cond = re.search(r"condition=%?([\w.\-]+)", line).group(1)
+        for c in comps[cond]:
+            m = re.search(r"constant\((\d+)\)", c)
+            if m:
+                return int(m.group(1))
+        return 1
+
+    def count(name):
+        n = under = 0
+        for line in comps[name]:
+            op = re.search(r"= .*? ([\w\-]+)\(", line)
+            if not op or op.group(1) in _NO_OP:
+                continue
+            if op.group(1) == "while":
+                a, b = count(re.search(r"body=%?([\w.\-]+)",
+                                       line).group(1))
+                n, under = n + trips(line) * a, under + trips(line) * b
+            else:
+                n, under = n + 1, under + (scope in line)
+        return n, under
+
+    return count(entry)
+
+
+def test_mla_decode_kernel_compiles_at_256_pages_a_slot(topo):
+    """``mla_paged_decode`` with a page table of 256 entries a slot (32 KB
+    of scalar prefetch) over the six-layer pool, which goes to the kernel as
+    it lies."""
+    from localai_tpu.ops.pallas.mla_decode import mla_paged_decode
+
+    A = _on(SingleDeviceSharding(topo.devices[0]))
+    f32, bf, i32 = jnp.float32, jnp.bfloat16, jnp.int32
+    mla = jax.jit(lambda *a: mla_paged_decode(*a, rank=512)).lower(
+        A((S_X, 32, 640), f32), A((S_X, 1, 640), bf),
+        A((6, POOL_X, PAGE, 1, 640), bf), A((S_X, C_X // PAGE), i32),
+        A((S_X,), i32), A((), i32)).compile()
+    assert "mla_paged_decode" in mla.as_text()
+    assert mla.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+def test_xing4_decode_step_compiles_in_place(topo):
+    """engine_decode at the cell's size with donated caches, routing
+    counters and all: 8.35 GB of weights and 3.02 GB of pool as arguments,
+    and temporaries far under a layer's experts (0.47 GB a projection) or a
+    layer of the pool (0.5 GB)."""
+    from localai_tpu.models import xing4
+
+    A = _on(SingleDeviceSharding(topo.devices[0]))
+    cfg, params, ck, cv = _abstract_xing4(A)
+    assert params["layers"]["w1"].shape == (4, 64, 3584, 1024)
+    assert params["layers"]["hc_w"].shape == (6, 2, 4 * 3584, 24)
+    assert params["layers"]["mla_qb"].shape == (6, 768, 32 * 192)
+    assert ck["pages"].shape == (6, POOL_X, PAGE, 1, 640)
+    assert cv["pages"].shape == (0, POOL_X, PAGE, 1, 640)
+    assert set(ck) == {"pages", "ptab"}
+    assert xing4.decode_attn_impl(cfg, ck) == "pallas:mla_paged_decode"
+
+    def decode(p, t, ln, act, ck, cv):
+        return xing4.engine_decode(p, cfg, t, ln, act, ck, cv,
+                                   route_stats=True)
+
+    compiled = jax.jit(decode, donate_argnums=(4, 5)).lower(
+        params, A((S_X,), jnp.int32), A((S_X,), jnp.int32),
+        A((S_X,), jnp.bool_), ck, cv).compile()
+    mem = compiled.memory_analysis()
+    assert 11.2e9 < mem.argument_size_in_bytes < 11.6e9
+    assert mem.temp_size_in_bytes < 256 << 20
+    assert "mla_paged_decode" in compiled.as_text()
+    # the hyper-connections' operations a step (ops/hyper.py's module doc:
+    # 1237 of 2394 with the Sinkhorn rounds over one array, 685 of 1870 with
+    # the entries as separate vectors)
+    total, hc = _executed_ops(compiled.as_text(), "layer/hc")
+    assert 200 < hc <= 700 and total <= 1900, (total, hc)
+
+
+@pytest.mark.parametrize("N", [512, 1024])
+def test_xing4_packed_prefill_compiles(topo, N):
+    """A continued pack: the materialised MLA form walking up to 256 pages
+    of a slot's committed latent rows in blocks of 512, the four streams
+    through twelve hyper-connections, the grouped expert products over all
+    64 experts: arguments and temporaries inside the chip's 16 GB."""
+    from localai_tpu.models import xing4
+
+    A = _on(SingleDeviceSharding(topo.devices[0]))
+    cfg, params, ck, cv = _abstract_xing4(A)
+    i32 = jnp.int32
+
+    def pack(p, t, pos, so, ss, st, off, ln, ck, cv):
+        return xing4.ragged_prefill(p, cfg, t, pos, so, ss, st, off, ln, ck,
+                                    cv, continued=True, route_stats=True)
+
+    compiled = jax.jit(pack, donate_argnums=(8, 9)).lower(
+        params, A((N,), i32), A((N,), i32), A((N,), i32),
+        *_seg_tables(A, S_X), ck, cv).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
