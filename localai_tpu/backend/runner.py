@@ -17,6 +17,7 @@ import logging
 import os
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
 import grpc
@@ -25,6 +26,7 @@ import numpy as np
 from localai_tpu.backend import contract_pb2 as pb
 from localai_tpu.backend.service import (BackendServicer, RpcPool,
                                          make_server, parse_options)
+from localai_tpu.engine.gguf import find_gguf
 from localai_tpu.services import sysobs
 
 log = logging.getLogger("localai_tpu.backend.runner")
@@ -105,6 +107,25 @@ def _sampling_from_predict(opts: pb.PredictOptions):
     )
 
 
+def _read_tokenizer(tracer, gguf_path: Optional[str], tok_dir: str):
+    """The tokenizer stage of a load (span ``load_tokenizer``): a GGUF
+    file's own vocabulary, or the tokenizer files of ``tok_dir``. It needs
+    nothing the weights produce and they need nothing of it, so ``_load``
+    runs it on a thread of its own beside them and takes the future's
+    result where the engine is first handed the tokenizer: the stage sets
+    nothing on the servicer (a load that fails before the join leaves no
+    half-set tokenizer), and its own failure is raised at the join as the
+    exception it was."""
+    with tracer.span("load_tokenizer", "load"):
+        if gguf_path is not None:
+            from localai_tpu.engine import gguf_tokenizer
+
+            return gguf_tokenizer.from_gguf(gguf_path)
+        from transformers import AutoTokenizer
+
+        return AutoTokenizer.from_pretrained(tok_dir)
+
+
 class EngineServicer(BackendServicer):
     """LLM serving: LoadModel/Predict/PredictStream/Embedding/Tokenize/
     Status/GetMetrics on top of the continuous-batching engine."""
@@ -179,6 +200,19 @@ class EngineServicer(BackendServicer):
                 return pb.Result(success=False, message=f"{type(e).__name__}: {e}")
 
     def _load(self, request: pb.ModelOptions):
+        model_dir = request.model
+        if request.model_path and not os.path.isabs(model_dir):
+            model_dir = os.path.join(request.model_path, model_dir)
+        gguf_path = find_gguf(model_dir)
+        # the tokenizer loads beside everything up to the engine's
+        # construction (a GGUF file's own vocabulary unless the request
+        # names a tokenizer directory)
+        pool = ThreadPoolExecutor(1, "load-tokenizer")
+        tokenizer = pool.submit(
+            _read_tokenizer, self.tracer,
+            None if request.tokenizer else gguf_path,
+            request.tokenizer or model_dir)
+        pool.shutdown(wait=False)   # the thread ends with its one task
         with self.tracer.span("load_imports", "load"):
             # a process's first load pays for importing jax and the
             # engine, and for jax reaching the chip
@@ -204,13 +238,9 @@ class EngineServicer(BackendServicer):
             enabled=str(extra.get("trace", "")).strip().lower()
             not in ("0", "false", "off", "no"))
         span = self.tracer.span
-        model_dir = request.model
-        if request.model_path and not os.path.isabs(model_dir):
-            model_dir = os.path.join(request.model_path, model_dir)
         dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}.get(
             request.dtype or "bfloat16", jnp.bfloat16
         )
-        gguf_path = weights.find_gguf(model_dir)
         family = None
         if gguf_path is not None:
             # GGUF checkpoint (ollama://, oci:// or gallery pull): config
@@ -382,17 +412,6 @@ class EngineServicer(BackendServicer):
             # the one wait of the load: what the per-leaf host calls left
             # in flight (copies to the device, the cast where it runs there)
             jax.block_until_ready(params)
-
-        with span("load_tokenizer", "load"):
-            if gguf_path is not None and not request.tokenizer:
-                from localai_tpu.engine import gguf_tokenizer
-
-                self.tokenizer = gguf_tokenizer.from_gguf(gguf_path)
-            else:
-                from transformers import AutoTokenizer
-
-                tok_dir = request.tokenizer or model_dir
-                self.tokenizer = AutoTokenizer.from_pretrained(tok_dir)
 
         ecfg = eng.EngineConfig(
             num_slots=request.num_slots or 8,
@@ -634,7 +653,7 @@ class EngineServicer(BackendServicer):
             ddir = request.draft_model
             if request.model_path and not os.path.isabs(ddir):
                 ddir = os.path.join(request.model_path, ddir)
-            dgguf = weights.find_gguf(ddir)
+            dgguf = find_gguf(ddir)
             if dgguf is not None:
                 from localai_tpu.engine import gguf as gguflib
 
@@ -660,6 +679,10 @@ class EngineServicer(BackendServicer):
         if self.rpc_pool is not None \
                 and ecfg.num_slots * n_engines > RPC_WORKERS:
             self.rpc_pool.grow(RPC_WORKERS_MANY_SLOTS)
+        with span("load_tokenizer_join", "load"):
+            # what the load still waits for the tokenizer's thread: near
+            # 0 when the stage hid behind the weights whole
+            self.tokenizer = tokenizer.result()
         if n_engines > 1 or ecfg.autoscale:
             # autoscale=1 needs the pool even at engines=1: the pool IS
             # the actuator (resize), and its build-arg stash is what lets
@@ -1103,6 +1126,11 @@ def main(argv=None):
     parser.add_argument("--log-level", default="info")
     args = parser.parse_args(argv)
     logging.basicConfig(level=args.log_level.upper())
+    # this process tokenizes and never calls torch: transformers' own
+    # switch keeps its import from loading torch with it, which beside
+    # the weights outlasts them (the load then waits 13 s at
+    # load_tokenizer_join) and holds 1 GB; an operator's setting stands
+    os.environ.setdefault("USE_TORCH", "0")
     from localai_tpu.utils.jaxtools import enable_compilation_cache
 
     enable_compilation_cache()

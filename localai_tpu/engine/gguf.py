@@ -20,8 +20,10 @@ happens in weights.load_llama_params.
 from __future__ import annotations
 
 import functools
+import glob
+import os
 import struct
-from typing import Any, BinaryIO
+from typing import Any, BinaryIO, Optional
 
 import numpy as np
 
@@ -133,6 +135,20 @@ class GGUFFile:
         if dtype is not np.float32:
             flat = flat.astype(dtype)
         return flat.reshape(tuple(reversed(dims)))
+
+
+def find_gguf(model_dir: str) -> Optional[str]:
+    """Path to the GGUF file a model dir/path refers to, if any: either the
+    path itself or the single *.gguf inside a directory with no safetensors
+    (the shape an ``ollama://`` / gallery pull produces)."""
+    if model_dir.endswith(".gguf") and os.path.isfile(model_dir):
+        return model_dir
+    if os.path.isdir(model_dir):
+        ggufs = sorted(glob.glob(os.path.join(model_dir, "*.gguf")))
+        sts = glob.glob(os.path.join(model_dir, "*.safetensors"))
+        if len(ggufs) == 1 and not sts:
+            return ggufs[0]
+    return None
 
 
 @functools.lru_cache(maxsize=4)

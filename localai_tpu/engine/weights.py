@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from localai_tpu.engine.gguf import find_gguf
 from localai_tpu.services.tracing import NO_TRACER
 
 log = logging.getLogger("localai_tpu.weights")
@@ -51,20 +52,6 @@ def _open_shards(model_dir: str):
             for name in h.keys():
                 name_to_file[name] = h
     return name_to_file
-
-
-def find_gguf(model_dir: str) -> Optional[str]:
-    """Path to the GGUF file a model dir/path refers to, if any: either the
-    path itself or the single *.gguf inside a directory with no safetensors
-    (the shape an ``ollama://`` / gallery pull produces)."""
-    if model_dir.endswith(".gguf") and os.path.isfile(model_dir):
-        return model_dir
-    if os.path.isdir(model_dir):
-        ggufs = sorted(glob.glob(os.path.join(model_dir, "*.gguf")))
-        sts = glob.glob(os.path.join(model_dir, "*.safetensors"))
-        if len(ggufs) == 1 and not sts:
-            return ggufs[0]
-    return None
 
 
 _QUANT_NAMES = {"embed", "lm_head", "wq", "wk", "wv", "wo",

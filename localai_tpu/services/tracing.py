@@ -86,6 +86,10 @@ FINISH_DETECT_SPAN = "finish_detect"
 # strings and a small dict: about 0.3 KB.
 DEFAULT_RING_SIZE = 131072
 
+# a ``load`` span's memory reading and its place in the ring, as one step
+_LOAD_SPAN_LOCK = threading.Lock()
+
+
 class _NullSpan:
     """What ``span()`` returns with tracing off: one shared object, so the
     call costs a branch. Writes to its ``args`` go nowhere."""
@@ -140,8 +144,15 @@ class _Span:
             self._ann.__exit__(*exc)
         if self.track == "load":
             # the runner's resident memory as every phase and leaf of a
-            # load left it (rss_mb, rss_peak_mb)
-            sysobs.HOST.on_load_span(self.name, self.args)
+            # load left it (rss_mb, rss_peak_mb); read and entered under
+            # one lock, because load spans end on two threads (the
+            # tokenizer's beside the weights') and the ring's order is
+            # the order of their readings
+            with _LOAD_SPAN_LOCK:
+                sysobs.HOST.on_load_span(self.name, self.args)
+                self._tr.record(self.name, self.track, self.t0, t1,
+                                self.rid, self.args or None)
+            return False
         self._tr.record(self.name, self.track, self.t0, t1, self.rid,
                         self.args or None)
         return False

@@ -606,6 +606,10 @@ def loaded_servicer(tmp_path_factory):
     write_tiny_checkpoint(d)
     write_tiny_tokenizer(d)
     os.environ["LOCALAI_PRECOMPILE"] = "0"
+    # a process record of this load's own: an earlier LoadModel in the
+    # worker's process (another test file's) has marked the shared one warm
+    process = pytest.MonkeyPatch()
+    process.setattr(sysobs, "PROCESS", sysobs.ProcessCompiles())
     sv = runner.EngineServicer()
     ring = sv.tracer
     res = sv.LoadModel(pb.ModelOptions(
@@ -615,6 +619,7 @@ def loaded_servicer(tmp_path_factory):
     assert res.success, res.message
     yield sv, ring
     sv.engine.shutdown()
+    process.undo()
     os.environ.pop("LOCALAI_PRECOMPILE", None)
 
 
@@ -627,8 +632,11 @@ def test_load_spans_account_for_load_model(loaded_servicer):
     for name in ("load_source", "load_quantize", "load_cast",
                  "load_device_wait", "load_engine_init", "load_precompile"):
         assert by[name]["count"] >= 1, name
+    # (load_tokenizer runs on its own thread beside the others: what of
+    # it the load waited for is load_tokenizer_join)
     parts = sum(v["total_ms"] for k, v in by.items()
-                if k.startswith("load_") and k != "load_model")
+                if k.startswith("load_")
+                and k not in ("load_model", "load_tokenizer"))
     assert parts == pytest.approx(by["load_model"]["total_ms"], rel=0.05)
     spans = {s["name"]: s for s in ring.spans()}
     lm = spans["load_model"]
@@ -674,8 +682,8 @@ def test_every_load_span_carries_the_runners_resident_memory(loaded_servicer):
     loads = [s for s in ring.spans() if s["track"] == "load"]
     assert {s["name"] for s in loads} >= {
         "load_model", "load_imports", "load_tokenizer", "load_source",
-        "load_quantize", "load_cast", "load_device_wait", "load_engine_init",
-        "load_precompile"}
+        "load_quantize", "load_cast", "load_device_wait",
+        "load_tokenizer_join", "load_engine_init", "load_precompile"}
     for s in loads:
         assert 0 < s["args"]["rss_mb"] <= s["args"]["rss_peak_mb"], s
     # spans enter the ring as they end: the high-water mark never falls
@@ -699,6 +707,49 @@ def test_every_load_span_carries_the_runners_resident_memory(loaded_servicer):
     cp = sv.engine.state_snapshot()["compiles_process"]
     assert cp["warm"] is True and cp["unowned_after_warmup"] == 0
     assert cp["compiles_total"] >= cp["unowned_compiles"] >= 0
+
+
+def test_load_spans_of_two_threads_enter_the_ring_in_reading_order(
+        monkeypatch):
+    """A load span that ends on another thread (load_tokenizer) reads the
+    memory and enters the ring in one step: the ring's high-water marks
+    never fall, though one thread's reading is slow and the other's span
+    ends inside it."""
+    import threading
+
+    reading = threading.Event()
+    real = sysobs.host_memory
+    plan = {"slow-span": (100, 0.3),
+            threading.current_thread().name: (200, 0.0)}
+
+    def host_memory():
+        # (an engine of this module's fixture samples it too, on its own)
+        if threading.current_thread().name not in plan:
+            return real()
+        peak, wait = plan[threading.current_thread().name]
+        reading.set()
+        time.sleep(wait)
+        return {"rss_bytes": peak * 10**6, "rss_peak_bytes": peak * 10**6}
+
+    monkeypatch.setattr(sysobs, "host_memory", host_memory)
+    monkeypatch.setattr(sysobs, "HOST", sysobs.HostMemory())
+    ring = RingTracer(16)
+
+    def slow():
+        with ring.span("load_tokenizer", "load"):
+            pass
+
+    t = threading.Thread(target=slow, name="slow-span")
+    t.start()
+    assert reading.wait(10)
+    with ring.span("load_source", "load", leaf="w_down"):
+        pass
+    t.join(10)
+    assert not t.is_alive()
+    got = [(s["name"], s["args"]["rss_peak_mb"]) for s in ring.spans()]
+    assert got == [("load_tokenizer", 100.0), ("load_source", 200.0)]
+    assert sysobs.HOST.peak_in_load == {
+        "bytes": 200 * 10**6, "span": "load_source", "leaf": "w_down"}
 
 
 def test_status_reports_what_the_runner_holds_now(loaded_servicer):
